@@ -145,7 +145,7 @@ _COMPILED = tuple(
 
 #: blessed exactness helpers: jitted-function name -> declared max-abs
 #: result bound. The auditor assigns the declared bound (exact integer,
-#: quantity kind) at the pjit call boundary and records the assumption;
+#: quantity kind) at the jit call boundary and records the assumption;
 #: graft_lint GL013 blesses the same names at the source level.
 EXACT_FN_BOUNDS = {
     # base-2^18 limb recombination (parallel/kernels.py join_limbs):

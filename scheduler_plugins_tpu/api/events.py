@@ -15,7 +15,7 @@ never match a registration); with this table the spelling exists once.
 `KIND_<RESOURCE>_<ACTION>` constants are plain strings so every existing
 comparison, dict key and JSON serialization keeps working unchanged.
 
-This module is also the delta taxonomy the serving engine consumes
+This module is also the delta classification the serving engine consumes
 (`serving.deltas`): `NODE_COLUMN_EVENTS` names exactly the kinds that can
 change the resident node tensors, and `SERVE_REBASE_EVENTS` the kinds
 whose effects the O(changed) scatter programs cannot express (row-order

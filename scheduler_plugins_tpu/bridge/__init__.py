@@ -4,37 +4,56 @@ The event-ingestion/snapshot-lowering hot path of the host shell — the part
 the reference implements as Go informer caches and the north star recasts as
 a bridge feeding the TPU solver (SURVEY.md §2.9) — implemented in C++
 (`snapshot_store.cc`) and consumed here without per-object Python overhead.
-The shared library builds on first use with g++ (cached next to the source).
+The shared library builds on first use with g++, next to the source, under
+a name that carries a hash of the source (`build_native`).
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import os
 import subprocess
 from pathlib import Path
 
 import numpy as np
 
 _SRC = Path(__file__).with_name("snapshot_store.cc")
-_LIB = Path(__file__).with_name("libsnapshot_store.so")
 
 _I64 = ctypes.POINTER(ctypes.c_int64)
 _I32 = ctypes.POINTER(ctypes.c_int32)
 
 
-def _build() -> Path:
-    if _LIB.exists() and _LIB.stat().st_mtime >= _SRC.stat().st_mtime:
-        return _LIB
-    subprocess.run(
-        ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", str(_SRC), "-o", str(_LIB)],
-        check=True,
-        capture_output=True,
-    )
-    return _LIB
+def native_lib_path(src: Path) -> Path:
+    """`lib<stem>.<sha256 of the source, 12 hex>.so` next to `src`: the
+    name changes when the source does, so a library built from other
+    source — stale, or copied in from another tree — is never loaded."""
+    digest = hashlib.sha256(src.read_bytes()).hexdigest()[:12]
+    return src.with_name(f"lib{src.stem}.{digest}.so")
+
+
+def build_native(src: Path) -> Path:
+    """The shared library of `src`, compiled with g++ unless the file
+    named for this exact source is already there. Written under a
+    temporary name and renamed, so a concurrent loader never maps a
+    half-written file."""
+    lib = native_lib_path(src)
+    if not lib.exists():
+        tmp = lib.with_name(f"{lib.name}.tmp.{os.getpid()}")
+        try:
+            subprocess.run(
+                ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", str(src),
+                 "-o", str(tmp)],
+                check=True, capture_output=True,
+            )
+            os.replace(tmp, lib)
+        finally:
+            tmp.unlink(missing_ok=True)
+    return lib
 
 
 def _load():
-    lib = ctypes.CDLL(str(_build()))
+    lib = ctypes.CDLL(str(build_native(_SRC)))
     lib.store_new.restype = ctypes.c_void_p
     lib.store_new.argtypes = [ctypes.c_int]
     lib.store_free.argtypes = [ctypes.c_void_p]

@@ -10,16 +10,15 @@ tensors the TPU path consumes and returns (pods_per_sec, placed).
 from __future__ import annotations
 
 import ctypes
-import subprocess
 import time
 from pathlib import Path
 
 import numpy as np
 
 from scheduler_plugins_tpu.api.resources import CANONICAL
+from scheduler_plugins_tpu.bridge import build_native
 
 _SRC = Path(__file__).with_name("ref_baseline.cc")
-_LIB = Path(__file__).with_name("libref_baseline.so")
 
 _I64 = ctypes.POINTER(ctypes.c_int64)
 _I32 = ctypes.POINTER(ctypes.c_int32)
@@ -29,17 +28,6 @@ _F64 = ctypes.POINTER(ctypes.c_double)
 _PODS_I = CANONICAL.index("pods")
 
 
-def _build() -> Path:
-    if _LIB.exists() and _LIB.stat().st_mtime >= _SRC.stat().st_mtime:
-        return _LIB
-    subprocess.run(
-        ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", str(_SRC), "-o", str(_LIB)],
-        check=True,
-        capture_output=True,
-    )
-    return _LIB
-
-
 _lib = None
 
 
@@ -47,7 +35,7 @@ def _load():
     global _lib
     if _lib is not None:
         return _lib
-    lib = ctypes.CDLL(str(_build()))
+    lib = ctypes.CDLL(str(build_native(_SRC)))
     c64, c32 = ctypes.c_int64, ctypes.c_int32
     lib.ref_seq_alloc.restype = c64
     lib.ref_seq_alloc.argtypes = [c64] * 3 + [_I64] * 4 + [_I32]

@@ -40,7 +40,7 @@ TestLanedCycleEquivalence and bench config 15):
   cluster store and (when serving) the one DeltaSink: the fence's
   merged decisions flow through `_cycle_bind`'s store mutators, whose
   sink events land at the next ingest boundary exactly like any other
-  delta (the PR 6 taxonomy). With `async_bind` the flush runs on the
+  delta (the PR 6 classification). With `async_bind` the flush runs on the
   "spt-lane-flusher" worker behind the same join-first fence as the
   pipelined engine; a flush crossing an external drain boundary is
   counted late (`scheduler_cycle_late_binds_total`) and absorbed.
@@ -276,7 +276,7 @@ class LanedCycle:
             if sink is not None and sink.drains != drains_at_submit:
                 # crossed an external drain boundary: the binds reach
                 # the resident serving state as ordinary deltas of a
-                # later window (the PR 6 conflict-fence taxonomy)
+                # later window (the PR 6 conflict-fence classification)
                 obs.metrics.inc(obs.CYCLE_LATE_BINDS)
 
         if self._flusher is not None:

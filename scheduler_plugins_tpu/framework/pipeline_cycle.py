@@ -29,7 +29,7 @@ TestPipelinedCycleEquivalence):
   outside the tick loop, e.g. `flush()` racing an external drain) still
   reaches the resident serving state exactly: each store mutator pushes
   its DeltaSink event, and a late bind is an ordinary delta of the PR 6
-  taxonomy (`scheduler_cycle_late_binds_total` counts them).
+  classification (`scheduler_cycle_late_binds_total` counts them).
 - **Overlap window.** Only report-local work runs while cycle N's solve
   is in flight: cycle N-1's failure attribution (when its per-pod codes
   already rode the solve result), quality observation (on host copies
@@ -322,7 +322,7 @@ class PipelinedCycle:
         # drain, so a crossing is only observable when an EXTERNAL
         # drain (a direct `engine.refresh`, a shutdown-path flush)
         # overtakes an in-flight bind — exactly the case the
-        # binds-as-deltas taxonomy absorbs
+        # binds-as-deltas classification absorbs
         sink = (
             getattr(self.serve, "_sink", None)
             if self.serve is not None else None
@@ -340,7 +340,7 @@ class PipelinedCycle:
                 # this flush crossed a drain boundary: its store
                 # mutations reach the resident serving state as
                 # ordinary DeltaSink deltas of a LATER window (the
-                # conflict-fence taxonomy) — resident state stays
+                # conflict-fence classification) — resident state stays
                 # exact, the binds are just observed one window later
                 tl.late_bind = True
                 obs.metrics.inc(obs.CYCLE_LATE_BINDS)

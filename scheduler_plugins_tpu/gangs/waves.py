@@ -119,14 +119,13 @@ def wave_solve_fn(mesh=None):
     if mesh is None:
         fn = jax.jit(wave_solve_body)
     else:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         lanes = P(GANGS_AXIS)
         rep = P()
 
         def sharded(gangs, free, eq_used, node_mask, ids):
-            body = shard_map(
+            body = jax.shard_map(
                 wave_solve_body,
                 mesh=mesh,
                 in_specs=(
@@ -134,7 +133,7 @@ def wave_solve_fn(mesh=None):
                     lanes,
                 ),
                 out_specs=(lanes, lanes, lanes, lanes, lanes),
-                check_rep=False,
+                check_vma=False,
             )
             return body(gangs, free, eq_used, node_mask, ids)
 

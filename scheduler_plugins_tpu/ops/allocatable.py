@@ -44,7 +44,7 @@ def demote_scores_int32(raw):
     A named jit boundary ON PURPOSE (XLA inlines it — no runtime cost):
     the < 2^23 result bound is enforced by the DYNAMIC shift, which an
     interval lattice cannot see, so `tools/kernel_audit.py` KA003
-    blesses the pjit call by name via `api.bounds.EXACT_FN_BOUNDS`
+    blesses the jit call by name via `api.bounds.EXACT_FN_BOUNDS`
     (declared result bound 2^24) instead of flagging the demotion."""
     max_abs = jnp.max(jnp.abs(raw))
     bits = jnp.ceil(jnp.log2(max_abs.astype(jnp.float64) + 1.0))

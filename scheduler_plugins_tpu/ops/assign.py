@@ -876,9 +876,9 @@ def waterfill_targeted_sharded(rank_free, node_ids, req, pod_mask,
     admission sums — the kernels move exact-integer limbs); call sites
     whose padded payload would exceed the kernel VMEM envelope
     (`kernels.PALLAS_MAX_ELECTION_ELEMS` — the mega whole-queue wave)
-    statically keep the lax collectives. `pallas_interpret` selects the
-    CPU interpret twins (the CI/differential path) versus the compiled
-    on-chip kernels.
+    statically keep the lax collectives, and `stats["pallas_sites"]` says
+    which did. `pallas_interpret` selects the CPU interpret twins (the
+    CI/differential path) versus the compiled on-chip kernels.
     """
     P, R = req.shape
     BS = rank_free.shape[0]
@@ -906,6 +906,13 @@ def waterfill_targeted_sharded(rank_free, node_ids, req, pod_mask,
             and pk.fits_election_budget(1 + PAYLOAD_ROWS, W)
             and pk.fits_election_budget(R, W)
         )
+
+    def elect_min_rank(ranks):
+        """`lax.pmin` of candidate ranks, as int32: ranks are bounded by
+        the padded node count, and the TPU compiler lowers a 64-bit
+        all-reduce only for sums ("Supported lowering only of Sum all
+        reduce" on an s64 minimum)."""
+        return jax.lax.pmin(ranks.astype(jnp.int32), axis_name)
 
     def winner_payload(prop_rank, free_l):
         """(1 + 3R, W) int32 payload for the shard's own proposal
@@ -965,7 +972,7 @@ def waterfill_targeted_sharded(rank_free, node_ids, req, pod_mask,
                 axis=0,
             )  # (W,) global
         else:
-            pos = jnp.max(jax.lax.pmin(cand, axis_name), axis=1)  # (W,)
+            pos = jnp.max(elect_min_rank(cand), axis=1)  # (W,)
         ranks = jnp.minimum(
             pos[None, :] + jnp.arange(LITE_PROBES)[:, None], n_real - 1
         )  # (LP, W) — saturate at the worst REAL rank, never the padding
@@ -989,7 +996,7 @@ def waterfill_targeted_sharded(rank_free, node_ids, req, pod_mask,
                 valid & (fit_rank < N), fit_rank.astype(jnp.int32), -1
             )
             return choice, jnp.zeros(W, bool), unpack_payload(pay)
-        fit_rank = jax.lax.pmin(prop, axis_name)  # (W,)
+        fit_rank = elect_min_rank(prop)  # (W,)
         choice = jnp.where(
             valid & (fit_rank < N), fit_rank.astype(jnp.int32), -1
         )
@@ -1040,7 +1047,7 @@ def waterfill_targeted_sharded(rank_free, node_ids, req, pod_mask,
                 jnp.minimum(rank, n_real - 1).astype(jnp.int32), -1,
             )
             return choice, valid & (total == 0), unpack_payload(pay)
-        rank = jax.lax.pmin(cand, axis_name)  # (W,)
+        rank = elect_min_rank(cand)  # (W,)
         choice = jnp.where(
             valid & (total > 0),
             jnp.minimum(rank, n_real - 1).astype(jnp.int32), -1,
@@ -1198,6 +1205,12 @@ def waterfill_targeted_sharded(rank_free, node_ids, req, pod_mask,
     )
     if collect_stats:
         return assignment, free_l, {
-            "occupancy": occ, "waves": 1 + w_lite + w_full
+            "occupancy": occ, "waves": 1 + w_lite + w_full,
+            # which election sites (whole-queue lite, windowed lite,
+            # rescue) ride the Pallas ring kernels: a site that statically
+            # gave way to the lax collectives reads False here
+            "pallas_sites": jnp.array(
+                [pallas_wave(P), pallas_wave(Wl), pallas_wave(K)]
+            ),
         }
     return assignment, free_l

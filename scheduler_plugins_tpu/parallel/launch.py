@@ -135,9 +135,7 @@ def distributed_solve(snap, mesh, weights, max_waves: int = 8):
     # process holds the full (P,) result locally
     from jax.sharding import NamedSharding, PartitionSpec
 
-    from scheduler_plugins_tpu.parallel.mesh import ambient_mesh
-
-    with ambient_mesh(mesh):
+    with jax.set_mesh(mesh):
         assignment = jax.jit(
             lambda a: a, out_shardings=NamedSharding(mesh, PartitionSpec())
         )(assignment)

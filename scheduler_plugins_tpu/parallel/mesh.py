@@ -12,17 +12,6 @@ PODS_AXIS = "pods"
 NODES_AXIS = "nodes"
 
 
-def ambient_mesh(mesh: Mesh):
-    """Context manager installing `mesh` as the ambient mesh for jit's
-    sharding propagation: `jax.set_mesh` where it exists (newer jax), else
-    the classic `with mesh:` entry (jax <= 0.4.x, where `set_mesh` is not
-    yet public). Both leave NamedSharding-committed inputs untouched."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh  # Mesh is itself a context manager
-
-
 def make_mesh(
     n_devices: Optional[int] = None, devices: Optional[Sequence] = None
 ) -> Mesh:

@@ -519,7 +519,7 @@ def _wrap_donated(fn):
             )
             return fn(*args, **kwargs)
 
-    return wrapped
+    return obs.expose_stages(wrapped, fn)
 
 
 def _donation_safe_state(state0):
@@ -1095,10 +1095,10 @@ def score_drift_vs_sequential(scheduler, snap, seq_assignment,
 def sharded_batch_solve(snap, mesh, weights, max_waves: int = 8):
     """Jit `batch_solve` with the snapshot sharded over `mesh`; XLA inserts
     the cross-shard collectives."""
-    from scheduler_plugins_tpu.parallel.mesh import ambient_mesh, shard_snapshot
+    from scheduler_plugins_tpu.parallel.mesh import shard_snapshot
 
     snap = shard_snapshot(snap, mesh)
-    with ambient_mesh(mesh):
+    with jax.set_mesh(mesh):
         fn = obs.compile_watch(
             jax.jit(lambda s, w: batch_solve(s, w, max_waves)),
             program="sharded_batch_solve",
@@ -1121,10 +1121,10 @@ def sharded_profile_batch_solve(scheduler, snap, mesh, max_waves: int = 8):
     Placement semantics are those of `profile_batch_solve` (sharding never
     changes the math, only its partitioning); `tests/test_parallel.py`
     asserts sharded == unsharded placements on an 8-device CPU mesh."""
-    from scheduler_plugins_tpu.parallel.mesh import ambient_mesh, shard_snapshot
+    from scheduler_plugins_tpu.parallel.mesh import shard_snapshot
 
     snap = shard_snapshot(snap, mesh)
-    with ambient_mesh(mesh):
+    with jax.set_mesh(mesh):
         return profile_batch_solve(scheduler, snap, max_waves=max_waves)
 
 
@@ -1191,7 +1191,6 @@ def sharded_wave_chunk_solver(mesh, n_nodes: int, max_waves: int = 8,
     gated by tests/test_differential.py and `make pallas-smoke`."""
     from functools import partial
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from scheduler_plugins_tpu.ops.assign import waterfill_targeted_sharded
@@ -1216,12 +1215,15 @@ def sharded_wave_chunk_solver(mesh, n_nodes: int, max_waves: int = 8,
         collect_stats=collect_stats,
         use_pallas=use_pallas, pallas_interpret=pallas_interpret,
     )
-    stats_spec = ({"occupancy": P(), "waves": P()},) if collect_stats else ()
-    sharded_body = shard_map(
+    stats_spec = (
+        ({"occupancy": P(), "waves": P(), "pallas_sites": P()},)
+        if collect_stats else ()
+    )
+    sharded_body = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(NODES_AXIS, None), P(NODES_AXIS), P(), P()),
         out_specs=(P(), P(NODES_AXIS, None)) + stats_spec,
-        check_rep=False,  # ppermute ring + replicated outputs via psum
+        check_vma=False,  # ppermute ring + replicated outputs via psum
     )
 
     def sharded_wave_chunk(node_ids, req_chunk, mask_chunk, rank_free):
@@ -1267,7 +1269,7 @@ def sharded_wave_solve(snap, mesh, weights, chunk: int | None = None,
     solver cache key carries the mode and an env toggle never reuses a
     differently-built program."""
     from scheduler_plugins_tpu.parallel import kernels as pk
-    from scheduler_plugins_tpu.parallel.mesh import NODES_AXIS, ambient_mesh
+    from scheduler_plugins_tpu.parallel.mesh import NODES_AXIS
     from scheduler_plugins_tpu.utils import sanitize
 
     use_pallas = pk.pallas_enabled() and not sanitize.enabled()
@@ -1304,7 +1306,7 @@ def sharded_wave_solve(snap, mesh, weights, chunk: int | None = None,
         # runs only, the hot path never pays it)
         census = _WAVE_CENSUS_CACHE.get(key)
         if census is None:
-            with ambient_mesh(mesh):
+            with jax.set_mesh(mesh):
                 census = _WAVE_CENSUS_CACHE[key] = collective_census(
                     solve_chunk, node_ids, snap.pods.req[:chunk],
                     admitted[:chunk], rank_free,
@@ -1314,7 +1316,7 @@ def sharded_wave_solve(snap, mesh, weights, chunk: int | None = None,
             args={"shards": n_shards, **census},
         )
     parts, stats_parts = [], []
-    with ambient_mesh(mesh):
+    with jax.set_mesh(mesh):
         for i, lo in enumerate(range(0, P, chunk)):
             start_ns = obs.tracer.now_ns() if tracing else 0
             out, rank_free = solve_chunk(
@@ -1348,6 +1350,7 @@ def sharded_wave_solve(snap, mesh, weights, chunk: int | None = None,
         stats = {
             "occupancy": sum(jnp.asarray(s["occupancy"]) for s in stats_parts),
             "waves": sum(jnp.asarray(s["waves"]) for s in stats_parts),
+            "pallas_sites": stats_parts[-1]["pallas_sites"],
         }
         return assignment, admitted, wait, stats
     return assignment, admitted, wait
@@ -1368,7 +1371,7 @@ COLLECTIVE_PRIMS = frozenset({
 
 def collective_census(fn, *args):
     """{collective primitive: equation count} over the traced `fn(*args)`
-    jaxpr, recursing through every sub-jaxpr (pjit/shard_map/while/scan/
+    jaxpr, recursing through every sub-jaxpr (jit/shard_map/while/scan/
     cond — and `pallas_call` kernel bodies, whose `dma_start` equations
     are the ring's neighbor transfers). Because the wave loops are
     `lax.while_loop`s, each wave BODY appears exactly once in the jaxpr —

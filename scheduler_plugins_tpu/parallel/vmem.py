@@ -49,6 +49,8 @@ __all__ = [
     "PEAK_FLOPS_PER_S",
     "HBM_BYTES_PER_S",
     "ROOFLINE_TARGETS",
+    "DEVICE_KIND_TARGETS",
+    "target_for_device_kind",
     "ring_buffer_copies",
     "derive_max_election_elems",
     "max_election_elems",
@@ -64,9 +66,37 @@ VMEM_BUDGET_BYTES = {
     "tpu_v5p": 16 * 1024 * 1024,
 }
 
-#: the audited lowering target (SPT_VMEM_TARGET to re-derive for another
-#: generation — the committed manifest pins the target it was written for)
+#: the STATIC audit tools' lowering target (tools/kernel_audit.py,
+#: tools/cost_observatory.py: SPT_VMEM_TARGET re-derives their manifests
+#: for another generation — a manifest pins the target it was written
+#: for). The solver's election gate is derived for the same target and
+#: cross-checked by the kernel auditor; every budget row is 16 MiB today,
+#: so the gate holds on each. Peaks for a RUNNING device come from
+#: `target_for_device_kind`, never from this default.
 VMEM_TARGET = os.environ.get("SPT_VMEM_TARGET", "tpu_v4")
+
+#: `jax.devices()[0].device_kind` -> hardware row. A v5e reports
+#: "TPU v5 lite"; a v5p reports "TPU v5" or "TPU v5p" by libtpu version.
+DEVICE_KIND_TARGETS = {
+    "TPU v4": "tpu_v4",
+    "TPU v5 lite": "tpu_v5e",
+    "TPU v5": "tpu_v5p",
+    "TPU v5p": "tpu_v5p",
+}
+
+
+def target_for_device_kind(device_kind: str) -> str:
+    """The hardware row of the RUNNING device. A device that is not in
+    the table is an error, never a default: a roofline share against the
+    wrong chip's peaks is worse than none."""
+    try:
+        return DEVICE_KIND_TARGETS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no hardware row for device_kind {device_kind!r} "
+            f"(known: {sorted(DEVICE_KIND_TARGETS)}); add its VMEM budget "
+            "and peaks to parallel/vmem.py"
+        ) from None
 
 # ---------------------------------------------------------------------------
 # Roofline peaks (ISSUE 20): ONE module owns all hardware numbers — the VMEM

@@ -33,7 +33,7 @@ import numpy as np
 #: device solve dispatch (`resilience.watchdog.Resilience._device_call`):
 #: kinds "hang" (worker sleeps past the deadline), "device-error"
 #: (RuntimeError from the dispatch), "garbage" (solve output corrupted —
-#: out-of-range node indices, the shape a desynced tunnel produces)
+#: out-of-range node indices, the shape a desynced backend produces)
 SOLVE_DISPATCH = "solve.dispatch"
 #: delta-sink event push (`serving.deltas.DeltaSink._push`): kinds
 #: "drop", "dup", "corrupt" (assign flipped to unassign — a sign error
@@ -151,7 +151,7 @@ class FaultPlan:
     @classmethod
     def standard(cls, seed: int, cycles: int, hang_seconds: float = 3.0,
                  stall_seconds: float = 0.05) -> "FaultPlan":
-        """The full fault taxonomy spread deterministically over
+        """The full fault classification spread deterministically over
         `cycles` (docs/ROBUSTNESS.md): one of each kind, cycle slots
         drawn without replacement from a seeded stream so no two faults
         land on the same cycle (each fault's recovery window is measured

@@ -1,4 +1,4 @@
-"""Delta taxonomy + the jittable O(changed) scatter-apply program.
+"""Delta classification + the jittable O(changed) scatter-apply program.
 
 The reference's watch-driven design never rebuilds state: informer events
 mutate NodeInfo incrementally and each cycle reads the live cache. This
@@ -11,7 +11,7 @@ jitted scatter program whose resident carry is DONATED — the node tensors
 thread cycle to cycle in place, and the per-cycle work is O(changed), not
 O(cluster).
 
-Delta taxonomy (the `api.events` kinds each group expresses):
+Delta classification (the `api.events` kinds each group expresses):
 
 - `NodeUpserts` — Node/Add, Node/Update: row overwrites of the static node
   columns (alloc, capacity, mask, region, zone). Expressed as

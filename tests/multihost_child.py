@@ -45,8 +45,6 @@ def main(proc_id: int, port: str, out_path: str) -> None:
 
     import jax
 
-    jax.config.update("jax_platforms", "cpu")
-
     from scheduler_plugins_tpu.parallel import launch
 
     assert launch.initialize(f"127.0.0.1:{port}", 2, proc_id) is True

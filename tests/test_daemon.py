@@ -116,6 +116,11 @@ class TestDaemonE2E:
                 health = json.loads(urllib.request.urlopen(
                     health_url, timeout=5).read())
                 assert health["ok"] and health["bound_total"] >= 2
+                # the ready line and /healthz name the device the solves
+                # run on, as JAX reports it
+                assert status["device"]["platform"] == "cpu"
+                assert status["device"]["count"] >= 1
+                assert health["device"] == status["device"]
                 # /metrics speaks prometheus text format 0.0.4 with real
                 # histogram buckets and per-plugin attribution
                 resp = urllib.request.urlopen(
@@ -612,6 +617,7 @@ class TestThreadTopology:
         daemon = SimpleNamespace(
             cycles=0, bound_total=0, last_pending=0, last_quality=None,
             last_memory=None,
+            device={"platform": "cpu", "device_kind": "cpu", "count": 8},
             feed=SimpleNamespace(address=("127.0.0.1", 0)),
             resilience=None, parked_cycles=0, pipeline=None, laned=None,
             engine=None, tuner=None, elector=None,

@@ -142,13 +142,14 @@ class TestConfig:
         assert rules_for(FIXTURES / "bad_i64_matmul.py") == {"GL003"}
 
     def test_config_owners_sanction_gl007(self):
-        # conftest.py pins the test platform via jax.config.update and is a
-        # sanctioned owner; the same code outside the owner list fires
-        conftest = REPO / "tests" / "conftest.py"
-        assert "GL007" not in {f.rule for f in lint_paths([str(REPO / "tests")])}
+        # __graft_entry__.py pins its virtual CPU platform via
+        # jax.config.update and is a sanctioned owner; the same code
+        # outside the owner list fires
+        entry = REPO / "__graft_entry__.py"
+        assert "GL007" not in {f.rule for f in lint_paths([str(entry)])}
         from tools.graft_lint import lint_file
 
-        findings, _, _ = lint_file(conftest)  # direct call: NOT owned
+        findings, _, _ = lint_file(entry)  # direct call: NOT owned
         assert "GL007" in {f.rule for f in findings}
 
     def test_exact_cast_owners_sanction_gl013(self):
@@ -169,8 +170,7 @@ class TestConfig:
 
         cfg = load_config()
         assert "tests/fixtures/graft_lint" in cfg["exclude"]
-        assert any(o.startswith("tests/conftest") for o in
-                   cfg["config-update-owners"])
+        assert "__graft_entry__.py" in cfg["config-update-owners"]
 
     def test_load_config_tolerates_comment_lines_in_lists(self, monkeypatch,
                                                           tmp_path):

@@ -469,7 +469,7 @@ class TestLateLaneBinds:
     def test_late_flusher_bind_absorbed_as_delta(self):
         """A lane flush overtaken by an EXTERNAL sink drain is counted
         late and absorbed as an ordinary delta of the next window — the
-        resident serving state stays byte-exact (the PR 6 taxonomy,
+        resident serving state stays byte-exact (the PR 6 classification,
         shared with the pipelined engine's flusher)."""
         import threading
 

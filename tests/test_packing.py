@@ -22,8 +22,6 @@ Compile budget: every jit entry here runs at ONE shared problem shape
 only traced arguments.
 """
 
-import json
-
 import numpy as np
 import pytest
 
@@ -300,41 +298,6 @@ class TestPackingCycle:
             for i in range(len(pending)) if a[i] >= 0
         }
         assert report.bound == expected
-
-
-class TestBenchLineSchema:
-    """The bench error/stale-replay builders stay schema-complete for
-    every config — the ISSUE 14 bugfix gate, covering config 13."""
-
-    DIAGNOSIS = {"kind": "timeout", "detail": "probe exceeded 45s"}
-
-    def test_error_line_schema_complete_for_every_config(self):
-        assert 13 in bench.CONFIG_METRICS
-        assert 15 in bench.CONFIG_METRICS  # the K-lane config (ISSUE 17)
-        for config in bench.CONFIG_METRICS:
-            line = bench.error_line(config, "sequential", self.DIAGNOSIS)
-            missing = [k for k in bench.LINE_SCHEMA_KEYS if k not in line]
-            assert not missing, (config, missing)
-            assert line["quality"] is None
-            assert line["drift"] is None
-            assert line["backend_probe"] == self.DIAGNOSIS
-            assert line["metric"] == bench.CONFIG_METRICS[config]
-            json.dumps(line)  # must be JSON-serializable
-
-    def test_stale_replay_line_schema_complete(self):
-        # a minimal legacy capture: predates every attribution column
-        replay = {"metric": bench.CONFIG_METRICS[13], "value": 123.4,
-                  "unit": "pods/s (replayed)", "vs_baseline": 1.0,
-                  "ts": 1_700_000_000, "config": 13, "mode": "sequential"}
-        line = bench.stale_replay_line(replay, self.DIAGNOSIS)
-        missing = [k for k in bench.LINE_SCHEMA_KEYS if k not in line]
-        assert not missing, missing
-        assert line["stale_capture"] is True
-        assert line["backend_probe"] == self.DIAGNOSIS
-        assert "config" not in line and "mode" not in line
-        # the pallas block describes THIS run, never the capture's
-        assert isinstance(line["pallas"], dict)
-        json.dumps(line)
 
 
 class TestElasticTransitionRecording:

@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from scheduler_plugins_tpu.ops import assign
@@ -39,9 +38,9 @@ def node_mesh(n):
 
 def shard_run(fn, mesh, x, out_specs):
     """Run a per-shard fn over the flattened-leading-axis input."""
-    f = jax.jit(shard_map(
+    f = jax.jit(jax.shard_map(
         fn, mesh=mesh, in_specs=P(AXIS), out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     ))
     return f(x)
 

@@ -285,7 +285,7 @@ class TestConflictFence:
     def test_late_bind_is_an_ordinary_delta(self):
         """A bind landing AFTER a refresh's ingest boundary reaches the
         resident columns as an ordinary DeltaSink delta (the PR 6
-        taxonomy): the next refresh absorbs it and the anti-entropy
+        classification): the next refresh absorbs it and the anti-entropy
         digest stays clean."""
         c = small_cluster(n_nodes=4, n_bound=4)
         engine = StreamingServeEngine().attach(c)

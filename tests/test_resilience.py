@@ -261,7 +261,7 @@ class TestFaultPlan:
         c = faults.FaultPlan.standard(43, 16)
         assert [(s.site, s.cycle, s.kind) for s in a.specs] != \
                [(s.site, s.cycle, s.kind) for s in c.specs]
-        # full taxonomy, one cycle each, all within (0, cycles-1)
+        # full classification, one cycle each, all within (0, cycles-1)
         kinds = {s.kind for s in a.specs}
         assert kinds == {"hang", "device-error", "garbage", "drop", "dup",
                          "corrupt", "stall", "crash"}

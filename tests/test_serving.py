@@ -6,7 +6,7 @@ O(changed) scatter deltas — never what the solver decides. These tests
 drive randomized event sequences through the delta path and assert
 bit-identical NodeState tensors against a fresh full re-snapshot, and
 identical placements against a full-resnapshot baseline run; the edge
-tests cover every transition in the docs/SERVING.md taxonomy (grow,
+tests cover every transition in the docs/SERVING.md classification (grow,
 re-base reasons, compatibility fallback and resumption).
 """
 
@@ -464,7 +464,7 @@ class TestEventKindTable:
             resource, _, action = kind.partition("/")
             assert resource and action in {"Add", "Update", "Delete"}, kind
 
-    def test_serve_taxonomy_is_within_the_table(self):
+    def test_serve_classification_is_within_the_table(self):
         assert ev.NODE_COLUMN_EVENTS <= ev.EVENT_KINDS
         assert ev.SERVE_REBASE_EVENTS <= ev.EVENT_KINDS
 

@@ -209,7 +209,6 @@ class TestCollectiveShape:
         # mesh, both dtypes the waves use
         from functools import partial
 
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         from scheduler_plugins_tpu.ops.assign import (
@@ -224,10 +223,10 @@ class TestCollectiveShape:
             excl, total = block_exclusive_offsets(x, "nodes", 8)
             return ring, excl, total
 
-        prog = shard_map(
+        prog = jax.shard_map(
             both, mesh=mesh, in_specs=(P("nodes", None),),
             out_specs=(P("nodes", None), P("nodes", None), P(None, None)),
-            check_rep=False,
+            check_vma=False,
         )
         for dtype, hi in ((jnp.float64, 1 << 40), (jnp.int32, 1 << 20)):
             x = jnp.asarray(

@@ -159,8 +159,8 @@ class TestCurrentTree:
         assert "sharded_wave_chunk" in names
         assert "entry" in names
         # ISSUE-13: the Pallas ring kernels and the full pallas-election
-        # chunk solver must keep AOT-lowering (the tpu-first-cycle gate
-        # checks exactly these three against the committed manifest)
+        # chunk solver must keep AOT-lowering (chip_smoke.py --devices 4
+        # compiles the same builds for real on four chips)
         assert {
             "pallas_ring_offsets", "pallas_fused_election",
             "sharded_wave_chunk_pallas",

@@ -10,7 +10,7 @@ AOT-lowers (bench cfgs 0-6 including the north-star chunk, both sharded
 solves, `entry()`) to closed jaxprs and walks them with a provenance
 lattice: every input leaf is tagged with its pytree path (snapshot family,
 SolverState carry, aux channel), and tags propagate forward through every
-equation — including pjit/scan/while/cond sub-jaxprs, with a fixpoint over
+equation — including jit/scan/while/cond sub-jaxprs, with a fixpoint over
 loop carries.
 
 Rules:
@@ -24,7 +24,7 @@ Rules:
   Cycle-initial snapshot reads are sanctioned by design (scores are
   documented cycle-initial) — the rule fires only on a dead carry.
 - **JA002 post-donation read** — a var passed in a DONATED position of an
-  inner jitted call (`donated_invars` on the pjit equation) is consumed by
+  inner jitted call (`donated_invars` on the jit equation) is consumed by
   any LATER equation, or returned, in the enclosing jaxpr. The
   compiled-level complement of graft-lint GL006: catches reuse routed
   through helpers or unrolled loop iterations that the lexical AST sweep
@@ -80,7 +80,7 @@ RULES = ("JA001", "JA002", "JA003", "JA004")
 #: call-like primitives whose sub-jaxpr invars align 1:1 with the equation
 #: operands (param name -> where the jaxpr lives)
 _CALL_PRIMS = {
-    "pjit": "jaxpr",
+    "jit": "jaxpr",
     "closed_call": "call_jaxpr",
     "core_call": "call_jaxpr",
     "remat": "jaxpr",
@@ -291,12 +291,12 @@ class Auditor:
         """Per-output taint sets for one `core.Jaxpr` given per-invar taint
         sets. Mutates the census/violation state; revisits (loop fixpoints)
         re-propagate taints but never double-count equations."""
-        from jax import core
+        from jax.extend.core import Literal
 
         env: dict = {}
 
         def read(v):
-            if isinstance(v, core.Literal):
+            if isinstance(v, Literal):
                 return _EMPTY
             return env.get(v, _EMPTY)
 
@@ -311,7 +311,7 @@ class Auditor:
             ts = [read(v) for v in eqn.invars]
             # JA002: consuming (or re-donating) an already-donated var
             for v in eqn.invars:
-                if not isinstance(v, core.Literal) and v in donated:
+                if not isinstance(v, Literal) and v in donated:
                     self._add(
                         "JA002",
                         f"var donated to {donated[v]!r} consumed later by "
@@ -330,13 +330,13 @@ class Auditor:
             if di and eqn.primitive.name in _CALL_PRIMS:
                 name = eqn.params.get("name", eqn.primitive.name)
                 for flag, v in zip(di, eqn.invars):
-                    if flag and not isinstance(v, core.Literal):
+                    if flag and not isinstance(v, Literal):
                         donated[v] = name
             for v, t in zip(eqn.outvars, out_ts):
                 if type(v).__name__ != "DropVar":
                     write(v, t)
         for v in jaxpr.outvars:
-            if not isinstance(v, core.Literal) and v in donated:
+            if not isinstance(v, Literal) and v in donated:
                 self._add(
                     "JA002",
                     f"var donated to {donated[v]!r} returned from the "
@@ -502,7 +502,7 @@ class Auditor:
 
 def used_inputs(closed_jaxpr) -> list[bool]:
     """Per-invar liveness: does the input contribute to any output? Uses
-    jax's own DCE (handles pjit/scan/while/cond sub-jaxpr recursion
+    jax's own DCE (handles jit/scan/while/cond sub-jaxpr recursion
     precisely); falls back to a coarse any-equation-reads-it sweep if the
     private API moves."""
     jaxpr = closed_jaxpr.jaxpr
@@ -521,18 +521,19 @@ def used_inputs(closed_jaxpr) -> list[bool]:
             file=sys.stderr,
         )
         from jax import core
+        from jax.extend.core import Literal
 
         read: set = set()
 
         def sweep(j):
             for eqn in j.eqns:
                 for v in eqn.invars:
-                    if not isinstance(v, core.Literal):
+                    if not isinstance(v, Literal):
                         read.add(v)
                 for sub in core.jaxprs_in_params(eqn.params):
                     sweep(getattr(sub, "jaxpr", sub))
             for v in j.outvars:
-                if not isinstance(v, core.Literal):
+                if not isinstance(v, Literal):
                     read.add(v)
 
         sweep(jaxpr)
@@ -562,10 +563,8 @@ def audit_fn(fn, args, roles=None, mesh=None) -> dict:
     wraps the trace in the ambient mesh (sharded programs)."""
     import jax
 
-    from scheduler_plugins_tpu.parallel.mesh import ambient_mesh
-
     if mesh is not None:
-        with ambient_mesh(mesh):
+        with jax.set_mesh(mesh):
             closed = jax.make_jaxpr(fn)(*args)
     else:
         closed = jax.make_jaxpr(fn)(*args)

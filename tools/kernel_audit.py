@@ -224,40 +224,31 @@ class KernelAuditor:
     @staticmethod
     def _kernel_name(eqn) -> str:
         """Stable kernel name of a pallas_call eqn: the explicit `name=`
-        (kernels._ring_call passes the vmem.RING_FAMILIES family) via
-        either the `name` param or jax 0.4.x's `name_and_src_info`."""
-        params = eqn.params
-        if params.get("name"):
-            return str(params["name"])
-        nsi = params.get("name_and_src_info")
-        nm = getattr(nsi, "name", None)
-        return str(nm) if nm else "pallas_kernel"
+        (kernels._ring_call passes the vmem.RING_FAMILIES family)."""
+        return str(eqn.params.get("name") or "pallas_kernel")
 
     @staticmethod
     def _site(eqn) -> str:
         """Best-effort `file:line(function)` of the traced call site —
         diagnostic text for the console report, NOT keyed into the
         manifest (line drift must not dirty the committed digest)."""
-        try:
-            from jax._src import source_info_util
+        from jax._src import source_info_util
 
-            frame = source_info_util.user_frame(eqn.source_info)
-            if frame is None:
-                return ""
-            fname = frame.file_name.rsplit("/", 1)[-1]
-            return f" at {fname}:{frame.start_line}({frame.function_name})"
-        except Exception:
+        frame = source_info_util.user_frame(eqn.source_info.traceback)
+        if frame is None:
             return ""
+        fname = frame.file_name.rsplit("/", 1)[-1]
+        return f" at {fname}:{frame.start_line}({frame.function_name})"
 
     # -- the walk -----------------------------------------------------
 
     def propagate(self, jaxpr, in_vals):
-        from jax import core
+        from jax.extend.core import Literal
 
         env: dict = {}
 
         def read(v):
-            if isinstance(v, core.Literal):
+            if isinstance(v, Literal):
                 return self._literal(v)
             return env.get(v, Val())
 
@@ -288,9 +279,9 @@ class KernelAuditor:
         """The concrete value of a jaxpr Literal operand, else None —
         sign-checkable constants (bit masks, clamp limits) support
         transfer rules that max-abs bounds alone cannot justify."""
-        from jax import core
+        from jax.extend.core import Literal
 
-        if isinstance(var, core.Literal):
+        if isinstance(var, Literal):
             try:
                 import numpy as np
 
@@ -320,7 +311,7 @@ class KernelAuditor:
     def _eqn(self, eqn, vals, first):
         name = eqn.primitive.name
         params = eqn.params
-        if name == "pjit":
+        if name == "jit":
             blessed = self.B.EXACT_FN_BOUNDS.get(params.get("name"))
             if blessed is not None:
                 union = frozenset().union(*[v.taint for v in vals]) if vals else _EMPTY
@@ -553,15 +544,15 @@ class KernelAuditor:
         semaphore-ref operand pairs with its immediately following index
         operand (a Literal slot in the unrolled ring; a traced index
         degrades to the wildcard slot '?')."""
-        from jax import core
+        from jax.extend.core import Literal
 
         toks = []
         invars = list(eqn.invars)
         for i, v in enumerate(invars):
-            if isinstance(v, core.Literal) or not _is_sem_ref(v):
+            if isinstance(v, Literal) or not _is_sem_ref(v):
                 continue
             slot = "?"
-            if i + 1 < len(invars) and isinstance(invars[i + 1], core.Literal):
+            if i + 1 < len(invars) and isinstance(invars[i + 1], Literal):
                 try:
                     slot = int(invars[i + 1].val)
                 except Exception:
@@ -1024,10 +1015,8 @@ def audit_fn(fn, args, roles=None, mesh=None) -> dict:
     import jax
 
     from scheduler_plugins_tpu.api import bounds as B
-    from scheduler_plugins_tpu.parallel.mesh import ambient_mesh
-
     if mesh is not None:
-        with ambient_mesh(mesh):
+        with jax.set_mesh(mesh):
             closed = jax.make_jaxpr(fn)(*args)
         axis_sizes = dict(mesh.shape)
     else:
