@@ -304,6 +304,7 @@ class HealthServer:
 
             def do_GET(self):
                 if self.path.startswith("/healthz"):
+                    started_ns = time.perf_counter_ns()
                     # lock-free: a probe must answer while a cycle (incl.
                     # first-compile) holds the feed lock; `last_pending`
                     # is the previous tick's cached count
@@ -395,7 +396,15 @@ class HealthServer:
                     if outer.elector is not None:
                         payload["leader"] = outer.elector.is_leader
                         payload["holder"] = outer.elector.observed_holder
-                    body = json.dumps(payload).encode()
+                    self._json_reply(200, payload)
+                    # entry to reply written: what a poll costs inside the
+                    # handler, its waits for the interpreter lock included
+                    # (registry only: this thread records no tracer span)
+                    obs.metrics.observe_ms(
+                        obs.HEALTHZ_HANDLER_MS,
+                        (time.perf_counter_ns() - started_ns) / 1e6,
+                    )
+                    return
                 elif self.path.startswith("/explain"):
                     # per-plugin score table for a recorded pod (flight
                     # recorder ring; 404 when off or uid not recorded)
@@ -775,13 +784,20 @@ class Daemon:
             return False
         return True
 
+    def _count_pending(self) -> int:
+        """The pending count `/healthz` serves; called under the feed
+        lock. O(pods) unless the store's pending index is on."""
+        with obs.tracer.span("PendingScan", tid="cycle",
+                             pods=len(self.cluster.pods)):
+            return len(self.cluster.pending_pods())
+
     def tick(self):
         if self.elector is not None and not self.elector.is_leader:
             # standby: reflectors keep the store warm, scheduling waits
             # (client-go leaderelection semantics — informers run, the
             # scheduling/reconcile loops gate on leadership)
             with self.feed.locked():
-                self.last_pending = len(self.cluster.pending_pods())
+                self.last_pending = self._count_pending()
             return None
         now_ms = int(time.time() * 1000)
         cycle_started = time.monotonic()
@@ -816,15 +832,24 @@ class Daemon:
             obs.logger.warning("cycle parked: %s", exc.reason)
             self.parked_cycles += 1
             with self.feed.locked():
-                self.last_pending = len(self.cluster.pending_pods())
+                self.last_pending = self._count_pending()
             return None
         obs.metrics.observe_ms(
             "scheduler_cycle", (time.monotonic() - cycle_started) * 1000
         )
-        with self.feed.locked():
-            events = reconcile_pod_groups(self.cluster, now_ms=now_ms)
-            events += reconcile_elastic_quotas(self.cluster)
-            self.last_pending = len(self.cluster.pending_pods())
+        # the tick's tail, on the tracer's "daemon" row. The cycle gave
+        # the lock up, and a feed thread that waited all cycle long has
+        # it now: taking it again is a wait worth a span of its own
+        lock = self.feed.locked()
+        with obs.tracer.span("TickTail/relock", tid="daemon"):
+            lock.acquire()
+        try:
+            with obs.tracer.span("TickTail/reconcile", tid="daemon"):
+                events = reconcile_pod_groups(self.cluster, now_ms=now_ms)
+                events += reconcile_elastic_quotas(self.cluster)
+                self.last_pending = self._count_pending()
+        finally:
+            lock.release()
         for line in events:
             obs.logger.info("controller: %s", line)
         if report.bound or report.failed:
@@ -842,22 +867,24 @@ class Daemon:
             # scheduling loop must keep its cadence
             self._unposted.update(report.bound)
             failures = 0
-            for uid, node in list(self._unposted.items()):
-                if failures >= 2:  # outage: stop burning connect timeouts
-                    break
-                if self._post_binding(uid, node):
-                    del self._unposted[uid]
-                else:
-                    failures += 1
+            with obs.tracer.span("TickTail/bind_back", tid="daemon",
+                                 unposted=len(self._unposted)):
+                for uid, node in list(self._unposted.items()):
+                    if failures >= 2:  # outage: stop burning timeouts
+                        break
+                    if self._post_binding(uid, node):
+                        del self._unposted[uid]
+                    else:
+                        failures += 1
         self.cycles += 1
         self.bound_total += len(report.bound)
         if report.quality is not None:
             self.last_quality = report.quality
         # device-memory watermark gauges: one allocator-stats read per
-        # cycle (no device sync, no transfer — inside the ≤ max(2%,
-        # jitter-floor) observability overhead bound, gated by
-        # tests/test_cost_observatory.py)
-        self.last_memory = costmodel.stamp_device_memory(obs.metrics)
+        # cycle (no device sync, no transfer:
+        # tests/test_cost_observatory.py); the span is what it costs
+        with obs.tracer.span("TickTail/memory", tid="daemon"):
+            self.last_memory = costmodel.stamp_device_memory(obs.metrics)
         return report
 
     def run(self):
@@ -890,7 +917,10 @@ class Daemon:
                     time.monotonic() - started
                 )
                 if remaining > 0:
-                    self.stop_event.wait(remaining)
+                    # with every tick's spans this tiles the thread's
+                    # wall clock: what is in neither is unaccounted
+                    with obs.tracer.span("Loop/sleep", tid="daemon"):
+                        self.stop_event.wait(remaining)
         finally:
             # graceful shutdown (SIGTERM/SIGINT path): every artifact the
             # process owns is flushed through the crash-safe

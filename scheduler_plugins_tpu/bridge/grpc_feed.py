@@ -35,7 +35,7 @@ import json
 import threading
 from typing import Optional
 
-from scheduler_plugins_tpu.bridge.feed import apply_event
+from scheduler_plugins_tpu.bridge.feed import FeedTally, apply_raw
 from scheduler_plugins_tpu.state.cluster import Cluster
 
 SERVICE = "scheduler_plugins_tpu.Feed"
@@ -58,23 +58,30 @@ class GrpcFeedServer:
         self.lock = lock if lock is not None else threading.Lock()
         self.rv_table = rv_table if rv_table is not None else {}
 
+        # one tally per worker thread of the pool below (an RPC runs on
+        # one of them from start to end): no field two threads write
+        local = threading.local()
+
+        def _tally() -> FeedTally:
+            tally = getattr(local, "tally", None)
+            if tally is None:
+                tally = local.tally = FeedTally()
+            return tally
+
         def _apply(raw: bytes) -> bytes:
-            try:
-                event = json.loads(raw)
-                with self.lock:
-                    ack = apply_event(
-                        self.cluster, event, rv_table=self.rv_table
-                    )
-            except Exception as exc:
-                ack = {"ok": False, "error": str(exc)}
-            return json.dumps(ack).encode()
+            return apply_raw(
+                _tally(), raw, self.cluster, self.lock, self.rv_table
+            )
 
         def apply_unary(request, context):
             return _apply(request)
 
         def apply_stream(request_iterator, context):
-            for request in request_iterator:
-                yield _apply(request)
+            try:
+                for request in request_iterator:
+                    yield _apply(request)
+            finally:
+                _tally().flush()  # the stream's end, like a connection's
 
         ident = lambda b: b  # noqa: E731 — JSON codec: bytes through
         handler = grpc.method_handlers_generic_handler(

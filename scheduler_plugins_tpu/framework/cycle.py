@@ -259,7 +259,9 @@ def _cycle_pending(ctx: CycleCtx) -> None:
         ctx.scheduler, ctx.cluster, ctx.now, ctx.report,
     )
     gangs, serve = ctx.gangs, ctx.serve
-    pending = cluster.pending_pods()
+    with obs.tracer.span("PendingScan", tid="cycle",
+                         pods=len(cluster.pods)):
+        pending = cluster.pending_pods()
     with obs.tracer.span("Requeue", tid="cycle"):
         pending = _requeue_eligible(
             scheduler, cluster, pending, now, report,
@@ -618,12 +620,13 @@ def _cycle_finalize(ctx: CycleCtx, attribution: bool = False) -> None:
             ctx.scheduler, ctx.snap, ctx.result, ctx.failed_idx, ctx.report,
             tid=ctx.tid, led=ctx.led,
         )
-    _observe_quality(
-        ctx.report, ctx.quality_view or ctx.snap,
-        ctx.assignment, ctx.admitted, ctx.wait,
-    )
-    if ctx.rec is not None:
-        ctx.rec.commit(ctx.report)
+    with obs.tracer.span("Finalize", tid=ctx.tid):
+        _observe_quality(
+            ctx.report, ctx.quality_view or ctx.snap,
+            ctx.assignment, ctx.admitted, ctx.wait,
+        )
+        if ctx.rec is not None:
+            ctx.rec.commit(ctx.report)
 
 
 def _quality_view(snap):
@@ -694,15 +697,43 @@ def run_cycle(scheduler: Scheduler, cluster: Cluster, now: int | None = None,
     swaps could solve and record under different weights), and
     `observe_report` after finalize (the probation window's
     quality-gauge comparison feeds on the report's quality stamp)."""
-    if now is None:
-        now = _now_ms()
-    if tuner is not None:
-        # the weight-swap seam: promotions/rollbacks apply only here, at
-        # the cycle boundary, never mid-cycle (docs/ROBUSTNESS.md)
-        tuner.begin_cycle(now_ms=now)
-    ctx = _cycle_open(
-        scheduler, cluster, now, stream_chunk=stream_chunk, serve=serve,
-        resilience=resilience, gangs=gangs,
+    # the `Cycle` span (tracer row "cycle"): this function, first statement
+    # to last, recorded at the close with the cycle's number (the registry's
+    # count of cycles, one higher each time: the identifier its inner spans
+    # share by nesting) and what it found and bound. The daemon enters here
+    # with the feed lock held, so a tick's lead-in to this span is its wait
+    # for the lock
+    span_from = obs.tracer.now_ns() if obs.tracer.enabled else None
+    ctx = None
+    try:
+        if now is None:
+            now = _now_ms()
+        if tuner is not None:
+            # the weight-swap seam: promotions/rollbacks apply only here,
+            # at the cycle boundary, never mid-cycle (docs/ROBUSTNESS.md)
+            tuner.begin_cycle(now_ms=now)
+        ctx = _cycle_open(
+            scheduler, cluster, now, stream_chunk=stream_chunk, serve=serve,
+            resilience=resilience, gangs=gangs,
+        )
+        return _cycle_stages(ctx, tuner)
+    finally:
+        if span_from is not None:
+            args = {"cycle": obs.metrics.get(obs.SCHEDULING_CYCLES)}
+            if ctx is not None:
+                args["pending"] = len(ctx.pending)
+                args["bound"] = len(ctx.report.bound)
+            obs.tracer.complete(
+                "Cycle", span_from, obs.tracer.now_ns() - span_from,
+                tid="cycle", args=args,
+            )
+
+
+def _cycle_stages(ctx: CycleCtx, tuner) -> CycleReport:
+    """`run_cycle` after the prologue: the stage functions, strictly
+    serially, and the ledger scope's close."""
+    scheduler, cluster, now, serve = (
+        ctx.scheduler, ctx.cluster, ctx.now, ctx.serve,
     )
     try:
         _cycle_pending(ctx)

@@ -285,9 +285,9 @@ def stamp_device_memory(metrics=None) -> dict:
     """Per-cycle watermark stamp: read the allocator stats once and set
     the ``scheduler_device_bytes_in_use`` / ``..._peak_bytes_in_use``
     gauges (last write wins). Returns the /healthz memory block. One
-    allocator read per cycle — far inside the established <= max(2%,
-    jitter-floor) observability overhead bound (gated by
-    tests/test_cost_observatory.py)."""
+    allocator read per local device and cycle, no transfer, no sync
+    (tests/test_cost_observatory.py holds it to that); its cost a tick is
+    the daemon's `TickTail/memory` span."""
     block = device_memory_block()
     if metrics is None:
         from scheduler_plugins_tpu.utils import observability as obs

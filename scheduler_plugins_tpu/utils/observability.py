@@ -464,6 +464,21 @@ DEVICE_BYTES_IN_USE = "scheduler_device_bytes_in_use"
 #: in docs/cost_model.json: the committed manifest predicts, this gauge
 #: measures
 DEVICE_PEAK_BYTES = "scheduler_device_peak_bytes_in_use"
+#: feed events applied (TCP and gRPC front ends; a malformed line counts:
+#: it cost a decode and an ack). Each connection / worker thread keeps a
+#: tally of its own and adds it here every 32 events, every 100 ms and
+#: when the connection ends (`bridge.feed.FeedTally`), so the registry
+#: lags an event by at most that much
+FEED_EVENTS = "scheduler_feed_events_total"
+#: nanoseconds those events spent per stage (labels: stage ∈ codec |
+#: lock_wait | apply): the JSON decode + ack encode, the wait for the feed
+#: lock, `apply_event` under it. Flushed with FEED_EVENTS;
+#: `rate(ns) / rate(events)` is the mean cost of an event per stage
+FEED_EVENT_NS = "scheduler_feed_event_ns_total"
+#: wall ms of one `GET /healthz`, entry to reply written: the SLI summary
+#: over the ledger ring, the thread census, the JSON, and the handler's
+#: own waits for the interpreter lock (histogram, one observation a poll)
+HEALTHZ_HANDLER_MS = "scheduler_healthz_handler_ms"
 
 #: `# HELP` registry for `prometheus_text` (exposition format 0.0.4
 #: requires families to be self-describing; families not listed here get
@@ -548,6 +563,15 @@ HELP: dict[str, str] = {
         "Device-memory bytes in use across local devices (gauge).",
     DEVICE_PEAK_BYTES:
         "Device-memory high-water mark across local devices (gauge).",
+    FEED_EVENTS:
+        "Feed events applied (malformed lines included), flushed per "
+        "connection every 32 events or 100 ms.",
+    FEED_EVENT_NS:
+        "Nanoseconds feed events spent per stage (codec, lock_wait, "
+        "apply); divide by scheduler_feed_events_total.",
+    HEALTHZ_HANDLER_MS:
+        "Wall ms of one GET /healthz inside its handler, entry to reply "
+        "written.",
 }
 
 
@@ -721,6 +745,7 @@ class Tracer:
         self._tids: dict[str, int] = {}
         self._lock = threading.Lock()
         self._origin_ns = 0
+        self._origin_monotonic_ns = 0
 
     @property
     def enabled(self) -> bool:
@@ -732,6 +757,7 @@ class Tracer:
                 self._events.clear()
                 self._tids.clear()
             self._origin_ns = time.perf_counter_ns()
+            self._origin_monotonic_ns = time.monotonic_ns()
             self._enabled = True
 
     def stop(self) -> None:
@@ -781,10 +807,13 @@ class Tracer:
             )
 
     def export(self) -> dict:
-        """{"traceEvents": [...]} — X spans plus M thread_name metadata."""
+        """{"traceEvents": [...]} — X spans plus M thread_name metadata.
+        `otherData.origin_monotonic_ns` is `start()` on CLOCK_MONOTONIC:
+        `ts` 0 of this file, so it can be laid beside a profiler trace."""
         with self._lock:
             events = list(self._events)
             tids = dict(self._tids)
+            origin = self._origin_monotonic_ns
         pid = os.getpid()
         meta = [
             {
@@ -796,7 +825,11 @@ class Tracer:
             }
             for row, tid in sorted(tids.items(), key=lambda kv: kv[1])
         ]
-        return {"traceEvents": meta + events, "displayTimeUnit": "ms"}
+        return {
+            "traceEvents": meta + events,
+            "displayTimeUnit": "ms",
+            "otherData": {"origin_monotonic_ns": origin},
+        }
 
     def write(self, path: str) -> None:
         """Export to `path` atomically (temp file + `os.replace`): a crash —

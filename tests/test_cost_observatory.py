@@ -571,37 +571,41 @@ class TestWatermarkGauges:
         if not block["available"]:  # the CPU/tier-1 case
             assert stub.gauges == {}
 
-    def test_stamp_overhead_within_bound(self):
-        """The established observability overhead discipline (ledger /
-        tracer precedent): interleaved paired deltas of a fixed host
-        workload with and without the per-cycle stamp appended, median
-        paired overhead <= max(2%, the off-series jitter floor measured
-        the same way on stamp-free pairs)."""
-        import time
+    def test_stamp_overhead_within_bound(self, monkeypatch):
+        """What bounds the stamp's cost, checked by stub: one
+        `memory_stats()` read per local device and nothing else of JAX —
+        no transfer, no sync. What it costs a tick in time is read on the
+        chip, from the daemon's `TickTail/memory` span (PERF.md); the
+        paired timing of a 300 us loop this test used to make failed
+        under six workers and said nothing about the chip."""
+        from scheduler_plugins_tpu.utils import observability as obs
 
-        import numpy as np
+        class Device:
+            __slots__ = ("id", "reads")  # any other attribute raises
 
-        work = np.arange(50_000, dtype=np.int64)
+            def __init__(self, device_id):
+                self.id, self.reads = device_id, 0
+
+            def memory_stats(self):
+                self.reads += 1
+                return {"bytes_in_use": 10 * (self.id + 1),
+                        "peak_bytes_in_use": 100}
+
+        devices = [Device(0), Device(1)]
+
+        class OnlyTheAllocatorStats:
+            """Stands in for the `jax` module: `device_get`,
+            `block_until_ready`, `device_put` and the rest are
+            AttributeErrors here."""
+
+            local_devices = staticmethod(lambda: devices)
+            default_backend = staticmethod(lambda: "tpu")
+
+        monkeypatch.setattr(costmodel, "jax", OnlyTheAllocatorStats)
         stub = _StubMetrics()
-
-        def cycle(stamp):
-            t0 = time.perf_counter()
-            for _ in range(3):
-                (work * 3 // 7).sum()
-            if stamp:
-                costmodel.stamp_device_memory(stub)
-            return time.perf_counter() - t0
-
-        for attempt in range(3):  # re-measure, not re-threshold, on noise
-            cycle(True), cycle(False)  # warm both paths
-            off_a = [cycle(False) for _ in range(20)]
-            pairs = [(cycle(False), cycle(True)) for _ in range(20)]
-            off_b = [cycle(False) for _ in range(20)]
-            jitter = abs(
-                float(np.median(off_b)) - float(np.median(off_a))
-            ) / float(np.median(off_a))
-            deltas = sorted((w - wo) / wo for wo, w in pairs)
-            overhead = deltas[len(deltas) // 2]
-            if overhead <= max(0.02, jitter):
-                break
-        assert overhead <= max(0.02, jitter), (overhead, jitter)
+        block = costmodel.stamp_device_memory(stub)
+        assert [d.reads for d in devices] == [1, 1]
+        assert block["available"] and len(block["devices"]) == 2
+        assert stub.gauges == {
+            obs.DEVICE_BYTES_IN_USE: 30, obs.DEVICE_PEAK_BYTES: 200,
+        }

@@ -1,6 +1,10 @@
 """Event-feed bridge tests: a remote agent drives the cluster over TCP and a
 scheduling cycle runs against the fed state."""
 
+import json
+
+import pytest
+
 from scheduler_plugins_tpu.api.resources import CPU, MEMORY, PODS
 from scheduler_plugins_tpu.bridge.feed import FeedClient, FeedServer, apply_event
 from scheduler_plugins_tpu.framework import Profile, Scheduler, run_cycle
@@ -553,3 +557,180 @@ class TestFencingEdgeCases:
             assert not ack["ok"] and "exceeds" in ack["error"]
         finally:
             server.stop()
+
+
+class TestFeedStageCounters:
+    """`scheduler_feed_events_total` / `scheduler_feed_event_ns_total{stage}`:
+    each connection tallies its own events and flushes every 32, every
+    100 ms and when it ends (`bridge.feed.FeedTally`). Deltas of the
+    process-wide registry, no wall-clock thresholds beyond the one the
+    test makes itself by holding the lock."""
+
+    STAGES = ("codec", "lock_wait", "apply")
+
+    @staticmethod
+    def _counters():
+        from scheduler_plugins_tpu.utils import observability as obs
+
+        return {
+            "events": obs.metrics.get(obs.FEED_EVENTS),
+            **{
+                stage: obs.metrics.get(obs.FEED_EVENT_NS, stage=stage)
+                for stage in TestFeedStageCounters.STAGES
+            },
+        }
+
+    @classmethod
+    def _delta_after(cls, before, events, timeout_s=10.0):
+        """The registry's rise since `before`, once `events` more events
+        are in it (a closed connection's handler flushes on its own
+        thread, a moment after the client's close returns)."""
+        import time
+
+        deadline = time.monotonic() + timeout_s
+        while True:
+            now = cls._counters()
+            delta = {k: now[k] - before[k] for k in now}
+            if delta["events"] >= events or time.monotonic() > deadline:
+                return delta
+            time.sleep(0.01)
+
+    @pytest.mark.parametrize("n", [1, 31, 32, 33, 100])
+    def test_every_event_is_counted_once_the_connection_ends(self, n):
+        server = FeedServer(Cluster()).start()
+        try:
+            before = self._counters()
+            client = FeedClient(*server.address)
+            for j in range(n):
+                assert client.send({
+                    "op": "upsert_node", "name": f"n{j}",
+                    "allocatable": {CPU: 1000, PODS: 10},
+                })["ok"]
+            client.close()
+            delta = self._delta_after(before, n)
+        finally:
+            server.stop()
+        assert delta["events"] == n
+        for stage in self.STAGES:
+            assert delta[stage] > 0, (stage, delta)
+
+    def test_a_full_tally_is_flushed_before_the_connection_ends(self):
+        from scheduler_plugins_tpu.bridge.feed import TALLY_FLUSH_EVENTS
+
+        server = FeedServer(Cluster()).start()
+        try:
+            before = self._counters()
+            client = FeedClient(*server.address)
+            for _ in range(TALLY_FLUSH_EVENTS):
+                client.send({"op": "sync"})
+            # the 32nd event's flush ran before its ack was written
+            assert self._counters()["events"] - before["events"] == (
+                TALLY_FLUSH_EVENTS
+            )
+            client.close()
+        finally:
+            server.stop()
+
+    def test_a_sender_blocked_on_the_lock_shows_as_lock_wait(self):
+        import threading
+        import time
+
+        server = FeedServer(Cluster()).start()
+        try:
+            before = self._counters()
+            client = FeedClient(*server.address)
+            acked = threading.Event()
+
+            def send():
+                client.send({"op": "sync"})
+                acked.set()
+
+            with server.locked():
+                sender = threading.Thread(
+                    target=send, daemon=True, name="test-feed-sender"
+                )
+                sender.start()
+                time.sleep(0.05)
+                assert not acked.is_set()  # it waits for the lock we hold
+            sender.join(timeout=10)
+            assert acked.is_set()
+            client.close()
+            delta = self._delta_after(before, 1)
+        finally:
+            server.stop()
+        assert delta["events"] == 1
+        # the sender asked for the lock within the first few ms of the 50
+        assert delta["lock_wait"] >= 40_000_000, delta
+        assert delta["apply"] < delta["lock_wait"]
+
+    def test_a_malformed_line_is_an_event_with_codec_time(self):
+        import socket
+
+        server = FeedServer(Cluster()).start()
+        try:
+            before = self._counters()
+            with socket.create_connection(server.address) as sock:
+                with sock.makefile("rwb") as f:
+                    f.write(b"{not json\n")
+                    f.flush()
+                    assert json.loads(f.readline())["ok"] is False
+            delta = self._delta_after(before, 1)
+        finally:
+            server.stop()
+        assert delta["events"] == 1
+        assert delta["codec"] > 0
+        # it never asked for the lock
+        assert delta["lock_wait"] == 0 and delta["apply"] == 0
+
+    def test_two_connections_add_up(self):
+        server = FeedServer(Cluster()).start()
+        try:
+            before = self._counters()
+            a = FeedClient(*server.address)
+            b = FeedClient(*server.address)
+            for _ in range(5):
+                a.send({"op": "sync"})
+            for _ in range(7):
+                b.send({"op": "sync"})
+            a.close()
+            b.close()
+            delta = self._delta_after(before, 12)
+        finally:
+            server.stop()
+        assert delta["events"] == 12
+
+    @pytest.mark.parametrize("rpc", ["unary", "stream"])
+    def test_grpc_front_end_counts_through_the_same_helper(self, rpc):
+        pytest.importorskip("grpc")
+        from scheduler_plugins_tpu.bridge.feed import TALLY_FLUSH_EVENTS
+        from scheduler_plugins_tpu.bridge.grpc_feed import (
+            GrpcFeedClient,
+            GrpcFeedServer,
+        )
+
+        server = GrpcFeedServer(Cluster()).start()
+        try:
+            before = self._counters()
+            client = GrpcFeedClient("127.0.0.1", server.port)
+            if rpc == "stream":
+                # a stream's end flushes its worker's tally
+                n = 5
+                client.send_batch([{"op": "sync"}] * n)
+            else:
+                # unary calls land on any worker of the pool: when 8
+                # workers have seen 8 * 32 events between them, at least
+                # one tally has filled and flushed
+                n = 8 * TALLY_FLUSH_EVENTS
+                for _ in range(n):
+                    client.send({"op": "sync"})
+            delta = self._delta_after(
+                before, n if rpc == "stream" else TALLY_FLUSH_EVENTS
+            )
+            client.close()
+        finally:
+            server.stop()
+        if rpc == "stream":
+            assert delta["events"] == n
+        else:
+            assert TALLY_FLUSH_EVENTS <= delta["events"] <= n
+        assert delta["codec"] > 0 and delta["apply"] > 0

@@ -230,6 +230,118 @@ class TestCycleTrace:
         assert len(obs.tracer.export()["traceEvents"]) == before
 
 
+class TestServedLoopNames:
+    """ISSUE 24: the spans and registry names that account for the served
+    loop's host time. The daemon-level spans (`Loop/sleep`, `TickTail/*`)
+    are exercised end to end in tests/test_daemon.py."""
+
+    _cluster = TestCycleTrace._cluster
+
+    def _traced_cycle(self):
+        obs.tracer.start()
+        report = run_cycle(
+            Scheduler(Profile(plugins=[NodeResourcesAllocatable()])),
+            self._cluster(), now=1000,
+        )
+        obs.tracer.stop()
+        trace = obs.tracer.export()
+        rows = {e["tid"]: e["args"]["name"]
+                for e in trace["traceEvents"] if e["ph"] == "M"}
+        return report, trace, rows
+
+    @pytest.mark.parametrize("name", [
+        obs.FEED_EVENTS, obs.FEED_EVENT_NS, obs.HEALTHZ_HANDLER_MS,
+    ])
+    def test_help_covers_the_new_names(self, name):
+        assert name.startswith("scheduler_") and obs.HELP[name]
+        m = obs.Metrics()
+        if name == obs.HEALTHZ_HANDLER_MS:
+            m.observe_ms(name, 1.5)
+            kind = "histogram"
+        else:
+            m.inc(name, 3)
+            kind = "counter"
+        text = m.prometheus_text()
+        assert f"# HELP {name} {obs.HELP[name]}" in text
+        assert f"# TYPE {name} {kind}" in text
+
+    @pytest.mark.parametrize("name", ["Cycle", "PendingScan", "Finalize"])
+    def test_traced_cycle_records_the_span_on_the_cycle_row(self, name):
+        _report, trace, rows = self._traced_cycle()
+        assert validate_trace(trace) == []
+        spans = [e for e in trace["traceEvents"]
+                 if e["ph"] == "X" and e["name"] == name]
+        assert len(spans) == 1 and rows[spans[0]["tid"]] == "cycle"
+
+    def test_cycle_span_covers_the_cycle_and_says_what_it_did(self):
+        before = obs.metrics.get(obs.SCHEDULING_CYCLES)
+        report, trace, _rows = self._traced_cycle()
+        xs = [e for e in trace["traceEvents"] if e["ph"] == "X"]
+        cycle = next(e for e in xs if e["name"] == "Cycle")
+        assert cycle["args"] == {
+            "cycle": before + 1, "pending": 2, "bound": len(report.bound),
+        }
+        # every other span of the cycle lies inside it
+        for e in xs:
+            assert cycle["ts"] <= e["ts"]
+            assert e["ts"] + e["dur"] <= cycle["ts"] + cycle["dur"]
+        scan = next(e for e in xs if e["name"] == "PendingScan")
+        assert scan["args"] == {"pods": 2}
+
+    def test_cycle_span_is_recorded_when_the_cycle_raises(self):
+        class Boom(Exception):
+            pass
+
+        class Exploding(NodeResourcesAllocatable):
+            def configure_cluster(self, cluster):
+                raise Boom()
+
+        obs.tracer.start()
+        with pytest.raises(Boom):
+            run_cycle(Scheduler(Profile(plugins=[Exploding()])),
+                      self._cluster(), now=1000)
+        obs.tracer.stop()
+        names = [e["name"] for e in obs.tracer.export()["traceEvents"]
+                 if e["ph"] == "X"]
+        assert names == ["Cycle"]
+
+    def test_tracer_off_reads_no_clock_and_records_nothing(self, monkeypatch):
+        def no_clock():
+            raise AssertionError("a disabled tracer read its clock")
+
+        t = obs.Tracer()
+        monkeypatch.setattr(t, "now_ns", no_clock)
+        with t.span("work", tid="daemon", pods=1):
+            pass
+        assert t.export()["traceEvents"] == []
+        # the whole cycle, `Cycle` span included, with the tracer off
+        monkeypatch.setattr(obs.tracer, "now_ns", no_clock)
+        before = len(obs.tracer.export()["traceEvents"])
+        report = run_cycle(
+            Scheduler(Profile(plugins=[NodeResourcesAllocatable()])),
+            self._cluster(), now=1000,
+        )
+        assert report.bound
+        assert len(obs.tracer.export()["traceEvents"]) == before
+
+    def test_export_carries_its_origin_on_the_monotonic_clock(self):
+        import time
+
+        t = obs.Tracer()
+        lo = time.monotonic_ns()
+        t.start()
+        hi = time.monotonic_ns()
+        with t.span("work"):
+            pass
+        t.stop()
+        origin = t.export()["otherData"]["origin_monotonic_ns"]
+        # `ts` 0 of the export: CLOCK_MONOTONIC at `start()`
+        assert lo <= origin <= hi
+        assert json.loads(json.dumps(t.export()))["otherData"] == {
+            "origin_monotonic_ns": origin
+        }
+
+
 class TestServeTraceRows:
     """PR 6 gap closure: ServeEngine.refresh stages appear as spans on
     the "serve" row of a traced serve-mode cycle, and the trace stays

@@ -163,7 +163,6 @@ def main(out_path=None, bound_pct=None):
     import bench
     from scheduler_plugins_tpu.utils import observability as obs
 
-    bench.apply_platform_override()
     if bound_pct is None:
         bound_pct = float(os.environ.get("SPT_TRACE_BOUND_PCT", 2.0))
     out_path = out_path or os.environ.get(
