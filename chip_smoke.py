@@ -48,10 +48,12 @@ import time
 import urllib.request
 
 #: problem sizes: what an operator would call real, and the rehearsal.
-#: `interval_s` is the daemon's tick period in phase A: a wave is sent in
-#: the quiet window after an idle tick, so the period has to be several
-#: times a wave's send time (2,500 acknowledged events take a few hundred
-#: ms over loopback).
+#: `interval_s` is the daemon's `--cycle-interval-s` in phase A. A wave is
+#: sent after an idle tick; its first pod starts a tick (the loop is paced
+#: by demand), so a wave is solved in a few cycles, whatever pod buckets
+#: they fall in (2,500 acknowledged events take a few hundred ms over
+#: loopback). Every recorded cycle is checked; `shapes_as_planned` says
+#: whether each wave came out as one cycle.
 SIZES = {
     "real": {
         "serve": dict(n_nodes=5000, init_pods=1000, waves=4, wave_pods=2500,
@@ -251,8 +253,8 @@ def _serve_client(status: dict, nodes, waves, box: dict) -> None:
             sent = 0
             box["send_s"] = []
             for wave in waves:
-                # send inside the quiet window that follows an idle tick,
-                # so a wave is solved whole: one pod bucket per wave size
+                # start a wave from an idle daemon: nothing of the wave
+                # before it is still pending
                 seen = healthz()["cycles"]
                 wait_for(lambda h: h["cycles"] > seen, "an idle tick")
                 t0 = time.perf_counter()

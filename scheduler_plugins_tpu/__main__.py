@@ -19,6 +19,9 @@ Wiring per tick:
            mirrors hot node columns into the C++ columnar store)
         -> Cluster store  (--scheduler-name gates the queue per profile)
     cycle loop:  [--leader-elect: only while holding the Lease]
+                 a tick when a pod becomes schedulable, as early as
+                 DEMAND_TICK_SPACING allows, and every --cycle-interval-s
+                 otherwise (`Daemon.run`)
                  run_cycle (QueueSort..Bind, collector ticks, NRT resync)
                  reconcile_pod_groups / reconcile_elastic_quotas
                  bindings POSTed back to the apiserver [--bind-back]
@@ -115,7 +118,13 @@ def parse_args(argv=None):
     ap.add_argument("--identity", default=None,
                     help="leader-election holder identity "
                          "(default hostname_pid)")
-    ap.add_argument("--cycle-interval-s", type=float, default=1.0)
+    ap.add_argument("--cycle-interval-s", type=float, default=1.0,
+                    help="the longest a schedulable pod waits for a tick "
+                         "to start: the loop ticks this often when nothing "
+                         "arrives (backoff expiry, PodGroup time-outs, "
+                         "reconcilers, a leader-election standby), and "
+                         "sooner when a pod becomes schedulable and the "
+                         "last tick took under a sixth of it")
     ap.add_argument("--health-port", type=int, default=0,
                     help="HTTP health/metrics port (0 = ephemeral; "
                          "-1 disables)")
@@ -210,6 +219,64 @@ def parse_args(argv=None):
                          "lifetime and flush a Perfetto-loadable JSON to "
                          "OUT.json on shutdown (SIGTERM included)")
     return ap.parse_args(argv)
+
+
+#: How far ahead of its interval the loop may run. A tick that took `d`
+#: (entry of `tick()` to its return: the cycle and the tail, both under the
+#: feed lock) is followed by a demand tick no sooner than this many `d`
+#: after it started, so whenever the loop runs early its thread is inside
+#: `tick()`, and the synchronous feed shut out, at most one sixth of the
+#: time. Why a sixth: a tick has a fixed cost whatever its batch, so ticks
+#: closer together raise the lock's share, and a closed backlog's
+#: throughput is what the lock leaves of the second (PERF.md finding 20:
+#: pods/s ∝ 1 − share). The parent held the lock (161.3 + 6.6) ms of every
+#: 1,000 in `basic-5000n.backlog` (ledger, PR 24), 16.8 %: the cell where
+#: share is throughput keeps the share it had, and a tick that costs a
+#: sixth of the interval or more keeps the interval's cadence exactly.
+DEMAND_TICK_SPACING = 6
+
+
+class _Doorbell:
+    """What the loop waits on between ticks: rung when a pod arrives and
+    when the daemon is told to stop. A bare lock used as a binary
+    semaphore (held = silent), not a `threading.Event`: `Event.set()` takes
+    a Python-level lock, and the SIGTERM handler runs in the loop's own
+    thread, which may hold that lock at that moment (a signal landing as
+    the loop entered `Event.wait()` deadlocked the daemon about once in
+    forty shutdowns). A lock's acquire and release are single C calls, so
+    nothing here is ever half done when a handler runs. A ring is kept
+    until a wait takes it."""
+
+    def __init__(self):
+        self._silent = threading.Lock()
+        self._silent.acquire()
+
+    def ring(self) -> None:
+        try:
+            self._silent.release()
+        except RuntimeError:
+            pass  # rung already
+
+    def wait(self, timeout: float) -> None:
+        """Until rung, `timeout` seconds at most; takes the ring."""
+        self._silent.acquire(timeout=max(timeout, 0.0))
+
+    def reset(self) -> None:
+        self._silent.acquire(blocking=False)
+
+
+class _StopEvent(threading.Event):
+    """The daemon's stop flag. Setting it also rings the loop's doorbell,
+    so a stop ends the loop's wait at once. The loop itself only reads
+    the flag (`is_set()` takes no lock): see `_Doorbell`."""
+
+    def __init__(self, doorbell: _Doorbell):
+        super().__init__()
+        self._doorbell = doorbell
+
+    def set(self):
+        super().set()
+        self._doorbell.ring()
 
 
 def decode_profile_file(path: str) -> dict:
@@ -539,6 +606,10 @@ class Daemon:
         self.cluster = Cluster()
         if args.scheduler_name:
             self.cluster.scheduler_names = set(args.scheduler_name)
+        # every engine reads the maintained pending index (the pipelined
+        # and laned ones switch it on themselves too): the scan's list in
+        # the scan's order, without a walk over every pod twice a tick
+        self.cluster.enable_pending_index()
         self.engine = None
         if args.serve:
             from scheduler_plugins_tpu.serving import (
@@ -643,6 +714,12 @@ class Daemon:
                 )
             except ValueError as exc:
                 raise SystemExit(f"--lanes: {exc}")
+        #: `run` waits on the doorbell between ticks; the store's hook
+        #: rings it for the first pod that enters the pending set after a
+        #: tick started, a stop rings it too
+        self._doorbell = _Doorbell()
+        self._pod_waiting = False
+        self.cluster.on_pending_gain = self._pod_arrived
         if args.trace:
             obs.tracer.start()
         if args.native_store:
@@ -680,7 +757,7 @@ class Daemon:
         self.parked_cycles = 0
         self._unposted: dict[str, str] = {}
         self.elector = None  # before HealthServer: /healthz reads it
-        self.stop_event = threading.Event()
+        self.stop_event = _StopEvent(self._doorbell)
         self.health = None
         if args.health_port >= 0:
             self.health = HealthServer(self, args.feed_host, args.health_port)
@@ -784,12 +861,24 @@ class Daemon:
             return False
         return True
 
+    def _pod_arrived(self) -> None:
+        """The store's `on_pending_gain` hook: once per pod that enters
+        the pending set, under the feed lock, by whichever thread applied
+        the event. Only the first arrival after a tick started rings: the
+        others find the flag up, which costs a tenth of a ring. No arrival
+        is lost to that: `run` lowers the flag before its tick asks for the
+        lock this caller holds, so a pod that found it up is in that tick's
+        batch."""
+        if not self._pod_waiting:
+            self._pod_waiting = True
+            self._doorbell.ring()
+
     def _count_pending(self) -> int:
         """The pending count `/healthz` serves; called under the feed
-        lock. O(pods) unless the store's pending index is on."""
+        lock. The size of the store's pending index."""
         with obs.tracer.span("PendingScan", tid="cycle",
                              pods=len(self.cluster.pods)):
-            return len(self.cluster.pending_pods())
+            return self.cluster.pending_count()
 
     def tick(self):
         if self.elector is not None and not self.elector.is_leader:
@@ -887,6 +976,39 @@ class Daemon:
             self.last_memory = costmodel.stamp_device_memory(obs.metrics)
         return report
 
+    def _wait_for_tick(self, started: float, duration: float) -> str:
+        """The loop's wait between two ticks, in its own thread; returns
+        what ended it. The last tick started at `started` and took
+        `duration` (both `time.monotonic()` seconds). The next one starts
+        one interval after `started` at the latest ("interval"), and
+        otherwise at the first moment from `DEMAND_TICK_SPACING` durations
+        after `started` at which a pod has entered the pending set
+        ("demand"). A leader-election standby ticks on the interval only.
+        `stop_event` ends the wait at once."""
+        interval = self.args.cycle_interval_s
+        heartbeat = started + interval
+        earliest = started + min(interval, DEMAND_TICK_SPACING * duration)
+        standby = self.elector is not None and not self.elector.is_leader
+        # with every tick's spans this tiles the thread's wall clock:
+        # what is in neither is unaccounted
+        with obs.tracer.span("Loop/sleep", tid="daemon") as said:
+            woke = "interval"
+            while not self.stop_event.is_set():
+                now = time.monotonic()
+                if now >= heartbeat:
+                    break
+                if now >= earliest and self._pod_waiting and not standby:
+                    woke = "demand"
+                    break
+                # to the next of the two moments, or a ring: a pod's
+                # first arrival, a stop (a ring before `earliest` only
+                # brings the loop round once more)
+                self._doorbell.wait(
+                    (earliest if now < earliest else heartbeat) - now
+                )
+            said["woke"] = woke
+        return woke
+
     def run(self):
         args = self.args
 
@@ -905,22 +1027,24 @@ class Daemon:
         print("daemon ready " + json.dumps(status), flush=True)
 
         try:
+            woke = "interval"  # the first tick waits for nothing
             while not self.stop_event.is_set():
                 started = time.monotonic()
+                # lowered as the tick starts, not as it ends: a pod that
+                # arrives while the tick runs is not in its batch
+                self._doorbell.reset()
+                self._pod_waiting = False
+                obs.metrics.inc(obs.TICKS)
+                obs.metrics.inc(obs.TICK_WAKEUPS, reason=woke)
                 self.tick()
                 self.ticks += 1
                 # ticks, not scheduling cycles: a bounded run must also
                 # terminate when leader-election standby skips every cycle
                 if args.max_cycles and self.ticks >= args.max_cycles:
                     break
-                remaining = args.cycle_interval_s - (
-                    time.monotonic() - started
+                woke = self._wait_for_tick(
+                    started, time.monotonic() - started
                 )
-                if remaining > 0:
-                    # with every tick's spans this tiles the thread's
-                    # wall clock: what is in neither is unaccounted
-                    with obs.tracer.span("Loop/sleep", tid="daemon"):
-                        self.stop_event.wait(remaining)
         finally:
             # graceful shutdown (SIGTERM/SIGINT path): every artifact the
             # process owns is flushed through the crash-safe

@@ -580,7 +580,7 @@ def _apply_op(cluster: Cluster, event: dict, op) -> dict:
             "ok": True,
             "nodes": len(cluster.nodes),
             "pods": len(cluster.pods),
-            "pending": len(cluster.pending_pods()),
+            "pending": cluster.pending_count(),
         }
     else:
         return {"ok": False, "error": f"unknown op {op!r}"}

@@ -14,7 +14,10 @@ from scheduler_plugins_tpu.state.cluster import Cluster
 
 def reconcile_elastic_quotas(cluster: Cluster) -> list[str]:
     """One reconcile pass over every ElasticQuota; returns emitted events.
-    Single sweep over pods bucketed by namespace — O(pods + quotas)."""
+    Single sweep over pods bucketed by namespace — O(pods + quotas); a
+    cluster without quotas is not walked."""
+    if not cluster.quotas:
+        return []
     by_ns: dict[str, dict[str, int]] = {}
     for pod in cluster.pods.values():
         if pod.phase != PodPhase.RUNNING:

@@ -10,7 +10,7 @@ failure times (/root/reference/pkg/coscheduling/core/core.go:134-192).
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Optional
+from typing import Callable, Optional
 
 from scheduler_plugins_tpu.api import events as ev
 from scheduler_plugins_tpu.api.objects import (
@@ -112,11 +112,11 @@ class Cluster:
     #: their `note_event` calls — the O(changed) feed the resident-state
     #: serving engine ingests instead of re-snapshotting (docs/SERVING.md)
     delta_sink: Optional[object] = None
-    #: opt-in O(changed) pending index (`enable_pending_index`, the
-    #: pipelined cycle engine's ingest path): uid -> Pod for every
-    #: currently-schedulable pod, maintained by the same mutators that
-    #: notify the delta sink. None (the default) keeps `pending_pods` as
-    #: the exact O(pods) scan the serial engine has always run.
+    #: opt-in O(changed) pending index (`enable_pending_index`: the
+    #: daemon switches it on for every engine, the pipelined and laned
+    #: engines themselves): uid -> Pod for every currently-schedulable
+    #: pod, maintained by the same mutators that notify the delta sink.
+    #: None (the default) keeps `pending_pods` as the exact O(pods) scan.
     _pending_idx: Optional[dict] = None
     #: admission serial per uid, reproducing the pods-dict iteration
     #: order the scan yields: assigned at FIRST add (dict updates keep
@@ -125,6 +125,14 @@ class Cluster:
     #: bit-identical to the scan's, ties and all
     _pod_order: dict = field(default_factory=dict)
     _order_next: int = 0
+    #: optional hook, called with no argument whenever the pending index
+    #: gains a uid it did not hold (first sighting, gate lifted,
+    #: reservation released): the daemon's loop waits on the doorbell it
+    #: rings. It runs under whatever lock guards the store, once per pod
+    #: on the ingest path, so it must be cheap. A pod a cycle leaves
+    #: pending never left the index, so it does not fire it. Needs the
+    #: index (`enable_pending_index`).
+    on_pending_gain: Optional[Callable[[], None]] = None
 
     def note_event(self, kind: str) -> None:
         """Record a cluster event ("Resource/Action", `api.events`) for
@@ -600,9 +608,15 @@ class Cluster:
         if self._pending_idx is None:
             return
         if pod is not None and self._pending_eligible(pod):
-            self._pending_idx[uid] = pod
+            self._index_hold(pod)
         else:
             self._pending_idx.pop(uid, None)
+
+    def _index_hold(self, pod: Pod) -> None:
+        gained = self.on_pending_gain
+        if gained is not None and pod.uid not in self._pending_idx:
+            gained()
+        self._pending_idx[pod.uid] = pod
 
     def _index_add_pod(self, pod: Pod, was_present: bool) -> None:
         if self._pending_idx is None:
@@ -612,7 +626,7 @@ class Cluster:
             self._pod_order[pod.uid] = self._order_next
             self._order_next += 1
         if self._pending_eligible(pod):
-            self._pending_idx[pod.uid] = pod
+            self._index_hold(pod)
         else:
             self._pending_idx.pop(pod.uid, None)
 
@@ -640,6 +654,13 @@ class Cluster:
             for p in self.pods.values()
             if self._pending_eligible(p)
         ]
+
+    def pending_count(self) -> int:
+        """`len(pending_pods())`: the index's size when it is on, the scan
+        otherwise."""
+        if self._pending_idx is not None:
+            return len(self._pending_idx)
+        return len(self.pending_pods())
 
     def admission_serial(self, uid: str) -> int:
         """The pod's position in admission order — the reproducible

@@ -479,6 +479,15 @@ FEED_EVENT_NS = "scheduler_feed_event_ns_total"
 #: over the ledger ring, the thread census, the JSON, and the handler's
 #: own waits for the interpreter lock (histogram, one observation a poll)
 HEALTHZ_HANDLER_MS = "scheduler_healthz_handler_ms"
+#: ticks `Daemon.run` started (leader-election standby ticks included:
+#: what `--max-cycles` counts)
+TICKS = "scheduler_ticks_total"
+#: the same ticks by what ended the wait before them (labels: reason ∈
+#: demand | interval): a pod entered the pending set and the last tick's
+#: length allowed an early start, or the cycle interval was up (the first
+#: tick, a standby's, and every tick that cost a sixth of the interval or
+#: more). The two add up to TICKS
+TICK_WAKEUPS = "scheduler_tick_wakeups_total"
 
 #: `# HELP` registry for `prometheus_text` (exposition format 0.0.4
 #: requires families to be self-describing; families not listed here get
@@ -572,6 +581,10 @@ HELP: dict[str, str] = {
     HEALTHZ_HANDLER_MS:
         "Wall ms of one GET /healthz inside its handler, entry to reply "
         "written.",
+    TICKS: "Ticks the daemon's loop started.",
+    TICK_WAKEUPS:
+        "Ticks by what ended the wait before them: a pod became "
+        "schedulable (demand) or the cycle interval was up (interval).",
 }
 
 
@@ -794,12 +807,14 @@ class Tracer:
 
     @contextmanager
     def span(self, name: str, tid: str = "host", **args):
+        """Yields the span's `args`: what the caller adds to them inside
+        the span (what it learned there) is recorded with it."""
         if not self._enabled:
-            yield
+            yield args
             return
         start_ns = self.now_ns()
         try:
-            yield
+            yield args
         finally:
             self.complete(
                 name, start_ns, self.now_ns() - start_ns, tid=tid,

@@ -144,3 +144,41 @@ class TestElasticQuotaController:
         assert events == ["Normal Synced ns/q"]
         # idempotent: no event when nothing changed
         assert reconcile_elastic_quotas(c) == []
+
+    def test_without_quotas_no_pod_is_walked(self):
+        class Unwalkable(dict):
+            def _refuse(self, *args):
+                raise AssertionError("the pods were walked")
+
+            __iter__ = keys = values = items = _refuse
+
+        c = Cluster()
+        c.add_pod(member("r1", PodPhase.RUNNING, ns="ns", cpu=300))
+        c.pods = Unwalkable(c.pods)
+        assert reconcile_elastic_quotas(c) == []
+        # with a quota the same store is walked again
+        c.add_quota(ElasticQuota(name="q", namespace="ns", min={CPU: 1000}))
+        try:
+            reconcile_elastic_quotas(c)
+        except AssertionError as walked:
+            assert "walked" in str(walked)
+        else:
+            raise AssertionError("a quota's used was summed from no pod")
+
+    def test_each_quota_sums_its_own_namespace(self):
+        c = Cluster()
+        a = ElasticQuota(name="a", namespace="ns-a", min={CPU: 1000})
+        b = ElasticQuota(name="b", namespace="ns-b", min={CPU: 1000})
+        c.add_quota(a)
+        c.add_quota(b)
+        c.add_pod(member("a1", PodPhase.RUNNING, ns="ns-a", cpu=300))
+        c.add_pod(member("a2", PodPhase.RUNNING, ns="ns-a", cpu=200))
+        c.add_pod(member("x1", PodPhase.RUNNING, ns="ns-x", cpu=900))
+        assert reconcile_elastic_quotas(c) == ["Normal Synced ns-a/a"]
+        assert (a.used, b.used) == ({CPU: 500}, {})
+        c.remove_pod("ns-a/a2")
+        c.add_pod(member("b1", PodPhase.RUNNING, ns="ns-b", cpu=50))
+        assert reconcile_elastic_quotas(c) == [
+            "Normal Synced ns-a/a", "Normal Synced ns-b/b",
+        ]
+        assert (a.used, b.used) == ({CPU: 300}, {CPU: 50})

@@ -700,9 +700,20 @@ class TestServedLoopTrace:
                 ), proc.stderr.read() if proc.poll() is not None else ""
                 urllib.request.urlopen(status["health"], timeout=5).read()
             client.close()
-            metrics = urllib.request.urlopen(
-                status["health"].replace("/healthz", "/metrics"), timeout=5
-            ).read().decode()
+            # a poll is observed once its reply is written, so the reply
+            # can reach this client before the observation the registry
+            metrics = ""
+
+            def polls_observed():
+                nonlocal metrics
+                metrics = urllib.request.urlopen(
+                    status["health"].replace("/healthz", "/metrics"),
+                    timeout=5,
+                ).read().decode()
+                return (f"scheduler_healthz_handler_ms_count {self.WAVES}"
+                        in metrics.splitlines())
+
+            _wait(polls_observed, timeout=10)
         finally:
             proc.send_signal(signal.SIGTERM)
             stdout, err = proc.communicate(timeout=60)

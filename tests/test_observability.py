@@ -251,6 +251,7 @@ class TestServedLoopNames:
 
     @pytest.mark.parametrize("name", [
         obs.FEED_EVENTS, obs.FEED_EVENT_NS, obs.HEALTHZ_HANDLER_MS,
+        obs.TICKS, obs.TICK_WAKEUPS,
     ])
     def test_help_covers_the_new_names(self, name):
         assert name.startswith("scheduler_") and obs.HELP[name]

@@ -13,11 +13,13 @@ import numpy as np
 import pytest
 
 from scheduler_plugins_tpu.api.objects import (
+    POD_GROUP_LABEL,
     REGION_LABEL,
     ZONE_LABEL,
     Container,
     Node,
     Pod,
+    PodGroup,
 )
 from scheduler_plugins_tpu.api.resources import CPU, MEMORY, PODS
 from scheduler_plugins_tpu.framework import (
@@ -31,7 +33,10 @@ from scheduler_plugins_tpu.framework.preemption import (
     PreemptionEngine,
     PreemptionMode,
 )
-from scheduler_plugins_tpu.plugins import NodeResourcesAllocatable
+from scheduler_plugins_tpu.plugins import (
+    Coscheduling,
+    NodeResourcesAllocatable,
+)
 from scheduler_plugins_tpu.serving import ServeEngine, StreamingServeEngine
 from scheduler_plugins_tpu.state.cluster import Cluster
 
@@ -44,10 +49,10 @@ def mknode(name, cpu=16_000):
     )
 
 
-def mkpod(name, cpu=500, priority=0, node=None, created=0):
+def mkpod(name, cpu=500, priority=0, node=None, created=0, **kw):
     p = Pod(
         name=name, creation_ms=created, priority=priority,
-        containers=[Container(requests={CPU: cpu, MEMORY: gib})],
+        containers=[Container(requests={CPU: cpu, MEMORY: gib})], **kw,
     )
     p.node_name = node
     return p
@@ -119,6 +124,123 @@ class TestPendingIndex:
             a = [p.uid for p in indexed.pending_pods()]
             b = [p.uid for p in scan.pending_pods()]
             assert a == b, (step, a[:4], b[:4])
+
+    #: what each churn mixes into a stream of plain arrivals, and the
+    #: profile it runs under (ISSUE 25: the daemon's serial engine reads
+    #: the index too)
+    SERIAL_CHURNS = {
+        "gates_lifted": lambda: Profile(plugins=[NodeResourcesAllocatable()]),
+        "gangs_reserve_and_release": lambda: Profile(plugins=[
+            NodeResourcesAllocatable(),
+            Coscheduling(permit_waiting_seconds=2, reject_percentage=100),
+        ]),
+        "nominations": lambda: Profile(
+            plugins=[NodeResourcesAllocatable()],
+            preemption=PreemptionEngine(PreemptionMode.DEFAULT),
+        ),
+        "delete_and_readd": lambda: Profile(
+            plugins=[NodeResourcesAllocatable()]
+        ),
+    }
+
+    @staticmethod
+    def _scan(c):
+        """`pending_pods()` as the store answers it with the index off."""
+        idx, c._pending_idx = c._pending_idx, None
+        try:
+            return c.pending_pods()
+        finally:
+            c._pending_idx = idx
+
+    @pytest.mark.parametrize("churn", sorted(SERIAL_CHURNS))
+    def test_serial_engine_index_equals_scan(self, churn):
+        """Seeded churn through `run_cycle` on an indexed store: after
+        every cycle the index yields the scan's list, order included."""
+        rng = np.random.default_rng(25)
+        c = Cluster()
+        for i in range(2):
+            c.add_node(mknode(f"n{i}", cpu=4000))
+        c.enable_pending_index()
+        sched = Scheduler(self.SERIAL_CHURNS[churn]())
+        gains = []
+        c.on_pending_gain = lambda: gains.append(1)
+        serial = 0
+
+        def name():
+            nonlocal serial
+            serial += 1
+            return f"p{serial}"
+
+        def some(uids):
+            uids = list(uids)
+            return uids[int(rng.integers(len(uids)))] if uids else None
+
+        seen = {"bound": 0, "reserved": 0, "released": 0, "nominated": 0,
+                "lifted": 0, "readded": 0}
+        for step in range(40):
+            now = 1000 * (step + 1)
+            if len(self._scan(c)) < 5:
+                c.add_pod(mkpod(name(), cpu=500, created=step))
+            gated = [p.uid for p in c.gated_pods()]
+            bound = [u for u, p in c.pods.items() if p.node_name]
+            r = rng.random()
+            if churn == "gates_lifted":
+                if r < 0.5:
+                    c.add_pod(mkpod(name(), created=step,
+                                    scheduling_gated=True))
+                elif gated:
+                    # the gate lifts as the feed applies it: an upsert
+                    old = c.pods[some(gated)]
+                    c.add_pod(mkpod(old.name, created=old.creation_ms))
+                    seen["lifted"] += 1
+            elif churn == "gangs_reserve_and_release":
+                if step % 8 == 0:
+                    # three members of 1,500 m: two reserve where there is
+                    # room, the third waits; the timer releases them
+                    gang = f"g{step}"
+                    c.add_pod_group(PodGroup(
+                        name=gang, namespace="default", min_member=3,
+                    ))
+                    for _ in range(3):
+                        c.add_pod(mkpod(
+                            name(), cpu=1500, created=step,
+                            labels={POD_GROUP_LABEL: gang},
+                        ))
+            elif churn == "nominations":
+                if r < 0.4:
+                    c.add_pod(mkpod(name(), cpu=3000, created=step,
+                                    priority=int(rng.integers(1, 50))))
+                for uid in [u for u, p in c.pods.items() if p.terminating]:
+                    c.remove_pod(uid)  # the victim's delete arrives
+            elif churn == "delete_and_readd":
+                uid = some(c.pods)
+                if uid is not None and r < 0.7:
+                    old = c.pods[uid]
+                    c.remove_pod(uid)
+                    if r < 0.5:
+                        c.add_pod(mkpod(old.name, created=old.creation_ms))
+                        seen["readded"] += 1
+            if bound and rng.random() < 0.5:
+                c.remove_pod(some(bound))  # departures make room again
+            before = set(c.reserved)
+            report = run_cycle(sched, c, now=now)
+            seen["bound"] += len(report.bound)
+            seen["reserved"] += len(report.reserved)
+            seen["released"] += len(before - set(c.reserved) - set(report.bound))
+            seen["nominated"] += len(report.preempted)
+            want = [p.uid for p in self._scan(c)]
+            assert [p.uid for p in c.pending_pods()] == want, (churn, step)
+            assert c.pending_count() == len(want)
+        # the churn did what its name says, and the hook fired for every
+        # pod that entered the set (at least once per pod ever bound)
+        assert seen["bound"] > 5 and len(gains) >= seen["bound"]
+        wanted = {"gates_lifted": "lifted",
+                  "gangs_reserve_and_release": "released",
+                  "nominations": "nominated",
+                  "delete_and_readd": "readded"}[churn]
+        assert seen[wanted] > 0, seen
+        if churn == "gangs_reserve_and_release":
+            assert seen["reserved"] > 0, seen
 
     def test_inplace_flip_needs_reindex(self):
         """In-place eligibility flips bypass the mutators (the delta
