@@ -25,7 +25,7 @@ Protocol with the parent, one JSON object per line:
 
 from __future__ import annotations
 
-import importlib
+import collections
 import json
 import os
 import sys
@@ -34,8 +34,7 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-from harness import cluster_gen as gen  # noqa: E402
-from harness import wire  # noqa: E402
+from harness import spec, wire  # noqa: E402
 
 
 def say(**message) -> None:
@@ -58,8 +57,7 @@ class Context:
         self.interval_ns = int(plan["cycle_interval_s"] * 1e9)
         self.cooldown_ns = int(plan["cooldown_s"] * 1e9)
         self.feed = wire.Feed(*plan["feed"])
-        self.nodes = gen.node_specs(self.cluster, self.seed)
-        self._requests = gen.stream(self.seed, "arrivals")
+        self.population = spec.population(self.config, self.seed)
         # parent's announcements
         self.window_start_ns = None
         self.window_end_ns = None
@@ -70,15 +68,24 @@ class Context:
         self._bound_base = 0
         self._bound = 0
         self.polls = []  # (t_ns, bound_total, pending, cycles, rtt_ns)
-        # samples
-        self.due = []     # per arrival: when it was due
+        # samples, one entry per arriving pod that is to bind (the pods of
+        # one unit share its due time)
+        self.due = []     # when it was due
         self.sent = []    # ... when its line was written
         self.acked = []   # ... when its ack was read
-        self.delete_ack_ns = []  # (sent_ns, ack latency ns) per delete
+        self.uids = []    # ... the uid the store knows it by
+        #: (sent_ns, ack latency ns) of every other line: a delete, a unit's
+        #: head, the pods of a unit that is not to bind
+        self.other_ack_ns = []
         self.refused = 0
-        self.deletes = 0
+        self.deletes = 0  # pods taken away again
+        self.held = 0     # pods of units that are not to bind: they stay
+        self.units_sent = 0
         self.forgiven_ns = 0
-        self.prefilled = []
+        self.prefilled = []  # the prefill's units
+        #: (pods, removal lines) of the units sent, to bind, not departed
+        self._live = collections.deque()
+        self._departed = 0   # pods of the arrivals that have departed
         self._side = []  # [next due ns, spec, issue number]
         self._marked = 0
 
@@ -131,27 +138,57 @@ class Context:
 
     # -- traffic ------------------------------------------------------------
     def arrive(self, due_ns: int) -> int:
-        """Send the next arrival, due at `due_ns`; returns how late its ack
-        came back. The arrival's index is its creation stamp and part of
-        its name, `a-<index>`."""
-        index = len(self.due)
-        cpu, mem = gen.draw_request(self._requests, self.cluster["pod_requests"])
-        line = gen.pod_line(f"a-{index:07d}", index, cpu, mem)
-        sent = self.now()
-        ack = self.feed.send_line(line)
-        acked = self.now()
-        self.refused += wire.refused(ack)
-        self.due.append(due_ns)
-        self.sent.append(sent)
-        self.acked.append(acked)
+        """Send the population's next unit of arrival, due at `due_ns`, one
+        acknowledged line at a time; returns how late its last ack came
+        back. Every pod of the unit is an arrival due at `due_ns`."""
+        head, pods, uids, removal, binds = self.population.unit(
+            "arrivals", self.units_sent
+        )
+        self.units_sent += 1
+        if not binds:
+            self._send_other(head + pods)
+            self.held += len(pods)
+            return self.now() - due_ns
+        if head:
+            self._send_other(head)
+        self.uids.extend(uids)  # first: `listen` slices by `acked`
+        for line in pods:
+            sent = self.now()
+            ack = self.feed.send_line(line)
+            acked = self.now()
+            self.refused += wire.refused(ack)
+            self.due.append(due_ns)
+            self.sent.append(sent)
+            self.acked.append(acked)
+        self._live.append((len(pods), removal))
         return acked - due_ns
 
-    def delete(self, name: str) -> None:
-        sent = self.now()
-        ack = self.feed.send_line(gen.delete_line(name))
-        self.delete_ack_ns.append((sent, self.now() - sent))
-        self.deletes += 1
-        self.refused += wire.refused(ack)
+    def _send_other(self, lines) -> None:
+        for line in lines:
+            sent = self.now()
+            ack = self.feed.send_line(line)
+            self.other_ack_ns.append((sent, self.now() - sent))
+            self.refused += wire.refused(ack)
+
+    def remove(self, unit) -> None:
+        """Take a unit away again, through its own removal lines."""
+        self._send_other(unit.removal)
+        self.deletes += len(unit.pods)
+
+    def depart_oldest(self, behind: int = 0) -> bool:
+        """Take away the oldest arrival still there, if the polled bound
+        count is `behind` pods or more past its last pod: it has bound, by
+        the order pods are solved in. A pending pod is never deleted."""
+        if not self._live:
+            return False
+        size, removal = self._live[0]
+        if self._departed + size > self._bound - self._bound_base - behind:
+            return False
+        self._live.popleft()
+        self._send_other(removal)
+        self.deletes += size
+        self._departed += size
+        return True
 
     def forgive(self, late_ns: int) -> None:
         """Warm-up only: a stall of several intervals is a program being
@@ -169,9 +206,7 @@ class Context:
             self._side.append([now + int(spec["period_s"] * 1e9), spec, 1])
 
     def _side_line(self, spec: dict, issue: int) -> bytes:
-        if spec["kind"] != "node_metrics":
-            raise ValueError(f"unknown side event kind {spec['kind']!r}")
-        return gen.node_metrics_line(self.nodes, spec, self.seed, issue)
+        return self.population.side(spec, issue)
 
     def phase_side_events(self) -> None:
         """The window is known now: put one report of each kind at its
@@ -210,26 +245,28 @@ def listen(ctx: Context) -> None:
         if "mark" in message:
             first, ctx._marked = ctx._marked, len(ctx.acked)
             with open(message["mark"], "w") as f:
-                json.dump({"first": first,
+                json.dump({"uids": ctx.uids[first:ctx._marked],
                            "due_ns": ctx.due[first:ctx._marked],
                            "sent_ns": ctx.sent[first:ctx._marked]}, f)
             say(event="marked", path=message["mark"])
         if message.get("stop"):
             ctx.stopped = True
+    # end of input: the parent is gone (it never closes this pipe while it
+    # lives), so there is nobody to report to and nothing may be left behind
+    os._exit(1)
 
 
 def load(ctx: Context) -> dict:
-    """Set-up traffic: the nodes, the mix's prefill of bound pods, the
-    configuration's first side events, then a `sync` fence."""
+    """Set-up traffic: the population's nodes, its objects, the mix's
+    prefill of bound pods, the configuration's first side events, then a
+    `sync` fence."""
     t0 = ctx.now()
-    refused = ctx.feed.send_all(gen.node_line(n) for n in ctx.nodes)
+    refused = ctx.feed.send_all(ctx.population.nodes())
+    refused += ctx.feed.send_all(ctx.population.objects())
     t1 = ctx.now()
-    ctx.prefilled = gen.prefill(
-        ctx.cluster, ctx.nodes, ctx.mix["prefill_bound_pods"], ctx.seed
-    )
+    ctx.prefilled = ctx.population.prefill(ctx.mix["prefill_bound_pods"])
     refused += ctx.feed.send_all(
-        gen.pod_line(name, 0, cpu, mem, node)
-        for name, cpu, mem, node in ctx.prefilled
+        line for unit in ctx.prefilled for line in unit.head + unit.pods
     )
     t2 = ctx.now()
     ctx.plan_side_events()
@@ -242,12 +279,12 @@ def load(ctx: Context) -> dict:
 
 
 def drain(ctx: Context) -> dict:
-    """After the last arrival: fence until nothing is pending. A pod counts
-    as failed by when it bound (the parent holds it to the grace), not by
-    how long this takes."""
+    """After the last arrival: fence until nothing is pending but the pods
+    that are not to bind. A pod counts as failed by when it bound (the
+    parent holds it to the grace), not by how long this takes."""
     deadline = ctx.now() + int(ctx.plan["drain_limit_s"] * 1e9)
     ack = ctx.feed.send({"op": "sync"})
-    while ack.get("pending") and ctx.now() < deadline:
+    while ack.get("pending", 0) > ctx.held and ctx.now() < deadline:
         time.sleep(0.05)
         ack = ctx.feed.send({"op": "sync"})
     return ack
@@ -258,16 +295,18 @@ def report(ctx: Context, sync_ack: dict) -> dict:
     window = [i for i, due in enumerate(ctx.due) if t0 <= due < t1]
     sent_in = [i for i, sent in enumerate(ctx.sent) if t0 <= sent < t1]
     return {
-        "arrivals": len(ctx.due), "deletes": ctx.deletes,
-        "prefilled": len(ctx.prefilled), "refused": ctx.refused,
+        # pods: sent to bind, sent to stay pending, taken away, prefilled
+        "arrivals": len(ctx.due), "held": ctx.held, "deletes": ctx.deletes,
+        "prefilled": sum(len(unit.pods) for unit in ctx.prefilled),
+        "units": ctx.units_sent, "refused": ctx.refused,
         "forgiven_s": ctx.forgiven_ns / 1e9,
-        # arrivals due inside the window: index and due stamp
-        "window_first": window[0] if window else None,
+        # arrivals due inside the window: uid and due stamp
+        "window_uids": [ctx.uids[i] for i in window],
         "window_due_ns": [ctx.due[i] for i in window],
         "window_late_ns": [ctx.sent[i] - ctx.due[i] for i in window],
         # every send whose line was written inside the window
         "window_ack_ns": [ctx.acked[i] - ctx.sent[i] for i in sent_in]
-        + [lat for sent, lat in ctx.delete_ack_ns if t0 <= sent < t1],
+        + [lat for sent, lat in ctx.other_ack_ns if t0 <= sent < t1],
         "polls": ctx.polls,
         "bound_base": ctx._bound_base,
         "sync": sync_ack,
@@ -277,6 +316,7 @@ def report(ctx: Context, sync_ack: dict) -> dict:
 
 def main() -> int:
     plan = json.loads(sys.stdin.readline())
+    spec.use_index(plan["index"])
     ctx = Context(plan)
     say(event="hello", ns=ctx.now(), pid=os.getpid())
     threading.Thread(target=listen, args=(ctx,), daemon=True,
@@ -289,7 +329,7 @@ def main() -> int:
     poller = threading.Thread(target=ctx.poll_forever, daemon=True,
                               name="client-poll")
     poller.start()
-    generator = importlib.import_module(f"generators.{ctx.mix['generator']}")
+    generator = spec.load_module("generators", ctx.mix["generator"])
     say(event="generating", ns=ctx.now())
     generator.run(ctx)
     sync_ack = drain(ctx)

@@ -5,7 +5,8 @@ a restart. The client sends as fast as acks allow while fewer than K pods
 are outstanding (sent minus the polled bound count), and deletes bound
 arrivals at the same pace, oldest first, once they are more than K behind
 the bound count, so occupancy is stationary. An arrival is due the moment
-the loop is free to send it. A pending pod is never deleted.
+the loop is free to send it. A pending pod is never deleted. Arrivals come
+and go by the population's unit (a pod; a gang); K and the counts are pods.
 """
 
 from __future__ import annotations
@@ -15,7 +16,6 @@ import time
 
 def run(ctx) -> None:
     outstanding = ctx.cell["outstanding"]
-    departed = 0
     while True:
         now = ctx.now()
         if ctx.over(now):
@@ -24,8 +24,6 @@ def run(ctx) -> None:
         bound = ctx.bound_total()
         if len(ctx.due) - bound < outstanding:
             ctx.arrive(now)
-            if departed < bound - outstanding:
-                ctx.delete(f"a-{departed:07d}")
-                departed += 1
+            ctx.depart_oldest(behind=outstanding)
         else:
             time.sleep(0.002)

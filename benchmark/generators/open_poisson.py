@@ -20,10 +20,9 @@ FORGIVE_INTERVALS = 3
 
 def run(ctx) -> None:
     gaps = gen.stream(ctx.seed, "gaps")
-    victims = [name for name, *_ in ctx.prefilled]
+    victims = list(ctx.prefilled)
     gen.stream(ctx.seed, "departures").shuffle(victims)
     victims.reverse()  # pop() takes them in the shuffled order
-    departed_arrivals = 0
     due = ctx.now()
     while True:
         due += int(gaps.expovariate(ctx.rate()) * 1e9)
@@ -35,7 +34,6 @@ def run(ctx) -> None:
             ctx.forgive(late)
             due += late
         if victims:
-            ctx.delete(victims.pop())
-        elif departed_arrivals < ctx.bound_total():
-            ctx.delete(f"a-{departed_arrivals:07d}")
-            departed_arrivals += 1
+            ctx.remove(victims.pop())
+        else:
+            ctx.depart_oldest()

@@ -10,12 +10,13 @@ from harness import stats
 
 
 def window_pods(run) -> list:
-    """[(due ns, bind ns or None), ...] for the arrivals due in the window;
-    arrival i is the pod `default/a-<i>`."""
-    first = run.client["window_first"] or 0
+    """[(due ns, bind ns or None), ...] for the arrivals due in the window,
+    joined on the uids the client's report gives them."""
+    bound_at = run.bound_at
     return [
-        (due, run.bound_at.get(f"default/a-{first + i:07d}"))
-        for i, due in enumerate(run.client["window_due_ns"])
+        (due, bound_at.get(uid))
+        for uid, due in zip(run.client["window_uids"],
+                            run.client["window_due_ns"])
     ]
 
 

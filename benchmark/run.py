@@ -69,6 +69,10 @@ def parse_args(argv=None):
     ap.add_argument("--rehearse-cpu", action="store_true",
                     help="the same code on the CPU backend, at the size of "
                          "the rehearsal blocks of the cell's files")
+    ap.add_argument("--index", default=None,
+                    help="another index than the repo's BENCHMARK.json (a "
+                         "test fixture's); files are looked for beside it "
+                         "first")
     return ap.parse_args(argv)
 
 
@@ -90,7 +94,7 @@ class Run:
         self.error = None
         self.child = None
         self.plan = None
-        self.warmed = 0
+        self.warmed = (0, 0)
         self.result = None
         # what the readers see
         self.t_process_start_ns = T_PROCESS_START_NS
@@ -185,6 +189,7 @@ class Run:
         cell, daemon = self.cell, self.daemon
         interval_s = daemon.args.cycle_interval_s
         self.plan = {
+            "index": str(spec.INDEX_PATH),
             "feed": list(daemon.feed.address),
             "health": "http://%s:%d/healthz" % daemon.health.address,
             "seed": self.args.seed, "cell": cell.params, "mix": cell.mix,
@@ -224,7 +229,7 @@ class Run:
         self.info("warmed", pod_counts=cell.params.get("warm_pod_counts", []),
                   seconds=(time.monotonic_ns() - t0) / 1e9,
                   compile_events=self.compile_events())
-        self.warmed = self.ledger.pods_bound
+        self.warmed = (self.ledger.pods_bound, self.ledger.pods_deleted)
         self._say(go=True)
         return self._hear("generating", CLIENT_REPLY_S)["ns"]
 
@@ -312,12 +317,9 @@ class Run:
         t0, t1 = self.window
         problems = checks.client_counts(report, self.ledger, self.warmed)
         with daemon.feed.locked():
-            problems += checks.capacity_audit(daemon.cluster)
+            problems += checks.audits(cell, daemon.cluster)
         resident = bool(cell.config["resident_state"])
-        if resident:
-            problems += checks.resident_state(daemon)
-        elif daemon.engine is not None and daemon.engine.rebases:
-            problems.append("a configuration without resident state rebased")
+        problems += checks.resident_state(daemon, resident)
 
         # a pod due inside the window has the cool-down and the mix's grace
         # to bind; one that took longer, or never bound, has failed
@@ -329,6 +331,7 @@ class Run:
         unbound = sum(1 for _due, at in pods if at is None or at > limit)
         batches = [n for at, n, _ in self.cycles if n and t0 <= at < t1]
         probe_size = sorted(batches)[(len(batches) - 1) // 2] if batches else 0
+        result = {}
         if probe_size:
             result = checks.probe(daemon, cell, self.args.seed, probe_size)
             self.info("probe", **result)
@@ -337,6 +340,24 @@ class Run:
             problems.append("no pod was bound inside the window")
         for problem in problems:
             self.info("problem", what=problem)
+        # every number compared, beside its limit; the comparisons are exact
+        compared = {
+            name: {"value": value, "limit": limit}
+            for name, value, limit in (
+                ("pending_after_drain", report["sync"].get("pending"),
+                 report["held"]),
+                ("bound_of_arrivals",
+                 report["healthz"]["bound_total"] - report["bound_base"],
+                 report["arrivals"]),
+                ("events_refused", report["refused"], 0),
+                ("probe_cycles_min", result.get("cycles", 0), 1),
+                ("probe_slots_differing", result.get("mismatches"), 0),
+                ("probe_hard_violations", result.get("hard_violations"), 0),
+                ("probe_reference_binds_unbound",
+                 result.get("reference_unbound"), 0),
+                ("problems", len(problems), 0),
+            )
+        }
 
         if self.args.trace:
             self._reduce_trace()
@@ -368,27 +389,32 @@ class Run:
             "failed": unbound + report["refused"],
             "metrics": metrics, "device": device,
         }
-        if self.device_trace is not None:
-            from harness import trace_reduce
+        self._attach_trace(device)
+        self.result["compared"] = compared  # the line's last key
 
-            w0, w1 = self.device_trace["window_ns"]
-            device["busy_s"] = self.device_trace["busy_ns"] / 1e9
-            device["window_s"] = (w1 - w0) / 1e9
-            spans = [
-                (name, s - self.trace_offset_ns, e - self.trace_offset_ns)
-                for name, s, e, _ in self.spans
-            ]
-            self.result["breakdown"] = {
-                "device_ops": trace_reduce.top(self.device_trace["op_ns"], 10),
-                "idle_gaps": trace_reduce.top(
-                    trace_reduce.idle_by_span(self.device_trace["gaps"], spans),
-                    10,
-                ),
-            }
-            self.info("device_modules", modules={
-                name: {"device_s": ns / 1e9, "runs": runs}
-                for name, (ns, runs) in self.device_trace["modules"].items()
-            })
+    def _attach_trace(self, device: dict) -> None:
+        if self.device_trace is None:
+            return
+        from harness import trace_reduce
+
+        w0, w1 = self.device_trace["window_ns"]
+        device["busy_s"] = self.device_trace["busy_ns"] / 1e9
+        device["window_s"] = (w1 - w0) / 1e9
+        spans = [
+            (name, s - self.trace_offset_ns, e - self.trace_offset_ns)
+            for name, s, e, _ in self.spans
+        ]
+        self.result["breakdown"] = {
+            "device_ops": trace_reduce.top(self.device_trace["op_ns"], 10),
+            "idle_gaps": trace_reduce.top(
+                trace_reduce.idle_by_span(self.device_trace["gaps"], spans),
+                10,
+            ),
+        }
+        self.info("device_modules", modules={
+            name: {"device_s": ns / 1e9, "runs": runs}
+            for name, (ns, runs) in self.device_trace["modules"].items()
+        })
 
     def _compiled_in_window(self) -> dict:
         """{counter: delta} of the compile counters that moved inside the
@@ -468,6 +494,8 @@ def main(argv=None) -> int:
 def execute(args, run_class) -> int:
     """Build the daemon, run it with `run_class`'s controller beside it,
     print the controller's result as the last line."""
+    if args.index:
+        spec.use_index(args.index)
     cell = spec.Cell(args.workload, rehearse=args.rehearse_cpu)
     if args.seconds is None:
         args.seconds = float(spec.index()["run_seconds"])
@@ -547,6 +575,10 @@ def execute(args, run_class) -> int:
             and not exit_line["parked_cycles"] and not exit_line["degraded"]):
         run.info("problem", what=f"daemon exit line not clean: {exit_line}")
         run.result["correct"] = False
+    for name, numbers in run.result.get("compared", {}).items():
+        print(f"compared {name}: {numbers['value']} (limit {numbers['limit']})",
+              file=sys.stderr)
+    print(f"correct: {run.result.get('correct')}", file=sys.stderr, flush=True)
     print(json.dumps(run.result), file=out, flush=True)
     return 0
 

@@ -53,9 +53,8 @@ class Sweep(harness_run.Run):
             time.sleep(2 * interval_ns / 1e9)  # let the last arrivals bind
             bound_at = dict(self.binds)
             delays = [
-                (bound_at.get(f"default/a-{marked['first'] + i:07d}",
-                              float("inf")) - due) / 1e6
-                for i, due in enumerate(marked["due_ns"])
+                (bound_at.get(uid, float("inf")) - due) / 1e6
+                for uid, due in zip(marked["uids"], marked["due_ns"])
             ]
             late = [s - d for s, d in zip(marked["sent_ns"], marked["due_ns"])]
             third = (t1 - t0) // 3
@@ -115,6 +114,7 @@ def main(argv=None) -> int:
                          "place of the cell's: a sweep crosses more buckets "
                          "than the cell's own rate does")
     ap.add_argument("--rehearse-cpu", action="store_true")
+    ap.add_argument("--index", default=None)
     args = ap.parse_args(argv)
     args.trace = 0
     return harness_run.execute(args, Sweep)
