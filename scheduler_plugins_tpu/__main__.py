@@ -750,7 +750,6 @@ class Daemon:
                 )
         self.cycles = 0
         self.ticks = 0
-        self.bound_total = 0
         self.last_pending = 0
         self.last_quality = None
         self.last_memory = None  # /healthz device-memory block (ISSUE 20)
@@ -800,6 +799,16 @@ class Daemon:
                 )
                 t.start()
                 self._agent_threads.append(t)
+
+    @property
+    def bound_total(self) -> int:
+        """Pods this daemon has bound: the store's own count of its binds
+        (`Cluster.binds_total`), which moves with the bind itself. A sum of
+        `len(report.bound)` kept by the tick moved in the tick's tail,
+        after the cycle gave the feed lock up: a client that saw the last
+        pod bound on the feed and then read `/healthz` could read a count
+        a whole batch behind the store (PERF.md, PR 28)."""
+        return self.cluster.binds_total
 
     def _tuner_state_path(self) -> str:
         """The tuner's persisted controller state rides NEXT TO the
@@ -966,7 +975,6 @@ class Daemon:
                     else:
                         failures += 1
         self.cycles += 1
-        self.bound_total += len(report.bound)
         if report.quality is not None:
             self.last_quality = report.quality
         # device-memory watermark gauges: one allocator-stats read per

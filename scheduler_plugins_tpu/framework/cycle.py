@@ -523,6 +523,8 @@ def _bind_decisions(ctx: CycleCtx) -> None:
             else:
                 cluster.bind(pod.uid, node_name, now)
                 report.bound[pod.uid] = node_name
+    # before the Permit fan-out and a rejection take any of them back
+    obs.metrics.inc(obs.GANG_WAIT_PODS, len(report.reserved))
 
     if ctx.serve_t0 is not None:
         # serve-mode decision latency: delta ingest through host-visible
@@ -602,8 +604,15 @@ def _postbind_store(ctx: CycleCtx, attribution: bool) -> None:
         "PostFilter", type(engine).__name__ if engine else "none",
         tid="framework" if ctx.tid == "cycle" else ctx.tid,
         failed=len(report.failed),
-    ):
-        _run_preemption(ctx.scheduler, cluster, ctx.pending, report, now)
+    ) as said:
+        # the host's victim search: how many of the failed pods it was
+        # run for (none of a gang rejected whole), and what it found
+        said["candidates"] = _run_preemption(
+            ctx.scheduler, cluster, ctx.pending, report, now
+        )
+        said["victims"] = sum(
+            len(victims) for _node, victims in report.preempted.values()
+        )
     obs.metrics.inc(obs.PODS_BOUND, len(report.bound))
     obs.metrics.inc(obs.PODS_FAILED, len(report.failed))
     obs.metrics.inc(obs.GANG_REJECTIONS, len(report.rejected_gangs))
@@ -832,6 +841,8 @@ def _attribute_failures(scheduler, snap, result, failed_idx, report,
             name = names[code] if code > 0 else names[0]
             report.failed_by[uid] = name
             obs.metrics.inc(obs.UNSCHEDULABLE_BY_PLUGIN, plugin=name)
+            if name == "CapacityScheduling":
+                obs.metrics.inc(obs.QUOTA_REFUSALS)
             if led is not None:
                 # blame fills IN PLACE on the observing cycle's
                 # Unschedulable event: this decode may run in the NEXT
@@ -938,7 +949,8 @@ def _run_preemption(scheduler, cluster, pending, report, now):
     """PostFilter preemption: for each still-failed pod in queue order, dry
     run victim removal across all nodes, nominate the best candidate, mark
     victims terminating (the apiserver DELETE boundary in the reference)
-    and record the nomination (SURVEY.md §3.3).
+    and record the nomination (SURVEY.md §3.3). Returns how many pods the
+    search was run for.
 
     Runs against a FRESH snapshot (this cycle's binds must count as node
     usage, or just-bound pods double as phantom victims) and threads the
@@ -947,7 +959,8 @@ def _run_preemption(scheduler, cluster, pending, report, now):
     nominated pods)."""
     engine = scheduler.profile.preemption
     if engine is None or not report.failed:
-        return
+        return 0
+    candidates = 0
     rejected = set(report.rejected_gangs)
     by_uid = {p.uid: p for p in pending}
     failed_pods = [by_uid[uid] for uid in report.failed if uid in by_uid]
@@ -993,6 +1006,7 @@ def _run_preemption(scheduler, cluster, pending, report, now):
         pg = cluster.pod_group_of(pod)
         if pg is not None and pg.full_name in rejected:
             continue  # the whole gang was rejected; no point preempting
+        candidates += 1
         obs.metrics.inc(obs.PREEMPTION_ATTEMPTS)
         # PodEligibleToPreemptOthers runs inside preempt(): while pods this
         # pod could benefit from are still terminating on its nominated
@@ -1049,6 +1063,7 @@ def _run_preemption(scheduler, cluster, pending, report, now):
         holds.append((n, demand, pod.priority, pod.uid))
         nominated_extra[n] -= victim_freed
         report.preempted[pod.uid] = (result.nominated_node, result.victims)
+    return candidates
 
 
 def _refresh_metrics(scheduler, cluster: Cluster, now: int):

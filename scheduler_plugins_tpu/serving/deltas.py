@@ -25,9 +25,9 @@ Delta classification (the `api.events` kinds each group expresses):
   `state.snapshot.build_snapshot` performs — scatter-add, where duplicate
   indices are well-defined (sum) and padded rows are zero.
 - Node/Delete (and anything the scatter programs cannot express — row
-  reordering, label re-interning, extended resources) re-bases instead:
-  `api.events.SERVE_REBASE_EVENTS`, the same rule the C++ columnar
-  mirror applies (`Cluster._native_rebuild`).
+  reordering, label re-interning, a resource the engine's axis does not
+  hold yet) re-bases instead: `api.events.SERVE_REBASE_EVENTS`, the same
+  rule the C++ columnar mirror applies (`Cluster._native_rebuild`).
 
 Both groups are padded to `utils.intmath.bucket_size` buckets so the jit
 cache stays warm across cycles (distinct (U, K) bucket pairs retrace once
@@ -46,53 +46,52 @@ from scheduler_plugins_tpu.resilience import faults as _faults
 from scheduler_plugins_tpu.state.snapshot import NodeState, nonzero_request
 from scheduler_plugins_tpu.utils.intmath import bucket_size
 
-#: serve mode pins the resource axis to the canonical four (the same
-#: constraint the C++ columnar store's 4-slot layout imposes); a pod or
-#: node naming an extended resource disengages the engine until a rebase
+#: the axis an engine starts from: the canonical four (what the C++
+#: columnar store's 4-slot layout holds). An engine's own axis
+#: (`ServeEngine.index`) is this plus the extended resources its store
+#: names, taken at a rebase; a cluster without any never leaves this one
 CANON_INDEX = ResourceIndex(())
+#: the canonical names come first on every axis: one slot for all of them
 PODS_I = CANON_INDEX.position(PODS)
 
 I64 = np.int64
 I32 = np.int32
 
-#: shared zero vector for events without a resource payload (terminating
-#: flips); read-only by convention
-ZERO_R = np.zeros(len(CANON_INDEX), I64)
-ZERO_R.setflags(write=False)
-
 
 class UnsupportedResource(ValueError):
-    """An object names a resource outside the canonical axis — the packed
-    delta vectors cannot carry it (serve falls back / re-bases)."""
+    """An object names a resource the given axis does not hold: the packed
+    delta vectors cannot carry it until a rebase has widened the axis."""
 
 
-def _encode(quantities: dict) -> np.ndarray:
+def _encode(quantities: dict, index: ResourceIndex) -> np.ndarray:
     try:
-        return CANON_INDEX.encode(quantities)
+        return index.encode(quantities)
     except KeyError as exc:
         raise UnsupportedResource(str(exc)) from exc
 
 
-def pod_quota_vector(pod) -> np.ndarray:
+def pod_quota_vector(pod, index: ResourceIndex) -> np.ndarray:
     """One assigned pod's contribution to its namespace's ElasticQuota
     `used` row — the RAW effective-request encode (no pods-slot override:
     `build_snapshot`'s quota accumulation sums `index.encode(
     pod.effective_request())` verbatim). Raises `UnsupportedResource` on
-    extended resources, like the usage vectors."""
-    return _encode(pod.effective_request())
+    a resource outside `index`, like the usage vectors."""
+    return _encode(pod.effective_request(), index)
 
 
-def pod_usage_vectors(pod) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+def pod_usage_vectors(
+    pod, index: ResourceIndex
+) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
     """(requested, nonzero_requested, limits) contribution of ONE assigned
     pod to its node's usage columns — the exact per-pod accumulation
     `build_snapshot` performs: nonzero defaults applied, limits clamped to
     >= requests per pod (SetMaxLimits), and the pods slot carrying the
     count contribution (1) on the requested/nonzero columns (the snapshot
     overwrites those slots with pod_count). Raises `UnsupportedResource`
-    on extended resources."""
-    req = _encode(pod.effective_request())
-    nz = nonzero_request(req, CANON_INDEX)
-    lim = np.maximum(_encode(pod.effective_limits()), req)
+    on a resource outside `index`."""
+    req = _encode(pod.effective_request(), index)
+    nz = nonzero_request(req, index)
+    lim = np.maximum(_encode(pod.effective_limits(), index), req)
     req = req.copy()
     req[PODS_I] = 1
     nz[PODS_I] = 1
@@ -304,11 +303,21 @@ class UsageDeltas:
         self.pod_count = pod_count
         self.terminating = terminating
 
-    #: bucket floor: steady churn wobbles around its Poisson mean, and a
-    #: 16/32/64 bucket flip-flop would retrace the apply program mid-run;
-    #: one 64-row floor covers typical per-cycle event counts with a
-    #: single compiled shape (padding 64 zero rows costs nothing)
-    MIN_BUCKET = 64
+    #: bucket floor. A cycle's rows are the binds of the cycle before and
+    #: the departures since, about twice its batch, and a program is
+    #: compiled per bucket of them (~0.5 s on a v5e, PERF.md finding 11).
+    #: A served daemon's batches run from a few pods (the tick after a
+    #: long one) to a thousand and more, so a 64-row floor met eight
+    #: buckets, 64 to 4,096, the small ones rarely and so inside a
+    #: measured window (PR 27's runs: nine `serve_delta_apply` shapes).
+    #: 1,024 rows is twice a batch of 512, the smallest batch a closed
+    #: backlog of 2,048 outstanding makes all the time: every rarer,
+    #: smaller batch shares its program, and what is left above it
+    #: (2,048, 3,072, 4,096) a start-up can warm by name. The scatter
+    #: costs ~0.25 us a row on the device (0.65-0.77 ms for 2,048-3,072
+    #: rows, my chip runs, PR 27's traces), so the floor is 0.25 ms a
+    #: cycle at most, and packing 1,024 zero rows ~10 us on the host.
+    MIN_BUCKET = 1024
 
     @classmethod
     def pack(cls, rows: list[tuple], R: int) -> "UsageDeltas":
@@ -488,12 +497,10 @@ class SideDeltas:
     """Packed side-table delta batch: gang rows (engine-stable gang row,
     d_assigned, d_gated, d_slack) + namespace rows (engine-stable ns row,
     d_used, d_count), bucket-padded with zero-delta rows (scatter-add
-    no-ops) so the jit cache stays warm across cycles."""
+    no-ops) to the tables' own sizes."""
 
     __slots__ = ("g_idx", "g_assigned", "g_gated", "g_slack",
                  "q_idx", "q_used", "q_count")
-
-    MIN_BUCKET = 16
 
     def __init__(self, g_idx, g_assigned, g_gated, g_slack, q_idx, q_used,
                  q_count):
@@ -507,27 +514,30 @@ class SideDeltas:
 
     @classmethod
     def pack(cls, gang_rows: list[tuple], ns_rows: list[tuple],
-             R: int) -> "SideDeltas":
+             R: int, Ug: int, Uq: int) -> "SideDeltas":
         """`gang_rows`: [(row, d_assigned, d_gated, d_slack_vec)];
-        `ns_rows`: [(row, d_used_vec, d_count)]. Duplicate rows sum."""
-        Ug = bucket_size(max(len(gang_rows), 1), minimum=cls.MIN_BUCKET)
-        Uq = bucket_size(max(len(ns_rows), 1), minimum=cls.MIN_BUCKET)
-        g_idx = np.zeros(Ug, I32)
+        `ns_rows`: [(row, d_used_vec, d_count)], at most one entry a row
+        (the engine coalesces). `Ug`, `Uq`: the batch's lengths, the
+        tables' own (bucketed) sizes, so the apply program has one shape
+        per pair of table sizes and none per count of rows a cycle
+        touched: gangs of 1 to 64 binding made that count wander over
+        five buckets, and the tables are a few thousand rows at most."""
+        # entry j carries row j's delta, zero where the cycle left the row
+        # alone: a scatter-add of zero is a no-op
+        g_idx = np.arange(Ug, dtype=I32)
         g_assigned = np.zeros(Ug, I32)
         g_gated = np.zeros(Ug, I32)
         g_slack = np.zeros((Ug, R), I64)
-        for j, (row, da, dg, ds) in enumerate(gang_rows):
-            g_idx[j] = row
-            g_assigned[j] = da
-            g_gated[j] = dg
-            g_slack[j] = ds
-        q_idx = np.zeros(Uq, I32)
+        for row, da, dg, ds in gang_rows:
+            g_assigned[row] += da
+            g_gated[row] += dg
+            g_slack[row] += ds
+        q_idx = np.arange(Uq, dtype=I32)
         q_used = np.zeros((Uq, R), I64)
         q_count = np.zeros(Uq, I32)
-        for j, (row, du, dc) in enumerate(ns_rows):
-            q_idx[j] = row
-            q_used[j] = du
-            q_count[j] = dc
+        for row, du, dc in ns_rows:
+            q_used[row] += du
+            q_count[row] += dc
         return cls(g_idx, g_assigned, g_gated, g_slack, q_idx, q_used,
                    q_count)
 
@@ -547,8 +557,8 @@ class SideDeltas:
 def apply_side_deltas(tables: SideTables, g_idx, g_assigned, g_gated,
                       g_slack, q_idx, q_used, q_count) -> SideTables:
     """Fold one packed side-table delta batch into the resident gang/
-    quota aggregates. Pure scatter-adds (duplicate rows sum; padded rows
-    are zero-delta no-ops at row 0), mirroring `apply_node_deltas`'s
+    quota aggregates. Pure scatter-adds (a row the cycle left alone adds
+    zero), mirroring `apply_node_deltas`'s
     discipline; the `tables` argument is donated at the jit boundary
     (`side_apply_program`) — callers rebind the resident carry from the
     result."""

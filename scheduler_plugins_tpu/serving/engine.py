@@ -17,13 +17,20 @@ Capacity policy (docs/SERVING.md):
   to the next `bucket_size` bucket device-side (cheap `jnp.pad`, usage
   history preserved; one retrace for the new shape).
 - **re-base** (the compact path): Node/Delete, an existing node's
-  region/zone label change, an extended-resource sighting, or a pod event
-  against a node the engine has never seen (cross-watch ordering) all
-  invalidate either the row order or the packed axis — the engine
-  rebuilds from a fresh `Cluster.snapshot` at the canonical bucket for
-  the new node count, exactly like the C++ columnar mirror's
+  region/zone label change, a resource name the axis does not hold yet,
+  or a pod event against a node the engine has never seen (cross-watch
+  ordering) all invalidate either the row order or the packed axis — the
+  engine rebuilds from a fresh `Cluster.snapshot` at the canonical
+  bucket for the new node count, exactly like the C++ columnar mirror's
   `_native_rebuild`. Rare control-plane events pay O(cluster); steady
   churn pays O(changed).
+- **the resource axis** (`ServeEngine.index`) is the canonical four plus
+  every extended resource the store names when the resident base is
+  built (nodes, assigned and pending pods, PodGroups, quotas). A node,
+  pod, PodGroup or quota that names another one later triggers ONE
+  rebase, which widens the axis (the names it had keep their columns);
+  the axis never narrows. `scheduler_serve_axis_rebases_total` counts
+  the rebases that changed it.
 
 Compatibility gate: the engine owns the snapshot while every side table
 is either None or one the resident state fully describes. Gang
@@ -81,6 +88,9 @@ class ServeEngine:
         self._node_labels: dict[str, tuple] = {}  # name -> (region, zone)
         self._tainted: set[str] = set()
         self._apply = D.delta_apply_program()
+        #: the resident columns' resource axis: canonical until a rebase
+        #: finds extended resources in the store, then widened by them
+        self._index = D.CANON_INDEX
         self._generation = 0
         self._rebases = 0
         self._staleness = 0  # delta events applied since last rebase
@@ -186,6 +196,17 @@ class ServeEngine:
         return self._generation
 
     @property
+    def index(self):
+        """The `ResourceIndex` of the resident columns and side tables."""
+        return self._index
+
+    def _extended(self) -> tuple:
+        """The axis's names after the canonical four: what a fresh snapshot
+        is told to hold (`extra_resources`), so that the names keep their
+        columns and the axis never narrows."""
+        return self._index.names[len(D.CANON_INDEX):]
+
+    @property
     def rebases(self) -> int:
         """Full re-snapshots THIS engine performed (the process-global
         `scheduler_serve_rebases_total` sums across engines/runs)."""
@@ -208,8 +229,9 @@ class ServeEngine:
         side table is either None or one the resident state fully
         describes. Gang (PodGroup) and quota (ElasticQuota) rosters are
         OWNED since ISSUE 12 — their aggregate tensors assemble from the
-        resident side tables — as long as their resources stay on the
-        canonical axis; NRTs/AppGroups/seccomp/metrics/selector-spec
+        resident side tables; a resource name is no reason to fall back
+        (`_outside_axis`: the axis widens by a rebase);
+        NRTs/AppGroups/seccomp/metrics/selector-spec
         pods/taints/nominations still fall back."""
         if (
             cluster.nrts
@@ -220,20 +242,6 @@ class ServeEngine:
             or self._tainted
         ):
             return False
-        # gang/quota objects naming an extended resource widen the fresh
-        # snapshot's packed axis past the canonical four (build_snapshot
-        # unions PodGroup.min_resources and quota min/max) — the resident
-        # columns cannot express that; O(G + Q), objects only
-        for pg in cluster.pod_groups.values():
-            if pg.min_resources and any(
-                r not in D.CANON_INDEX for r in pg.min_resources
-            ):
-                return False
-        for eq in cluster.quotas.values():
-            if any(r not in D.CANON_INDEX for r in eq.min) or any(
-                r not in D.CANON_INDEX for r in eq.max
-            ):
-                return False
         # nominations OUTSIDE the pending batch still count into the full
         # snapshot's nominated column / nominee holds: scheduling-gated
         # nominees (sink-tracked at upsert) and reserved nominees
@@ -245,23 +253,40 @@ class ServeEngine:
             if p is not None and p.nominated_node_name is not None:
                 return False
         # batch-local specs (O(batch), not O(cluster)): node affinity
-        # feeds SchedulingState; nominations feed the nominee holds;
-        # extended resources fall outside the canonical packed axis
+        # feeds SchedulingState; nominations feed the nominee holds
         for pod in pending:
             if (
                 pod.node_selector
                 or pod.node_affinity_required
                 or pod.node_affinity_preferred
                 or pod.nominated_node_name is not None
-                or any(
-                    r not in D.CANON_INDEX for r in pod.effective_request()
-                )
-                or any(
-                    r not in D.CANON_INDEX for r in pod.effective_limits()
-                )
             ):
                 return False
         return True
+
+    def _outside_axis(self, cluster, pending) -> bool:
+        """True when a PodGroup, a quota or a pending pod names a resource
+        the resident axis does not hold: the fresh snapshot's axis is the
+        union of all of them (`build_snapshot`), so the next refresh
+        rebases and widens. Nodes and assigned pods are caught where their
+        events are classified. O(G + Q + batch), objects only."""
+        index = self._index
+        for pg in cluster.pod_groups.values():
+            if pg.min_resources and any(
+                r not in index for r in pg.min_resources
+            ):
+                return True
+        for eq in cluster.quotas.values():
+            if any(r not in index for r in eq.min) or any(
+                r not in index for r in eq.max
+            ):
+                return True
+        for pod in pending:
+            if any(r not in index for r in pod.effective_request()) or any(
+                r not in index for r in pod.effective_limits()
+            ):
+                return True
+        return False
 
     # -- the per-cycle entry --------------------------------------------
     def refresh(self, cluster, pending, now_ms: int = 0):
@@ -308,7 +333,10 @@ class ServeEngine:
             self._last = None
             return None
 
-        if rebase or self._nodes is None:
+        if (
+            rebase or self._nodes is None
+            or self._outside_axis(cluster, pending)
+        ):
             return self._rebase(cluster, pending, now_ms)
         if grow:
             self._grow(bucket_size(n_nodes))
@@ -323,11 +351,10 @@ class ServeEngine:
         if (cluster.pod_groups or cluster.quotas) and not self._ensure_side(
             cluster
         ):
-            # defensive: the side tables could not be rebuilt (an
-            # extended-resource assigned pod appeared between the axis
-            # checks) — serve this cycle from the full snapshot
-            self._last = None
-            return None
+            # defensive: the side tables could not be rebuilt (an assigned
+            # pod names a resource the classification did not see): the
+            # rebase widens the axis and rebuilds them
+            return self._rebase(cluster, pending, now_ms)
         return self._assemble(cluster, pending, now_ms)
 
     # -- event classification -------------------------------------------
@@ -344,7 +371,9 @@ class ServeEngine:
         ElasticQuota `used` row's raw request encode. The streaming
         subclass memoizes this per pod object (`final` marks the pod's
         last event, releasing its entry)."""
-        return D.pod_usage_vectors(pod) + (D.pod_quota_vector(pod),)
+        return D.pod_usage_vectors(pod, self._index) + (
+            D.pod_quota_vector(pod, self._index),
+        )
 
     def _row_cache(self):
         """Per-pod assembly memo passed to `build_pod_state` (None in the
@@ -390,7 +419,10 @@ class ServeEngine:
         # side aggregates coalesce per engine-stable row (sums)
         gang_acc: dict[int, list] = {}
         ns_acc: dict[int, list] = {}
-        R = len(D.CANON_INDEX)
+        index = self._index
+        R = len(index)
+        # events without a resource payload (terminating flips)
+        zero = np.zeros(R, np.int64)
         rebase = None
 
         def fail(reason):
@@ -457,8 +489,8 @@ class ServeEngine:
                         # that exist) — rebuild rather than drift
                         self._side_dirty = True
                 try:
-                    alloc = D._encode(node.allocatable)
-                    cap = D._encode(node.capacity)
+                    alloc = D._encode(node.allocatable, index)
+                    cap = D._encode(node.capacity, index)
                 except D.UnsupportedResource:
                     fail("extended-resource")
                     continue
@@ -492,7 +524,7 @@ class ServeEngine:
                     if slot is None:
                         fail("unknown-node")
                         continue
-                    usage.append((slot, D.ZERO_R, D.ZERO_R, D.ZERO_R, 0, 1))
+                    usage.append((slot, zero, zero, zero, 0, 1))
                     continue
                 sign = 1 if kind == D.POD_ASSIGN else -1
                 try:
@@ -548,7 +580,7 @@ class ServeEngine:
         import jax
         import jax.numpy as jnp
 
-        R = len(D.CANON_INDEX)
+        R = len(self._index)
         ups = D.NodeUpserts.pack(upsert_rows, R)
         use = D.UsageDeltas.pack(usage_rows, R)
         # slot indices are host-validated (< npad); the jit scatter relies
@@ -605,11 +637,13 @@ class ServeEngine:
             return None
         import warnings
 
-        R = len(D.CANON_INDEX)
         need_g = max((row for row, *_ in gang_rows), default=-1) + 1
         need_q = max((row for row, *_ in ns_rows), default=-1) + 1
         self._grow_side(need_g, need_q)
-        packed = D.SideDeltas.pack(gang_rows, ns_rows, R)
+        packed = D.SideDeltas.pack(
+            gang_rows, ns_rows, len(self._index),
+            self._side_gpad, self._side_qpad,
+        )
         with warnings.catch_warnings():
             # CPU backends never donate and list every buffer
             warnings.filterwarnings(
@@ -620,13 +654,27 @@ class ServeEngine:
             )
         return packed.as_dict()
 
+    @staticmethod
+    def _side_floor(cluster) -> tuple:
+        """(gang rows, namespace rows) the side tables hold at the least:
+        as many as the store has PodGroups, and namespaces or quotas. A
+        gang or a namespace takes its row when its first member is
+        assigned, so tables sized to the rows taken so far grew bucket by
+        bucket through a run's first minute, a `serve_side_apply` program
+        each; sized to the objects they have their shape from the start."""
+        return (
+            max(len(cluster.pod_groups), 1),
+            max(len(cluster.namespaces), len(cluster.quotas), 1),
+        )
+
     def _grow_side(self, need_g: int, need_q: int) -> None:
         """Pad the resident side tables to cover rows `need_g`/`need_q`
         (bucketed, zero-padded — new gangs/namespaces appear mid-run)."""
         import jax.numpy as jnp
 
-        new_g = bucket_size(max(need_g, self._side_gpad, 1))
-        new_q = bucket_size(max(need_q, self._side_qpad, 1))
+        floor_g, floor_q = self._side_floor(self._cluster)
+        new_g = bucket_size(max(need_g, self._side_gpad, floor_g))
+        new_q = bucket_size(max(need_q, self._side_qpad, floor_q))
         if new_g == self._side_gpad and new_q == self._side_qpad:
             return
 
@@ -685,25 +733,15 @@ class ServeEngine:
 
     def _rebase_inner(self, cluster, pending, now_ms: int):
         npad = bucket_size(max(len(cluster.nodes), 1))
+        # whatever else the store names now is appended to the axis
         snap, meta = cluster.snapshot(
             pending, now_ms=now_ms, pad_nodes=npad,
+            extra_resources=self._extended(),
         )
-        if len(meta.index) != len(D.CANON_INDEX):
-            # an extended resource somewhere in the store (node column or
-            # an ASSIGNED pod's requests) widens the packed axis past the
-            # canonical four the delta vectors carry — the resident
-            # columns cannot own this state. Serve this cycle from the
-            # fresh snapshot and keep re-basing (full-snapshot cost,
-            # exact) until the extended objects go away.
-            self._nodes = None
-            self._side_dirty = True
-            self._generation += 1
-            self._staleness = 0
-            self._rebases += 1
-            obs.metrics.inc(obs.SERVE_REBASES)
-            self._observe()
-            self._last = None
-            return snap, meta
+        if meta.index.names != self._index.names:
+            self._index = meta.index
+            obs.metrics.inc(obs.SERVE_AXIS_REBASES)
+            self._axis_changed()
         self._nodes = snap.nodes
         self._npad = npad
         self._names = list(meta.node_names)
@@ -736,6 +774,10 @@ class ServeEngine:
         self._observe()
         return snap, meta
 
+    def _axis_changed(self) -> None:
+        """The axis widened: per-pod vectors memoized on the old one are
+        the wrong length (the streaming engine drops its memo)."""
+
     # -- resident gang/quota side tables --------------------------------
     def _ensure_side(self, cluster) -> bool:
         """Side tables ready for assembly: rebuild them from one O(pods)
@@ -751,10 +793,9 @@ class ServeEngine:
         [assigned, gated, slack_vec]} + {namespace: [used_vec, count]}.
         Shared by the rebuild (packs them resident) and the anti-entropy
         verify (compares them against the resident copies). Raises
-        `UnsupportedResource` on extended-resource assigned pods — the
-        same condition that already keeps the engine on the
-        full-snapshot rebase path."""
-        R = len(D.CANON_INDEX)
+        `UnsupportedResource` when an assigned pod names a resource
+        outside the axis (the next rebase widens it)."""
+        R = len(self._index)
         gangs: dict[str, list] = {}
         namespaces: dict[str, list] = {}
 
@@ -802,10 +843,8 @@ class ServeEngine:
     def _rebuild_side_tables(self, cluster) -> bool:
         """Rebuild the resident side tables from the store (O(pods), the
         rare path — steady state is the O(changed) `_apply_side`).
-        Returns False (tables stay dirty) when an extended-resource
-        assigned pod makes the canonical-axis aggregates unrepresentable
-        — the axis-width rebase rule already keeps the engine off the
-        resident path in exactly that state."""
+        Returns False (tables stay dirty) when an assigned pod names a
+        resource outside the axis: the caller rebases, which widens it."""
         import jax.numpy as jnp
 
         with obs.tracer.span(
@@ -817,11 +856,12 @@ class ServeEngine:
             except D.UnsupportedResource:
                 self._side_dirty = True
                 return False
-            R = len(D.CANON_INDEX)
+            R = len(self._index)
             self._gang_rows = {name: i for i, name in enumerate(gangs)}
             self._ns_rows = {name: i for i, name in enumerate(namespaces)}
-            self._side_gpad = bucket_size(max(len(gangs), 1))
-            self._side_qpad = bucket_size(max(len(namespaces), 1))
+            floor_g, floor_q = self._side_floor(cluster)
+            self._side_gpad = bucket_size(max(len(gangs), floor_g))
+            self._side_qpad = bucket_size(max(len(namespaces), floor_q))
             ga = np.zeros(self._side_gpad, np.int32)
             gg = np.zeros(self._side_gpad, np.int32)
             gs = np.zeros((self._side_gpad, R), np.int64)
@@ -876,7 +916,7 @@ class ServeEngine:
         for name, row in self._gang_rows.items():
             exp = gangs.pop(name, None)
             if exp is None:
-                exp = [0, 0, np.zeros(len(D.CANON_INDEX), np.int64)]
+                exp = [0, 0, np.zeros(len(self._index), np.int64)]
             if (
                 int(host["gang_assigned"][row]) != exp[0]
                 or int(host["gang_gated"][row]) != exp[1]
@@ -888,7 +928,7 @@ class ServeEngine:
         for name, row in self._ns_rows.items():
             exp = namespaces.pop(name, None)
             if exp is None:
-                exp = [np.zeros(len(D.CANON_INDEX), np.int64), 0]
+                exp = [np.zeros(len(self._index), np.int64), 0]
             if (
                 int(host["ns_assigned"][row]) != exp[1]
                 or not (host["quota_used"][row] == exp[0]).all()
@@ -910,7 +950,7 @@ class ServeEngine:
         try:
             gangs, namespaces = self._scan_side_aggregates(cluster)
         except D.UnsupportedResource:
-            return None  # axis-width rule owns this state
+            return "axis-width"
         return self._side_divergence(gangs, namespaces)
 
     # -- anti-entropy ----------------------------------------------------
@@ -941,10 +981,12 @@ class ServeEngine:
             if self._nodes is None:
                 return None
             fresh, meta = cluster.snapshot(
-                [], now_ms=0, pad_nodes=self._npad
+                [], now_ms=0, pad_nodes=self._npad,
+                extra_resources=self._extended(),
             )
             reason = None
-            if len(meta.index) != len(D.CANON_INDEX):
+            if meta.index.names != self._index.names:
+                # the store names a resource the axis does not hold
                 reason = "axis-width"
             elif list(meta.node_names) != self._names:
                 reason = "row-order"
@@ -996,6 +1038,7 @@ class ServeEngine:
             "generation": self._generation,
             "staleness": self._staleness,
             "names": self._names,
+            "resources": list(self._index.names),
             "regions": self._regions,
             "zones": self._zones,
             "node_labels": {k: list(v) for k, v in
@@ -1059,6 +1102,12 @@ class ServeEngine:
                 )}
             )
         self._npad = int(header["npad"])
+        # a checkpoint written before the axis could widen holds the
+        # canonical four and no names
+        from scheduler_plugins_tpu.api.resources import ResourceIndex
+
+        self._index = ResourceIndex(header.get("resources", ()))
+        self._axis_changed()
         self._generation = int(header["generation"])
         self._staleness = int(header["staleness"])
         self._names = list(header["names"])
@@ -1103,22 +1152,12 @@ class ServeEngine:
         columns re-lower O(G + Q) through the SAME
         `gang_object_tables`/`quota_object_tables` the fresh path uses,
         the per-pod AGGREGATES come from the O(changed)-maintained side
-        tables — never an O(cluster) pod loop."""
-        with obs.tracer.span(
-            "ServeRefresh/assemble", tid="serve", pending=len(pending)
-        ):
-            return self._assemble_inner(cluster, pending, now_ms)
-
-    def _assemble_inner(self, cluster, pending, now_ms: int = 0):
-        P = bucket_size(max(len(pending), 1))
-        R = len(D.CANON_INDEX)
-        meta = SnapshotMeta(index=D.CANON_INDEX)
-        meta.node_names = list(self._names)
-        meta.pod_names = [p.uid for p in pending]
-        meta.regions = list(self._regions)
-        meta.zones = list(self._zones)
-        ns_in = _Interner(meta.namespaces)
-
+        tables — never an O(cluster) pod loop. Two spans side by side:
+        `ServeRefresh/assemble` (one a served cycle: the pod tensors) and,
+        where the store holds PodGroups or quotas, `ServeRefresh/gangs`
+        (their tensors)."""
+        index = self._index
+        meta = SnapshotMeta(index=index)
         # gang interning in pod_groups-dict order — build_snapshot's own
         # first-seen rule, so codes match the fresh path's exactly
         pod_groups = list(cluster.pod_groups.values())
@@ -1126,41 +1165,71 @@ class ServeEngine:
         gang_pos = {
             pg.full_name: gangs_in.code(pg.full_name) for pg in pod_groups
         }
-
-        def gang_of(pod):
-            name = pod.pod_group()
-            if not name:
-                return -1
-            return gang_pos.get(f"{pod.namespace}/{name}", -1)
-
+        ns_in = _Interner(meta.namespaces)
         batch_counts: dict[int, int] = {}
-        if pod_groups:
-            def gang_of_counted(pod, _inner=gang_of):
-                g = _inner(pod)
-                if g >= 0:
-                    batch_counts[g] = batch_counts.get(g, 0) + 1
-                return g
-            gang_code = gang_of_counted
-        else:
-            gang_code = gang_of
-        pod_state = build_pod_state(
-            pending, P, D.CANON_INDEX, ns_in, gang_code,
-            cluster.tlp_prediction, row_cache=self._row_cache(),
-        )
+        with obs.tracer.span(
+            "ServeRefresh/assemble", tid="serve", pending=len(pending)
+        ):
+            P = bucket_size(max(len(pending), 1))
+            meta.node_names = list(self._names)
+            meta.pod_names = [p.uid for p in pending]
+            meta.regions = list(self._regions)
+            meta.zones = list(self._zones)
 
+            def gang_of(pod):
+                name = pod.pod_group()
+                if not name:
+                    return -1
+                return gang_pos.get(f"{pod.namespace}/{name}", -1)
+
+            if pod_groups:
+                def gang_of_counted(pod, _inner=gang_of):
+                    g = _inner(pod)
+                    if g >= 0:
+                        batch_counts[g] = batch_counts.get(g, 0) + 1
+                    return g
+                gang_code = gang_of_counted
+            else:
+                gang_code = gang_of
+            pods = self._stage_pods(build_pod_state(
+                pending, P, index, ns_in, gang_code,
+                cluster.tlp_prediction, row_cache=self._row_cache(),
+            ))
         gang_state = quota_state = None
-        side = (
-            self._side_host() if (pod_groups or cluster.quotas) else None
+        if pod_groups or cluster.quotas:
+            with obs.tracer.span(
+                "ServeRefresh/gangs", tid="serve",
+                gangs=len(pod_groups), quotas=len(cluster.quotas),
+            ):
+                gang_state, quota_state = self._assemble_side(
+                    cluster, pod_groups, gang_pos, batch_counts, ns_in,
+                    meta, P, now_ms,
+                )
+        snap = ClusterSnapshot(
+            nodes=self._nodes, pods=pods, gangs=gang_state,
+            quota=quota_state,
         )
+        return snap, meta
+
+    def _assemble_side(self, cluster, pod_groups, gang_pos, batch_counts,
+                       ns_in, meta, P: int, now_ms: int):
+        """(GangState | None, QuotaState | None), staged, on `bucket_size`
+        buckets of the PodGroups and namespaces there are: a row none of
+        them holds is inert, and objects that come and go give the solve
+        no shape each (`build_snapshot` does the same)."""
+        index = self._index
+        R = len(index)
+        side = self._side_host()
+        gang_state = quota_state = None
         if pod_groups:
-            G = max(len(gang_pos), 1)
+            G = bucket_size(len(gang_pos))
             backed_off = [
                 name
                 for name, until in cluster.gang_backoff_until_ms.items()
                 if until > now_ms
             ]
             obj = gang_object_tables(
-                pod_groups, gang_pos, D.CANON_INDEX, G, backed_off
+                pod_groups, gang_pos, index, G, backed_off
             )
             assigned = np.zeros(G, np.int32)
             gated = np.zeros(G, np.int32)
@@ -1178,13 +1247,13 @@ class ServeEngine:
             total = (assigned + gated).astype(np.int32)
             for g, count in batch_counts.items():
                 total[g] += count
-            gang_state = GangState(
+            gang_state = self._stage_pods(GangState(
                 total_members=total,
                 assigned=assigned,
                 gated=gated,
                 cluster_slack=slack,
                 **obj,
-            )
+            ))
         if cluster.quotas:
             quotas = list(cluster.quotas.values())
             # fresh interning order: batch namespaces (above), then quota
@@ -1196,10 +1265,8 @@ class ServeEngine:
             for name, row in self._ns_rows.items():
                 if side["ns_assigned"][row] > 0:
                     ns_in.code(name)
-            Q = max(len(meta.namespaces), 1)
-            qmin, qmax, qhas = quota_object_tables(
-                quotas, D.CANON_INDEX, ns_in, Q
-            )
+            Q = bucket_size(len(meta.namespaces))
+            qmin, qmax, qhas = quota_object_tables(quotas, index, ns_in, Q)
             qused = np.zeros((Q, R), np.int64)
             for q in quotas:
                 row = self._ns_rows.get(q.namespace)
@@ -1208,20 +1275,12 @@ class ServeEngine:
             nom_req, nom_in_eq, nom_total, nom_batch = empty_quota_nominees(
                 R, P
             )
-            quota_state = QuotaState(
+            quota_state = self._stage_pods(QuotaState(
                 min=qmin, max=qmax, used=qused, has_quota=qhas,
                 nom_req=nom_req, nom_in_eq_mask=nom_in_eq,
                 nom_total_mask=nom_total, nom_batch_idx=nom_batch,
-            )
-        snap = ClusterSnapshot(
-            nodes=self._nodes,
-            pods=self._stage_pods(pod_state),
-            gangs=self._stage_pods(gang_state)
-            if gang_state is not None else None,
-            quota=self._stage_pods(quota_state)
-            if quota_state is not None else None,
-        )
-        return snap, meta
+            ))
+        return gang_state, quota_state
 
     def _observe(self) -> None:
         obs.metrics.set_gauge(obs.SERVE_GENERATION, self._generation)
@@ -1328,7 +1387,9 @@ class StreamingServeEngine(ServeEngine):
             if final:
                 del self._vec_cache[pod.uid]
             return ent[1]
-        vecs = D.pod_usage_vectors(pod) + (D.pod_quota_vector(pod),)
+        vecs = D.pod_usage_vectors(pod, self._index) + (
+            D.pod_quota_vector(pod, self._index),
+        )
         if final:
             self._vec_cache.pop(pod.uid, None)
         else:
@@ -1336,6 +1397,9 @@ class StreamingServeEngine(ServeEngine):
                 self._vec_cache.clear()
             self._vec_cache[pod.uid] = (pod, vecs)
         return vecs
+
+    def _axis_changed(self) -> None:
+        self._vec_cache.clear()
 
     def _stage_args(self, args):
         # pjit stages numpy args itself in one C++ pass; the explicit
@@ -1360,7 +1424,7 @@ class StreamingServeEngine(ServeEngine):
                 if pod.node_name is not None or pod.uid in cluster.reserved:
                     self._pod_vectors(pod)
         except D.UnsupportedResource:
-            pass  # extended resources: verify falls back to base anyway
+            pass  # outside the axis: verify falls back to base anyway
         if self._nodes is not None and self._npad not in self._compact_warm:
             # compile the compaction program for this resident shape NOW,
             # on a throwaway zero-state (NEVER the live carry — the
@@ -1448,8 +1512,8 @@ class StreamingServeEngine(ServeEngine):
         corrupted state). Independence is preserved: the resident
         columns were built through the sink+device path, the expectation
         comes straight from the store objects. Anything outside the
-        canonical axis (an extended resource) falls back to the base
-        engine's full verify, which classifies it exactly."""
+        engine's axis falls back to the base engine's full verify, which
+        classifies it exactly."""
         from scheduler_plugins_tpu.utils import flightrec
 
         if self._nodes is None:
@@ -1464,10 +1528,10 @@ class StreamingServeEngine(ServeEngine):
                     cluster, names, want_side=self._side_verify_live(cluster)
                 )
             except D.UnsupportedResource:
-                # extended resource somewhere: the packed axis is wider
-                # than the canonical four — delegate to the base
-                # engine's fresh-snapshot verify BEFORE opening this
-                # path's span/counter (one check = one count, one span)
+                # a resource outside the axis somewhere: delegate to the
+                # base engine's fresh-snapshot verify, which names it,
+                # BEFORE opening this path's span/counter (one check =
+                # one count, one span)
                 return super().verify(cluster)
         with obs.tracer.span(
             "ServeRefresh/verify", tid="serve", staleness=self._staleness,
@@ -1509,7 +1573,8 @@ class StreamingServeEngine(ServeEngine):
         gang/quota side aggregates (`_scan_side_aggregates` semantics —
         one store walk covers both verifications); returns
         (columns, (gangs, namespaces) | None)."""
-        R = len(D.CANON_INDEX)
+        index = self._index
+        R = len(index)
         side_gangs: dict = {}
         side_ns: dict = {}
 
@@ -1554,8 +1619,8 @@ class StreamingServeEngine(ServeEngine):
         node_pos = {}
         for i, node in enumerate(cluster.nodes.values()):
             node_pos[node.name] = i
-            alloc[i] = D._encode(node.allocatable)
-            capacity[i] = D._encode(node.capacity)
+            alloc[i] = D._encode(node.allocatable, index)
+            capacity[i] = D._encode(node.capacity, index)
             mask[i] = not node.unschedulable
             if node.region:
                 region[i] = regions.setdefault(node.region, len(regions))
@@ -1693,7 +1758,7 @@ def side_lower_args(n_gangs: int = 8, n_ns: int = 4, n_rows: int = 16):
     ns_rows = [
         (j % n_ns, np.ones(R, np.int64), 1) for j in range(n_rows)
     ]
-    packed = D.SideDeltas.pack(gang_rows, ns_rows, R)
+    packed = D.SideDeltas.pack(gang_rows, ns_rows, R, G, Q)
     args = (tables, *(jnp.asarray(a) for a in packed.as_args()))
     return D.side_apply_program(), args
 

@@ -75,6 +75,10 @@ class Cluster:
     #: yet (the trimaran PodAssignEventHandler ScheduledPodsCache,
     #: /root/reference/pkg/trimaran/handler.go:47-171): uid -> (bind ms, node)
     recent_bindings: dict[str, tuple[int, str]] = field(default_factory=dict)
+    #: binds this store has made (`bind`), counted where the pod's
+    #: `node_name` is set: whoever has seen a pod bound reads a count that
+    #: holds it. The daemon's `bound_total` is this number
+    binds_total: int = 0
     #: uids of LIVE pods carrying spread/affinity specs — the native
     #: snapshot fast path must disengage while any exist, because the
     #: scheduling tables need the assigned pod objects it skips
@@ -705,6 +709,7 @@ class Cluster:
             # bound pods never count toward the nominated column
             self.delta_sink.forget_nomination(uid)
         self.pods[uid].node_name = node_name
+        self.binds_total += 1
         self._index_drop_pod(uid)
         led = podledger.LEDGER
         if led.enabled:

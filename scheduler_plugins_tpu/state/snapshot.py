@@ -48,6 +48,7 @@ from scheduler_plugins_tpu.api.resources import (
     ResourceIndex,
 )
 from scheduler_plugins_tpu.state import scheduling as _sched
+from scheduler_plugins_tpu.utils import observability as obs
 from scheduler_plugins_tpu.utils.intmath import bucket_size
 
 I64 = np.int64
@@ -489,7 +490,9 @@ def gang_object_tables(pod_groups, gang_pos, index, G: int,
     R = len(index)
     pods_i = index.position(PODS)
     backed_off = set(backed_off_gangs)
-    gang_min = np.ones(G, I32)
+    # a row no PodGroup holds (G is a bucket) stays inert: no member asks
+    # for it, `min_member` 0, mask False
+    gang_min = np.zeros(G, I32)
     gang_minres = np.zeros((G, R), I64)
     gang_has_minres = np.zeros(G, bool)
     gang_created = np.zeros(G, I64)
@@ -546,6 +549,64 @@ def empty_quota_nominees(R: int, P: int):
         np.zeros((1, P), bool),
         np.zeros((1, P), bool),
         np.full(1, -1, I32),
+    )
+
+
+def _build_quota(quotas, pending_pods, assigned_pods, extra_pods, index,
+                 ns_in: "_Interner", meta, P: int) -> QuotaState:
+    """`build_snapshot`'s ElasticQuota section: the object tables, the
+    per-namespace usage of the assigned pods and the nominee tables."""
+    R = len(index)
+    for q in quotas:
+        ns_in.code(q.namespace)
+    for pod in assigned_pods:
+        ns_in.code(pod.namespace)
+    # a bucket, like G: namespaces that come and go must not give the
+    # solve a shape each; a row no namespace holds has no quota
+    Q = bucket_size(len(meta.namespaces))
+    qused = np.zeros((Q, R), I64)
+    qmin, qmax, qhas = quota_object_tables(quotas, index, ns_in, Q)
+    for pod in assigned_pods:
+        if pod.node_name is None:
+            continue
+        nsi = ns_in.get(pod.namespace)
+        if qhas[nsi]:
+            qused[nsi] += index.encode(pod.effective_request())
+    # nominated-pod tables
+    nominated = [
+        p
+        for p in list(pending_pods) + list(extra_pods)
+        if p.nominated_node_name is not None and p.node_name is None
+    ]
+    batch_pos = {p.uid: i for i, p in enumerate(pending_pods)}
+    M = max(len(nominated), 1)
+    nom_req = np.zeros((M, R), I64)
+    nom_in_eq_mask = np.zeros((M, P), bool)
+    nom_total_mask = np.zeros((M, P), bool)
+    nom_batch_idx = np.full(M, -1, I32)
+    if nominated:
+        from scheduler_plugins_tpu.ops.quota import nominee_contribution
+
+        over_min = np.any(qused > qmin, axis=1)  # (Q,) usedOverMin
+        for j, m in enumerate(nominated):
+            m_ns = ns_in.get(m.namespace)
+            if m_ns < 0 or not qhas[m_ns]:
+                continue
+            nom_req[j] = index.encode(m.effective_request())
+            nom_batch_idx[j] = batch_pos.get(m.uid, -1)
+            for i, pod in enumerate(pending_pods):
+                if m.uid == pod.uid:
+                    continue
+                in_eq, total = nominee_contribution(
+                    m.namespace == pod.namespace, m.priority,
+                    pod.priority, bool(over_min[m_ns]),
+                )
+                nom_in_eq_mask[j, i] = in_eq
+                nom_total_mask[j, i] = total
+    return QuotaState(
+        min=qmin, max=qmax, used=qused, has_quota=qhas,
+        nom_req=nom_req, nom_in_eq_mask=nom_in_eq_mask,
+        nom_total_mask=nom_total_mask, nom_batch_idx=nom_batch_idx,
     )
 
 
@@ -698,11 +759,6 @@ def build_snapshot(
     gang_pos = {}
     for pg in pod_groups:
         gang_pos[pg.full_name] = gangs_in.code(pg.full_name)
-    G = max(len(gang_pos), 1)
-    obj = gang_object_tables(pod_groups, gang_pos, index, G,
-                             backed_off_gangs)
-    gang_total = np.zeros(G, I32)
-    gang_assigned = np.zeros(G, I32)
 
     def _gang_of(pod: Pod) -> int:
         name = pod.pod_group()
@@ -710,35 +766,44 @@ def build_snapshot(
             return -1
         return gang_pos.get(f"{pod.namespace}/{name}", -1)
 
-    gang_gated = np.zeros(G, I32)
-    # cluster_slack[g] = total demand of already-assigned members, added back
-    # in the cluster sweep (getNodeResource removes the gang's own pods,
-    # core.go:433-467; raw sums make the correction a plain total)
-    gang_slack = np.zeros((G, R), I64)
-    for pod in list(pending_pods) + list(assigned_pods) + list(extra_pods):
-        g = _gang_of(pod)
-        if g >= 0:
-            gang_total[g] += 1
-            if pod.node_name is not None:
-                gang_assigned[g] += 1
-                if pod.node_name in node_pos:
-                    vec = index.encode(pod.effective_request())
-                    vec[pods_i] = 1
-                    gang_slack[g] += vec
-            elif pod.scheduling_gated:
-                gang_gated[g] += 1
-
-    gang_state = (
-        GangState(
-            total_members=gang_total,
-            assigned=gang_assigned,
-            gated=gang_gated,
-            cluster_slack=gang_slack,
-            **obj,
-        )
-        if pod_groups
-        else None
-    )
+    gang_state = None
+    if pod_groups:
+        with obs.tracer.span("Snapshot/gangs", tid="snapshot",
+                             gangs=len(gang_pos)):
+            # a bucket, as nodes and pods land on: PodGroups that come and
+            # go must not give the solve a shape each
+            G = bucket_size(len(gang_pos))
+            obj = gang_object_tables(pod_groups, gang_pos, index, G,
+                                     backed_off_gangs)
+            gang_total = np.zeros(G, I32)
+            gang_assigned = np.zeros(G, I32)
+            gang_gated = np.zeros(G, I32)
+            # cluster_slack[g] = total demand of already-assigned members,
+            # added back in the cluster sweep (getNodeResource removes the
+            # gang's own pods, core.go:433-467; raw sums make the
+            # correction a plain total)
+            gang_slack = np.zeros((G, R), I64)
+            for pod in (
+                list(pending_pods) + list(assigned_pods) + list(extra_pods)
+            ):
+                g = _gang_of(pod)
+                if g >= 0:
+                    gang_total[g] += 1
+                    if pod.node_name is not None:
+                        gang_assigned[g] += 1
+                        if pod.node_name in node_pos:
+                            vec = index.encode(pod.effective_request())
+                            vec[pods_i] = 1
+                            gang_slack[g] += vec
+                    elif pod.scheduling_gated:
+                        gang_gated[g] += 1
+            gang_state = GangState(
+                total_members=gang_total,
+                assigned=gang_assigned,
+                gated=gang_gated,
+                cluster_slack=gang_slack,
+                **obj,
+            )
 
     # --- pods (pending batch) -----------------------------------------
     pod_state = build_pod_state(
@@ -748,55 +813,12 @@ def build_snapshot(
     # --- quota ---------------------------------------------------------
     quota_state = None
     if quotas:
-        for q in quotas:
-            ns_in.code(q.namespace)
-        for pod in assigned_pods:
-            ns_in.code(pod.namespace)
-        Q = max(len(meta.namespaces), 1)
-        qused = np.zeros((Q, R), I64)
-        qmin, qmax, qhas = quota_object_tables(quotas, index, ns_in, Q)
-        for pod in assigned_pods:
-            if pod.node_name is None:
-                continue
-            nsi = ns_in.get(pod.namespace)
-            if qhas[nsi]:
-                qused[nsi] += index.encode(pod.effective_request())
-        # nominated-pod tables
-        nominated = [
-            p
-            for p in list(pending_pods) + list(extra_pods)
-            if p.nominated_node_name is not None and p.node_name is None
-        ]
-        batch_pos = {p.uid: i for i, p in enumerate(pending_pods)}
-        M = max(len(nominated), 1)
-        nom_req = np.zeros((M, R), I64)
-        nom_in_eq_mask = np.zeros((M, P), bool)
-        nom_total_mask = np.zeros((M, P), bool)
-        nom_batch_idx = np.full(M, -1, I32)
-        if nominated:
-            over_min = np.any(qused > qmin, axis=1)  # (Q,) usedOverMin
-            for j, m in enumerate(nominated):
-                m_ns = ns_in.get(m.namespace)
-                if m_ns < 0 or not qhas[m_ns]:
-                    continue
-                nom_req[j] = index.encode(m.effective_request())
-                nom_batch_idx[j] = batch_pos.get(m.uid, -1)
-                from scheduler_plugins_tpu.ops.quota import nominee_contribution
-
-                for i, pod in enumerate(pending_pods):
-                    if m.uid == pod.uid:
-                        continue
-                    in_eq, total = nominee_contribution(
-                        m.namespace == pod.namespace, m.priority,
-                        pod.priority, bool(over_min[m_ns]),
-                    )
-                    nom_in_eq_mask[j, i] = in_eq
-                    nom_total_mask[j, i] = total
-        quota_state = QuotaState(
-            min=qmin, max=qmax, used=qused, has_quota=qhas,
-            nom_req=nom_req, nom_in_eq_mask=nom_in_eq_mask,
-            nom_total_mask=nom_total_mask, nom_batch_idx=nom_batch_idx,
-        )
+        with obs.tracer.span("Snapshot/quota", tid="snapshot",
+                             quotas=len(quotas)):
+            quota_state = _build_quota(
+                quotas, pending_pods, assigned_pods, extra_pods, index,
+                ns_in, meta, P,
+            )
 
     # --- metrics --------------------------------------------------------
     metrics_state = None

@@ -313,6 +313,12 @@ PODS_FAILED = "scheduler_pods_unschedulable_total"
 PREEMPTION_ATTEMPTS = "scheduler_preemption_attempts_total"
 PREEMPTION_VICTIMS = "scheduler_preemption_victims_total"
 GANG_REJECTIONS = "scheduler_gang_rejections_total"
+#: pods an ElasticQuota refused in a cycle (CapacityScheduling's PreFilter
+#: made them unschedulable: own Max, or the aggregate over Min)
+QUOTA_REFUSALS = "scheduler_quota_refusals_total"
+#: pods Permit told to wait in a cycle: placed and reserved, their gang
+#: short of its quorum
+GANG_WAIT_PODS = "scheduler_gang_wait_pods_total"
 CACHE_RESYNC_FLUSHES = "scheduler_nrt_cache_flushes_total"
 #: per-plugin attribution (labels: plugin) — the upstream
 #: `UnschedulablePlugins` signal: which plugin made each pod unschedulable
@@ -349,8 +355,12 @@ SERVE_STALENESS = "scheduler_serve_state_staleness_events"
 #: depth the engine saw — sustained growth means ingest is falling behind)
 SERVE_PENDING_DELTAS = "scheduler_serve_pending_deltas"
 #: full re-snapshots the serving engine performed (node deletes, label
-#: re-interning, extended resources — docs/SERVING.md classification)
+#: re-interning, a new resource name — docs/SERVING.md classification)
 SERVE_REBASES = "scheduler_serve_rebases_total"
+#: the rebases among them that changed the engine's resource axis: the
+#: cold build of a store that names an extended resource, and each later
+#: first sighting of another (serving/engine.py, "the resource axis")
+SERVE_AXIS_REBASES = "scheduler_serve_axis_rebases_total"
 #: serve refreshes that fell back to the full snapshot while the cluster
 #: carried PodGroups. Gang/quota rosters serve RESIDENT since ISSUE 12
 #: (gang/quota side tables), so on a compatible gang roster this stays 0
@@ -871,10 +881,10 @@ def extension_span(extension_point: str, plugin: str, tid: str = "framework",
     Perfetto validity gate)."""
     with tracer.span(
         f"{extension_point}/{plugin}", tid=tid, **args
-    ):
+    ) as said:
         start = time.perf_counter_ns()
         try:
-            yield
+            yield said  # what the caller adds is recorded with the span
         finally:
             metrics.observe_ms(
                 PLUGIN_EXECUTION,

@@ -73,6 +73,40 @@ def make_scheduler():
     return Scheduler(Profile(plugins=[NodeResourcesAllocatable()]))
 
 
+def trim_pads(snap, meta):
+    """`snap` with its gang and quota arrays cut to the rows that PodGroups
+    and namespaces hold: the arrays as they were sized before they landed
+    on buckets (`G = max(len(gang_pos), 1)`, `Q = max(len(namespaces),
+    1)`). A solve of it is the solve without the inert rows."""
+    import jax
+
+    out = snap
+    if snap.gangs is not None:
+        g = max(len(meta.gang_names), 1)
+        out = out.replace(gangs=jax.tree.map(lambda a: a[:g], snap.gangs))
+    if snap.quota is not None:
+        q = max(len(meta.namespaces), 1)
+        quota = snap.quota
+        out = out.replace(quota=quota.replace(
+            min=quota.min[:q], max=quota.max[:q], used=quota.used[:q],
+            has_quota=quota.has_quota[:q],
+        ))
+    return out
+
+
+def solve_exact_axes(cluster):
+    """Make `cluster.snapshot` return gang and quota arrays of exact size,
+    so that a cycle on it solves what the program solved before the
+    arrays were padded: the twin a padded solve has to equal."""
+    padded = cluster.snapshot
+
+    def snapshot(pending, now_ms=0, **kwargs):
+        snap, meta = padded(pending, now_ms=now_ms, **kwargs)
+        return trim_pads(snap, meta), meta
+
+    cluster.snapshot = snapshot
+
+
 def assert_resident_matches(engine, cluster, now):
     """Drain the sink (deltas from the cycle's own binds apply at the next
     refresh), then compare the delta-maintained resident columns against a
@@ -244,10 +278,10 @@ class TestServeEdgePaths:
         assert obs.metrics.get(obs.SERVE_REBASES) == rebases0 + 1
         assert_resident_matches(engine, cluster, 2500)
 
-    def test_extended_resource_node_disengages_then_resumes(self):
-        """A node naming a resource outside the canonical axis widens the
-        packed axis — the engine must not own that state (serves from
-        fresh snapshots), and must resume once the node goes away."""
+    def test_extended_resource_node_rebases_once_then_serves(self):
+        """A node naming a resource outside the axis triggers ONE rebase
+        that widens it; the engine owns the wider state from then on
+        (placements equal a fresh-snapshot twin's, no further rebase)."""
         cluster = make_cluster(4)
         engine = ServeEngine().attach(cluster)
         sched = make_scheduler()
@@ -258,6 +292,9 @@ class TestServeEdgePaths:
         base_sched = make_scheduler()
         run_cycle(base_sched, base, now=1000)
         assert engine.resident_nodes is not None
+        assert EXT not in engine.index
+        rebases0 = engine.rebases
+        axis0 = obs.metrics.get(obs.SERVE_AXIS_REBASES)
         cluster.add_node(make_node(50, extra={EXT: 4}))
         cluster.add_pod(make_pod(1, 1500))
         base.add_node(make_node(50, extra={EXT: 4}))
@@ -266,33 +303,58 @@ class TestServeEdgePaths:
         base_report = run_cycle(base_sched, base, now=2000)
         assert serve_report.bound == base_report.bound
         assert serve_report.bound
-        assert engine.resident_nodes is None  # disowned, not corrupted
-        # extended node drained away: serving resumes
-        for uid in [
-            u for u, p in cluster.pods.items() if p.node_name == "n050"
-        ]:
-            cluster.remove_pod(uid)
-        cluster.remove_node("n050")
+        assert engine.resident_nodes is not None  # owned, on a wider axis
+        assert engine.index.names[-1] == EXT
+        assert engine.rebases == rebases0 + 1
+        assert obs.metrics.get(obs.SERVE_AXIS_REBASES) == axis0 + 1
+        assert_resident_matches(engine, cluster, 2500)
+        # served from then on: more churn, no further rebase
         cluster.add_pod(make_pod(2, 2500))
-        run_cycle(sched, cluster, now=3000, serve=engine)
-        assert engine.resident_nodes is not None  # serving resumed
+        base.add_pod(make_pod(2, 2500))
+        assert (
+            run_cycle(sched, cluster, now=3000, serve=engine).bound
+            == run_cycle(base_sched, base, now=3000).bound
+        )
+        assert engine.rebases == rebases0 + 1
         assert_resident_matches(engine, cluster, 3500)
+        assert engine.verify(cluster) is None
 
-    def test_extended_resource_pending_pod_falls_back(self):
+    def test_extended_resource_pending_pod_rebases_once_then_serves(self):
+        """A pending pod naming a new resource is no reason to fall back:
+        the refresh that meets it rebases, the axis holds the name after."""
         cluster = make_cluster(4)
         engine = ServeEngine().attach(cluster)
         sched = make_scheduler()
+        cluster.add_pod(make_pod(90, 500))
         run_cycle(sched, cluster, now=1000, serve=engine)
+        rebases0 = engine.rebases
         pod = Pod(
             name="gpu-pod", creation_ms=1500,
             containers=[Container(requests={CPU: 100, EXT: 1})],
         )
         cluster.add_pod(pod)
         pending = cluster.pending_pods()
-        assert not engine.compatible(cluster, pending)
+        assert engine.compatible(cluster, pending)
+        assert engine._outside_axis(cluster, pending)
+        report = run_cycle(sched, cluster, now=2000, serve=engine)
+        assert pod.uid in report.failed  # no node declares the resource
+        assert engine.rebases == rebases0 + 1
+        assert EXT in engine.index
+        assert not engine._outside_axis(cluster, cluster.pending_pods())
         cluster.remove_pod(pod.uid)
-        assert engine.compatible(cluster, cluster.pending_pods())
-        assert_resident_matches(engine, cluster, 2000)
+        # the axis never narrows: a fresh snapshot told to hold the name
+        # is what the resident columns equal
+        assert engine.refresh(cluster, [], now_ms=2500) is not None
+        snap, _ = cluster.snapshot(
+            [], now_ms=2500, pad_nodes=engine.npad, extra_resources=(EXT,)
+        )
+        for col in NODE_COLUMNS:
+            np.testing.assert_array_equal(
+                np.asarray(getattr(engine.resident_nodes, col)),
+                np.asarray(getattr(snap.nodes, col)), err_msg=col,
+            )
+        assert engine.verify(cluster) is None
+        assert engine.rebases == rebases0 + 1
 
     def test_side_table_fallback_absorbs_deltas(self):
         """While a still-gating side table (node metrics) disqualifies
@@ -578,8 +640,13 @@ class TestResidentGangQuota:
                     got, want, err_msg=f"{fam}.{f.name}"
                 )
 
+    @pytest.mark.parametrize("baseline_axes", ["padded", "exact"])
     @pytest.mark.parametrize("seed", [0, 1])
-    def test_randomized_gang_quota_streams(self, seed):
+    def test_randomized_gang_quota_streams(self, seed, baseline_axes):
+        """`baseline_axes` "exact": the fresh-snapshot twin solves gang and
+        quota arrays of exact size, as before they landed on buckets; the
+        served side's are padded. Equal placements cycle for cycle: the
+        padded rows are inert."""
         from scheduler_plugins_tpu.api.objects import (
             POD_GROUP_LABEL,
             PodGroup,
@@ -588,6 +655,8 @@ class TestResidentGangQuota:
         rng = np.random.default_rng(100 + seed)
         serve_cluster = self._gang_quota_cluster()
         base_cluster = self._gang_quota_cluster()
+        if baseline_axes == "exact":
+            solve_exact_axes(base_cluster)
         engine = ServeEngine().attach(serve_cluster)
         s_sched, b_sched = self._gang_sched(), self._gang_sched()
 
