@@ -121,6 +121,13 @@ POD_TERMINATING = "pod_terminating"
 #: the mutators push this kind with the delta captured at EVENT time
 #: (the gate/terminating flags mutate in place)
 GANG_GATED = "gang_gated"
+#: what a pod adds to its node's unreported CPU may have changed (the
+#: resident `missing_cpu_millis` column): it was bound, or it is a recent
+#: binding whose pod object was replaced or deleted. Carries the uid alone:
+#: the engine reads the store at drain time, as the full snapshot would,
+#: so a dropped, doubled or reordered event cannot add a pod twice. Sent
+#: only while the store holds a load watcher's report
+BINDING_TOUCHED = "binding_touched"
 
 
 class DeltaSink:
@@ -205,6 +212,10 @@ class DeltaSink:
         drain-time re-read could double- or under-count a flip landing in
         the same drain window (the POD_ASSIGN terminating-flag rule)."""
         self._push((GANG_GATED, gang_full_name, delta))
+
+    # -- resident node metrics -----------------------------------------
+    def binding_touched(self, uid: str) -> None:
+        self._push((BINDING_TOUCHED, uid))
 
     # -- sticky compatibility flags -------------------------------------
     def note_nomination(self, pod) -> None:

@@ -38,10 +38,12 @@ is either None or one the resident state fully describes. Gang
 their aggregate tensors assemble O(G + Q) from resident side tables
 (`serving.deltas.SideTables`) maintained O(changed) from the same
 drained delta stream, docs/SERVING.md "Resident gang/quota side
-tables". NRTs/AppGroups/seccomp profiles/node metrics/selector-spec
-pods/node taints and any nomination or extended resource still gate
-(the same shape of condition as the native-store fast path in
-`Cluster.snapshot`). While incompatible, `refresh` returns None (the
+tables". The load watcher's report (`Cluster.node_metrics`) is OWNED
+since ISSUE 29 — its nine columns are lowered once per report and the
+unreported-CPU column is kept O(changed), docs/SERVING.md "Resident
+node metrics". NRTs/AppGroups/seccomp profiles/selector-spec pods/node
+taints and any nomination still gate (the same shape of condition as
+the native-store fast path in `Cluster.snapshot`). While incompatible, `refresh` returns None (the
 cycle falls back to the full snapshot) but KEEPS absorbing deltas, so
 the resident columns stay in sync and serving resumes without a rebase
 once the side objects go away.
@@ -49,6 +51,7 @@ once the side objects go away.
 
 from __future__ import annotations
 
+import heapq
 from typing import Optional
 
 import numpy as np
@@ -57,12 +60,14 @@ from scheduler_plugins_tpu.serving import deltas as D
 from scheduler_plugins_tpu.state.snapshot import (
     ClusterSnapshot,
     GangState,
+    MetricsState,
     QuotaState,
     SnapshotMeta,
     _Interner,
     build_pod_state,
     empty_quota_nominees,
     gang_object_tables,
+    node_metric_columns,
     quota_object_tables,
 )
 from scheduler_plugins_tpu.utils import observability as obs
@@ -152,6 +157,36 @@ class ServeEngine:
         #: a quota-less cluster would pay a side-delta row (and a second
         #: apply dispatch) for tables nobody reads
         self._quota_tracking = False
+        # -- resident node metrics (ISSUE 29; docs/SERVING.md) ----------
+        #: the report (`cluster.node_metrics`) the columns below were
+        #: lowered from, by identity: its writers (feed, collector) replace
+        #: the dict wholesale. None while the store holds none, and then
+        #: there is no column, no span and no staging
+        self._report = None
+        #: host `MetricsState` columns by field, rows in `_names` order,
+        #: padded to `_npad`: the nine a report gives, and under
+        #: `missing_cpu_millis` what the report itself says of it
+        self._metric_cols: Optional[dict] = None
+        #: (npad,) int64, the recent bindings' predicted CPU by node row:
+        #: what `Cluster._metrics_with_missing` adds to the report
+        self._unreported: Optional[np.ndarray] = None
+        #: uid -> (bind ms, row | None, millis) of every binding counted
+        #: in `_unreported` (row None: its node has no row, it counts 0),
+        #: and a heap of (bind ms, uid) to expire them by
+        self._recent: dict = {}
+        self._recent_heap: list = []
+        #: uids whose `BINDING_TOUCHED` events the last drain held
+        self._touched: set = set()
+        #: the `MetricsState` `_assemble` hands the solve, staged; its
+        #: arrays are replaced, never written, when a column changes
+        self._metrics_state = None
+        #: nothing is lowered yet, the rows moved (node added or compacted
+        #: away, bucket grown), or events were absorbed on a fallback
+        #: cycle: lower everything from the store
+        self._metrics_stale = True
+        #: the clock and the prediction `_unreported` was last brought to
+        self._metrics_now = 0
+        self._tlp: Optional[tuple] = None
 
     @staticmethod
     def _verify_every_default() -> int:
@@ -190,6 +225,7 @@ class ServeEngine:
         self._side_dirty = True
         self._gang_rows.clear()
         self._ns_rows.clear()
+        self._drop_metrics()
 
     @property
     def generation(self) -> int:
@@ -229,15 +265,15 @@ class ServeEngine:
         side table is either None or one the resident state fully
         describes. Gang (PodGroup) and quota (ElasticQuota) rosters are
         OWNED since ISSUE 12 — their aggregate tensors assemble from the
-        resident side tables; a resource name is no reason to fall back
-        (`_outside_axis`: the axis widens by a rebase);
-        NRTs/AppGroups/seccomp/metrics/selector-spec
-        pods/taints/nominations still fall back."""
+        resident side tables; the load watcher's report is OWNED since
+        ISSUE 29 (`_sync_metrics`); a resource name is no reason to fall
+        back (`_outside_axis`: the axis widens by a rebase);
+        NRTs/AppGroups/seccomp/selector-spec pods/taints/nominations
+        still fall back."""
         if (
             cluster.nrts
             or cluster.app_groups
             or cluster.seccomp_profiles
-            or cluster.node_metrics is not None
             or cluster._selector_spec_pods
             or self._tainted
         ):
@@ -330,6 +366,10 @@ class ServeEngine:
                 if grow:
                     self._grow(bucket_size(n_nodes))
                 self._apply_batch(upserts, usage, side)
+            # the metrics columns are not kept up on a cycle they do not
+            # serve: the next one that does lowers them from the store
+            self._metrics_stale = True
+            self._touched.clear()
             self._last = None
             return None
 
@@ -341,11 +381,12 @@ class ServeEngine:
         if grow:
             self._grow(bucket_size(n_nodes))
         self._apply_batch(upserts, usage, side)
+        self._sync_metrics(cluster, now_ms)
         self._refreshes += 1
         if self._verify_pending or (
             self.verify_every and self._refreshes % self.verify_every == 0
         ):
-            divergence = self.verify(cluster)
+            divergence = self.verify(cluster, now_ms)
             if divergence is not None:
                 return self._rebase(cluster, pending, now_ms)
         if (cluster.pod_groups or cluster.quotas) and not self._ensure_side(
@@ -455,6 +496,9 @@ class ServeEngine:
                 # delta; see Cluster._gang_gated_key)
                 gang_add(ev[1], 0, ev[2], None)
                 continue
+            if kind == D.BINDING_TOUCHED:
+                self._touched.add(ev[1])
+                continue
             if kind == D.NODE_DELETE:
                 # the row order dies with the node — but so do its label/
                 # taint entries: a deleted node must not pin `compatible`
@@ -482,6 +526,8 @@ class ServeEngine:
                     slot = len(self._names)
                     self._slots[node.name] = slot
                     self._names.append(node.name)
+                    # the report, or a recent binding, may name it
+                    self._metrics_stale = True
                     if self._gang_rows:
                         # a NEW node name can resurrect gang slack for
                         # pods already bound to it (cross-watch arrival:
@@ -721,6 +767,7 @@ class ServeEngine:
             nominated=pad1(nodes.nominated),
         )
         self._npad = new_npad
+        self._metrics_stale = True
 
     def _rebase(self, cluster, pending, now_ms: int):
         """Full re-snapshot: rebuild the resident base from the store (the
@@ -759,6 +806,9 @@ class ServeEngine:
         # tables in the same breath (their aggregates must match the
         # fresh snapshot this rebase just served from)
         self._rebuild_side_tables(cluster)
+        # and the metrics columns, through the code the snapshot just used
+        self._metrics_stale = True
+        self._sync_metrics(cluster, now_ms)
         self._generation += 1
         self._staleness = 0
         self._rebases += 1
@@ -953,6 +1003,144 @@ class ServeEngine:
             return "axis-width"
         return self._side_divergence(gangs, namespaces)
 
+    # -- resident node metrics -------------------------------------------
+    def _drop_metrics(self) -> None:
+        self._report = None
+        self._metric_cols = None
+        self._unreported = None
+        self._metrics_state = None
+        self._metrics_stale = True
+        self._recent.clear()
+        self._recent_heap.clear()
+        self._touched.clear()
+        self._tlp = None
+
+    def _sync_metrics(self, cluster, now_ms: int) -> None:
+        """Bring the resident `MetricsState` to the store's report and to
+        `now_ms`, after the drained events were classified and applied
+        (`_slots` is the store's node order). Where the store holds no
+        report this is one test. A report not seen before is lowered once,
+        O(nodes), through `node_metric_columns`, the snapshot path's own
+        code; the unreported-CPU column follows the drained
+        `BINDING_TOUCHED` events and the clock, O(changed). Equal, leaf
+        for leaf, to `cluster.snapshot(..., now_ms=now_ms)[0].metrics`
+        (tests/test_serving.py::TestResidentMetrics)."""
+        report = cluster.node_metrics
+        if report is None:
+            if self._report is not None or self._touched:
+                self._drop_metrics()
+            return
+        with obs.tracer.span("ServeRefresh/metrics", tid="serve"):
+            lower = self._metrics_stale or report is not self._report
+            if lower:
+                self._metric_cols = node_metric_columns(
+                    report, self._slots, self._npad
+                )
+                self._report = report
+                obs.metrics.inc(obs.SERVE_METRICS_RELOWERS)
+            if (
+                self._metrics_stale
+                # a clock set back revives bindings already expired here,
+                # and another prediction re-prices every one of them
+                or now_ms < self._metrics_now
+                or cluster.tlp_prediction != self._tlp
+            ):
+                self._rebuild_unreported(cluster, now_ms)
+                moved = True
+            else:
+                moved = self._expire_bindings(cluster, now_ms)
+                for uid in self._touched:
+                    moved = self._recount_binding(cluster, uid, now_ms) or moved
+            self._touched.clear()
+            self._metrics_stale = False
+            self._metrics_now = now_ms
+            if not (lower or moved):
+                return
+            missing = (
+                self._metric_cols["missing_cpu_millis"] + self._unreported
+            )
+            if lower:
+                self._metrics_state = self._stage_pods(MetricsState(**{
+                    **self._metric_cols, "missing_cpu_millis": missing,
+                }))
+            else:
+                self._metrics_state = self._metrics_state.replace(
+                    missing_cpu_millis=self._stage_pods(missing)
+                )
+
+    def _rebuild_unreported(self, cluster, now_ms: int) -> None:
+        """The recent bindings' column from the store, O(recent bindings):
+        the first build, and whenever rows, clock or prediction moved
+        under the entries held."""
+        self._unreported = np.zeros(self._npad, np.int64)
+        self._recent.clear()
+        self._recent_heap.clear()
+        self._tlp = cluster.tlp_prediction
+        for uid in cluster.recent_bindings:
+            self._recount_binding(cluster, uid, now_ms)
+
+    def _recount_binding(self, cluster, uid: str, now_ms: int) -> bool:
+        """Set what `uid` adds to `_unreported` to what the store says
+        now: `Cluster._metrics_with_missing`'s rule for one binding (its
+        pod exists, it is younger than the report interval). True when the
+        column changed."""
+        moved = False
+        old = self._recent.pop(uid, None)
+        if old is not None and old[1] is not None:
+            self._unreported[old[1]] -= old[2]
+            moved = True
+        entry = cluster.recent_bindings.get(uid)
+        pod = cluster.pods.get(uid)
+        if (
+            entry is None or pod is None
+            or now_ms - entry[0] >= cluster.METRICS_REPORT_INTERVAL_MS
+        ):
+            return moved
+        ts, node = entry
+        row = self._slots.get(node)
+        millis = pod.tlp_predicted_cpu_millis(*cluster.tlp_prediction)
+        self._recent[uid] = (ts, row, millis)
+        heapq.heappush(self._recent_heap, (ts, uid))
+        if row is not None:
+            self._unreported[row] += millis
+            moved = True
+        return moved
+
+    def _expire_bindings(self, cluster, now_ms: int) -> bool:
+        """Drop the bindings that have aged past the report interval at
+        `now_ms` (the snapshot path's inequality). O(expired); a heap
+        entry whose binding was since replaced or dropped is skipped."""
+        heap = self._recent_heap
+        interval = cluster.METRICS_REPORT_INTERVAL_MS
+        moved = False
+        while heap and now_ms - heap[0][0] >= interval:
+            ts, uid = heapq.heappop(heap)
+            held = self._recent.get(uid)
+            if held is None or held[0] != ts:
+                continue
+            del self._recent[uid]
+            if held[1] is not None:
+                self._unreported[held[1]] -= held[2]
+                moved = True
+        return moved
+
+    def _metrics_divergence(self, expected) -> Optional[str]:
+        """The staged metrics columns against `expected` (a `MetricsState`
+        of the store at the clock they were brought to, or None)."""
+        from scheduler_plugins_tpu.utils import flightrec
+
+        mine = self._metrics_state
+        if mine is None or expected is None:
+            return None if mine is expected else "metrics-presence"
+        fields = tuple(self._metric_cols)
+        if flightrec._pack_digest(
+            {k: np.asarray(getattr(mine, k)) for k in fields}
+        ) != flightrec._pack_digest(
+            {k: np.asarray(getattr(expected, k)) for k in fields}
+        ):
+            return "metrics-digest"
+        return None
+
     # -- anti-entropy ----------------------------------------------------
     def note_fault(self, reason: Optional[str] = None) -> None:
         """Treat any runtime fault (watchdog timeout/device error/garbage
@@ -962,16 +1150,23 @@ class ServeEngine:
         self._verify_pending = True
         self.last_fault = reason
 
-    def verify(self, cluster) -> Optional[str]:
+    def verify(self, cluster, now_ms: Optional[int] = None
+               ) -> Optional[str]:
         """Anti-entropy digest: blake2b over the canonical tensor bytes
         of the resident node columns (the flight-recorder content-address
-        scheme) vs the same columns of a freshly built snapshot. Returns
+        scheme) vs the same columns of a freshly built snapshot, and the
+        resident metrics columns vs that snapshot's. Returns
         a divergence reason (caller re-bases) or None (resident state is
         byte-exact). O(cluster) host work — cadenced by `verify_every`,
         forced by `note_fault`; a corrupted or dropped delta can
         therefore poison at most one verification window
-        (tests/test_resilience.py::TestAntiEntropy)."""
+        (tests/test_resilience.py::TestAntiEntropy). The snapshot is
+        taken at `now_ms`, by default the clock of the last refresh: what
+        the unreported-CPU column holds depends on it."""
         from scheduler_plugins_tpu.utils import flightrec
+
+        if now_ms is None:
+            now_ms = self._metrics_now
 
         with obs.tracer.span(
             "ServeRefresh/verify", tid="serve", staleness=self._staleness
@@ -981,7 +1176,7 @@ class ServeEngine:
             if self._nodes is None:
                 return None
             fresh, meta = cluster.snapshot(
-                [], now_ms=0, pad_nodes=self._npad,
+                [], now_ms=now_ms, pad_nodes=self._npad,
                 extra_resources=self._extended(),
             )
             reason = None
@@ -1001,6 +1196,8 @@ class ServeEngine:
                 )
                 if mine != theirs:
                     reason = "column-digest"
+            if reason is None:
+                reason = self._metrics_divergence(fresh.metrics)
             if reason is None:
                 reason = self._verify_side(cluster)
             if reason is not None:
@@ -1127,6 +1324,9 @@ class ServeEngine:
         self._gang_rows = {}
         self._ns_rows = {}
         self._quota_tracking = False
+        # the metrics columns likewise: lowered from the store's report
+        # at the first refresh (O(nodes + recent bindings), no rebase)
+        self._drop_metrics()
         self._base_digest = None
         self._last = None
         self.note_fault("checkpoint-restore")
@@ -1152,7 +1352,9 @@ class ServeEngine:
         columns re-lower O(G + Q) through the SAME
         `gang_object_tables`/`quota_object_tables` the fresh path uses,
         the per-pod AGGREGATES come from the O(changed)-maintained side
-        tables — never an O(cluster) pod loop. Two spans side by side:
+        tables — never an O(cluster) pod loop. The load watcher's report
+        rides along as the resident `MetricsState` (`_sync_metrics` brought
+        it to this cycle's clock). Two spans side by side:
         `ServeRefresh/assemble` (one a served cycle: the pod tensors) and,
         where the store holds PodGroups or quotas, `ServeRefresh/gangs`
         (their tensors)."""
@@ -1207,7 +1409,7 @@ class ServeEngine:
                 )
         snap = ClusterSnapshot(
             nodes=self._nodes, pods=pods, gangs=gang_state,
-            quota=quota_state,
+            quota=quota_state, metrics=self._metrics_state,
         )
         return snap, meta
 
@@ -1500,7 +1702,8 @@ class StreamingServeEngine(ServeEngine):
         return ups, use, side, rebase if rebase is not None else seg_rebase
 
     # -- O(assigned) anti-entropy ---------------------------------------
-    def verify(self, cluster) -> Optional[str]:
+    def verify(self, cluster, now_ms: Optional[int] = None
+               ) -> Optional[str]:
         """Anti-entropy digest without the O(cluster) snapshot rebuild:
         the expected node columns are accumulated directly from the store
         objects through the SAME shared per-pod encode
@@ -1520,8 +1723,10 @@ class StreamingServeEngine(ServeEngine):
             self._verify_pending = False
             obs.metrics.inc(obs.ANTIENTROPY_CHECKS)
             return None
+        if now_ms is None:
+            now_ms = self._metrics_now
         names = list(cluster.nodes)
-        expected = side_exp = None
+        expected = side_exp = metrics_exp = None
         if names == self._names:
             try:
                 expected, side_exp = self._expected_columns(
@@ -1532,7 +1737,8 @@ class StreamingServeEngine(ServeEngine):
                 # base engine's fresh-snapshot verify, which names it,
                 # BEFORE opening this path's span/counter (one check =
                 # one count, one span)
-                return super().verify(cluster)
+                return super().verify(cluster, now_ms)
+            metrics_exp = self._expected_metrics(cluster, now_ms)
         with obs.tracer.span(
             "ServeRefresh/verify", tid="serve", staleness=self._staleness,
             fast=True,
@@ -1550,6 +1756,8 @@ class StreamingServeEngine(ServeEngine):
                 theirs = flightrec._pack_digest(expected)
                 if mine != theirs:
                     reason = "column-digest"
+            if reason is None and expected is not None:
+                reason = self._metrics_divergence(metrics_exp)
             if reason is None and side_exp is not None:
                 reason = self._side_divergence(*side_exp)
             if reason is not None:
@@ -1562,6 +1770,19 @@ class StreamingServeEngine(ServeEngine):
                     if self.last_fault else "",
                 )
             return reason
+
+    def _expected_metrics(self, cluster, now_ms: int):
+        """The `MetricsState` a fresh `build_snapshot` at this padding and
+        clock would produce, from the store's own merge
+        (`Cluster._metrics_with_missing`) and the shared lowering: nothing
+        of the delta path is read. None where the store holds no report."""
+        merged = cluster._metrics_with_missing(now_ms)
+        if merged is None:
+            return None
+        node_pos = {name: i for i, name in enumerate(cluster.nodes)}
+        return MetricsState(
+            **node_metric_columns(merged, node_pos, self._npad)
+        )
 
     def _expected_columns(self, cluster, names, want_side=False):
         """The node columns a fresh `build_snapshot` at this padding
@@ -1713,6 +1934,7 @@ class StreamingServeEngine(ServeEngine):
                 )
             self._names.pop(slot)
             self._slots = {n: i for i, n in enumerate(self._names)}
+            self._metrics_stale = True
             if self._gang_rows:
                 # fresh snapshots drop gang slack of pods bound to a
                 # deleted node — rebuild rather than drift (the base

@@ -388,6 +388,7 @@ class Cluster:
             if new_hold is not None:
                 self.delta_sink.pod_assigned(pod, new_hold)
             self.delta_sink.note_nomination(pod)
+        self._binding_touched(pod.uid)
         if self._has_selector_specs(pod):
             # spread/affinity tables need ASSIGNED pod objects at snapshot
             # build, which the native fast path skips (pod specs are
@@ -406,6 +407,7 @@ class Cluster:
         self.unschedulable_since.pop(uid, None)
         self._clear_backoff(uid)
         pod = self.pods.pop(uid, None)
+        self._binding_touched(uid)
         # after the pop: release_reservation may have re-indexed the
         # still-present pod above; a removed uid must leave both tables
         # (a later re-add lands at the end, like the pods dict)
@@ -715,6 +717,9 @@ class Cluster:
         if led.enabled:
             led.on_bind(uid, node_name)
         self.recent_bindings[uid] = (now_ms, node_name)
+        if self.binds_total % self.BINDING_CACHE_PRUNE_EVERY == 0:
+            self._prune_recent_bindings(now_ms)
+        self._binding_touched(uid)
         if self.nrt_cache is not None:
             # Reserve -> bind -> PostBind lifecycle for the NRT cache
             self.nrt_cache.reserve(node_name, self.pods[uid])
@@ -771,6 +776,36 @@ class Cluster:
     METRICS_REPORT_INTERVAL_MS = 60_000
     #: ScheduledPodsCache GC horizon (handler.go: 5 minutes)
     BINDING_CACHE_GC_MS = 300_000
+    #: `bind` prunes the cache once in this many binds, so that it is
+    #: bounded on every path: the walk in `_metrics_with_missing` runs only
+    #: where a fresh snapshot is built, which the resident path never does
+    BINDING_CACHE_PRUNE_EVERY = 1024
+
+    def _prune_recent_bindings(self, now_ms: int) -> None:
+        """Drop the entries at the front of `recent_bindings` (a dict keeps
+        bind order) that are past the GC horizon: O(dropped), and it stops
+        at the first entry that is not — a uid bound again keeps its old
+        place with its new stamp and holds the ones behind it that much
+        longer, no more."""
+        stale = []
+        for uid, (ts, _) in self.recent_bindings.items():
+            if now_ms - ts <= self.BINDING_CACHE_GC_MS:
+                break
+            stale.append(uid)
+        for uid in stale:
+            del self.recent_bindings[uid]
+
+    def _binding_touched(self, uid: str) -> None:
+        """Tell the delta sink that what `uid` adds to its node's
+        unreported CPU (`_metrics_with_missing`) may have changed: it was
+        bound, or it is a recent binding whose pod object was replaced or
+        deleted. Nothing is sent while there is no report to add it to."""
+        if (
+            self.node_metrics is not None
+            and self.delta_sink is not None
+            and uid in self.recent_bindings
+        ):
+            self.delta_sink.binding_touched(uid)
 
     def _metrics_with_missing(self, now_ms: int):
         """Augment node metrics with the missing-utilization compensation

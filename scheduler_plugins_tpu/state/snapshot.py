@@ -610,6 +610,58 @@ def _build_quota(quotas, pending_pods, assigned_pods, extra_pods, index,
     )
 
 
+def node_metric_columns(node_metrics: dict, node_pos: dict, N: int) -> dict:
+    """The load watcher's report as `MetricsState`'s (N,) columns, by field
+    name: `node_pos` maps a node's name to its row, a node the report does
+    not name keeps zeros / not valid, and a name outside `node_pos` is
+    skipped. Shared by `build_snapshot` and the serving engine's resident
+    metrics (`serving.engine.ServeEngine._relower_metrics`) so the two
+    lowerings cannot drift."""
+    cpu_avg = np.zeros(N, F64)
+    cpu_tlp = np.zeros(N, F64)
+    cpu_peaks = np.zeros(N, F64)
+    cpu_std = np.zeros(N, F64)
+    mem_avg = np.zeros(N, F64)
+    mem_std = np.zeros(N, F64)
+    cpu_valid = np.zeros(N, bool)
+    cpu_tlp_valid = np.zeros(N, bool)
+    mem_valid = np.zeros(N, bool)
+    missing = np.zeros(N, I64)
+    for name, m in node_metrics.items():
+        if name not in node_pos:
+            continue
+        i = node_pos[name]
+        if "cpu_avg" in m:
+            cpu_avg[i] = m["cpu_avg"]
+        cpu_tlp[i] = m.get("cpu_tlp", m.get("cpu_avg", 0.0))
+        cpu_peaks[i] = m.get(
+            "cpu_peaks", m.get("cpu_tlp", m.get("cpu_avg", 0.0))
+        )
+        cpu_std[i] = m.get("cpu_std", 0.0)
+        # a node with ANY cpu sample (avg/latest or std-only) is valid:
+        # GetResourceData returns isValid=true, avg=0 for std-only
+        # (resourcestats.go:88-106)
+        cpu_valid[i] = "cpu_avg" in m or "cpu_std" in m
+        cpu_tlp_valid[i] = "cpu_tlp" in m or "cpu_avg" in m
+        if "mem_avg" in m:
+            mem_avg[i] = m["mem_avg"]
+        mem_valid[i] = "mem_avg" in m or "mem_std" in m
+        mem_std[i] = m.get("mem_std", 0.0)
+        missing[i] = m.get("missing_cpu_millis", 0)
+    return dict(
+        cpu_avg=cpu_avg,
+        cpu_tlp=cpu_tlp,
+        cpu_peaks=cpu_peaks,
+        cpu_std=cpu_std,
+        mem_avg=mem_avg,
+        mem_std=mem_std,
+        cpu_valid=cpu_valid,
+        cpu_tlp_valid=cpu_tlp_valid,
+        mem_valid=mem_valid,
+        missing_cpu_millis=missing,
+    )
+
+
 def build_snapshot(
     nodes: Sequence[Node],
     pending_pods: Sequence[Pod],
@@ -823,48 +875,8 @@ def build_snapshot(
     # --- metrics --------------------------------------------------------
     metrics_state = None
     if node_metrics is not None:
-        cpu_avg = np.zeros(N, F64)
-        cpu_tlp = np.zeros(N, F64)
-        cpu_peaks = np.zeros(N, F64)
-        cpu_std = np.zeros(N, F64)
-        mem_avg = np.zeros(N, F64)
-        mem_std = np.zeros(N, F64)
-        cpu_valid = np.zeros(N, bool)
-        cpu_tlp_valid = np.zeros(N, bool)
-        mem_valid = np.zeros(N, bool)
-        missing = np.zeros(N, I64)
-        for name, m in node_metrics.items():
-            if name not in node_pos:
-                continue
-            i = node_pos[name]
-            if "cpu_avg" in m:
-                cpu_avg[i] = m["cpu_avg"]
-            cpu_tlp[i] = m.get("cpu_tlp", m.get("cpu_avg", 0.0))
-            cpu_peaks[i] = m.get(
-                "cpu_peaks", m.get("cpu_tlp", m.get("cpu_avg", 0.0))
-            )
-            cpu_std[i] = m.get("cpu_std", 0.0)
-            # a node with ANY cpu sample (avg/latest or std-only) is valid:
-            # GetResourceData returns isValid=true, avg=0 for std-only
-            # (resourcestats.go:88-106)
-            cpu_valid[i] = "cpu_avg" in m or "cpu_std" in m
-            cpu_tlp_valid[i] = "cpu_tlp" in m or "cpu_avg" in m
-            if "mem_avg" in m:
-                mem_avg[i] = m["mem_avg"]
-            mem_valid[i] = "mem_avg" in m or "mem_std" in m
-            mem_std[i] = m.get("mem_std", 0.0)
-            missing[i] = m.get("missing_cpu_millis", 0)
         metrics_state = MetricsState(
-            cpu_avg=cpu_avg,
-            cpu_tlp=cpu_tlp,
-            cpu_peaks=cpu_peaks,
-            cpu_std=cpu_std,
-            mem_avg=mem_avg,
-            mem_std=mem_std,
-            cpu_valid=cpu_valid,
-            cpu_tlp_valid=cpu_tlp_valid,
-            mem_valid=mem_valid,
-            missing_cpu_millis=missing,
+            **node_metric_columns(node_metrics, node_pos, N)
         )
 
     # --- numa -----------------------------------------------------------

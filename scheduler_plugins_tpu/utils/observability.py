@@ -367,6 +367,12 @@ SERVE_AXIS_REBASES = "scheduler_serve_axis_rebases_total"
 #: — the production signal that the resident-gang win is actually
 #: engaged (`make endurance-smoke` gates it)
 SERVE_GANG_FALLBACKS = "scheduler_serve_gang_fallbacks_total"
+#: times the serving engine lowered the load watcher's report into its
+#: resident metrics columns, O(nodes): once per report (and per change of
+#: the node rows under one). A count that grows with cycles means the
+#: O(nodes) lowering is back on every tick (serving/engine.py
+#: `_sync_metrics`)
+SERVE_METRICS_RELOWERS = "scheduler_serve_metrics_relowers_total"
 #: gauge (labels: objective): the latest cycle's placement-quality
 #: objective values (tuning.quality — fragmentation, util_imbalance,
 #: gang_wait_frac, unplaced_frac, preemptions, nominations), stamped by
@@ -533,6 +539,8 @@ HELP: dict[str, str] = {
     SERVE_GANG_FALLBACKS:
         "Serve refreshes that fell back to a full snapshot on a gang "
         "roster.",
+    SERVE_METRICS_RELOWERS:
+        "Lowerings of the load watcher's report into resident columns.",
     PLACEMENT_QUALITY:
         "Latest cycle's placement-quality objective values (gauge).",
     DEGRADED: "1 while serving from the host-side parity solve (gauge).",

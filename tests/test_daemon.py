@@ -201,8 +201,10 @@ class TestDaemonE2E:
                     except urllib.error.HTTPError:
                         return False  # cycle not recorded yet
                     # find() prefers complete records (outputs captured),
-                    # so placed resolves once the first cycle commits
-                    if t.get("placed") is None:
+                    # so placed resolves once the first cycle commits; a
+                    # cycle that ran before the node list landed has no
+                    # candidate to show
+                    if t.get("placed") is None or not t.get("candidates"):
                         return False
                     tables.append(t)
                     return True
@@ -833,3 +835,102 @@ class TestServedLoopTrace:
     def test_export_carries_its_origin_on_the_monotonic_clock(self, served):
         origin = served["trace"]["otherData"]["origin_monotonic_ns"]
         assert isinstance(origin, int) and origin > 0
+
+
+class TestServedTrimaran:
+    """The load-aware profile on the served path (ISSUE 29): the load
+    watcher's report arrives over the feed as a `metrics` event and is
+    resident state of the engine, so no cycle falls back, each report is
+    lowered once, and the placements are those of the same feed replayed
+    through a daemon that builds a fresh snapshot every cycle."""
+
+    PROFILE = {"plugins": ["TargetLoadPacking", "LoadVariationRiskBalancing"],
+               "pluginConfig": []}
+    NODES = 12
+
+    def _report(self, wave: int) -> dict:
+        return {"op": "metrics", "nodes": {
+            f"n{i:02d}": {"cpu_avg": float((7 * i + 13 * wave) % 60),
+                          "cpu_std": float(i % 5),
+                          "mem_avg": float((11 * i + wave) % 50),
+                          "mem_std": 1.0}
+            for i in range(self.NODES) if (i + wave) % 5  # some unnamed
+        }}
+
+    def _replay(self, tmp_path, monkeypatch, *flags):
+        from scheduler_plugins_tpu import __main__ as daemon_main
+        from scheduler_plugins_tpu.bridge.feed import apply_event
+        from scheduler_plugins_tpu.utils import observability as obs
+
+        monkeypatch.setattr(daemon_main.signal, "signal", lambda *_: None)
+        profile = tmp_path / "trimaran.json"
+        profile.write_text(json.dumps(self.PROFILE))
+        daemon = daemon_main.Daemon(daemon_main.parse_args([
+            "--profile", str(profile), "--health-port", "-1", "--no-ledger",
+            "--cycle-interval-s", "1.0", *flags,
+        ]))
+        relowers0 = obs.metrics.get(obs.SERVE_METRICS_RELOWERS) or 0
+        fallbacks = []
+        try:
+            def apply(*events):
+                with daemon.feed.locked():
+                    for event in events:
+                        assert apply_event(daemon.cluster, event)["ok"], event
+
+            apply(*[
+                {"op": "upsert_node", "name": f"n{i:02d}",
+                 "allocatable": {"cpu": 4000 * (1 + i % 3),
+                                 "memory": 16 << 30, "pods": 110}}
+                for i in range(self.NODES)
+            ])
+            placed = {}
+            for wave in range(2):
+                # a wave applied whole is one cycle's batch on both daemons
+                apply(self._report(wave), *[
+                    {"op": "upsert_pod", "name": f"w{wave}p{j:02d}",
+                     "creation_ms": 100 * wave + j,
+                     "requests": {"cpu": 100 + 50 * (j % 7),
+                                  "memory": 256 << 20}}
+                    for j in range(20)
+                ])
+                if wave:
+                    # a departure inside the minute: its unreported CPU
+                    # leaves its node on both paths
+                    apply({"op": "delete_pod", "name": "w0p03"})
+                daemon.tick()
+                if daemon.engine is not None:
+                    fallbacks.append(daemon.engine._last is None)
+                with daemon.feed.locked():
+                    placed.update({
+                        uid: pod.node_name
+                        for uid, pod in daemon.cluster.pods.items()
+                    })
+            relowers = (
+                obs.metrics.get(obs.SERVE_METRICS_RELOWERS) or 0
+            ) - relowers0
+            return placed, daemon.engine, relowers, fallbacks
+        finally:
+            daemon.feed.stop()
+
+    def test_reports_are_resident_and_placements_match_fresh_snapshots(
+        self, tmp_path, monkeypatch
+    ):
+        fresh, no_engine, none_lowered, _ = self._replay(
+            tmp_path, monkeypatch
+        )
+        assert no_engine is None and none_lowered == 0
+        served, engine, relowers, fallbacks = self._replay(
+            tmp_path, monkeypatch, "--serve"
+        )
+        assert all(node is not None for node in served.values())
+        assert len(set(served.values())) > 1
+        assert served == fresh
+        assert engine.rebases == 1  # the cold build, and nothing since
+        assert fallbacks == [False, False]
+        assert relowers == 2  # one per report sent
+        assert engine.antientropy_divergences == 0
+        # the last cycle's binds are still in the sink: drain, then compare
+        assert engine.refresh(
+            engine._cluster, [], now_ms=engine._metrics_now
+        ) is not None
+        assert engine.verify(engine._cluster) is None

@@ -537,6 +537,29 @@ class TestStreamingServeEngine:
         engine._names = list(reversed(engine._names))
         assert engine.verify(c) == "row-order"
 
+    @pytest.mark.parametrize("column", ["cpu_tlp_valid", "missing_cpu_millis"])
+    def test_fast_verify_covers_the_metrics_columns(self, column):
+        """The expectation of the resident metrics columns comes from the
+        store's own merge at the clock of the last refresh: clean state
+        verifies, one flipped cell does not, and the base engine's
+        fresh-snapshot verify says the same of the same state."""
+        c, engine = self._churny()
+        c.node_metrics = {f"n{i}": {"cpu_avg": 20.0 + i} for i in range(4)}
+        sched = Scheduler(Profile(plugins=[NodeResourcesAllocatable()]))
+        c.add_pod(mkpod("seed", created=40))
+        run_cycle(sched, c, now=1000, serve=engine)
+        assert engine.refresh(c, [], now_ms=1500) is not None
+        assert c.recent_bindings  # the seed's bind is unreported
+        assert engine.verify(c) is None
+        assert ServeEngine.verify(engine, c) is None
+        assert engine.verify(c, now_ms=70_000) == "metrics-digest"  # expired
+        state = engine._metrics_state
+        cell = np.asarray(getattr(state, column)).copy()
+        cell[4] = not cell[4] if cell.dtype == bool else cell[4] + 1
+        engine._metrics_state = state.replace(**{column: cell})
+        assert engine.verify(c) == "metrics-digest"
+        assert ServeEngine.verify(engine, c) == "metrics-digest"
+
     def test_row_cache_is_bit_identical(self):
         from scheduler_plugins_tpu.serving import deltas as D
         from scheduler_plugins_tpu.state.snapshot import (

@@ -342,6 +342,39 @@ class TestAntiEntropy:
         assert engine.antientropy_divergences == div1
         assert r.bound == rc.bound
 
+    @pytest.mark.parametrize("column", ["cpu_avg", "missing_cpu_millis"])
+    def test_corrupted_metrics_column_recovers_in_one_window(
+        self, shared_scheduler, column
+    ):
+        """The resident metrics columns (the load watcher's report and the
+        unreported CPU) are under the same digest: a corrupted cell is
+        detected at the next refresh and re-based away."""
+        s = shared_scheduler
+        cluster = make_cluster()
+        cluster.node_metrics = {
+            name: {"cpu_avg": 10.0 + i, "mem_avg": 5.0}
+            for i, name in enumerate(cluster.nodes)
+        }
+        engine = ServeEngine().attach(cluster)
+        engine.verify_every = 1
+        for now in (1000, 2000):
+            serve_cycle(s, cluster, engine, now, serial=[now])
+        assert engine.refresh(cluster, [], now_ms=2500) is not None
+        assert engine.verify(cluster) is None
+        state = engine._metrics_state
+        cell = np.asarray(getattr(state, column)).copy()
+        cell[1] += 7
+        engine._metrics_state = state.replace(**{column: cell})
+        assert engine.verify(cluster) == "metrics-digest"
+        div0, rebases0 = engine.antientropy_divergences, engine.rebases
+        serve_cycle(s, cluster, engine, 3000, serial=[3000])
+        assert engine.antientropy_divergences == div0 + 1  # detected
+        assert engine.rebases == rebases0 + 1
+        serve_cycle(s, cluster, engine, 4000, serial=[4000])
+        assert engine.antientropy_divergences == div0 + 1  # and gone
+        assert engine.refresh(cluster, [], now_ms=4500) is not None
+        assert engine.verify(cluster) is None
+
     def test_dropped_sink_event_detected_within_window(
         self, shared_scheduler, no_faults
     ):

@@ -21,6 +21,7 @@ from scheduler_plugins_tpu.api.objects import (
     ElasticQuota,
     Node,
     Pod,
+    SeccompProfile,
     Taint,
 )
 from scheduler_plugins_tpu.api.resources import CPU, MEMORY, PODS
@@ -357,11 +358,13 @@ class TestServeEdgePaths:
         assert engine.rebases == rebases0 + 1
 
     def test_side_table_fallback_absorbs_deltas(self):
-        """While a still-gating side table (node metrics) disqualifies
+        """While a still-gating side table (a seccomp profile) disqualifies
         serve mode, the cycle falls back to full snapshots but the
         resident columns keep absorbing deltas — serving resumes WITHOUT
         a rebase. (Gang/quota rosters no longer gate — ISSUE 12's
-        resident side tables own them; see TestResidentGangQuota.)"""
+        resident side tables own them, see TestResidentGangQuota; nor
+        does the load watcher's report since ISSUE 29, see
+        TestResidentMetrics.)"""
         cluster = make_cluster(6)
         engine = ServeEngine().attach(cluster)
         sched = make_scheduler()
@@ -369,14 +372,16 @@ class TestServeEdgePaths:
         run_cycle(sched, cluster, now=1000, serve=engine)
         assert engine.resident_nodes is not None
         rebases0 = obs.metrics.get(obs.SERVE_REBASES)
-        cluster.node_metrics = {"n000": {"cpu_avg": 50.0}}
+        cluster.add_seccomp_profile(
+            SeccompProfile(name="sp", syscalls=frozenset({"read"}))
+        )
         assert not engine.compatible(cluster, [])
         for cycle in range(3):
             now = 2000 + 1000 * cycle
             cluster.add_pod(make_pod(cycle + 1, now))
             report = run_cycle(sched, cluster, now=now, serve=engine)
             assert report.bound  # fallback cycles still place
-        cluster.node_metrics = None
+        cluster.seccomp_profiles.clear()
         assert obs.metrics.get(obs.SERVE_REBASES) == rebases0
         assert_resident_matches(engine, cluster, 9000)
 
@@ -854,3 +859,325 @@ class TestResidentGangQuota:
         cluster.pod_groups.clear()
         assert engine.refresh(cluster, [], now_ms=3000) is None
         assert engine.gang_fallbacks == 1
+
+
+# ---------------------------------------------------------------------------
+# resident node metrics (ISSUE 29)
+# ---------------------------------------------------------------------------
+
+
+def report_for(names, base=20.0):
+    """A load watcher's report naming `names`: cpu and memory averages that
+    differ by node, as the feed's `metrics` op would install it."""
+    return {
+        name: {
+            "cpu_avg": base + i, "cpu_std": 1.0 + i,
+            "mem_avg": 2 * base + i, "mem_std": 0.5,
+        }
+        for i, name in enumerate(names)
+    }
+
+
+def bind_new(cluster, serial, node, now, cpu=500):
+    pod = make_pod(serial, now, cpu=cpu)
+    cluster.add_pod(pod)
+    cluster.bind(pod.uid, node, now_ms=now)
+    return pod.uid
+
+
+def assert_metrics_equal_fresh(engine, cluster, now):
+    """The engine's `MetricsState` at `now` against the fresh path's at the
+    same clock: leaf by leaf, dtype, shape and every value."""
+    import dataclasses
+
+    refreshed = engine.refresh(cluster, [], now_ms=now)
+    assert refreshed is not None, "engine fell back while compatible"
+    mine = refreshed[0].metrics
+    fresh = cluster.snapshot([], now_ms=now, pad_nodes=engine.npad)[0].metrics
+    if fresh is None:
+        assert mine is None
+        return
+    assert mine is not None
+    for leaf in dataclasses.fields(fresh):
+        a = np.asarray(getattr(mine, leaf.name))
+        b = np.asarray(getattr(fresh, leaf.name))
+        assert a.dtype == b.dtype, (leaf.name, now)
+        assert a.shape == b.shape, (leaf.name, now)
+        np.testing.assert_array_equal(
+            a, b, err_msg=f"metrics column {leaf.name} at {now} ms"
+        )
+    assert engine.verify(cluster) is None
+
+
+def _case_first_report(c, check):
+    check(1000)  # no report yet: no MetricsState on either side
+    c.node_metrics = report_for(list(c.nodes))
+    check(2000)
+    check(3000)
+
+
+def _case_binds_inside_the_minute(c, check):
+    c.node_metrics = report_for(list(c.nodes))
+    check(500)
+    bind_new(c, 1, "n000", 1000)
+    bind_new(c, 2, "n001", 2000, cpu=1500)
+    bind_new(c, 3, "n000", 2000)
+    check(3000)
+    bind_new(c, 4, "n002", 30_000)
+    check(30_000)
+    check(59_000)
+
+
+def _case_clock_crosses_sixty_seconds(c, check):
+    c.node_metrics = report_for(list(c.nodes))
+    bind_new(c, 1, "n000", 1000)
+    bind_new(c, 2, "n001", 1001)
+    check(1500)
+    check(60_999)  # both still unreported
+    check(61_000)  # now - ts == 60,000: the first is dropped (>=)
+    check(61_001)  # and the second
+    check(62_000)
+
+
+def _case_pod_deleted_inside_the_minute(c, check):
+    c.node_metrics = report_for(list(c.nodes))
+    uid = bind_new(c, 1, "n000", 1000)
+    bind_new(c, 2, "n000", 1000)
+    check(2000)
+    c.remove_pod(uid)
+    check(3000)
+    # the uid comes back unbound inside the minute: the fresh path counts
+    # its binding again (the entry outlives the pod)
+    c.add_pod(make_pod(1, 4000, cpu=700))
+    check(5000)
+
+
+def _case_second_report(c, check):
+    names = list(c.nodes)
+    c.node_metrics = report_for(names)
+    bind_new(c, 1, "n001", 1000)
+    check(2000)
+    c.node_metrics = {
+        # n000 and n002 dropped; n001 gains the two overrides; n003 has a
+        # deviation only; n004 says itself what is unreported
+        "n001": {"cpu_avg": 30.0, "cpu_tlp": 44.0, "cpu_peaks": 55.0,
+                 "mem_avg": 10.0},
+        "n003": {"cpu_std": 3.5, "mem_std": 1.25},
+        "n004": {"cpu_tlp": 12.0, "missing_cpu_millis": 250},
+        "n005": {"cpu_avg": 7.0},
+    }
+    check(31_000)
+    bind_new(c, 2, "n004", 32_000)
+    check(33_000)
+
+
+def _case_node_the_report_does_not_name(c, check):
+    c.node_metrics = report_for(["n000", "n001", "ghost"])
+    check(1000)
+    bind_new(c, 1, "n004", 2000)  # not in the report: counted all the same
+    check(3000)
+
+
+def _case_node_added(c, check):
+    c.node_metrics = report_for(list(c.nodes) + ["n006", "n040"])
+    bind_new(c, 1, "n002", 1000)
+    check(2000)
+    c.add_node(make_node(6))  # the report already names it
+    bind_new(c, 2, "n006", 3000)
+    check(4000)
+    for i in range(7, 70):  # past the bucket: the columns grow
+        c.add_node(make_node(i))
+    check(5000)
+    bind_new(c, 3, "n040", 6000)
+    check(7000)
+
+
+def _case_node_deleted(c, check):
+    c.node_metrics = report_for(list(c.nodes))
+    uid = bind_new(c, 1, "n001", 1000)
+    bind_new(c, 2, "n003", 1000)
+    check(2000)
+    c.remove_pod(uid)
+    c.remove_node("n001")  # rebase, or row compaction on the streaming engine
+    check(3000)
+    bind_new(c, 3, "n005", 3500)
+    check(4000)
+    # a node deleted under a recent binding takes its share with it
+    c.remove_node("n003")
+    check(5000)
+    check(70_000)
+
+
+def _case_report_none_and_back(c, check):
+    c.node_metrics = report_for(list(c.nodes))
+    bind_new(c, 1, "n000", 1000)
+    check(2000)
+    c.node_metrics = None
+    bind_new(c, 2, "n001", 3000)  # no event is sent while there is none
+    check(4000)
+    c.node_metrics = report_for(list(c.nodes), base=40.0)
+    check(5000)
+    bind_new(c, 3, "n001", 6000)
+    check(7000)
+
+
+def _case_rebase_mid_sequence(c, check):
+    c.node_metrics = report_for(list(c.nodes))
+    bind_new(c, 1, "n000", 1000)
+    check(2000)
+    relabelled = make_node(2)
+    relabelled.labels[ZONE_LABEL] = "z9"  # a label change rebases
+    c.add_node(relabelled)
+    bind_new(c, 2, "n002", 2500)
+    check(3000)
+    bind_new(c, 3, "n000", 3500)
+    check(4000)
+    check(61_000)
+
+
+def _case_pod_replaced_or_rebound(c, check):
+    c.node_metrics = report_for(list(c.nodes))
+    uid = bind_new(c, 1, "n000", 1000)
+    check(2000)
+    bigger = make_pod(1, 1000, cpu=2000)  # same uid, another prediction
+    bigger.node_name = "n000"
+    c.add_pod(bigger)
+    check(3000)
+    c.add_pod(make_pod(1, 1000, cpu=900))  # a stale echo drops the node
+    check(4000)
+    c.bind(uid, "n004", now_ms=5000)  # bound again, elsewhere, later
+    check(6000)
+    check(61_000)  # the first binding's minute is over, the second's is not
+    check(65_000)
+
+
+def _case_clock_set_back_and_prediction_changed(c, check):
+    c.node_metrics = report_for(list(c.nodes))
+    bind_new(c, 1, "n000", 1000)
+    bind_new(c, 2, "n001", 40_000)
+    check(62_000)  # the first has expired
+    check(50_000)  # a clock set back revives it
+    c.tlp_prediction = (2.0, 500)
+    check(51_000)
+
+
+def _case_fallback_interlude(c, check):
+    c.node_metrics = report_for(list(c.nodes))
+    bind_new(c, 1, "n000", 1000)
+    check(2000)
+    c.add_seccomp_profile(
+        SeccompProfile(name="sp", syscalls=frozenset({"read"}))
+    )
+    bind_new(c, 2, "n001", 3000)  # absorbed on a cycle that falls back
+    c.node_metrics = report_for(list(c.nodes), base=33.0)
+    # the engine under test is the one `check` refreshes
+    c.seccomp_profiles.clear()
+    check(5000)
+
+
+METRICS_CASES = {
+    "first_report": _case_first_report,
+    "binds_inside_the_minute": _case_binds_inside_the_minute,
+    "clock_crosses_sixty_seconds": _case_clock_crosses_sixty_seconds,
+    "pod_deleted_inside_the_minute": _case_pod_deleted_inside_the_minute,
+    "second_report": _case_second_report,
+    "node_the_report_does_not_name": _case_node_the_report_does_not_name,
+    "node_added": _case_node_added,
+    "node_deleted": _case_node_deleted,
+    "report_none_and_back": _case_report_none_and_back,
+    "rebase_mid_sequence": _case_rebase_mid_sequence,
+    "pod_replaced_or_rebound": _case_pod_replaced_or_rebound,
+    "clock_set_back_and_prediction_changed":
+        _case_clock_set_back_and_prediction_changed,
+    "fallback_interlude": _case_fallback_interlude,
+}
+
+
+class TestResidentMetrics:
+    """The load watcher's report as resident state (ISSUE 29): after every
+    step of a scripted sequence the engine's `MetricsState` equals the
+    fresh snapshot's at the same clock, and `verify` agrees."""
+
+    @pytest.mark.parametrize("case", sorted(METRICS_CASES))
+    @pytest.mark.parametrize("streaming", [False, True],
+                             ids=["base", "streaming"])
+    def test_resident_metrics_equal_fresh(self, case, streaming):
+        from scheduler_plugins_tpu.serving import StreamingServeEngine
+
+        cluster = make_cluster(6)
+        engine = (StreamingServeEngine if streaming else ServeEngine)()
+        engine.attach(cluster)
+        assert engine.refresh(cluster, [], now_ms=0) is not None  # cold build
+
+        def check(now):
+            assert_metrics_equal_fresh(engine, cluster, now)
+
+        METRICS_CASES[case](cluster, check)
+        assert engine.antientropy_divergences == 0
+
+    def test_one_lowering_per_report(self):
+        """`scheduler_serve_metrics_relowers_total` counts reports, not
+        cycles: binds and expiries never lower the report again."""
+        cluster = make_cluster(6)
+        engine = ServeEngine().attach(cluster)
+        engine.refresh(cluster, [], now_ms=0)
+        count0 = obs.metrics.get(obs.SERVE_METRICS_RELOWERS) or 0
+        cluster.node_metrics = report_for(list(cluster.nodes))
+        for cycle in range(50):
+            now = 1000 + 2000 * cycle  # 100 s: binds are added and expire
+            bind_new(cluster, cycle, f"n{cycle % 6:03d}", now)
+            assert engine.refresh(cluster, [], now_ms=now) is not None
+        assert obs.metrics.get(obs.SERVE_METRICS_RELOWERS) == count0 + 1
+        assert 0 < len(engine._recent) <= 30
+        cluster.node_metrics = report_for(list(cluster.nodes), base=50.0)
+        assert_metrics_equal_fresh(engine, cluster, 200_000)
+        assert obs.metrics.get(obs.SERVE_METRICS_RELOWERS) == count0 + 2
+        assert engine.rebases == 1
+
+    def test_no_report_no_events_no_state(self):
+        """Where the store holds no report the mechanism is absent: no
+        `binding_touched` event, no column, no `MetricsState`."""
+        from scheduler_plugins_tpu.serving import deltas as D
+
+        cluster = make_cluster(6)
+        engine = ServeEngine().attach(cluster)
+        engine.refresh(cluster, [], now_ms=0)
+        uid = bind_new(cluster, 1, "n000", 1000)
+        cluster.remove_pod(uid)
+        assert not [
+            e for e in engine._sink.events if e[0] == D.BINDING_TOUCHED
+        ]
+        snap, _ = engine.refresh(cluster, [], now_ms=2000)
+        assert snap.metrics is None
+        assert engine._metric_cols is None and not engine._recent
+
+    @pytest.mark.parametrize("with_report", [False, True],
+                             ids=["no_report", "report"])
+    def test_recent_bindings_stay_bounded(self, with_report):
+        """1,000 resident cycles with the clock advancing: the store's
+        binding cache is pruned where pods are bound (no fresh snapshot is
+        ever built here), and the engine's own entries live a minute."""
+        cluster = make_cluster(6)
+        engine = ServeEngine().attach(cluster)
+        engine.verify_every = 0
+        if with_report:
+            cluster.node_metrics = report_for(list(cluster.nodes))
+        peak = 0
+        for cycle in range(1000):
+            now = 2000 * cycle  # 2 s a cycle, 2,000 s in all
+            for j in range(3):
+                uid = bind_new(cluster, 3 * cycle + j, f"n{j:03d}", now)
+            cluster.remove_pod(uid)  # one departure per cycle
+            assert engine.refresh(cluster, [], now_ms=now) is not None
+            peak = max(peak, len(cluster.recent_bindings))
+        assert engine.rebases == 1
+        # 5 minutes of binds at 3 per 2 s and what gathers between two
+        # prunings, against 3,000 without
+        assert peak <= (
+            3 * (cluster.BINDING_CACHE_GC_MS // 2000 + 1)
+            + cluster.BINDING_CACHE_PRUNE_EVERY
+        )
+        if with_report:
+            assert len(engine._recent) <= 3 * 30
+            assert len(engine._recent_heap) <= 3 * 31
+            assert_metrics_equal_fresh(engine, cluster, 2_000_000)

@@ -67,7 +67,8 @@ def gpu_pod(name, namespace, now, gang=None, gpus=1, extra=None):
 
 def assert_leaf_for_leaf(engine, cluster, now):
     """The engine's snapshot of the store as it stands against a fresh
-    `build_snapshot` of it: every leaf of nodes, pods, gangs and quota."""
+    `build_snapshot` of it: every leaf of nodes, pods, gangs, quota and
+    metrics."""
     pending = cluster.pending_pods()
     refreshed = engine.refresh(cluster, pending, now_ms=now)
     assert refreshed is not None, "the engine fell back"
@@ -78,7 +79,7 @@ def assert_leaf_for_leaf(engine, cluster, now):
     assert meta.index.names == fresh_meta.index.names == engine.index.names
     assert meta.gang_names == fresh_meta.gang_names
     assert set(meta.namespaces) == set(fresh_meta.namespaces)
-    for family in ("nodes", "pods", "gangs", "quota"):
+    for family in ("nodes", "pods", "gangs", "quota", "metrics"):
         got, want = getattr(mine, family), getattr(fresh, family)
         assert (got is None) == (want is None), family
         if got is None:
@@ -241,10 +242,6 @@ def _seccomp(cluster, engine):
     cluster.seccomp_profiles["default/sp"] = object()
 
 
-def _node_metrics(cluster, engine):
-    cluster.node_metrics = {"n000": {"cpu_avg": 50.0}}
-
-
 def _tainted_node(cluster, engine):
     node = Node(name="n000", allocatable={CPU: 8000, MEMORY: 32 * gib,
                                           PODS: 32})
@@ -278,7 +275,7 @@ def _pending(**spec):
 
 
 @pytest.mark.parametrize("refuse", [
-    _nrt, _app_group, _seccomp, _node_metrics, _tainted_node,
+    _nrt, _app_group, _seccomp, _tainted_node,
     _gated_nominee, _reserved_nominee,
     _pending(node_selector={"disk": "ssd"}),
     _pending(node_affinity_required=[{"disk": ["ssd"]}]),
@@ -295,3 +292,17 @@ def test_compatible_still_refuses_each_of_its_other_cases(refuse):
     pending = cluster.pending_pods()
     assert not engine.compatible(cluster, pending)
     assert engine.refresh(cluster, pending, now_ms=2000) is None
+
+
+def test_compatible_no_longer_refuses_a_load_watchers_report():
+    """ISSUE 29 took `node_metrics` off the list above: the report is
+    resident state, the cycle is served and its snapshot carries it."""
+    cluster = make_cluster(4)
+    engine = ServeEngine().attach(cluster)
+    cluster.add_pod(make_pod(1, 500))
+    run_cycle(make_scheduler(), cluster, now=1000, serve=engine)
+    cluster.node_metrics = {"n000": {"cpu_avg": 50.0}}
+    cluster.add_pod(make_pod(2, 1500))
+    assert engine.compatible(cluster, cluster.pending_pods())
+    assert_leaf_for_leaf(engine, cluster, 2000)
+    assert engine.rebases == 1
