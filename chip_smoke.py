@@ -18,11 +18,12 @@ starts no child.
   in four fenced waves. Every pod must bind; every solved cycle must come
   from resident state and be bit-equal to the numpy twin
   `resilience.host_sequential_solve` on the recorded cycle inputs.
-- Phase B, the throughput path: the north-star chunk pipeline as
-  `bench.py --config 6` builds it, 10,240 nodes x 102,400 pods.
-- Phase C, every plugin profile once at its BASELINE shape: `bench.py`
-  configs 2-5 through `Scheduler.solve`, on the chip and on the host CPU
-  backend in this same process, bit-equal.
+- Phase B, the throughput path: the north-star chunk pipeline
+  (`parallel.pipeline.north_star_chunk_solver` on
+  `models.problems.north_star_problem`), 10,240 nodes x 102,400 pods.
+- Phase C, every plugin profile once at its BASELINE shape:
+  `models.problems.config_problem` configs 2-5 through `Scheduler.solve`,
+  on the chip and on the host CPU backend in this same process, bit-equal.
 - Phase D (`--devices 4` only): the sharded wave solve on a real four-chip
   node mesh, lax collectives and compiled Pallas ring kernels, bit-equal to
   phase B's one-device placements.
@@ -58,7 +59,7 @@ SIZES = {
     "real": {
         "serve": dict(n_nodes=5000, init_pods=1000, waves=4, wave_pods=2500,
                       interval_s=2.0),
-        "north_star": None,  # bench.NORTH_STAR_SHAPE
+        "north_star": None,  # problems.NORTH_STAR_SHAPE
         "profiles": {2: None, 3: None, 4: None, 5: None},  # BASELINE shapes
     },
     "rehearsal": {
@@ -439,23 +440,22 @@ def phase_north_star(phase: Phase, shape: dict) -> dict:
     import jax.numpy as jnp
     import numpy as np
 
-    import bench
+    from scheduler_plugins_tpu.models import problems
     from scheduler_plugins_tpu.ops.fit import free_capacity
-    from scheduler_plugins_tpu.parallel.pipeline import run_chunk_pipeline
+    from scheduler_plugins_tpu.parallel.pipeline import (
+        north_star_chunk_solver,
+        run_chunk_pipeline,
+    )
     from scheduler_plugins_tpu.tuning import gates
     from scheduler_plugins_tpu.utils import observability as obs
 
     n_pods, chunk = shape["n_pods"], shape["chunk"]
-    _cluster, snap, _meta, weights, raw, padded = bench.north_star_problem(
+    _cluster, snap, _meta, weights, raw, padded = problems.north_star_problem(
         shape["n_nodes"], n_pods, chunk
     )
-    solve_chunk = bench.north_star_chunk_solver()
+    solve_chunk = north_star_chunk_solver()
     req_np = np.asarray(snap.pods.req)
-    mask_np = np.asarray(snap.pods.mask)
-    chunk_inputs = [
-        (req_np[lo:lo + chunk], mask_np[lo:lo + chunk])
-        for lo in range(0, padded, chunk)
-    ]
+    chunk_inputs = problems.pod_chunks(snap, chunk)
     free0 = np.asarray(free_capacity(snap.nodes.alloc, snap.nodes.requested))
 
     # warm-up chunk: compiles the one chunk shape, donates its own carry.
@@ -524,15 +524,15 @@ def phase_profiles(phase: Phase, shapes: dict) -> None:
     import jax
     import numpy as np
 
-    import bench
     from scheduler_plugins_tpu.framework import Profile, Scheduler
+    from scheduler_plugins_tpu.models import problems
     from scheduler_plugins_tpu.tuning import gates
 
     fields = ("assignment", "admitted", "wait", "failed_plugin")
     cpu = jax.devices("cpu")[0]
     configs = {}
     for config, shape in shapes.items():
-        cluster, plugins, detail = bench.config_problem(config, shape=shape)
+        cluster, plugins, detail = problems.config_problem(config, shape=shape)
         scheduler = Scheduler(Profile(plugins=plugins))
         pending = scheduler.sort_pending(cluster.pending_pods(), cluster)
 
@@ -679,11 +679,10 @@ def main(argv=None) -> int:
               f"{len(devices)} present", file=sys.stderr)
         return 2
 
+    from scheduler_plugins_tpu.models import problems
     from scheduler_plugins_tpu.obs import costmodel
     from scheduler_plugins_tpu.parallel import vmem
     from scheduler_plugins_tpu.utils import compile_cache
-
-    import bench
 
     device = costmodel.device_identity()
     row = (
@@ -707,7 +706,7 @@ def main(argv=None) -> int:
 
     with Phase("A_served_daemon", stamp) as phase:
         phase_serve(phase, sizes["serve"], args.seed)
-    north_star_shape = sizes["north_star"] or bench.NORTH_STAR_SHAPE
+    north_star_shape = sizes["north_star"] or problems.NORTH_STAR_SHAPE
     with Phase("B_north_star_pipeline", stamp) as phase:
         north_star = phase_north_star(phase, north_star_shape)
     with Phase("C_plugin_profiles", stamp) as phase:

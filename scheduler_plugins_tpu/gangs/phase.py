@@ -23,7 +23,7 @@ Responsibilities per cycle:
 3. Solve (jit by default; `host_twin=True` runs the numpy sequential
    twin instead — the degraded-mode path). With `check_twin=True` BOTH
    run and `last_drift` records whether they disagreed (0.0 = bit-equal;
-   the gang-smoke gate pins this at 0.0).
+   tests/test_gangs.py pins this at 0.0).
 4. Bind placed ranks, reject quorum-failed gangs whole (zero partial
    ranks — members are parked unschedulable with the standard backoff),
    update the resident rank ledger O(changed), and stash the capture for
@@ -290,7 +290,7 @@ class GangPhase:
         #: the last solved cycle (check_twin), else the mismatch fraction
         self.last_drift: Optional[float] = None
         #: the WORST drift over every solved cycle of this phase's
-        #: lifetime — the gate value (`make gang-smoke` asserts on this;
+        #: lifetime — the gate value (a multi-cycle check asserts on this;
         #: last_drift alone would let a mid-run divergence be masked by a
         #: later clean cycle)
         self.max_drift: Optional[float] = None

@@ -51,8 +51,7 @@ anchored across blocks, contention localized) validates most lanes and
 turns G sequential scan steps into G/W parallel dispatches.
 
 `wave_gang_solve` is gated bit-identical to `gang_solve_np` (and hence
-to the sequential jit scan) by tests/test_differential.py; the mega
-bench (bench.py --config 12) runs it at 10k nodes x 1k gangs. The wave
+to the sequential jit scan) by tests/test_differential.py. The wave
 axis optionally shards over a ("gangs",) device mesh via shard_map —
 free/eq/problem tensors replicate, gang lanes shard, zero collectives.
 """

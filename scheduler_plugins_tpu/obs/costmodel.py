@@ -6,7 +6,7 @@ COMPILED program — the same ints on any machine under any load — so a
 cost delta between two commits has a ZERO noise floor. These are counts,
 not speed: what a program costs on a chip is measured on the chip
 (``chip_smoke.py``, PERF.md). This module is the one copy of that
-arithmetic, read by four consumers:
+arithmetic, read by three consumers:
 
 - ``tools/cost_observatory.py`` measures the full 24-program registry
   (the same one ``tools/tpu_lower.py`` / jaxpr_audit / kernel_audit
@@ -14,9 +14,6 @@ arithmetic, read by four consumers:
 - ``tools/perf_sentry.py`` runs the cost arm: the deterministic second
   verdict that flags an algorithmic regression even on a host where the
   timing arm downgrades to ``degraded-host``;
-- ``bench.py`` stamps every JSON line with the solve program's cost
-  digest and, on a TPU, a measured-vs-roofline calibration ratio against
-  the running device's row;
 - the daemon (``__main__.py``) and ``utils/flightrec.py`` stamp runtime
   device-memory watermarks and bundle cost provenance.
 
@@ -48,7 +45,6 @@ __all__ = [
     "cost_digest",
     "manifest_digest",
     "load_manifest",
-    "program_row",
     "budget_violations",
     "default_budgets",
     "device_identity",
@@ -216,22 +212,13 @@ def manifest_digest(manifest: dict) -> str:
 
 def load_manifest(path: str | os.PathLike | None = None) -> dict | None:
     """The committed docs/cost_model.json, or None when absent/unreadable
-    (callers are null-safe: a missing manifest degrades bench columns to
-    null and fails ONLY the explicit `make cost-audit-check` gate)."""
+    (callers are null-safe: a missing manifest fails ONLY the explicit
+    `make cost-audit-check` gate)."""
     p = Path(path) if path is not None else MANIFEST_PATH
     try:
         return json.loads(p.read_text())
     except (OSError, ValueError):
         return None
-
-
-def program_row(name: str, manifest: dict | None = None) -> dict | None:
-    """One program's committed cost row (manifest defaulting to the
-    committed file), or None."""
-    m = manifest if manifest is not None else load_manifest()
-    if not m:
-        return None
-    return m.get("programs", {}).get(name)
 
 
 # ---------------------------------------------------------------------------
@@ -242,7 +229,7 @@ def program_row(name: str, manifest: dict | None = None) -> dict | None:
 def device_identity() -> dict:
     """What JAX says it runs on — ``{"platform", "device_kind",
     "count"}`` of ``jax.devices()`` — stamped on the daemon's ready line
-    and /healthz, every bench line and every chip_smoke line, so a number
+    and /healthz and every chip_smoke line, so a number
     can never be read without the device it came from. Initializes the
     backend, and raises its error when the device asked for is absent."""
     devices = jax.devices()

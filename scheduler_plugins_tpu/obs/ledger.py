@@ -19,7 +19,7 @@ Design:
   the open interval (`stages[state] += t - last_ns; last_ns = t`), so
   `sum(stages) == retired_ns - first_seen_ns` holds EXACTLY, by
   construction, for every pod — the decomposition invariant
-  `make ledger-smoke` and tests/test_ledger.py gate.
+  tests/test_ledger.py gates.
 
 - **Engine-independent sequences.** Events carry `(cycle, lane, seq)`:
   the cycle that observed them, a lane (0 = ingest/solve-side, 1 = the
@@ -513,7 +513,7 @@ class Ledger:
     def decomposition_errors(self) -> list[tuple]:
         """(uid, sum(stages), e2e) for every retired record where the
         telescoping invariant does NOT hold — always empty by
-        construction; gated by tests and `make ledger-smoke`."""
+        construction; gated by tests/test_ledger.py."""
         with self._lock:
             bad = []
             for rec in self._retired:

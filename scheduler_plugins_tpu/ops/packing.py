@@ -38,8 +38,8 @@ Moves never change WHICH pods are placed — only where — so namespace
 quota usage and gang quorum counts are untouched by refinement, and the
 caller's `finalize_assignment` tail (queue-order quota prefix + Permit
 quorum) enforces those families exactly as the wave path does. The
-`tuning.gates` numpy replay oracles certify every packing solve in the
-bench/CI gates (`make pack-smoke`).
+`tuning.gates` numpy replay oracles certify the packing solves in
+tests/test_packing.py.
 
 Why this strictly improves the packing objectives: an emptied donor
 removes its (large) free vector from the packed numerator of

@@ -20,9 +20,10 @@ carry argument
 (`donated_chunk_solver`), so the free-capacity tensor threads chunk to
 chunk in place instead of being copied at every dispatch boundary.
 
-Consumers: `bench.py north_star` (the 10,240x102,400 headline run) and the
-daemon cycle loop (`framework.cycle.run_cycle(stream_chunk=...)`) via
-`streamed_profile_solve` below.
+Consumers: the north-star chunk program below (`north_star_chunk_solver`:
+the 10,240x102,400 run of `chip_smoke.py` phase B) and the daemon cycle
+loop (`framework.cycle.run_cycle(stream_chunk=...)`) via
+`streamed_profile_solve`.
 """
 
 from __future__ import annotations
@@ -53,9 +54,8 @@ class PipelineTimeline:
     directly observable;
     `summary(solve_ms=...)` therefore takes a device-busy ESTIMATE the
     caller derives from a synchronously-timed calibration solve scaled by
-    the per-chunk `collect_stats` wave counters (bench.north_star does
-    exactly this), and charges the remainder of the wall time as the
-    pipeline bubble.
+    the per-chunk `collect_stats` wave counters, and charges the remainder
+    of the wall time as the pipeline bubble.
     """
 
     n_chunks: int = 0
@@ -201,6 +201,37 @@ def donated_chunk_solver(fn, carry_argnum: int):
     else:
         jitted = jax.jit(fn, donate_argnums=(carry_argnum,))
     return obs.compile_watch(jitted, program=f"chunk:{name}")
+
+
+def north_star_solve_chunk(raw, node_mask, req_chunk, mask_chunk, free0):
+    """One north-star chunk: static allocatable scores -> targeted
+    waterfill, O(P*R) per lite wave instead of the (P, N) matrix (masked
+    nodes fit nothing with zeroed free capacity). rescue_window=256 halves
+    the end-game (K, N) rescue cost at this scale (8 waves x 256 slots
+    still drains every straggler, all pods placed).
+
+    Returns ((assignment, wave_stats), free) — the pipeline calling
+    convention (`run_chunk_pipeline`): the free carry is DONATED at the jit
+    boundary (`donated_chunk_solver`) so it threads chunk to chunk in
+    place. Chunk-invariant tensors (raw scores, node mask) are ARGUMENTS,
+    not jit closure captures, so the compiled program is exactly the one
+    tools/tpu_lower.py lowers and digests. Problems for it:
+    `models.problems.north_star_problem`."""
+    from scheduler_plugins_tpu.ops.assign import waterfill_assign_targeted
+
+    assignment, free, stats = waterfill_assign_targeted(
+        raw, req_chunk, mask_chunk,
+        jnp.where(node_mask[:, None], free0, 0), max_waves=8,
+        rescue_window=256, collect_stats=True,
+    )
+    return (assignment, stats), free
+
+
+def north_star_chunk_solver():
+    """The jitted, carry-donating north-star chunk program: one constructor
+    so what `chip_smoke.py` runs and what the audit registry lowers cannot
+    drift apart."""
+    return donated_chunk_solver(north_star_solve_chunk, carry_argnum=4)
 
 
 def run_chunk_pipeline(solve_chunk, invariant_args, chunk_inputs, carry,

@@ -403,8 +403,8 @@ def packing_profile_solve(scheduler, snap, mover_cap: int = 128,
     """Run the packing-mode profile solve; returns a `PackingSolveView`.
     Under `SPT_PACK_CERTIFY=1` the solve is additionally certified by the
     `tuning.gates` numpy replay oracles (fit/mask/quota/gang-quorum) and
-    raises on ANY violation — the per-solve certification hook the
-    pack-smoke CI gate runs unconditionally."""
+    raises on ANY violation — the per-solve certification hook
+    (tests/test_packing.py runs the same oracles unconditionally)."""
     import os
 
     fn, args = packing_profile_fn(
@@ -1376,9 +1376,9 @@ def collective_census(fn, *args):
     are the ring's neighbor transfers). Because the wave loops are
     `lax.while_loop`s, each wave BODY appears exactly once in the jaxpr —
     so the static census directly bounds the PER-WAVE collective count,
-    independent of how many waves a solve actually runs: the shard-smoke
-    gate asserts it stays O(shards) and that no full-axis gather ever
-    appears."""
+    independent of how many waves a solve actually runs:
+    tests/test_shard_wave.py asserts it stays O(shards) and that no
+    full-axis gather ever appears."""
     from jax import core
 
     closed = jax.make_jaxpr(fn)(*args)

@@ -1,7 +1,7 @@
 """Deterministic fault injection: seeded plans fired at named sites.
 
-The chaos harness (`bench.py --config 9` / `make chaos-smoke`) and the
-resilience tests drive the runtime through the SAME code paths production
+The resilience tests (tests/test_resilience.py, tests/test_shadow_tuner.py)
+drive the runtime through the SAME code paths production
 faults would take — a hung device solve, a device error, garbage solve
 output, dropped/duplicated/corrupted `DeltaSink` events, a stalled feed,
 a crash mid-cycle — by installing a `FaultPlan` into this module's

@@ -131,7 +131,7 @@ class ServeEngine:
         #: Since ISSUE 12 a gang/quota roster is served RESIDENT (the
         #: side tables below) — this counts only fallbacks forced by some
         #: OTHER incompatibility while gangs were present, so a compatible
-        #: gang roster keeps it at 0 (`make endurance-smoke` gates that).
+        #: gang roster keeps it at 0 (tests/test_gangs.py gates that).
         #: Exported as `scheduler_serve_gang_fallbacks_total`.
         self.gang_fallbacks = 0
         # -- resident gang/quota side tables (ISSUE 12; docs/SERVING.md)

@@ -15,8 +15,7 @@
 
 Drivers: `tools/tune.py` (corpus sweep + gated profile emission), the
 serving daemon's `--tune` flag (`tuning.shadow.ShadowTuner`),
-`tools/replay.py quality` (score a recorded bundle), `bench.py` (quality
-columns on every JSON line; config 14 drives the tuned lane).
+`tools/replay.py quality` (score a recorded bundle).
 """
 
 from scheduler_plugins_tpu.tuning import gates, promotion, quality, sweep

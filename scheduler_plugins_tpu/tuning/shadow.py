@@ -57,10 +57,11 @@ or silently regressed by its own tuner:
   serving continues exactly as if `--tune` had never been passed.
 
 Chaos sites `tune.sweep` (hang / garbage) and `tune.promote` (crash)
-instrument the seams (`resilience.faults`); `make chaos-smoke` proves
-every injected tuner fault leaves live placements bit-identical to a
-no-tuner control. Bench config 14 ("drifting mix") is the measured
-claim; `make tune-live-smoke` is the CI gate.
+instrument the seams (`resilience.faults`);
+tests/test_shadow_tuner.py::TestTunedServingEndToEnd proves every injected
+tuner fault leaves live placements bit-identical to a no-tuner control,
+and drives the whole lane (ring records, sweeps, gated promotion,
+injected-regression rollback) on a micro drifting mix.
 """
 
 from __future__ import annotations

@@ -11,9 +11,9 @@ time:
 - unset: `<checkout>/.jax_cache` (git-ignored), the one fixed path every
   entry point shares. Never a temp name, a pid or a time.
 
-`configure()` is called by the three entry points (`python -m
-scheduler_plugins_tpu`, `bench.py`, `chip_smoke.py`) before their first
-compile. Tests call nothing and get no persistent cache.
+`configure()` is called by the entry points (`python -m
+scheduler_plugins_tpu`, `chip_smoke.py`, `benchmark/run.py` through the
+daemon it starts) before their first compile. Tests call nothing and get no persistent cache.
 """
 
 from __future__ import annotations

@@ -403,8 +403,8 @@ class FlightRecorder:
         trusts arrays the manifest names, so a crash mid-save leaves at
         worst orphan blobs, never a manifest with missing data. An
         existing manifest in `directory` is appended to, not replaced
-        (blobs are content-addressed, so successive runs — e.g. several
-        `bench.py --record` invocations — accumulate into one bundle);
+        (blobs are content-addressed, so successive runs accumulate into
+        one bundle);
         records already present verbatim are not duplicated. Returns a
         small summary dict."""
         records = [r for r in self.records() if r.manifest.get("snapshot")]
@@ -506,7 +506,7 @@ class FlightRecorder:
 
 
 #: global recorder, off by default (`run_cycle` hooks, daemon `--record`,
-#: `bench.py --record dir/`, `tools/replay.py smoke` turn it on)
+#: `tools/replay.py smoke` turn it on)
 recorder = FlightRecorder()
 
 

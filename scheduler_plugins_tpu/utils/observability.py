@@ -208,9 +208,9 @@ class Metrics:
 
     def scoped(self) -> "ScopedMetrics":
         """A snapshot/diff view: reads return counts accumulated SINCE
-        this call. Arm-vs-arm benches read per-arm deltas through one of
-        these instead of the process-global totals (the PR 12 `rebases`
-        fix, generalized — see bench.py's sweep baselines)."""
+        this call. Arm-vs-arm comparisons read per-arm deltas through one
+        of these instead of the process-global totals (the PR 12 `rebases`
+        fix, generalized)."""
         return ScopedMetrics(self)
 
     def prometheus_text(self) -> str:
@@ -365,7 +365,7 @@ SERVE_AXIS_REBASES = "scheduler_serve_axis_rebases_total"
 #: carried PodGroups. Gang/quota rosters serve RESIDENT since ISSUE 12
 #: (gang/quota side tables), so on a compatible gang roster this stays 0
 #: — the production signal that the resident-gang win is actually
-#: engaged (`make endurance-smoke` gates it)
+#: engaged (tests/test_gangs.py, tests/test_serving.py gate it)
 SERVE_GANG_FALLBACKS = "scheduler_serve_gang_fallbacks_total"
 #: times the serving engine lowered the load watcher's report into its
 #: resident metrics columns, O(nodes): once per report (and per change of
@@ -872,7 +872,7 @@ class Tracer:
         atomic_write(path, json.dumps(self.export()))
 
 
-#: global tracer, off by default (`bench.py --trace out.json` and
+#: global tracer, off by default (the daemon's `--trace out.json` and
 #: `tools/trace_smoke.py` turn it on around their runs)
 tracer = Tracer()
 

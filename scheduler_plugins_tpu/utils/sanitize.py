@@ -14,8 +14,8 @@ Semantics under sanitize mode:
   an error value through the program that must not alias a donated buffer.
 - errors surface as STRUCTURED JSON (one line per checked invocation on
   stderr when an error fired) and accumulate in an in-process report list;
-  `drain()` hands them to drivers — `bench.py --sanitize-smoke` fails CI
-  on any, `framework.cycle.run_cycle` attaches them to its CycleReport.
+  `drain()` hands them to drivers — tests/test_sanitize.py fails on any,
+  `framework.cycle.run_cycle` attaches them to its CycleReport.
 - the mode is decided when a solver is BUILT (solver caches key on it), so
   flipping the env var mid-process yields fresh, correctly-instrumented
   jits instead of stale cache hits.

@@ -458,74 +458,6 @@ class TestBundleCostStamp:
 
 
 # ---------------------------------------------------------------------------
-# bench cost columns (null-safe schema)
-# ---------------------------------------------------------------------------
-
-
-class TestBenchCostColumns:
-    @pytest.fixture(scope="class")
-    def bench(self):
-        import bench
-
-        return bench
-
-    def test_schema_includes_cost_columns(self, bench):
-        assert "cost_digest" in bench.LINE_SCHEMA_KEYS
-        assert "roofline_calibration" in bench.LINE_SCHEMA_KEYS
-
-    def test_cpu_run_gets_the_digest_and_no_calibration(self, bench):
-        manifest = costmodel.load_manifest()
-        cols = bench._cost_columns("tpu_smoke_pods_per_sec", 1000.0)
-        row = manifest["programs"]["bench_cfg0_tpu_smoke"]
-        assert cols["cost_digest"] == row["cost_digest"]
-        # no chip ran this: nothing to hold the time against
-        assert cols["roofline_calibration"] is None
-
-    def test_tpu_run_is_calibrated_against_the_running_devices_row(
-        self, bench, monkeypatch
-    ):
-        monkeypatch.setattr(costmodel, "device_identity", lambda: {
-            "platform": "tpu", "device_kind": "TPU v5 lite", "count": 1,
-        })
-        cols = bench._cost_columns("tpu_smoke_pods_per_sec", 1000.0)
-        row = costmodel.load_manifest()["programs"]["bench_cfg0_tpu_smoke"]
-        cal = cols["roofline_calibration"]
-        assert cal["target"] == "tpu_v5e"  # not the manifest's audit target
-        floor = costmodel.roofline(
-            row["flops"], row["bytes_accessed"], target="tpu_v5e"
-        )["step_floor_us"]
-        assert cal["floor_us"] == floor
-        # 256 pods at 1000 pods/s = 256000 us measured vs the floor
-        assert cal["measured_over_floor"] == pytest.approx(
-            256_000 / floor, rel=1e-3
-        )
-
-    def test_unknown_tpu_kind_raises(self, bench, monkeypatch):
-        monkeypatch.setattr(costmodel, "device_identity", lambda: {
-            "platform": "tpu", "device_kind": "TPU v9", "count": 1,
-        })
-        with pytest.raises(ValueError, match="no hardware row"):
-            bench._cost_columns("tpu_smoke_pods_per_sec", 1000.0)
-
-    def test_emitted_line_carries_the_schema_and_the_device(
-        self, bench, capsys
-    ):
-        bench._emit("pods_scheduled_per_sec", 1000.0, "detail", 10.0)
-        line = json.loads(capsys.readouterr().out)
-        assert not [k for k in bench.LINE_SCHEMA_KEYS if k not in line]
-        assert line["platform"] == "cpu" and line["device_kind"] == "cpu"
-        assert line["devices"] == 8  # conftest's virtual mesh
-        assert line["roofline_calibration"] is None
-
-    def test_unregistered_metric_is_null_safe(self, bench):
-        cols = bench._cost_columns("mega_pods_per_sec", 1000.0)
-        assert cols == {"cost_digest": None, "roofline_calibration": None}
-        assert bench._cost_columns(None) == {
-            "cost_digest": None, "roofline_calibration": None,
-        }
-
-
-# ---------------------------------------------------------------------------
 # runtime watermark gauges
 # ---------------------------------------------------------------------------
 

@@ -1,33 +1,32 @@
 """Drift regression guard (ISSUE 2 satellite): the batched throughput
 mode's placement-quality drift vs the bit-faithful sequential path is a
-DOCUMENTED trade (bench.py emits it per run as the `drift` column), not a
-free variable — this pins it.
+DOCUMENTED trade, not a free variable — this pins it.
 
 - cfg-2 (trimaran TLP+LVRB, the config whose batch mode trades quality for
-  throughput) must stay within the −0.05 envelope the bench reports
-  (measured −0.04 at the full 5000-node shape; the reduced shape here uses
-  the same generator/roster).
+  throughput) must stay within the −0.05 envelope (−0.04 counted at the
+  full 5000-node shape; the reduced shape here,
+  `problems.SMOKE_COMPARE_SHAPES`, uses the same generator/roster).
 - The NUMA roster (cfg-3 shape) batch path is score-identical to
   sequential on its shared objective — drift exactly 0.0.
 - Sequential mode is the anchor: drift 0.0 by definition (the shared
   definition `score_drift_vs_sequential` must return exactly 0.0 for the
-  anchor against itself — bench's sequential lines hardcode the same).
+  anchor against itself, and so must its host-side twin
+  `tuning.quality.score_drift`).
 
-All drifts are computed with `parallel.solver.score_drift_vs_sequential`,
-the single definition bench.py's `drift` column uses, so this test and the
-bench cannot measure different quantities.
+All drifts are computed with `parallel.solver.score_drift_vs_sequential`.
 """
 
 import numpy as np
 
 from scheduler_plugins_tpu.framework import Profile, Scheduler
+from scheduler_plugins_tpu.models.problems import SMOKE_COMPARE_SHAPES
 from scheduler_plugins_tpu.parallel.solver import (
     profile_batch_solve,
     score_drift_vs_sequential,
 )
 
-#: the documented envelope for the cfg-2 batch drift (bench reports −0.04;
-#: anything below −0.05 is a quality regression, not noise)
+#: the documented envelope for the cfg-2 batch drift (−0.04 at the full
+#: shape; anything below −0.05 is a quality regression, not noise)
 CFG2_DRIFT_ENVELOPE = -0.05
 
 
@@ -46,11 +45,10 @@ def _solve_both(cluster, plugins):
 
 class TestDriftBounds:
     def test_cfg2_batch_drift_within_envelope(self):
-        import bench
         from scheduler_plugins_tpu import plugins as P
         from scheduler_plugins_tpu.models import trimaran_scenario
 
-        cluster = trimaran_scenario(**bench.SMOKE_COMPARE_SHAPES[2])
+        cluster = trimaran_scenario(**SMOKE_COMPARE_SHAPES[2])
         drift, placed_seq, placed_bat = _solve_both(
             cluster, [P.TargetLoadPacking(), P.LoadVariationRiskBalancing()]
         )
@@ -61,11 +59,10 @@ class TestDriftBounds:
         )
 
     def test_numa_batch_drift_zero(self):
-        import bench
         from scheduler_plugins_tpu import plugins as P
         from scheduler_plugins_tpu.models import numa_scenario
 
-        cluster = numa_scenario(**bench.SMOKE_COMPARE_SHAPES[3])
+        cluster = numa_scenario(**SMOKE_COMPARE_SHAPES[3])
         drift, placed_seq, placed_bat = _solve_both(
             cluster, [P.NodeResourceTopologyMatch()]
         )
@@ -73,10 +70,9 @@ class TestDriftBounds:
         assert drift == 0.0, drift
 
     def test_sequential_anchor_exactly_zero(self):
-        # the anchor against itself MUST be exactly 0.0 (the definition
-        # bench's sequential lines rely on), not merely close
-        import bench
+        # the anchor against itself MUST be exactly 0.0, not merely close
         from scheduler_plugins_tpu import plugins as P
+        from scheduler_plugins_tpu.tuning.quality import score_drift
         from scheduler_plugins_tpu.models import numa_scenario
 
         cluster = numa_scenario(n_nodes=64, n_pods=64, zones=4)
@@ -88,7 +84,7 @@ class TestDriftBounds:
         drift, _, _ = score_drift_vs_sequential(sched, snap, seq, seq)
         assert drift == 0.0
 
-        # bench's flagship drift helper obeys the same anchor identity
-        scores = np.arange(16, dtype=np.int64)
+        # the host-side definition obeys the same anchor identity
+        scores = np.arange(64, dtype=np.int64).reshape(4, 16)
         ref = np.array([3, 1, -1, 2])
-        assert bench._score_sum_drift(scores, ref.copy(), ref.copy()) == 0.0
+        assert score_drift(scores, ref.copy(), ref.copy()) == 0.0

@@ -237,6 +237,51 @@ class TestGangPhaseCycle:
         assert set(cluster.event_last) <= ev.EVENT_KINDS
         assert ev.POD_UPDATE in cluster.event_last  # the binds
 
+    def test_rank_aware_placement_costs_less_than_quorum_only(self):
+        """The same scenario through the gang phase and through quorum-only
+        Coscheduling, both audited with the solver's own yardstick
+        (`block_cost_view` + `gang_cost_stats`): the rank-aware arm's worst
+        inter-rank pair is strictly cheaper, with every gang admitted."""
+        from scheduler_plugins_tpu import plugins as P
+        from scheduler_plugins_tpu.gangs.phase import block_cost_view
+
+        shape = dict(n_nodes=48, n_regions=2, zones_per_region=2, n_mpi=4,
+                     mpi_ranks=6, n_dl=2, dl_min=2, dl_desired=3, dl_max=6)
+
+        def worst_pair(phase):
+            cluster = rank_gang_scenario(seed=0, **shape)
+            scheduler = Scheduler(Profile(plugins=[
+                NodeResourcesAllocatable(), P.Coscheduling(),
+                P.CapacityScheduling(),
+            ]))
+            for cycle in range(8):
+                run_cycle(scheduler, cluster, now=10_000 * (cycle + 1),
+                          gangs=phase)
+                if not cluster.pending_pods():
+                    break
+            node_pos, zones, block_cost = block_cost_view(cluster)
+            gangs = [pg for _, pg in sorted(cluster.pod_groups.items())
+                     if pg.rank_aware]
+            placed = [
+                [node_pos[p.node_name] for p in cluster.gang_members(pg)
+                 if p.node_name in node_pos]
+                for pg in gangs
+            ]
+            assert all(len(b) >= pg.min_member
+                       for b, pg in zip(placed, gangs))
+            M = max(len(b) for b in placed)
+            rank_nodes = np.full((len(placed), M), -1, I32)
+            for g, b in enumerate(placed):
+                rank_nodes[g, :len(b)] = b
+            max_cost, _ = gang_cost_stats(
+                rank_nodes, rank_nodes >= 0, zones, block_cost
+            )
+            return int(max_cost.max())
+
+        phase = GangPhase(check_twin=True)
+        assert worst_pair(phase) < worst_pair(None)
+        assert phase.last_drift == 0.0
+
     def test_quorum_fail_parks_all_members_with_backoff(self):
         # a fleet too small for one gang: every member parks, none binds
         cluster, scheduler, phase = self._arm()

@@ -196,13 +196,13 @@ class TestConfig:
             [tool.graft-lint]
             exclude = ["tests/fixtures/graft_lint"]  # known-bad corpus
             config-update-owners = [
-             "bench.py",
+             "__graft_entry__.py",
             ]
         """))
         monkeypatch.setattr(G, "REPO", tmp_path)
         cfg = G.load_config()
         assert cfg["exclude"] == ["tests/fixtures/graft_lint"]
-        assert cfg["config-update-owners"] == ["bench.py"]
+        assert cfg["config-update-owners"] == ["__graft_entry__.py"]
 
     def test_load_config_fails_loudly_on_malformed_list(self, monkeypatch,
                                                         tmp_path):

@@ -498,35 +498,119 @@ class TestLateLaneBinds:
         laned.close()
 
 
-class TestLaneBenchMicro:
-    """bench.py config 15 plumbing on a micro shape: per-cycle digest
-    identity at every K, clean capacity audit, contended tail forcing
-    conflicts, and the schema the smoke gate reads. Timing columns are
-    present but NOT gated here (CI hosts time-slice; `make lane-smoke`
-    owns the ratio bound on its calibrated shape)."""
+class TestLaneChurnMicro:
+    """Zoned-tenant churn with an adversarial contended tail, solved by
+    the defined serial order AND by `LaneSolver` at K in {1, 2} on the same
+    snapshot every cycle: assignment, admitted and wait bit-equal on every
+    cycle, clean hard-constraint audit, no serial fallback, and real
+    cross-lane conflicts re-resolved through the fence on the tail."""
 
-    def test_lane_scaling_micro_line(self):
-        import bench
+    #: 8 tenants over 4 zone resources on 8 deep nodes that never fill,
+    #: plus one node whose scarce `hot` resource 4 distinct-tenant bidders
+    #: race for 2 slots of on the contended cycle
+    N, ZONES, TENANTS, ARRIVE, DEPART = 8, 4, 8, 64, 64
+    HOT_SLOTS, HOT_BIDDERS, CYCLES = 2, 4, 4
 
-        shape = dict(
-            n_nodes=8, zones=4, tenants=8, prefill=32,
-            cycles=3, warmup=1, lam_arrive=64, lam_depart=64,
-            contend_cycles=1, hot_slots=2, hot_bidders=4,
-            ks=(1, 2), headline_k=2, reps=1,
-        )
-        line = bench.lane_scaling(shape=shape, emit=False)
-        assert line["digests_match"], line["lanes"]["digest_mismatches"]
-        assert line["capacity_violations"] == 0
-        assert line["serial_fallbacks"] == 0
-        assert line["conflicts"] > 0  # the contended tail really collides
-        assert line["re_resolved"] > 0
-        curve = {c["k"]: c for c in line["lanes"]["curve"]}
-        assert set(curve) == {1, 2}
-        for c in curve.values():
-            for col in ("ratio", "ratio_full", "ratio_wall",
-                        "pods_per_sec", "conflicts", "re_resolved",
-                        "serial_fallbacks", "partition_ms_mean",
-                        "max_lane_ms_mean", "fence_ms_mean"):
-                assert col in c, col
-        assert line["lanes"]["headline_k"] == 2
-        assert line["lane_ratio"] == curve[2]["ratio"]
+    def _cluster(self, rng):
+        c = Cluster()
+        for i in range(self.N):
+            c.add_node(Node(
+                name=f"node-{i:04d}",
+                allocatable={CPU: 256_000, MEMORY: 1024 * gib, PODS: 1024,
+                             f"example.com/zone-{i % self.ZONES}": 100_000},
+            ))
+        c.add_node(Node(
+            name="node-hot",
+            allocatable={CPU: 64_000, MEMORY: 256 * gib, PODS: 512,
+                         "example.com/hot": self.HOT_SLOTS},
+        ))
+        for i in range(32):
+            j = i % self.N
+            pod = Pod(
+                name=f"bound-{i:06d}", creation_ms=i,
+                namespace=f"tenant-{i % self.TENANTS:03d}",
+                containers=[Container(requests={
+                    CPU: int(rng.integers(100, 900)), MEMORY: gib,
+                    f"example.com/zone-{j % self.ZONES}": 1,
+                })],
+            )
+            pod.node_name = f"node-{j:04d}"
+            c.add_pod(pod)
+        c.enable_pending_index()
+        return c
+
+    def test_identity_conflicts_and_no_fallback(self):
+        from scheduler_plugins_tpu.tuning.gates import hard_violations
+
+        rng = np.random.default_rng(1)
+        cluster = self._cluster(rng)
+        sched = Scheduler(Profile(plugins=[NodeResourcesAllocatable()]))
+        solvers = {
+            k: LaneSolver(sched, k=k, partition="namespace",
+                          dispatch="sequential")
+            for k in (1, 2)
+        }
+        serial_no = 0
+
+        def arrive(n, hot=False):
+            nonlocal serial_no
+            for _ in range(n):
+                serial_no += 1
+                t = serial_no % self.TENANTS
+                extra = ("example.com/hot" if hot
+                         else f"example.com/zone-{t % self.ZONES}")
+                cluster.add_pod(Pod(
+                    name=f"{'hot' if hot else 'arr'}-{serial_no:06d}",
+                    namespace=f"tenant-{t:03d}",
+                    creation_ms=1_000_000 + serial_no,
+                    containers=[Container(requests={
+                        CPU: int(rng.integers(100, 900)), MEMORY: gib,
+                        extra: 1,
+                    })],
+                ))
+
+        conflicts = re_resolved = 0
+        for cycle in range(self.CYCLES):
+            now = 1000 * (cycle + 1)
+            contended = cycle == self.CYCLES - 1
+            arrive(self.ARRIVE - (self.HOT_BIDDERS if contended else 0))
+            if contended:
+                arrive(self.HOT_BIDDERS, hot=True)
+            bound = sorted(u for u, p in cluster.pods.items()
+                           if p.node_name is not None)
+            for i in sorted(rng.choice(len(bound), replace=False,
+                                       size=min(self.DEPART, len(bound)))):
+                cluster.remove_pod(bound[int(i)])
+            pending = cluster.pending_pods()
+            P = len(pending)
+            snap, meta = cluster.snapshot(pending, now_ms=now)
+            sched.prepare(meta, cluster)
+            res = sched.solve(snap, mode="sequential")
+            serial = tuple(np.asarray(x) for x in (
+                res.assignment, res.admitted, res.wait))
+            a_ser, ok_ser, w_ser = serial
+            assert hard_violations(snap, a_ser, w_ser)["total"] == 0
+            for k, solver in solvers.items():
+                *laned, _codes, st = solver.solve(
+                    snap, pending, cluster, meta=meta
+                )
+                for got, want in zip(laned, serial):
+                    np.testing.assert_array_equal(
+                        np.asarray(got)[:P], want[:P], err_msg=f"{cycle} {k}"
+                    )
+                if k > 1:
+                    assert st.path != "serial", (cycle, st)
+                    if contended:
+                        conflicts += sum(st.conflicts or [])
+                        re_resolved += st.re_resolved
+            for i, pod in enumerate(pending):
+                if ok_ser[i] and a_ser[i] >= 0:
+                    cluster.bind(pod.uid, meta.node_names[int(a_ser[i])],
+                                 now_ms=now)
+        for solver in solvers.values():
+            solver.close()
+        # the contended tail really collides, and the fence re-resolves
+        assert conflicts > 0 and re_resolved > 0
+        hot_bound = [p for p in cluster.pods.values()
+                     if p.name.startswith("hot-") and p.node_name]
+        assert len(hot_bound) == self.HOT_SLOTS

@@ -12,8 +12,6 @@
 - cycle wiring: a packing-mode profile solves through `run_cycle`
   (binds land, quality stamped, the flight recorder labels the outputs
   "packing");
-- bench line schema: the error/stale-replay builders stay
-  schema-complete for EVERY config in CONFIG_METRICS, including 13;
 - recorder: GangPhase elastic desired-width transitions land on the
   manifest (ROADMAP item 3's recorder slice).
 
@@ -25,7 +23,6 @@ only traced arguments.
 import numpy as np
 import pytest
 
-import bench
 from scheduler_plugins_tpu.api.config import load_profile, profile_spec
 from scheduler_plugins_tpu.framework import (
     PackingConfig,
@@ -33,6 +30,7 @@ from scheduler_plugins_tpu.framework import (
     Scheduler,
     run_cycle,
 )
+from scheduler_plugins_tpu.models.problems import packing_problem
 from scheduler_plugins_tpu.ops.packing import (
     pack_aux_vector,
     packing_refine,
@@ -52,7 +50,7 @@ _SHAPE = dict(n_nodes=24, demand_frac=0.85, empty_frac=0.15, seed=0)
 
 @pytest.fixture(scope="module")
 def problem():
-    cluster, snap, meta, weights = bench.packing_problem(**_SHAPE)
+    cluster, snap, meta, weights = packing_problem(**_SHAPE)
     return cluster, snap, meta, weights
 
 
@@ -177,6 +175,21 @@ class TestPackingSolve:
         qp = Q.cycle_quality(snap, np.asarray(a_p), None, np.asarray(wait_p))
         assert qp["packed_utilization"] > qw["packed_utilization"]
         assert qp["fragmentation"] <= qw["fragmentation"]
+        # what consolidation costs on the wave path's own objective (the
+        # static allocatable scores) stays inside the documented bound
+        from scheduler_plugins_tpu.ops.allocatable import (
+            MODE_LEAST,
+            allocatable_scores,
+        )
+
+        scores = np.asarray(
+            allocatable_scores(snap.nodes.alloc, weights, MODE_LEAST)
+        )
+        drift = Q.score_drift(
+            np.broadcast_to(scores, (snap.num_pods, scores.shape[0])),
+            np.asarray(a_p), np.asarray(a_w),
+        )
+        assert abs(drift) <= 0.15, drift
 
 
 class TestPackingConfigSurface:
@@ -247,7 +260,7 @@ class TestPackingConfigSurface:
 
 class TestPackingCycle:
     def _cluster(self):
-        cluster, _, _, _ = bench.packing_problem(**_SHAPE)
+        cluster, _, _, _ = packing_problem(**_SHAPE)
         return cluster
 
     def test_run_cycle_with_packing_profile(self):

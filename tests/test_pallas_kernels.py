@@ -277,3 +277,42 @@ class TestLaxElectionCollectiveEdges:
             assert (u == v).all()
         want = np.cumsum(vals, axis=0) - vals
         assert (outs[0][0].reshape(S, 3) == want).all()
+
+
+class TestCommittedPallasCensus:
+    """What the committed manifests record of the Pallas build of the
+    sharded wave chunk program (`make cost-audit-check` and
+    `make tpu-lower-check` hold the manifests to the code): the ring
+    kernels REPLACED every per-wave framework collective, no gather of the
+    node axis appeared, and the kernel programs are in the lowering
+    manifest beside it."""
+
+    @staticmethod
+    def _manifest(name):
+        import json
+        from pathlib import Path
+
+        docs = Path(__file__).resolve().parent.parent / "docs"
+        return json.loads((docs / name).read_text())["programs"]
+
+    def test_ring_kernels_replaced_the_framework_collectives(self):
+        cost = self._manifest("cost_model.json")
+        pallas = cost["sharded_wave_chunk_pallas"]["collectives"]
+        lax = cost["sharded_wave_chunk"]["collectives"]
+        assert pallas.get("pallas_call", 0) > 0
+        assert lax.get("pallas_call", 0) == 0 and sum(lax.values()) > 0
+        for census in (pallas, lax):
+            for gather in ("all_gather", "all_gather_invariant",
+                           "all_to_all"):
+                assert census.get(gather, 0) == 0, gather
+        for collective in ("psum", "pmin", "pmax", "ppermute"):
+            assert pallas.get(collective, 0) == 0, collective
+
+    def test_kernel_programs_are_in_the_lowering_manifest(self):
+        lowered = self._manifest("tpu_lowering.json")
+        for name in ("pallas_ring_offsets", "pallas_fused_election",
+                     "sharded_wave_chunk_pallas"):
+            assert lowered[name]["landmines"] == 0
+            assert lowered[name]["ops"].get("custom_call", 0) > 0, name
+        assert "all_reduce" not in lowered["sharded_wave_chunk_pallas"]["ops"]
+        assert lowered["sharded_wave_chunk"]["ops"]["all_reduce"] > 0

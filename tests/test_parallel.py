@@ -454,7 +454,7 @@ class TestBatchedSequentialDrift:
         sched.prepare(meta, cluster)
         seq = np.asarray(sched.solve(snap).assignment)
         bat = np.asarray(profile_batch_solve(sched, snap)[0])
-        # the shared definition bench.py emits per batch run
+        # the shared definition (tests/test_drift_bounds.py pins it)
         rel, placed_seq, placed_bat = score_drift_vs_sequential(
             sched, snap, seq, bat
         )

@@ -82,7 +82,7 @@ class TestVerdictTables:
 class TestHistoryIngestion:
     def test_committed_wrapper_failed_run_is_unusable(self):
         samples = perf_sentry.extract_samples(
-            {"n": 1, "cmd": "python bench.py", "rc": 1, "tail": "boom",
+            {"n": 1, "cmd": "python run_it.py", "rc": 1, "tail": "boom",
              "parsed": None},
             "run1.json",
         )

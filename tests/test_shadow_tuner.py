@@ -8,12 +8,12 @@ controller cannot flap), the tune.sweep / tune.promote chaos sites, the
 live-weights rollout seam (traced-argument weights, zero recompiles),
 and the tuner state persistence round trip.
 
-The end-to-end tuned-serving claim (shadow sweeps over real ring
-records, gated promotion, measured quality win, injected-regression
-rollback) is `make tune-live-smoke` (bench config 14); the tuner-fault
-bit-identity claim is the chaos gate's tuner phase (`make chaos-smoke`).
-These tests stay host-side where possible — only the live-weights seam
-class compiles a (tiny) solve."""
+`TestTunedServingEndToEnd` runs the whole lane on a micro drifting-mix
+workload: shadow sweeps over real ring records, a gated promotion, an
+injected regression rolled back, and injected tuner faults that leave live
+placements bit-identical to a no-tuner control. The other classes stay
+host-side where possible — only the live-weights seam class compiles a
+(tiny) solve."""
 
 from types import SimpleNamespace
 
@@ -479,3 +479,228 @@ class TestLiveWeightsSeam:
         sched.prepare(meta, cluster)
         with pytest.raises(ValueError, match="sequential parity path"):
             sched.solve(snap)
+
+
+# ---------------------------------------------------------------------------
+# end to end: a micro drifting-mix workload through the real cycle
+# ---------------------------------------------------------------------------
+
+#: a hot/cold fleet serving churn whose mix drifts at `drift_at`: the cold
+#: class turns metric-noisy while the pod sizes go bimodal, so the static
+#: weights (LVRB 20 : TLP 1, which trust the variance signal) start
+#: steering arrivals onto the hot nodes — the opening the tuner must find
+DRIFT = dict(n_nodes=24, hot=6, hot_util=0.62, cold_util=0.15,
+             arrivals=8, departs=3, drift_at=4)
+
+
+def drift_cluster():
+    from scheduler_plugins_tpu.api.objects import Container, Node, Pod
+    from scheduler_plugins_tpu.api.resources import CPU, MEMORY, PODS
+    from scheduler_plugins_tpu.state.cluster import Cluster
+
+    gib = 1 << 30
+    cluster = Cluster()
+    serial = 0
+    for i in range(DRIFT["n_nodes"]):
+        cluster.add_node(Node(
+            name=f"node-{i:05d}",
+            allocatable={CPU: 64_000, MEMORY: 256 * gib, PODS: 512},
+        ))
+        util = DRIFT["hot_util" if i < DRIFT["hot"] else "cold_util"]
+        for _ in range(-(-int(64_000 * util) // 2000)):
+            serial += 1
+            pod = Pod(
+                name=f"base-{serial:06d}", creation_ms=serial,
+                containers=[Container(requests={CPU: 2000,
+                                                MEMORY: 4 * gib})],
+            )
+            pod.node_name = f"node-{i:05d}"
+            cluster.add_pod(pod)
+    return cluster
+
+
+def drift_script(cycles, seed=1):
+    """[(phase, arrivals [(name, cpu, mem)], departures [names])] from the
+    rng stream alone, independent of placements: every arm replays the
+    identical workload."""
+    rng = np.random.default_rng(seed)
+    gib = 1 << 30
+    serial, live, script = 0, [], []
+    for c in range(cycles):
+        phase = "a" if c < DRIFT["drift_at"] else "b"
+        k = min(DRIFT["departs"], len(live))
+        picks = set(
+            int(x) for x in rng.choice(len(live), size=k, replace=False)
+        ) if k else set()
+        departs = [live[i] for i in sorted(picks)]
+        live = [nm for i, nm in enumerate(live) if i not in picks]
+        arrivals = []
+        for _ in range(DRIFT["arrivals"]):
+            serial += 1
+            if phase == "a":
+                cpu = int(rng.integers(800, 1600))
+                mem = int(rng.integers(gib, 2 * gib))
+            elif rng.random() < 0.5:
+                cpu, mem = 600, gib // 2  # sidecar dust
+            else:
+                cpu, mem = 4200, 3 * gib  # fat batch pods
+            arrivals.append((f"arr-{serial:06d}", cpu, mem))
+            live.append(arrivals[-1][0])
+        script.append((phase, arrivals, departs))
+    return script
+
+
+def drift_step(cluster, phase, arrivals, departs, now):
+    """Apply one cycle's events, then refresh the load watcher's report:
+    averages mirror the requested utilization, the variance term drifts
+    with the phase."""
+    from scheduler_plugins_tpu.api.objects import Container, Pod
+    from scheduler_plugins_tpu.api.resources import CPU, MEMORY
+
+    for name in departs:
+        if f"default/{name}" in cluster.pods:
+            cluster.remove_pod(f"default/{name}")
+    for name, cpu, mem in arrivals:
+        cluster.add_pod(Pod(
+            name=name, creation_ms=now,
+            containers=[Container(requests={CPU: cpu, MEMORY: mem})],
+        ))
+    used = {name: [0, 0] for name in cluster.nodes}
+    for pod in cluster.pods.values():
+        if pod.node_name is not None:
+            req = pod.effective_request()
+            used[pod.node_name][0] += req.get(CPU, 0)
+            used[pod.node_name][1] += req.get(MEMORY, 0)
+    metrics = {}
+    for i, (name, node) in enumerate(cluster.nodes.items()):
+        noisy = phase == "b" and i >= DRIFT["hot"]
+        metrics[name] = {
+            "cpu_avg": min(100.0 * used[name][0] / node.allocatable[CPU],
+                           100.0),
+            "cpu_std": 60.0 if noisy else 3.0,
+            "mem_avg": min(100.0 * used[name][1] / node.allocatable[MEMORY],
+                           100.0),
+            "mem_std": 8.0 if noisy else 2.0,
+        }
+    cluster.node_metrics = metrics
+
+
+def drift_scheduler():
+    from scheduler_plugins_tpu import plugins as P
+
+    lvrb = P.LoadVariationRiskBalancing()
+    lvrb.weight = 20
+    return Scheduler(Profile(plugins=[P.TargetLoadPacking(), lvrb]))
+
+
+def run_drift(cycles, tuner_kw=None, plan=None, inject=None):
+    """One arm over the drift script; `tuner_kw` arms the flight recorder
+    and a synchronous ShadowTuner. `inject` stages a known-bad vector past
+    the gates once the real promotion has been confirmed. Returns the
+    per-cycle bound maps, the tuner, the weights each cycle solved under
+    and the cycle the injection happened at."""
+    from scheduler_plugins_tpu.framework import run_cycle
+    from scheduler_plugins_tpu.utils import flightrec
+
+    cluster, scheduler = drift_cluster(), drift_scheduler()
+    tuner = None
+    if tuner_kw is not None:
+        flightrec.recorder.start(capacity=4)
+        tuner = ShadowTuner(
+            scheduler, corpus_cycles=2, sweep_every=2, tolerance=0.01,
+            probation_cycles=8, baseline_window=8, baseline_min=2,
+            baseline_recent=3, hysteresis=0.002, regress_cycles=2,
+            cooldown_cycles=16, sync=True, seed=0, **tuner_kw,
+        )
+    if plan is not None:
+        faults.install(plan)
+    bound, trail, injected_at = [], [], None
+    try:
+        for c, (phase, arrivals, departs) in enumerate(drift_script(cycles)):
+            now = 1000 * (c + 1)
+            drift_step(cluster, phase, arrivals, departs, now)
+            if plan is not None:
+                plan.begin_cycle(c)
+            if tuner is not None:
+                st = tuner.status()
+                if (
+                    inject is not None and injected_at is None
+                    and st["state"] == "idle"
+                    and st["promotions"] > st["rollbacks"]
+                    and st["active_weights"] == st["last_known_good"]
+                ):
+                    tuner.inject_promotion(inject)
+                    injected_at = c
+                tuner.begin_cycle(now_ms=now)
+                trail.append(tuner.status()["active_weights"])
+            report = run_cycle(scheduler, cluster, now=now)
+            if tuner is not None:
+                tuner.observe_report(report)
+            bound.append(dict(report.bound))
+            snap, _ = cluster.snapshot([], now_ms=now)
+            assert (np.asarray(snap.nodes.requested)
+                    <= np.asarray(snap.nodes.alloc)).all(), c
+    finally:
+        if plan is not None:
+            faults.clear()
+        if tuner is not None:
+            flightrec.recorder.stop()
+    return bound, tuner, trail, injected_at
+
+
+class TestTunedServingEndToEnd:
+    def test_ring_sweep_promotes_and_injected_regression_rolls_back(self):
+        bound, tuner, trail, injected_at = run_drift(
+            27, tuner_kw=dict(candidates=8, confirm_sweeps=2),
+            inject=(1, 64),
+        )
+        st = tuner.status()
+        # a gated promotion out of sweeps over real ring records, after
+        # the drift, confirmed through probation before the injection
+        promoted = next(w for w in trail if w != [1, 20])
+        assert promoted != [1, 64]
+        assert DRIFT["drift_at"] <= trail.index(promoted) < injected_at
+        assert st["sweeps"] >= 2 and st["sweep_failures"] == 0
+        # the injected vector went live, was caught on probation within
+        # two cycles of being detectable, and the confirmed weights rule
+        applied = trail.index([1, 64])
+        assert applied >= injected_at
+        assert st["promotions"] == 2 and st["rollbacks"] == 1
+        assert st["last_rollback_reason"].startswith("quality-regression")
+        assert st["last_rollback_detect_cycles"] <= 2
+        assert trail.count([1, 64]) <= 1 + st["last_rollback_detect_cycles"]
+        # no flapping: the confirmed weights rule to the end of the run
+        assert all(w == promoted for w in trail[applied + 3:])
+        assert st["state"] == "cooldown"
+        assert st["active_weights"] == st["last_known_good"] == promoted
+        assert all(bound)  # every cycle placed pods
+
+    def test_tuner_faults_never_reach_live_placements(self):
+        cycles = 14
+        control, _t, _trail, _i = run_drift(cycles)
+        plan = faults.FaultPlan(seed=5)
+        plan.specs = [
+            # garbage sweep output: the numpy oracles must disqualify
+            # every corrupted lane
+            faults.FaultSpec(site=faults.TUNE_SWEEP, cycle=5,
+                             kind="garbage", sticky=True),
+        ] + [
+            # every promotion application crashes: nothing the sweeps
+            # stage may ever reach the live weights
+            faults.FaultSpec(site=faults.TUNE_PROMOTE, cycle=c,
+                             kind="crash")
+            for c in range(cycles)
+        ]
+        chaos, tuner, trail, _i = run_drift(
+            cycles, tuner_kw=dict(candidates=8, confirm_sweeps=1),
+            plan=plan,
+        )
+        assert chaos == control  # bit-identical, cycle for cycle
+        st = tuner.status()
+        fired = {(site, kind) for _c, site, kind in plan.log}
+        assert (faults.TUNE_SWEEP, "garbage") in fired
+        assert (faults.TUNE_PROMOTE, "crash") in fired
+        assert st["promotions"] == 0 and st["sweep_failures"] >= 1
+        assert all(w == [1, 20] for w in trail)
+        assert st["last_known_good"] == [1, 20]
+        assert st["state"] in ("idle", "cooldown", "disabled")

@@ -136,8 +136,8 @@ REPO = Path(__file__).resolve().parent.parent
 #: tool trees (known-bad fixture corpora are excluded via the pyproject
 #: config, not path hacks)
 DEFAULT_PATHS = (
-    "scheduler_plugins_tpu", "bench.py", "chip_smoke.py",
-    "__graft_entry__.py", "tests", "tools",
+    "scheduler_plugins_tpu", "chip_smoke.py", "__graft_entry__.py",
+    "tests", "tools",
 )
 
 

@@ -114,7 +114,7 @@ def extract_samples(obj, source: str) -> list[dict]:
         return extract_samples(parsed, source)
     if "metric" in obj:
         return [_sample_from_line(obj, source)]
-    # bench.py multi-line runs: {"lines": [...]} or dict of named lines
+    # multi-line runs: {"lines": [...]} or dict of named lines
     if "lines" in obj and isinstance(obj["lines"], list):
         return extract_samples(obj["lines"], source)
     return []

@@ -2,8 +2,7 @@
 """Flight-recorder replay + explain CLI, and the `make replay-smoke` gate.
 
 Subcommands over bundles written by `utils.flightrec` (the daemon's
-`--record/--record-dir`, `bench.py --record dir/`, or `FlightRecorder
-.save`):
+`--record/--record-dir`, or `FlightRecorder.save`):
 
 - `info BUNDLE` — list recorded cycles (digest, mode, batch size, placed).
 - `replay BUNDLE [--cycle K]` — re-run recorded cycles offline through the
@@ -21,14 +20,11 @@ Subcommands over bundles written by `utils.flightrec` (the daemon's
   corpus-level gang admission latency when gangs are recorded) for every
   recorded cycle's placements, diffed against the per-cycle stamp
   `run_cycle` recorded when one exists.
-- `smoke` — the CI gate (`make replay-smoke`): record a reduced bench
-  cycle through the REAL `run_cycle` hooks, save/load the bundle, replay
-  it (diff must be empty), validate the explain JSON against
-  `EXPLAIN_SCHEMA`, check the explain columns sum to the solver's total,
-  and bound recorder-enabled overhead the same way tools/trace_smoke.py
-  bounds tracer overhead: interleaved off/on medians,
-  ≤ max(SPT_RECORD_BOUND_PCT [default 2%], the off series' p10-p90
-  spread).
+- `smoke` — the CI gate (`make replay-smoke`): record a reduced
+  gang+quota cycle through the REAL `run_cycle` hooks, save/load the
+  bundle, replay it (diff must be empty), validate the explain JSON
+  against `EXPLAIN_SCHEMA`, and check the explain columns sum to the
+  solver's total.
 
 One JSON line per action on stdout; rc 1 on any failure.
 """
@@ -40,22 +36,14 @@ import json
 import os
 import sys
 import tempfile
-import time
 from pathlib import Path
 
 REPO = Path(__file__).resolve().parent.parent
 if str(REPO) not in sys.path:  # `python tools/replay.py` from anywhere
     sys.path.insert(0, str(REPO))
 
-#: reduced gang+quota roster shape for the smoke gate: big enough that a
-#: cycle is not pure dispatch overhead, small enough for a 2-core runner
+#: reduced gang+quota roster shape (BASELINE config 4) for the smoke gate
 SMOKE_SHAPE = dict(n_gangs=4, gang_size=8, n_nodes=64)
-#: interleaved off/on pairs. 17 (was 7): the overhead statistic is the
-#: median of PAIRED deltas, and on a noisy 2-core host a 7-pair median
-#: flaked at ~13% both ways (PR 7 notes it failed identically on
-#: pre-PR HEAD) — more pairs + pairing makes the gate measure the
-#: recorder, not the host's scheduler jitter
-SMOKE_RUNS = 17
 
 
 # ---------------------------------------------------------------------------
@@ -355,80 +343,23 @@ def cmd_quality(args) -> int:
 # ---------------------------------------------------------------------------
 
 
-def _smoke_cluster():
-    """A fresh seed-0 cluster per cycle (run_cycle binds its pending pods,
-    so a cluster is single-use here); the Scheduler is built ONCE and
-    shared across cycles so every measured cycle hits the jit cache — the
-    overhead bound must compare recorder capture against a warm solve,
-    not against trace+compile noise that would swamp any regression."""
-    import bench
-
-    cluster, plugins, _ = bench.config_problem(4, shape=SMOKE_SHAPE)
-    return cluster, plugins
-
-
 def cmd_smoke(args) -> int:
-    import numpy as np
-
-    import bench
-    from scheduler_plugins_tpu.framework import run_cycle
+    from scheduler_plugins_tpu.framework import Profile, Scheduler, run_cycle
+    from scheduler_plugins_tpu.models import problems
     from scheduler_plugins_tpu.utils import flightrec
 
-    bench.apply_platform_override()
-    bound_pct = float(os.environ.get("SPT_RECORD_BOUND_PCT", 2.0))
     out_dir = args.out or os.path.join(
         tempfile.mkdtemp(prefix="replay_smoke_"), "bundle"
     )
-
-    from scheduler_plugins_tpu.framework import Profile, Scheduler
-
-    _, plugins = _smoke_cluster()
+    cluster, plugins, _ = problems.config_problem(4, shape=SMOKE_SHAPE)
     scheduler = Scheduler(Profile(plugins=plugins))
-
-    def one_cycle():
-        cluster, _plugins = _smoke_cluster()
-        start = time.perf_counter()
-        report = run_cycle(scheduler, cluster, now=1000)
-        return time.perf_counter() - start, report
-
-    one_cycle()  # compile warmup (recorder off; later cycles hit the cache)
-    # recorder-path warmup: the FIRST capture pays lazy imports (struct
-    # registry, digest machinery) that are one-time process cost, not
-    # per-cycle recorder overhead — keep them out of the measured pairs
     flightrec.recorder.start(capacity=2)
     flightrec.recorder.seed = 0  # config_problem scenarios are seed-0
-    one_cycle()
-
-    # interleaved off/on pairs: drift hits both arms of a pair equally,
-    # so the overhead statistic is the MEDIAN OF PAIRED deltas — robust
-    # to the 2-core host's scheduler jitter in a way two independent
-    # medians are not (the pre-fix gate flaked at ~13% both directions)
-    off, on, pair_pct = [], [], []
-    report = None
-    for _ in range(SMOKE_RUNS):
+    try:
+        report = run_cycle(scheduler, cluster, now=1000)
+        save = flightrec.recorder.save(out_dir)
+    finally:
         flightrec.recorder.stop()
-        t_off, _r = one_cycle()
-        off.append(t_off)
-        flightrec.recorder.start(capacity=2)
-        flightrec.recorder.seed = 0
-        t_on, report = one_cycle()
-        on.append(t_on)
-        pair_pct.append(100.0 * (t_on - t_off) / t_off)
-    median_off = sorted(off)[len(off) // 2]
-    median_on = sorted(on)[len(on) // 2]
-    overhead_pct = sorted(pair_pct)[len(pair_pct) // 2]
-    # noise floor: the off series' own p10-p90 spread — overhead below
-    # the run's jitter is not attributable to the recorder
-    off_sorted = sorted(off)
-    spread_pct = 100.0 * (
-        off_sorted[int(0.9 * (len(off) - 1))]
-        - off_sorted[int(0.1 * (len(off) - 1))]
-    ) / median_off
-    bound = max(bound_pct, spread_pct)
-
-    # save the LAST recorded cycle and round-trip it
-    save = flightrec.recorder.save(out_dir)
-    flightrec.recorder.stop()
     cycles = flightrec.load_bundle(out_dir)
     replay = flightrec.replay_cycle(cycles[-1])
     replay_ok = (
@@ -441,23 +372,13 @@ def cmd_smoke(args) -> int:
     # explain a failed pod when the cycle had one, else the first pod;
     # schema validation includes the columns-sum-to-total invariant
     pod_names = cycles[-1].manifest["meta"]["pod_names"]
-    uid = (report.failed[0] if report and report.failed else pod_names[0])
+    uid = report.failed[0] if report.failed else pod_names[0]
     table = flightrec.explain_record(cycles[-1], uid)
     schema_errors = validate_explain(table)
 
-    ok = (
-        replay_ok
-        and not schema_errors
-        and overhead_pct <= bound
-        and bool(report.bound)
-    )
+    ok = replay_ok and not schema_errors and bool(report.bound)
     print(json.dumps({
         "metric": "replay_smoke",
-        "off_cycle_ms": round(median_off * 1000, 2),
-        "on_cycle_ms": round(median_on * 1000, 2),
-        "overhead_pct": round(overhead_pct, 2),
-        "bound_pct": round(bound, 2),
-        "noise_floor_pct": round(spread_pct, 2),
         "bundle": save,
         "replay": {k: v for k, v in replay.items()
                    if not k.startswith("_")},

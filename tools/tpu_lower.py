@@ -13,9 +13,12 @@ patterns CLAUDE.md documents:
   takes on TPU — can hang compiles);
 - convolutions fed by i64 operands.
 
-Programs covered (the full bench surface + the sharded solves + the graft
-entry): bench configs 0-6 — including the north-star chunk loop — both
-sharded solves in `parallel/solver.py`, and `__graft_entry__.entry()`.
+Programs covered: BASELINE configs 0-6 — including the north-star chunk
+loop — both sharded solves in `parallel/solver.py`, and
+`__graft_entry__.entry()`. Problems come from `models/problems.py`. The
+`bench_` prefix of the config programs' registry names is historical (the
+builders lived in a root script once); the names are keys in the five
+manifests under `docs/` and stay.
 
 A digest manifest (`docs/tpu_lowering.json`: program -> StableHLO SHA-256 +
 op histogram, loc-metadata stripped) is committed so program regressions
@@ -205,15 +208,14 @@ def stablehlo_digest(text: str) -> str:
 
 
 def _batch_solve_program(shape):
-    """Configs 0/1: `bench.flagship_solve_stats` on `bench.alloc_problem` —
-    the exact construction + jitted fn bench ships (wave-occupancy stats
-    included: the timed program is the certified program)."""
+    """Configs 0/1: `problems.flagship_solve_stats` on
+    `problems.alloc_problem` (wave-occupancy stats included)."""
     import jax
 
-    import bench
+    from scheduler_plugins_tpu.models import problems
 
-    _, snap, _, weights = bench.alloc_problem(**shape)
-    return jax.jit(bench.flagship_solve_stats), (snap, weights), None
+    _, snap, _, weights = problems.alloc_problem(**shape)
+    return jax.jit(problems.flagship_solve_stats), (snap, weights), None
 
 
 def build_entry():
@@ -226,27 +228,27 @@ def build_entry():
 
 
 def build_cfg0_tpu_smoke():
-    import bench
+    from scheduler_plugins_tpu.models import problems
 
-    return _batch_solve_program(bench.SMOKE_SHAPE)
+    return _batch_solve_program(problems.SMOKE_SHAPE)
 
 
 def build_cfg1_flagship():
-    import bench
+    from scheduler_plugins_tpu.models import problems
 
-    return _batch_solve_program(bench.FLAGSHIP_SHAPE)
+    return _batch_solve_program(problems.FLAGSHIP_SHAPE)
 
 
 def _sequential_program(config):
     """Configs 2-5: the bit-faithful sequential solve on
-    `bench.config_problem`'s scenario/roster table (the one copy of those
+    `problems.config_problem`'s scenario/roster table (the one copy of those
     shapes), traced with the TPU-path scan unroll (runtime._scan_unroll
     returns 8 on TPU device kinds — mirror that here so the digest covers
     the program the chip would run, not the CPU test trace)."""
-    import bench
     from scheduler_plugins_tpu.framework import Profile, Scheduler
+    from scheduler_plugins_tpu.models import problems
 
-    cluster, plugins, _ = bench.config_problem(config)
+    cluster, plugins, _ = problems.config_problem(config)
     scheduler = Scheduler(Profile(plugins=plugins))
     pending = scheduler.sort_pending(cluster.pending_pods(), cluster)
     snap, meta = cluster.snapshot(pending, now_ms=0)
@@ -274,18 +276,22 @@ def build_cfg5_network_sequential():
 
 
 def build_cfg6_north_star_chunk():
-    """The north-star chunk loop body — `bench.north_star_chunk_solver()`
-    (the DONATED jit: donation changes the exported calling convention, so
-    the certified program must carry it), at the real node-count/chunk
-    shapes from `bench.NORTH_STAR_SHAPE`, with the chunk-invariant tensors
-    as arguments exactly as bench jits it (one pod chunk of cluster build
-    suffices: every chunk shares this one compiled program)."""
-    import bench
+    """The north-star chunk loop body —
+    `parallel.pipeline.north_star_chunk_solver()` (the DONATED jit:
+    donation changes the exported calling convention, so the certified
+    program must carry it), at the real node-count/chunk shapes from
+    `problems.NORTH_STAR_SHAPE`, with the chunk-invariant tensors as
+    arguments exactly as `chip_smoke.py` calls it (one pod chunk of cluster
+    build suffices: every chunk shares this one compiled program)."""
+    from scheduler_plugins_tpu.models import problems
     from scheduler_plugins_tpu.ops.fit import free_capacity
+    from scheduler_plugins_tpu.parallel.pipeline import (
+        north_star_chunk_solver,
+    )
 
-    shape = bench.NORTH_STAR_SHAPE
+    shape = problems.NORTH_STAR_SHAPE
     chunk = shape["chunk"]
-    _, snap, meta, weights, raw, _ = bench.north_star_problem(
+    _, snap, meta, weights, raw, _ = problems.north_star_problem(
         shape["n_nodes"], chunk, chunk
     )
     free = free_capacity(snap.nodes.alloc, snap.nodes.requested)
@@ -296,7 +302,7 @@ def build_cfg6_north_star_chunk():
         snap.pods.mask[:chunk],
         free,
     )
-    return bench.north_star_chunk_solver(), args, None
+    return north_star_chunk_solver(), args, None
 
 
 def _mesh8():
@@ -400,19 +406,19 @@ def build_serving_node_compact():
 def _sharded_wave_chunk_program(use_pallas: bool):
     """Shared staging for the two sharded-wave-chunk manifest entries —
     ONE copy of the reduced shard-smoke problem, mesh and
-    `rank_order_inputs` pre-permutation (exactly as bench stages it), so
+    `rank_order_inputs` pre-permutation (as `chip_smoke.py` phase D does), so
     the lax and pallas entries can never drift onto different shapes. The
     resident rank-ordered free carry is DONATED (the exported calling
     convention must carry it, like cfg6's chunk program)."""
-    import bench
+    from scheduler_plugins_tpu.models import problems
     from scheduler_plugins_tpu.parallel.mesh import make_node_mesh
     from scheduler_plugins_tpu.parallel.solver import (
         rank_order_inputs,
         sharded_wave_chunk_solver,
     )
 
-    shape = bench.SHARD_SMOKE_SHAPE
-    problem = bench.mega_problem(
+    shape = problems.SHARD_SMOKE_SHAPE
+    problem = problems.mega_problem(
         shape["n_nodes"], shape["n_pods"], shape["chunk"]
     )
     mesh = make_node_mesh(shape["devices"])
@@ -636,14 +642,14 @@ def build_packing_solve():
     finalize tail) at the reduced pack-smoke shape. The iteration
     budget, fragmentation-price weight and temperature schedule are the
     traced `pack_aux` argument, so ONE program serves every budget the
-    bench frontier sweeps — the property the lowering certifies for
+    caller sweeps — the property the lowering certifies for
     TPU (the refinement's `lax.while_loop` bound is a traced scalar)."""
-    import bench
+    from scheduler_plugins_tpu.models import problems
     from scheduler_plugins_tpu.ops.packing import pack_aux_vector
     from scheduler_plugins_tpu.parallel.solver import packing_solve_fn
 
-    shape = bench.PACK_SMOKE_SHAPE
-    _, snap, _, weights = bench.packing_problem(
+    shape = problems.PACK_SMOKE_SHAPE
+    _, snap, _, weights = problems.packing_problem(
         shape["n_nodes"], shape["demand_frac"], shape["empty_frac"]
     )
     fn = packing_solve_fn(collect_stats=True)

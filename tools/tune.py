@@ -326,9 +326,6 @@ def _record_smoke_corpus(out_dir: str) -> None:
 
 
 def cmd_smoke(args) -> int:
-    import bench
-
-    bench.apply_platform_override()
     out_dir = args.out or os.path.join(
         tempfile.mkdtemp(prefix="tune_smoke_"), "bundle"
     )
