@@ -62,6 +62,7 @@ from scheduler_plugins_tpu.controllers.elasticquota import (
 )
 from scheduler_plugins_tpu.controllers.podgroup import reconcile_pod_groups
 from scheduler_plugins_tpu.framework import Scheduler
+from scheduler_plugins_tpu.framework.cycle import cycle_report_stages
 from scheduler_plugins_tpu.obs import costmodel, ledger as podledger
 from scheduler_plugins_tpu.state.cluster import Cluster
 from scheduler_plugins_tpu.utils import compile_cache, observability as obs
@@ -221,18 +222,20 @@ def parse_args(argv=None):
     return ap.parse_args(argv)
 
 
-#: How far ahead of its interval the loop may run. A tick that took `d`
-#: (entry of `tick()` to its return: the cycle and the tail, both under the
-#: feed lock) is followed by a demand tick no sooner than this many `d`
-#: after it started, so whenever the loop runs early its thread is inside
-#: `tick()`, and the synchronous feed shut out, at most one sixth of the
-#: time. Why a sixth: a tick has a fixed cost whatever its batch, so ticks
-#: closer together raise the lock's share, and a closed backlog's
-#: throughput is what the lock leaves of the second (PERF.md finding 20:
-#: pods/s ∝ 1 − share). The parent held the lock (161.3 + 6.6) ms of every
-#: 1,000 in `basic-5000n.backlog` (ledger, PR 24), 16.8 %: the cell where
-#: share is throughput keeps the share it had, and a tick that costs a
-#: sixth of the interval or more keeps the interval's cadence exactly.
+#: How far ahead of its interval the loop may run. A tick that kept the
+#: feed lock for `d` (`Daemon.tick_locked_s`: from asking for the lock to
+#: giving it up, for the cycle and again for the tail; what the tick does
+#: with no lock held, the cycle's report-only epilogue above all, is not in
+#: it) is followed by a demand tick no sooner than this many `d` after it
+#: started, so whenever the loop runs early the synchronous feed is shut
+#: out at most one sixth of the time. Why a sixth: a tick has a fixed cost
+#: whatever its batch, so ticks closer together raise the lock's share,
+#: and a closed backlog's throughput is what the lock leaves of the second
+#: (PERF.md finding 20: pods/s ∝ 1 − share). The parent held the lock
+#: (161.3 + 6.6) ms of every 1,000 in `basic-5000n.backlog` (ledger, PR
+#: 24), 16.8 %: the cell where share is throughput keeps the share it had,
+#: and a tick that keeps the lock a sixth of the interval or more keeps the
+#: interval's cadence exactly.
 DEMAND_TICK_SPACING = 6
 
 
@@ -750,6 +753,9 @@ class Daemon:
                 )
         self.cycles = 0
         self.ticks = 0
+        #: seconds the last tick kept the feed lock, its waits for it
+        #: included: what `DEMAND_TICK_SPACING` multiplies
+        self.tick_locked_s = 0.0
         self.last_pending = 0
         self.last_quality = None
         self.last_memory = None  # /healthz device-memory block (ISSUE 20)
@@ -890,15 +896,17 @@ class Daemon:
             return self.cluster.pending_count()
 
     def tick(self):
+        entered = time.monotonic()
         if self.elector is not None and not self.elector.is_leader:
             # standby: reflectors keep the store warm, scheduling waits
             # (client-go leaderelection semantics — informers run, the
             # scheduling/reconcile loops gate on leadership)
             with self.feed.locked():
                 self.last_pending = self._count_pending()
+            self.tick_locked_s = time.monotonic() - entered
             return None
         now_ms = int(time.time() * 1000)
-        cycle_started = time.monotonic()
+        ctx = None
         try:
             engine = self.pipeline or self.laned
             if engine is not None:
@@ -914,7 +922,9 @@ class Daemon:
                 if self.tuner is not None and report is not None:
                     self.tuner.observe_report(report)
             else:
-                report = self.feed.run_cycle(
+                # the stages that touch the store, under the feed lock;
+                # the report-only rest comes after the tail, lock given up
+                ctx = self.feed.cycle_store_stages(
                     self.scheduler, now=now_ms, serve=self.engine,
                     resilience=self.resilience, tuner=self.tuner,
                 )
@@ -931,10 +941,11 @@ class Daemon:
             self.parked_cycles += 1
             with self.feed.locked():
                 self.last_pending = self._count_pending()
+            self.tick_locked_s = time.monotonic() - entered
             return None
-        obs.metrics.observe_ms(
-            "scheduler_cycle", (time.monotonic() - cycle_started) * 1000
-        )
+        tail_from = time.monotonic()
+        cycle_s = tail_from - entered
+        obs.metrics.observe_ms("scheduler_cycle", cycle_s * 1000)
         # the tick's tail, on the tracer's "daemon" row. The cycle gave
         # the lock up, and a feed thread that waited all cycle long has
         # it now: taking it again is a wait worth a span of its own
@@ -948,6 +959,14 @@ class Daemon:
                 self.last_pending = self._count_pending()
         finally:
             lock.release()
+        self.tick_locked_s = cycle_s + (time.monotonic() - tail_from)
+        obs.metrics.observe_ms(obs.TICK_LOCKED, self.tick_locked_s * 1000)
+        if ctx is not None:
+            # `Finalize`, with no lock held and outside `tick_locked_s`:
+            # it reads the resident node columns the engine's next
+            # refresh donates, and this thread, the only one that
+            # refreshes, does that in its next tick
+            report = cycle_report_stages(ctx, self.tuner)
         for line in events:
             obs.logger.info("controller: %s", line)
         if report.bound or report.failed:
@@ -986,8 +1005,9 @@ class Daemon:
 
     def _wait_for_tick(self, started: float, duration: float) -> str:
         """The loop's wait between two ticks, in its own thread; returns
-        what ended it. The last tick started at `started` and took
-        `duration` (both `time.monotonic()` seconds). The next one starts
+        what ended it. The last tick started at `started` and kept the
+        feed lock for `duration` (both `time.monotonic()` seconds, the
+        second `tick_locked_s`). The next one starts
         one interval after `started` at the latest ("interval"), and
         otherwise at the first moment from `DEMAND_TICK_SPACING` durations
         after `started` at which a pod has entered the pending set
@@ -1050,9 +1070,7 @@ class Daemon:
                 # terminate when leader-election standby skips every cycle
                 if args.max_cycles and self.ticks >= args.max_cycles:
                     break
-                woke = self._wait_for_tick(
-                    started, time.monotonic() - started
-                )
+                woke = self._wait_for_tick(started, self.tick_locked_s)
         finally:
             # graceful shutdown (SIGTERM/SIGINT path): every artifact the
             # process owns is flushed through the crash-safe

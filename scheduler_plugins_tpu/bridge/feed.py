@@ -764,14 +764,28 @@ class FeedServer:
         """Context manager guarding store access against the feed threads."""
         return self.lock
 
-    def run_cycle(self, scheduler, now=None, serve=None, resilience=None,
-                  tuner=None):
-        """One scheduling cycle holding the feed lock."""
-        from scheduler_plugins_tpu.framework.cycle import run_cycle
+    def cycle_store_stages(self, scheduler, now=None, **options):
+        """The part of one scheduling cycle that reads and writes the
+        store (`framework.cycle.cycle_store_stages`), holding the feed
+        lock; the context it returns goes to `cycle_report_stages`, which
+        needs no lock."""
+        from scheduler_plugins_tpu.framework.cycle import cycle_store_stages
 
         with self.lock:
-            return run_cycle(scheduler, self.cluster, now, serve=serve,
-                             resilience=resilience, tuner=tuner)
+            return cycle_store_stages(scheduler, self.cluster, now, **options)
+
+    def run_cycle(self, scheduler, now=None, serve=None, resilience=None,
+                  tuner=None):
+        """One scheduling cycle. The feed lock is held through the cycle's
+        last store mutation and given up before its report-only epilogue
+        (placement quality, the flight recorder's commit), which shuts no
+        feed thread out."""
+        from scheduler_plugins_tpu.framework.cycle import cycle_report_stages
+
+        ctx = self.cycle_store_stages(
+            scheduler, now, serve=serve, resilience=resilience, tuner=tuner,
+        )
+        return cycle_report_stages(ctx, tuner)
 
 
 class FeedClient:

@@ -20,6 +20,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+import contextlib
 import os
 import time
 from collections import deque
@@ -212,8 +213,13 @@ class CycleCtx:
     #: captured at the fence by the pipelined engine, whose deferred
     #: finalize runs AFTER the next refresh consumed (donated) the
     #: resident node tensors; None on the serial path (quality reads the
-    #: live snapshot before any donation)
+    #: live snapshot before any donation: its thread finalizes, then
+    #: refreshes)
     quality_view: object = None
+    #: `serve.generation` as this cycle's refresh left it, on a served
+    #: cycle: `_cycle_finalize` reads the resident node columns only while
+    #: the engine still stands there
+    serve_generation: int | None = None
     #: this cycle's pod-lifecycle ledger context (`obs.ledger.LedgerCycle`)
     #: — None whenever the ledger is disabled, so every hook below guards
     #: on it and the off path costs one attribute read
@@ -325,6 +331,7 @@ def _cycle_snapshot(ctx: CycleCtx) -> None:
             if refreshed is not None:
                 snap, meta = refreshed
                 ctx.served = True
+                ctx.serve_generation = serve.generation
         if snap is None:
             snap, meta = cluster.snapshot(pending, now_ms=now)
     ctx.snap, ctx.meta = snap, meta
@@ -629,10 +636,19 @@ def _cycle_finalize(ctx: CycleCtx, attribution: bool = False) -> None:
             ctx.scheduler, ctx.snap, ctx.result, ctx.failed_idx, ctx.report,
             tid=ctx.tid, led=ctx.led,
         )
+    view = ctx.quality_view
+    if view is None:
+        if ctx.served and ctx.serve.generation != ctx.serve_generation:
+            raise RuntimeError(
+                "Finalize after the serving engine's next refresh: the "
+                "resident node columns this cycle solved on were donated "
+                f"to it (generation {ctx.serve_generation} -> "
+                f"{ctx.serve.generation}) and no host copy was taken"
+            )
+        view = ctx.snap
     with obs.tracer.span("Finalize", tid=ctx.tid):
         _observe_quality(
-            ctx.report, ctx.quality_view or ctx.snap,
-            ctx.assignment, ctx.admitted, ctx.wait,
+            ctx.report, view, ctx.assignment, ctx.admitted, ctx.wait,
         )
         if ctx.rec is not None:
             ctx.rec.commit(ctx.report)
@@ -641,7 +657,11 @@ def _cycle_finalize(ctx: CycleCtx, attribution: bool = False) -> None:
 def _quality_view(snap):
     """Host copies of exactly the snapshot columns `cycle_quality_np`
     reads, in the same attribute shape — safe to read after the resident
-    node tensors were donated to a later cycle's delta apply."""
+    node tensors were donated to a later cycle's delta apply. The pipelined
+    engine needs them: its deferred finalize runs after the next refresh.
+    The serial engine's two calls (`cycle_store_stages`, then
+    `cycle_report_stages`) are the opposite case and take no copy: only
+    the tick's thread calls `refresh`, and it finalizes first."""
     from types import SimpleNamespace
 
     return SimpleNamespace(
@@ -706,29 +726,58 @@ def run_cycle(scheduler: Scheduler, cluster: Cluster, now: int | None = None,
     swaps could solve and record under different weights), and
     `observe_report` after finalize (the probation window's
     quality-gauge comparison feeds on the report's quality stamp)."""
-    # the `Cycle` span (tracer row "cycle"): this function, first statement
-    # to last, recorded at the close with the cycle's number (the registry's
-    # count of cycles, one higher each time: the identifier its inner spans
-    # share by nesting) and what it found and bound. The daemon enters here
-    # with the feed lock held, so a tick's lead-in to this span is its wait
-    # for the lock
-    span_from = obs.tracer.now_ns() if obs.tracer.enabled else None
-    ctx = None
-    try:
-        if now is None:
-            now = _now_ms()
-        if tuner is not None:
-            # the weight-swap seam: promotions/rollbacks apply only here,
-            # at the cycle boundary, never mid-cycle (docs/ROBUSTNESS.md)
-            tuner.begin_cycle(now_ms=now)
-        ctx = _cycle_open(
-            scheduler, cluster, now, stream_chunk=stream_chunk, serve=serve,
-            resilience=resilience, gangs=gangs,
+    with _cycle_span() as seen:
+        ctx = _store_stages(
+            seen, scheduler, cluster, now, stream_chunk=stream_chunk,
+            serve=serve, resilience=resilience, gangs=gangs, tuner=tuner,
         )
-        return _cycle_stages(ctx, tuner)
+        return cycle_report_stages(ctx, tuner)
+
+
+def cycle_store_stages(scheduler: Scheduler, cluster: Cluster,
+                       now: int | None = None, **options) -> CycleCtx:
+    """`run_cycle` as two calls, the first (`options`: `run_cycle`'s own):
+    every stage that reads or writes the store, through `_cycle_postbind`
+    and the ledger scope's close, inside a `Cycle` span of its own. What a
+    caller that guards the store with a lock (`bridge.feed.FeedServer`)
+    holds the lock for. The returned context goes to `cycle_report_stages`
+    before the next `serve.refresh` of this cluster's engine: on a served
+    cycle the epilogue reads the resident node columns in place, and that
+    refresh donates them (`_cycle_finalize` raises when asked later). Both
+    calls come from one thread, so their order is all that takes."""
+    with _cycle_span() as seen:
+        return _store_stages(seen, scheduler, cluster, now, **options)
+
+
+def cycle_report_stages(ctx: CycleCtx, tuner=None) -> CycleReport:
+    """`run_cycle` as two calls, the second: the report-only epilogue
+    (`_cycle_finalize`) and the tuner's `observe_report`. Touches no store
+    state and needs no lock; a cycle that ended early (no batch, a
+    gang-only cycle) has nothing to finalize."""
+    if not ctx.done:
+        _cycle_finalize(ctx)
+    if tuner is not None:
+        tuner.observe_report(ctx.report)
+    return ctx.report
+
+
+@contextlib.contextmanager
+def _cycle_span():
+    """The `Cycle` span (tracer row "cycle"): the body, first statement to
+    last, recorded at the close with the cycle's number (the registry's
+    count of cycles, one higher each time: the identifier its inner spans
+    share by nesting) and what it found and bound, once `_store_stages` has
+    left its context under "ctx" in the dict this yields. The daemon enters
+    with the feed lock held, so a tick's lead-in to this span is its wait
+    for the lock."""
+    span_from = obs.tracer.now_ns() if obs.tracer.enabled else None
+    seen: dict = {}
+    try:
+        yield seen
     finally:
         if span_from is not None:
             args = {"cycle": obs.metrics.get(obs.SCHEDULING_CYCLES)}
+            ctx = seen.get("ctx")
             if ctx is not None:
                 args["pending"] = len(ctx.pending)
                 args["bound"] = len(ctx.report.bound)
@@ -738,18 +787,25 @@ def run_cycle(scheduler: Scheduler, cluster: Cluster, now: int | None = None,
             )
 
 
-def _cycle_stages(ctx: CycleCtx, tuner) -> CycleReport:
-    """`run_cycle` after the prologue: the stage functions, strictly
-    serially, and the ledger scope's close."""
-    scheduler, cluster, now, serve = (
-        ctx.scheduler, ctx.cluster, ctx.now, ctx.serve,
+def _store_stages(seen: dict, scheduler, cluster, now, stream_chunk=None,
+                  serve=None, resilience=None, gangs=None,
+                  tuner=None) -> CycleCtx:
+    """The prologue and the stage functions through `_cycle_postbind`,
+    strictly serially, and the ledger scope's close."""
+    if now is None:
+        now = _now_ms()
+    if tuner is not None:
+        # the weight-swap seam: promotions/rollbacks apply only here,
+        # at the cycle boundary, never mid-cycle (docs/ROBUSTNESS.md)
+        tuner.begin_cycle(now_ms=now)
+    ctx = seen["ctx"] = _cycle_open(
+        scheduler, cluster, now, stream_chunk=stream_chunk, serve=serve,
+        resilience=resilience, gangs=gangs,
     )
     try:
         _cycle_pending(ctx)
         if ctx.done:
-            if tuner is not None:
-                tuner.observe_report(ctx.report)
-            return ctx.report
+            return ctx
 
         from scheduler_plugins_tpu.utils import sanitize
 
@@ -779,10 +835,7 @@ def _cycle_stages(ctx: CycleCtx, tuner) -> CycleReport:
             _cycle_post_solve(ctx)
         _cycle_bind(ctx)
         _cycle_postbind(ctx, attribution=True)
-        _cycle_finalize(ctx)
-        if tuner is not None:
-            tuner.observe_report(ctx.report)
-        return ctx.report
+        return ctx
     finally:
         # the lane-0 scope opened in `_cycle_open` — popped HERE (not in a
         # stage function) so early returns and raises cannot leak it, and

@@ -504,6 +504,12 @@ TICKS = "scheduler_ticks_total"
 #: tick, a standby's, and every tick that cost a sixth of the interval or
 #: more). The two add up to TICKS
 TICK_WAKEUPS = "scheduler_tick_wakeups_total"
+#: wall ms a tick kept the feed lock, from asking for it to giving it up,
+#: for the cycle and again for the tail (histogram, one observation a tick
+#: that ran a cycle): `scheduler_cycle` plus the tail. What the loop's
+#: spacing rule multiplies (`__main__.DEMAND_TICK_SPACING`); the cycle's
+#: report-only epilogue (`Finalize`) runs after it, with no lock held
+TICK_LOCKED = "scheduler_tick_locked"
 
 #: `# HELP` registry for `prometheus_text` (exposition format 0.0.4
 #: requires families to be self-describing; families not listed here get
@@ -600,6 +606,9 @@ HELP: dict[str, str] = {
         "Wall ms of one GET /healthz inside its handler, entry to reply "
         "written.",
     TICKS: "Ticks the daemon's loop started.",
+    TICK_LOCKED:
+        "Wall ms a tick kept the feed lock (cycle and tail, waits for it "
+        "included); demand ticks are spaced by six times this.",
     TICK_WAKEUPS:
         "Ticks by what ended the wait before them: a pod became "
         "schedulable (demand) or the cycle interval was up (interval).",

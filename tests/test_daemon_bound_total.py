@@ -58,17 +58,17 @@ class HeldDaemon:
         ]))
         self.cycle_done = threading.Event()
         self.go_on = threading.Event()
-        cycle = self.daemon.feed.run_cycle
+        cycle = self.daemon.feed.cycle_store_stages
 
         def held_cycle(*args, **kwargs):
-            report = cycle(*args, **kwargs)
-            if report.bound or report.failed:
+            ctx = cycle(*args, **kwargs)
+            if ctx.report.bound or ctx.report.failed:
                 self.cycle_done.set()
                 assert self.go_on.wait(60), "the test never let the tick go"
                 self.go_on.clear()
-            return report
+            return ctx
 
-        self.daemon.feed.run_cycle = held_cycle
+        self.daemon.feed.cycle_store_stages = held_cycle
         self.thread = threading.Thread(
             target=self.daemon.run, daemon=True, name="pacing-loop",
         )

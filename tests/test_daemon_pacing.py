@@ -78,11 +78,13 @@ class Loop:
         return f"default/{name}"
 
     def fake_tick(self, duration_s: float) -> None:
-        """Replace the tick by one that takes `duration_s` and leaves the
-        store alone; `starts` holds when each began."""
+        """Replace the tick by one that takes `duration_s`, all of it
+        counted as under the feed lock, and leaves the store alone;
+        `starts` holds when each began."""
         def tick():
             self.starts.append(time.monotonic())
             time.sleep(duration_s)
+            self.daemon.tick_locked_s = time.monotonic() - self.starts[-1]
 
         self.daemon.tick = tick  # `run` looks `tick` up on the instance
 
@@ -288,3 +290,251 @@ def test_the_wait_is_one_span_that_says_what_ended_it(loop):
     assert [e["args"] for e in sleeps] == [
         {"woke": "interval"}, {"woke": "demand"},
     ]
+
+
+# --- ISSUE 31: `Finalize` outside the feed lock and the spacing clock ------
+
+
+def _tick_on_a_thread(daemon) -> threading.Thread:
+    thread = threading.Thread(
+        target=daemon.tick, daemon=True, name="pacing-tick",
+    )
+    thread.start()
+    return thread
+
+
+def test_the_feed_is_served_during_finalize_and_not_during_bind(
+    loop, monkeypatch
+):
+    from scheduler_plugins_tpu.bridge.feed import FeedClient
+    from scheduler_plugins_tpu.framework import cycle as cyc
+
+    lp = loop(5.0, "--serve")
+    d = lp.daemon
+    lp.add_node()
+    lp.add_pod()
+    d.tick()  # compiles outside the held stretch
+    held = {name: (threading.Event(), threading.Event())
+            for name in ("bind", "finalize")}
+
+    def holding(name, inner):
+        def stage(*args, **kwargs):
+            reached, go_on = held[name]
+            reached.set()
+            assert go_on.wait(60), f"the test never let {name} go on"
+            return inner(*args, **kwargs)
+        return stage
+
+    monkeypatch.setattr(
+        cyc, "_bind_decisions", holding("bind", cyc._bind_decisions)
+    )
+    monkeypatch.setattr(
+        cyc, "_observe_quality", holding("finalize", cyc._observe_quality)
+    )
+    client = FeedClient(*d.feed.address)
+    acks = []
+
+    def send(name):
+        acks.append(client.send({
+            "op": "upsert_pod", "name": name,
+            "requests": {"cpu": 100, "memory": 1 << 20},
+        }))
+
+    try:
+        uid = lp.add_pod()
+        tick = _tick_on_a_thread(d)
+        assert held["bind"][0].wait(30), "the tick never reached Bind"
+        during_bind = threading.Thread(
+            target=send, args=("during-bind",), daemon=True,
+            name="pacing-feed",
+        )
+        during_bind.start()
+        during_bind.join(timeout=0.3)
+        assert during_bind.is_alive() and not acks  # shut out: the lock
+        held["bind"][1].set()
+        assert held["finalize"][0].wait(30), "the tick never finalized"
+        # inside Finalize the cycle's binds are in the store, its tail has
+        # run, and the lock is free: the waiting event and a new one are
+        # both acknowledged while the epilogue stands still
+        during_bind.join(timeout=30)
+        send("during-finalize")
+        assert [a["ok"] for a in acks] == [True, True]
+        assert tick.is_alive() and d.cluster.pods[uid].node_name == "n0"
+        assert not d.feed.lock.locked()
+        assert {"default/during-bind", "default/during-finalize"} <= set(
+            d.cluster.pods
+        )
+    finally:
+        for _reached, go_on in held.values():
+            go_on.set()
+        client.close()
+    tick.join(timeout=30)
+    assert not tick.is_alive() and d.last_quality is not None
+
+
+class FakeClock:
+    """`time` for `__main__`, moved by hand: `monotonic()` reads it, the
+    doorbell's wait advances it by its whole timeout."""
+
+    def __init__(self):
+        self.now = 1000.0
+
+    def monotonic(self) -> float:
+        return self.now
+
+    def time(self) -> float:
+        return 1.7e9 + self.now
+
+
+def test_demand_spacing_counts_the_locked_time_not_the_finalize(
+    loop, monkeypatch
+):
+    lp = loop(1.0, "--max-cycles", "2")
+    d = lp.daemon
+    clock = FakeClock()
+    monkeypatch.setattr(daemon_main, "time", clock)
+    starts = []
+
+    def cycle_store_stages(*_args, **_kwargs):
+        starts.append(clock.now)
+        d._pod_arrived()  # a pod is waiting whenever the loop looks
+        clock.now += 0.010  # the lock is held 10 ms ...
+        return types.SimpleNamespace(report=types.SimpleNamespace(
+            bound={}, failed=[], quality=None,
+        ))
+
+    def cycle_report_stages(ctx, _tuner):
+        clock.now += 0.010  # ... and Finalize takes 10 more, unlocked
+        return ctx.report
+
+    def wait(timeout):
+        clock.now += max(timeout, 0.0)
+
+    monkeypatch.setattr(d.feed, "cycle_store_stages", cycle_store_stages)
+    monkeypatch.setattr(daemon_main, "cycle_report_stages", cycle_report_stages)
+    monkeypatch.setattr(d._doorbell, "wait", wait)
+    locked = obs.metrics.scoped()
+    before = _counters()
+    d.run()
+    assert _since(before) == {"ticks": 2, "demand": 1, "interval": 1}
+    # 6 x the 10 ms under the lock, not 6 x the 20 ms the tick took
+    assert starts[1] - starts[0] == pytest.approx(
+        DEMAND_TICK_SPACING * 0.010, abs=1e-9
+    )
+    assert d.tick_locked_s == pytest.approx(0.010, abs=1e-9)
+    assert locked.hist_count(obs.TICK_LOCKED) == 2
+    assert locked.hist_sum(obs.TICK_LOCKED) == pytest.approx(20.0, abs=1e-6)
+    assert locked.hist_sum("scheduler_cycle") == pytest.approx(20.0, abs=1e-6)
+
+
+def test_a_ticks_finalize_comes_before_the_next_ticks_refresh(
+    loop, monkeypatch
+):
+    from scheduler_plugins_tpu.framework import cycle as cyc
+
+    lp = loop(5.0, "--serve")
+    d = lp.daemon
+    lp.add_node()
+    order, contexts = [], []
+    refresh, finalize = d.engine.refresh, cyc._cycle_finalize
+
+    def logged_refresh(*args, **kwargs):
+        order.append(("refresh", d.engine.generation))
+        return refresh(*args, **kwargs)
+
+    def logged_finalize(ctx, **kwargs):
+        order.append(("finalize", d.engine.generation))
+        contexts.append(ctx)
+        assert not d.feed.lock.locked()
+        return finalize(ctx, **kwargs)
+
+    monkeypatch.setattr(d.engine, "refresh", logged_refresh)
+    monkeypatch.setattr(cyc, "_cycle_finalize", logged_finalize)
+    for _ in range(2):
+        uid = lp.add_pod()
+        report = d.tick()
+        assert report.bound == {uid: "n0"} and report.quality is not None
+        assert d.last_quality == report.quality
+    first, second = contexts
+    assert first.served and second.served
+    assert [what for what, _gen in order] == [
+        "refresh", "finalize", "refresh", "finalize",
+    ]
+    # each Finalize found the engine where its own cycle's refresh left
+    # it; the second refresh, which donates the columns the first cycle
+    # solved on, came after the first Finalize
+    assert order[1][1] == first.serve_generation
+    assert order[3][1] == second.serve_generation > first.serve_generation
+    # asked again now, the first cycle's Finalize refuses: it will not
+    # read a donated buffer
+    with pytest.raises(RuntimeError, match="next refresh"):
+        finalize(first)
+    assert finalize(second) is None
+
+
+def test_a_traced_tick_finalizes_after_its_cycle_span_and_its_tail(loop):
+    from tools.trace_smoke import validate_trace
+
+    lp = loop(5.0, "--serve")
+    d = lp.daemon
+    lp.add_node()
+    lp.add_pod()
+    obs.tracer.start()
+    try:
+        d.tick()
+    finally:
+        obs.tracer.stop()
+    trace = obs.tracer.export()
+    assert validate_trace(trace) == []
+    rows = {e["tid"]: e["args"]["name"]
+            for e in trace["traceEvents"] if e["ph"] == "M"}
+    spans = {}
+    for e in trace["traceEvents"]:
+        if e["ph"] == "X":
+            assert e["name"] not in spans or e["name"] == "PendingScan"
+            spans[e["name"]] = (e["ts"], e["ts"] + e["dur"], rows[e["tid"]])
+    cycle, relock, reconcile, finalize, memory = (
+        spans[name] for name in (
+            "Cycle", "TickTail/relock", "TickTail/reconcile", "Finalize",
+            "TickTail/memory",
+        )
+    )
+    # a sibling after `Cycle`, not its last child: same row, no overlap
+    assert cycle[2] == finalize[2] == "cycle"
+    assert cycle[1] <= relock[0] <= relock[1] <= reconcile[0]
+    assert reconcile[1] <= finalize[0] <= finalize[1] <= memory[0]
+    assert spans["Bind"][1] <= cycle[1]
+
+
+def test_arrivals_during_unlocked_finalizes_leave_resident_state_exact(
+    loop, arrivals
+):
+    # stress: feed threads apply events while the loop's thread is inside
+    # its unlocked `Finalize`, at a switch interval that interleaves them
+    # often; a lost delta or a finalize on the wrong columns would show as
+    # a divergence of resident state or an overcommitted node
+    import sys
+
+    ticks = 60
+    lp = loop(0.05, "--serve", "--max-cycles", str(ticks))
+    d = lp.daemon
+    lp.add_node(cpu=10 ** 9)
+    lp.add_pod()
+    d.tick()  # compiles before the loop starts
+    switch = sys.getswitchinterval()
+    sys.setswitchinterval(1e-4)
+    try:
+        arrivals(lp)
+        lp.start()
+        lp.thread.join(timeout=120)
+    finally:
+        sys.setswitchinterval(switch)
+    assert not lp.thread.is_alive() and d.ticks == ticks
+    assert d.cycles >= ticks // 2 and d.last_quality is not None
+    with d.feed.locked():
+        bound = [p for p in d.cluster.pods.values() if p.node_name]
+        assert len(bound) == d.bound_total > ticks
+        assert {p.node_name for p in bound} == {"n0"}
+        assert d.engine.refresh(d.cluster, [], now_ms=0) is not None
+        assert d.engine.verify(d.cluster) is None
+        assert d.engine.rebases == 1

@@ -251,12 +251,12 @@ class TestServedLoopNames:
 
     @pytest.mark.parametrize("name", [
         obs.FEED_EVENTS, obs.FEED_EVENT_NS, obs.HEALTHZ_HANDLER_MS,
-        obs.TICKS, obs.TICK_WAKEUPS,
+        obs.TICKS, obs.TICK_WAKEUPS, obs.TICK_LOCKED,
     ])
     def test_help_covers_the_new_names(self, name):
         assert name.startswith("scheduler_") and obs.HELP[name]
         m = obs.Metrics()
-        if name == obs.HEALTHZ_HANDLER_MS:
+        if name in (obs.HEALTHZ_HANDLER_MS, obs.TICK_LOCKED):
             m.observe_ms(name, 1.5)
             kind = "histogram"
         else:
