@@ -582,6 +582,55 @@ def apply_side_deltas(tables: SideTables, g_idx, g_assigned, g_gated,
     )
 
 
+# ---------------------------------------------------------------------------
+# resident selector counts (ISSUE 32; docs/SERVING.md)
+# ---------------------------------------------------------------------------
+
+
+class SelectorDeltas:
+    """Packed +-1 contributions to the resident (TR, D) selector counts:
+    one row per (track, domain) a window's binds and deletes touched, the
+    host having summed what falls on the same cell. Padded with zero rows
+    (a scatter-add of zero is a no-op) to the usage batch's own buckets
+    (`UsageDeltas.MIN_BUCKET` and up): a zone-keyed track touches a handful
+    of cells whatever the batch, a hostname-keyed one about as many as the
+    batch has pods, so the program has the usage program's few shapes."""
+
+    __slots__ = ("track", "domain", "delta")
+
+    def __init__(self, track, domain, delta):
+        self.track = track
+        self.domain = domain
+        self.delta = delta
+
+    @classmethod
+    def pack(cls, cells: dict) -> "SelectorDeltas":
+        """`cells`: {(track row, domain code): signed count}."""
+        K = bucket_size(max(len(cells), 1), minimum=UsageDeltas.MIN_BUCKET)
+        track = np.zeros(K, I32)
+        domain = np.zeros(K, I32)
+        delta = np.zeros(K, I64)
+        for j, ((t, d), count) in enumerate(cells.items()):
+            track[j] = t
+            domain[j] = d
+            delta[j] = count
+        return cls(track, domain, delta)
+
+    def as_args(self) -> tuple:
+        return (self.track, self.domain, self.delta)
+
+    def as_dict(self) -> dict:
+        return {"track": self.track, "domain": self.domain,
+                "delta": self.delta}
+
+
+def apply_selector_deltas(track_base, track, domain, delta):
+    """Fold one packed batch into the resident (TR, D) matching-pod counts:
+    a plain scatter-add, like the usage columns'. `track_base` is donated at
+    the jit boundary (`selector_apply_program`)."""
+    return track_base.at[track, domain].add(delta)
+
+
 #: process-wide memo keyed by sanitize mode: every `ServeEngine` (and a
 #: chaos-harness crash restart, which builds a fresh one mid-run) shares
 #: ONE jitted apply program per mode, so engine reconstruction never pays
@@ -589,6 +638,31 @@ def apply_side_deltas(tables: SideTables, g_idx, g_assigned, g_gated,
 _APPLY_PROGRAMS: dict = {}
 _COMPACT_PROGRAMS: dict = {}
 _SIDE_PROGRAMS: dict = {}
+_SELECTOR_PROGRAMS: dict = {}
+
+
+def selector_apply_program():
+    """The jitted selector-count apply program with the resident table
+    DONATED: same constructor and memo discipline as
+    `delta_apply_program`."""
+    import jax
+
+    from scheduler_plugins_tpu.utils import observability as obs
+    from scheduler_plugins_tpu.utils import sanitize
+
+    key = sanitize.enabled()
+    if key in _SELECTOR_PROGRAMS:
+        return _SELECTOR_PROGRAMS[key]
+    if key:
+        jitted = sanitize.checkified(
+            apply_selector_deltas, program="serve_selector_apply"
+        )
+    else:
+        jitted = jax.jit(apply_selector_deltas, donate_argnums=(0,))
+    _SELECTOR_PROGRAMS[key] = obs.compile_watch(
+        jitted, program="serve_selector_apply"
+    )
+    return _SELECTOR_PROGRAMS[key]
 
 
 def side_apply_program():

@@ -41,12 +41,14 @@ drained delta stream, docs/SERVING.md "Resident gang/quota side
 tables". The load watcher's report (`Cluster.node_metrics`) is OWNED
 since ISSUE 29 — its nine columns are lowered once per report and the
 unreported-CPU column is kept O(changed), docs/SERVING.md "Resident
-node metrics". NRTs/AppGroups/seccomp profiles/selector-spec pods/node
-taints and any nomination still gate (the same shape of condition as
-the native-store fast path in `Cluster.snapshot`). While incompatible, `refresh` returns None (the
-cycle falls back to the full snapshot) but KEEPS absorbing deltas, so
-the resident columns stay in sync and serving resumes without a rebase
-once the side objects go away.
+node metrics". PodTopologySpread's selector and topology-domain counts
+are OWNED since ISSUE 32 (`serving.selectors.ResidentSelectors`;
+docs/SERVING.md "Resident selector counts"). What still gates is listed,
+clause by clause, in `ServeEngine.fallback_reason` (the same shape of
+condition as the native-store fast path in `Cluster.snapshot`). While
+incompatible, `refresh` returns None (the cycle falls back to the full
+snapshot) but KEEPS absorbing deltas, so the resident columns stay in sync
+and serving resumes without a rebase once the side objects go away.
 """
 
 from __future__ import annotations
@@ -57,6 +59,7 @@ from typing import Optional
 import numpy as np
 
 from scheduler_plugins_tpu.serving import deltas as D
+from scheduler_plugins_tpu.serving.selectors import ResidentSelectors
 from scheduler_plugins_tpu.state.snapshot import (
     ClusterSnapshot,
     GangState,
@@ -187,6 +190,11 @@ class ServeEngine:
         #: the clock and the prediction `_unreported` was last brought to
         self._metrics_now = 0
         self._tlp: Optional[tuple] = None
+        # -- resident selector counts (ISSUE 32; docs/SERVING.md) -------
+        #: PodTopologySpread's O(assigned) tables, kept O(changed) from the
+        #: same drained events; inert while no pod of the store declares a
+        #: spread constraint
+        self._selectors = ResidentSelectors(self)
 
     @staticmethod
     def _verify_every_default() -> int:
@@ -226,6 +234,7 @@ class ServeEngine:
         self._gang_rows.clear()
         self._ns_rows.clear()
         self._drop_metrics()
+        self._selectors.reset()
 
     @property
     def generation(self) -> int:
@@ -261,44 +270,73 @@ class ServeEngine:
 
     # -- compatibility gate ---------------------------------------------
     def compatible(self, cluster, pending) -> bool:
-        """True when the engine can own this cycle's snapshot: every
-        side table is either None or one the resident state fully
-        describes. Gang (PodGroup) and quota (ElasticQuota) rosters are
-        OWNED since ISSUE 12 — their aggregate tensors assemble from the
-        resident side tables; the load watcher's report is OWNED since
-        ISSUE 29 (`_sync_metrics`); a resource name is no reason to fall
-        back (`_outside_axis`: the axis widens by a rebase);
-        NRTs/AppGroups/seccomp/selector-spec pods/taints/nominations
-        still fall back."""
-        if (
-            cluster.nrts
-            or cluster.app_groups
-            or cluster.seccomp_profiles
-            or cluster._selector_spec_pods
-            or self._tainted
-        ):
-            return False
+        """True when the engine can own this cycle's snapshot
+        (`fallback_reason` names no clause)."""
+        return self.fallback_reason(cluster, pending) is None
+
+    def fallback_reason(self, cluster, pending) -> Optional[str]:
+        """The clause that hands this cycle back to the O(cluster)
+        `Cluster.snapshot`, or None when every side table is either absent
+        or one the resident state fully describes. Gang (PodGroup) and
+        quota (ElasticQuota) rosters are OWNED since ISSUE 12, the load
+        watcher's report since ISSUE 29 (`_sync_metrics`), topology-spread
+        constraints since ISSUE 32 (`ResidentSelectors`: any key, the
+        hostname key included); a resource name is no reason to fall back
+        (`_outside_axis`: the axis widens by a rebase). What still falls
+        back, each counted under its reason in
+        `scheduler_serve_fallback_total`:
+
+        - `nrt`: the store holds NodeResourceTopology objects;
+        - `app-group`: it holds AppGroups (network-aware tables);
+        - `seccomp`: it holds seccomp profiles (SySched tables);
+        - `taints`: some node carries a taint (`tol_ok` / `tol_prefer`);
+        - `pod-affinity`: some pod, pending OR bound, carries a pod
+          (anti-)affinity term (`aff_*`, `anti_*`, `waff_*`,
+          `exist_anti_*`, `sym_*` need the assigned pod objects);
+        - `nomination`: a nominated node anywhere (a gated or reserved
+          nominee, or one in the batch);
+        - `node-affinity`: a pod of the batch has a nodeSelector or a node
+          affinity term (`node_term_ok`, `pref_score`);
+        - `spread-node-counts`: a pod of the batch names, in one class,
+          several topology keys that some node carries only in part, so
+          its domains are counted by node (`spread_needs_node_counts`,
+          the (TR, N) `track_node_base`)."""
+        if cluster.nrts:
+            return "nrt"
+        if cluster.app_groups:
+            return "app-group"
+        if cluster.seccomp_profiles:
+            return "seccomp"
+        if self._tainted:
+            return "taints"
+        if cluster._affinity_spec_pods:
+            return "pod-affinity"
         # nominations OUTSIDE the pending batch still count into the full
         # snapshot's nominated column / nominee holds: scheduling-gated
         # nominees (sink-tracked at upsert) and reserved nominees
         # (O(reserved), in practice unreachable without gangs)
         if self._sink.nominated_unbound:
-            return False
+            return "nomination"
         for uid in cluster.reserved:
             p = cluster.pods.get(uid)
             if p is not None and p.nominated_node_name is not None:
-                return False
+                return "nomination"
         # batch-local specs (O(batch), not O(cluster)): node affinity
         # feeds SchedulingState; nominations feed the nominee holds
         for pod in pending:
+            if pod.nominated_node_name is not None:
+                return "nomination"
             if (
                 pod.node_selector
                 or pod.node_affinity_required
                 or pod.node_affinity_preferred
-                or pod.nominated_node_name is not None
             ):
-                return False
-        return True
+                return "node-affinity"
+        if cluster.selectors.tracks and self._selectors.needs_node_counts(
+            cluster, pending
+        ):
+            return "spread-node-counts"
+        return None
 
     def _outside_axis(self, cluster, pending) -> bool:
         """True when a PodGroup, a quota or a pending pod names a resource
@@ -352,7 +390,9 @@ class ServeEngine:
         n_nodes = len(cluster.nodes)
         grow = self._nodes is not None and n_nodes > self._npad
 
-        if not self.compatible(cluster, pending):
+        reason = self.fallback_reason(cluster, pending)
+        if reason is not None:
+            obs.metrics.inc(obs.SERVE_FALLBACKS, reason=reason)
             if cluster.pod_groups:
                 self.gang_fallbacks += 1
                 obs.metrics.inc(obs.SERVE_GANG_FALLBACKS)
@@ -362,6 +402,7 @@ class ServeEngine:
             if rebase:
                 self._nodes = None
                 self._side_dirty = True
+                self._selectors.invalidate()
             elif self._nodes is not None:
                 if grow:
                     self._grow(bucket_size(n_nodes))
@@ -382,6 +423,7 @@ class ServeEngine:
             self._grow(bucket_size(n_nodes))
         self._apply_batch(upserts, usage, side)
         self._sync_metrics(cluster, now_ms)
+        self._selectors.ensure(cluster, self._names, self._npad)
         self._refreshes += 1
         if self._verify_pending or (
             self.verify_every and self._refreshes % self.verify_every == 0
@@ -507,6 +549,7 @@ class ServeEngine:
                 name = ev[1]
                 self._tainted.discard(name)
                 self._node_labels.pop(name, None)
+                self._selectors.invalidate()
                 fail("node-delete")
             elif kind == D.NODE_UPSERT:
                 node = ev[1]
@@ -522,7 +565,8 @@ class ServeEngine:
                     fail("label-change")
                 self._node_labels[node.name] = labels
                 slot = self._slots.get(node.name)
-                if slot is None:
+                new_node = slot is None
+                if new_node:
                     slot = len(self._names)
                     self._slots[node.name] = slot
                     self._names.append(node.name)
@@ -534,6 +578,7 @@ class ServeEngine:
                         # fresh snapshots include slack only for nodes
                         # that exist) — rebuild rather than drift
                         self._side_dirty = True
+                self._selectors.node_row(node, slot, new_node)
                 try:
                     alloc = D._encode(node.allocatable, index)
                     cap = D._encode(node.capacity, index)
@@ -602,6 +647,7 @@ class ServeEngine:
                 # between event and drain mutates the pod in place and
                 # queues its own +1 — a drain-time read would double-count
                 term = 1 if ev[3] else 0
+                self._selectors.pod_event(pod, slot, sign)
                 usage.append((
                     slot, sign * req, sign * nz, sign * lim, sign,
                     sign * term,
@@ -656,6 +702,7 @@ class ServeEngine:
                     w.message, w.category, w.filename, w.lineno
                 )
         side_dict = self._apply_side(side)
+        self._selectors.apply()
         self._generation += 1
         n_events = len(upsert_rows) + len(usage_rows)
         self._staleness += n_events
@@ -665,6 +712,8 @@ class ServeEngine:
         }
         if side_dict is not None:
             self._last["side"] = side_dict
+        if self._selectors.last_packed is not None:
+            self._last["selectors"] = self._selectors.last_packed
         self._observe()
 
     def _apply_side(self, side):
@@ -768,6 +817,7 @@ class ServeEngine:
         )
         self._npad = new_npad
         self._metrics_stale = True
+        self._selectors.grow(new_npad)
 
     def _rebase(self, cluster, pending, now_ms: int):
         """Full re-snapshot: rebuild the resident base from the store (the
@@ -809,6 +859,14 @@ class ServeEngine:
         # and the metrics columns, through the code the snapshot just used
         self._metrics_stale = True
         self._sync_metrics(cluster, now_ms)
+        # and the selector tables; the cycle is handed them in the resident
+        # layout (padded axes, the registry's row order), so that the solve
+        # of the cold build is the program of every cycle after it
+        self._selectors.rebuild(cluster, self._names, npad)
+        if snap.scheduling is not None:
+            snap = snap.replace(scheduling=self._assemble_selectors(
+                cluster, pending, snap.num_pods
+            ))
         self._generation += 1
         self._staleness = 0
         self._rebases += 1
@@ -1200,6 +1258,8 @@ class ServeEngine:
                 reason = self._metrics_divergence(fresh.metrics)
             if reason is None:
                 reason = self._verify_side(cluster)
+            if reason is None:
+                reason = self._selectors.divergence(cluster, self._names)
             if reason is not None:
                 self.antientropy_divergences += 1
                 obs.metrics.inc(obs.ANTIENTROPY_DIVERGENCE)
@@ -1327,6 +1387,8 @@ class ServeEngine:
         # the metrics columns likewise: lowered from the store's report
         # at the first refresh (O(nodes + recent bindings), no rebase)
         self._drop_metrics()
+        # and the selector tables: built from the store at the first refresh
+        self._selectors.reset()
         self._base_digest = None
         self._last = None
         self.note_fault("checkpoint-restore")
@@ -1410,8 +1472,22 @@ class ServeEngine:
         snap = ClusterSnapshot(
             nodes=self._nodes, pods=pods, gangs=gang_state,
             quota=quota_state, metrics=self._metrics_state,
+            scheduling=self._assemble_selectors(cluster, pending, P),
         )
         return snap, meta
+
+    def _assemble_selectors(self, cluster, pending, P: int):
+        """This cycle's `SchedulingState` over the resident selector
+        tables: the O(batch) rows (`pend_match`, `spread_*`) under the span
+        `ServeRefresh/selectors`, opened only where the store's pods
+        declare a spread constraint. None where the batch carries none,
+        as a fresh build has it."""
+        if not cluster.selectors.tracks:
+            return None
+        with obs.tracer.span(
+            "ServeRefresh/selectors", tid="serve", pending=len(pending)
+        ):
+            return self._selectors.scheduling_state(pending, P, self._npad)
 
     def _assemble_side(self, cluster, pod_groups, gang_pos, batch_counts,
                        ns_in, meta, P: int, now_ms: int):
@@ -1515,6 +1591,8 @@ class ServeEngine:
             }
             if "side" in self._last:
                 packed["side"] = self._last["side"]
+            if "selectors" in self._last:
+                packed["selectors"] = self._last["selectors"]
             serve["deltas"] = pack_pytree(packed, rec.blobs)
         rec.manifest["serve"] = serve
 
@@ -1760,6 +1838,8 @@ class StreamingServeEngine(ServeEngine):
                 reason = self._metrics_divergence(metrics_exp)
             if reason is None and side_exp is not None:
                 reason = self._side_divergence(*side_exp)
+            if reason is None and expected is not None:
+                reason = self._selectors.divergence(cluster, self._names)
             if reason is not None:
                 self.antientropy_divergences += 1
                 obs.metrics.inc(obs.ANTIENTROPY_DIVERGENCE)
@@ -1935,6 +2015,7 @@ class StreamingServeEngine(ServeEngine):
             self._names.pop(slot)
             self._slots = {n: i for i, n in enumerate(self._names)}
             self._metrics_stale = True
+            self._selectors.invalidate()
             if self._gang_rows:
                 # fresh snapshots drop gang slack of pods bound to a
                 # deleted node — rebuild rather than drift (the base

@@ -27,6 +27,7 @@ from scheduler_plugins_tpu.api.objects import (
     SeccompProfile,
 )
 from scheduler_plugins_tpu.obs import ledger as podledger
+from scheduler_plugins_tpu.state.scheduling import SelectorRegistry
 from scheduler_plugins_tpu.state.snapshot import build_snapshot
 
 
@@ -79,10 +80,16 @@ class Cluster:
     #: `node_name` is set: whoever has seen a pod bound reads a count that
     #: holds it. The daemon's `bound_total` is this number
     binds_total: int = 0
-    #: uids of LIVE pods carrying spread/affinity specs — the native
-    #: snapshot fast path must disengage while any exist, because the
-    #: scheduling tables need the assigned pod objects it skips
-    _selector_spec_pods: set = field(default_factory=set)
+    #: uids of LIVE pods, pending or bound, carrying pod (anti-)affinity
+    #: terms: the scheduling tables then need the assigned pod objects,
+    #: which the native snapshot fast path skips and the resident engine
+    #: does not keep (`ServeEngine.compatible`, reason "pod-affinity")
+    _affinity_spec_pods: set = field(default_factory=set)
+    #: the spread tracks the store's pods declare, interned where a pod is
+    #: added and released where it is removed (pod specs are immutable):
+    #: the resident engine keeps its selector counts by them
+    #: (docs/SERVING.md "Resident selector counts")
+    selectors: SelectorRegistry = field(default_factory=SelectorRegistry)
     # EnqueueExtensions bookkeeping (upstream scheduling queue): a monotonic
     # event counter, the last counter value per event kind, and per-pod
     # unschedulable records (event counter at failure, flush deadline).
@@ -327,10 +334,9 @@ class Cluster:
             self._native_rebuild()
 
     @staticmethod
-    def _has_selector_specs(pod: Pod) -> bool:
+    def _has_affinity_terms(pod: Pod) -> bool:
         return bool(
-            pod.topology_spread
-            or pod.pod_affinity_required
+            pod.pod_affinity_required
             or pod.pod_anti_affinity_required
             or pod.pod_affinity_preferred
             or pod.pod_anti_affinity_preferred
@@ -389,11 +395,15 @@ class Cluster:
                 self.delta_sink.pod_assigned(pod, new_hold)
             self.delta_sink.note_nomination(pod)
         self._binding_touched(pod.uid)
-        if self._has_selector_specs(pod):
-            # spread/affinity tables need ASSIGNED pod objects at snapshot
-            # build, which the native fast path skips (pod specs are
-            # immutable, so count on add/remove)
-            self._selector_spec_pods.add(pod.uid)
+        if self._has_affinity_terms(pod):
+            # affinity tables need ASSIGNED pod objects at snapshot build,
+            # which the native fast path skips (pod specs are immutable,
+            # so count on add/remove)
+            self._affinity_spec_pods.add(pod.uid)
+        elif old is not None:
+            self._affinity_spec_pods.discard(pod.uid)
+        if pod.topology_spread or old is not None:
+            self.selectors.add(pod)
         if self.nrt_cache is not None and hasattr(self.nrt_cache, "track_pod"):
             # foreign-pod detection (cache/foreign_pods.go:42-99)
             self.nrt_cache.track_pod(pod)
@@ -403,7 +413,8 @@ class Cluster:
 
     def remove_pod(self, uid: str):
         self.release_reservation(uid)  # notifies the NRT cache too
-        self._selector_spec_pods.discard(uid)
+        self._affinity_spec_pods.discard(uid)
+        self.selectors.remove(uid)
         self.unschedulable_since.pop(uid, None)
         self._clear_backoff(uid)
         pod = self.pods.pop(uid, None)
@@ -921,7 +932,13 @@ class Cluster:
             and not self.quotas
             and not self.app_groups
             and not self.seccomp_profiles
-            and not self._selector_spec_pods
+            and not self._affinity_spec_pods
+            # assigned pods' spread constraints are nobody's input: only a
+            # batch that carries one needs the assigned objects' labels
+            and not (
+                self.selectors.tracks
+                and any(p.topology_spread for p in pending)
+            )
         ):
             exports = self._native.export_nodes()
             if len(exports["ids"]) == len(self.nodes) and all(

@@ -47,6 +47,7 @@ from scheduler_plugins_tpu.api.objects import (
     Node,
     Pod,
 )
+from scheduler_plugins_tpu.utils import observability as obs
 
 I64 = np.int64
 I32 = np.int32
@@ -328,6 +329,17 @@ def build_scheduling(
                 elif taint.effect == "PreferNoSchedule":
                     tol_prefer[s, n] += 1
 
+    # the selector tables, whoever asks for a fresh build (the span is the
+    # fallback path's twin of the engine's `ServeRefresh/selectors`)
+    with obs.tracer.span("Snapshot/selectors", tid="snapshot",
+                         assigned=len(assigned)):
+        selector_tables = _build_selector_tables(
+            nodes, pending, assigned, N, P, namespaces,
+            pod_aff_rows=node_term_ok[
+                np.where(pod_node_term < 0, T, pod_node_term)
+            ],
+            pod_tol_rows=tol_ok[pod_tol],
+        )
     return SchedulingState(
         node_term_ok=node_term_ok,
         pod_node_term=np.where(pod_node_term < 0, T, pod_node_term).astype(I32),
@@ -336,13 +348,7 @@ def build_scheduling(
         tol_ok=tol_ok,
         tol_prefer=tol_prefer,
         pod_tol=pod_tol,
-        **_build_selector_tables(
-            nodes, pending, assigned, N, P, namespaces,
-            pod_aff_rows=node_term_ok[
-                np.where(pod_node_term < 0, T, pod_node_term)
-            ],
-            pod_tol_rows=tol_ok[pod_tol],
-        ),
+        **selector_tables,
     )
 
 
@@ -387,6 +393,230 @@ def _term_scope(pod: Pod, term, namespaces) -> tuple:
     return tuple(sorted(scope))
 
 
+class SelectorAxes:
+    """First-seen interning of selector groups (namespace scope, selector),
+    topology keys and tracks (group, key): the S, K and TR axes. A fresh
+    build interns in the order its batch names them; the resident engine
+    (`serving.selectors`) keeps one across cycles, in the order of the
+    store's registry, so a row means the same thing from cycle to cycle."""
+
+    def __init__(self):
+        self.sels: dict = {}  # (ns scope, selector key) -> index
+        self.sel_objs: list = []  # (ns tuple, LabelSelector-or-None)
+        self.keys: dict = {}  # topology key -> index
+        self.key_names: list[str] = []
+        self.tracks: dict = {}  # (sel idx, key idx) -> track index
+
+    def sel_id(self, ns_scope: tuple, selector) -> int:
+        k = (ns_scope, None if selector is None else selector._key())
+        s = self.sels.get(k)
+        if s is None:
+            s = self.sels[k] = len(self.sels)
+            self.sel_objs.append((ns_scope, selector))
+        return s
+
+    def key_id(self, name: str) -> int:
+        k = self.keys.get(name)
+        if k is None:
+            k = self.keys[name] = len(self.keys)
+            self.key_names.append(name)
+        return k
+
+    def track_id(self, s: int, k: int) -> int:
+        t = self.tracks.get((s, k))
+        if t is None:
+            t = self.tracks[(s, k)] = len(self.tracks)
+        return t
+
+
+def spread_track_keys(pod: Pod) -> list:
+    """[(ns scope, selector, topology key), ...] of the pod's spread
+    constraints, matchLabelKeys merged: what a track is made of. The
+    store's registry (`SelectorRegistry`) and `spread_rows` both intern
+    from this."""
+    return [
+        ((pod.namespace,), _merged_spread_selector(pod, tsc),
+         tsc.topology_key)
+        for tsc in pod.topology_spread
+    ]
+
+
+def spread_rows(axes: SelectorAxes, pending, P: int, CT=None) -> dict:
+    """The (P, CT) per-pod spread-constraint rows, O(batch): one function
+    for the fresh build and the resident engine. `CT` pads the constraint
+    axis (the engine's bucket); by default it is the batch's widest pod."""
+    widest = max((len(p.topology_spread) for p in pending), default=1) or 1
+    CT = widest if CT is None else max(CT, widest)
+    spread_track = np.zeros((P, CT), I32)
+    spread_topo = np.zeros((P, CT), I32)
+    spread_max_skew = np.zeros((P, CT), I64)
+    spread_hard = np.zeros((P, CT), bool)
+    spread_self = np.zeros((P, CT), bool)
+    spread_mask = np.zeros((P, CT), bool)
+    spread_min_domains = np.zeros((P, CT), I64)
+    spread_policy_affinity = np.zeros((P, CT), bool)
+    spread_policy_taints = np.zeros((P, CT), bool)
+    for i, pod in enumerate(pending):
+        if not pod.topology_spread:
+            continue
+        for c, (tsc, (scope, sel, key)) in enumerate(
+            zip(pod.topology_spread, spread_track_keys(pod))
+        ):
+            s = axes.sel_id(scope, sel)
+            k = axes.key_id(key)
+            spread_track[i, c] = axes.track_id(s, k)
+            spread_topo[i, c] = k
+            spread_max_skew[i, c] = tsc.max_skew
+            spread_hard[i, c] = tsc.when_unsatisfiable == "DoNotSchedule"
+            spread_self[i, c] = _sel_matches(sel, scope, pod)
+            spread_mask[i, c] = True
+            spread_min_domains[i, c] = tsc.min_domains or 0
+            spread_policy_affinity[i, c] = (
+                tsc.node_affinity_policy != "Ignore"
+            )
+            spread_policy_taints[i, c] = tsc.node_taints_policy == "Honor"
+    return dict(
+        spread_track=spread_track,
+        spread_topo=spread_topo,
+        spread_max_skew=spread_max_skew,
+        spread_hard=spread_hard,
+        spread_self=spread_self,
+        spread_mask=spread_mask,
+        spread_min_domains=spread_min_domains,
+        spread_policy_affinity=spread_policy_affinity,
+        spread_policy_taints=spread_policy_taints,
+    )
+
+
+def topology_tables(key_names, nodes, N: int, K=None) -> tuple:
+    """(topo_code (K, N), topo_has (K, N), domain_values [dict, ...]): each
+    node's domain under each key, a key's values interned in node order.
+    `K` pads the key axis; the caller sizes the domain axis from
+    `domain_values`."""
+    K = max(len(key_names), 1) if K is None else K
+    topo_code = np.full((K, N), -1, I32)
+    topo_has = np.zeros((K, N), bool)
+    domain_values: list[dict] = [dict() for _ in range(K)]
+    for k, name in enumerate(key_names):
+        dv = domain_values[k]
+        for n, node in enumerate(nodes):
+            val = node.labels.get(name)
+            if val is None:
+                continue
+            code = dv.get(val)
+            if code is None:
+                code = dv[val] = len(dv)
+            topo_code[k, n] = code
+            topo_has[k, n] = True
+    return topo_code, topo_has, domain_values
+
+
+def domain_exists_table(domain_values, K: int, D: int) -> np.ndarray:
+    exists = np.zeros((K, D), bool)
+    for k, dv in enumerate(domain_values):
+        for code in dv.values():
+            exists[k, code] = True
+    return exists
+
+
+def track_counts(axes: SelectorAxes, assigned, node_pos, topo_code,
+                 TR: int, N: int, D: int, per_node: bool = True) -> tuple:
+    """(track_node_base (TR, N) | None, track_base (TR, D)): the assigned
+    pods each track's selector matches, by node and by the node's domain
+    under the track's key. O(assigned x tracks): the part of the selector
+    tables that a resident engine keeps instead of recounting."""
+    track_node_base = np.zeros((TR, N), I64) if per_node else None
+    track_base = np.zeros((TR, D), I64)
+    tracks = [
+        (t, k, *axes.sel_objs[s]) for (s, k), t in axes.tracks.items()
+    ]
+    for pod in assigned:
+        n = node_pos.get(pod.node_name)
+        if n is None:
+            continue
+        for t, k, ns, selector in tracks:
+            if _sel_matches(selector, ns, pod):
+                if per_node:
+                    track_node_base[t, n] += 1
+                code = topo_code[k, n]
+                if code >= 0:
+                    track_base[t, code] += 1
+    return track_node_base, track_base
+
+
+def labels_key(pod: Pod) -> tuple:
+    """What a selector can tell two pods apart by: namespace and labels."""
+    return (pod.namespace, tuple(sorted(pod.labels.items())))
+
+
+def pend_match_rows(sel_objs, pending, P: int, S=None, memo=None
+                    ) -> np.ndarray:
+    """(S, P) bool: pending pod i is in selector group s. O(batch x S) for
+    the fresh build; the resident engine pads the selector axis (`S`) and
+    lends a `memo` of columns by `labels_key`, which holds for as long as
+    `sel_objs` does: replicas of one workload then cost a lookup each."""
+    S = len(sel_objs) if S is None else S
+    pend_match = np.zeros((S, P), bool)
+    for i, pod in enumerate(pending):
+        key = labels_key(pod) if memo is not None else None
+        column = memo.get(key) if memo is not None else None
+        if column is None:
+            column = np.zeros(S, bool)
+            for s, (ns, selector) in enumerate(sel_objs):
+                column[s] = _sel_matches(selector, ns, pod)
+            if memo is not None:
+                memo[key] = column
+        pend_match[:, i] = column
+    return pend_match
+
+
+class SelectorRegistry:
+    """The spread tracks the store's pods declare, pending or bound:
+    interned where a pod is added and released where it is removed (pod
+    specs are immutable), so nothing has to rediscover them from every
+    assigned pod at every snapshot. `version` moves when the SET of live
+    tracks does: a resident engine rebuilds its selector tables then, and
+    only then. O(constraints) a pod."""
+
+    def __init__(self):
+        #: (ns scope, selector key | None, topology key) -> [pods, selector]
+        self.tracks: dict = {}
+        self._by_pod: dict = {}  # uid -> its track keys
+        self.version = 0
+
+    def add(self, pod: Pod) -> None:
+        """`pod` replaces whatever was held under its uid."""
+        keys = [
+            ((scope, None if sel is None else sel._key(), topo), scope, sel)
+            for scope, sel, topo in spread_track_keys(pod)
+        ] if pod.topology_spread else []
+        for key, _scope, sel in keys:
+            held = self.tracks.get(key)
+            if held is None:
+                self.tracks[key] = [1, sel]
+                self.version += 1
+            else:
+                held[0] += 1
+        self.remove(pod.uid)
+        if keys:
+            self._by_pod[pod.uid] = [key for key, _, _ in keys]
+
+    def remove(self, uid: str) -> None:
+        for key in self._by_pod.pop(uid, ()):
+            held = self.tracks[key]
+            held[0] -= 1
+            if not held[0]:
+                del self.tracks[key]
+                self.version += 1
+
+    def axes(self) -> SelectorAxes:
+        """The live tracks as axes, in the registry's order."""
+        axes = SelectorAxes()
+        for (scope, _selkey, topo), (_refs, sel) in self.tracks.items():
+            axes.track_id(axes.sel_id(scope, sel), axes.key_id(topo))
+        return axes
+
+
 def _build_selector_tables(
     nodes, pending, assigned, N, P, namespaces=(),
     pod_aff_rows=None, pod_tol_rows=None,
@@ -398,29 +628,11 @@ def _build_selector_tables(
     if not _has_selector_specs(pending, assigned):
         return {}
 
-    sels: dict = {}  # (ns scope, selector key) -> index
-    sel_objs: list = []  # (ns tuple, LabelSelector-or-None)
-    keys: dict = {}  # topology key -> index
-    key_names: list[str] = []
-    tracks: dict = {}  # (sel idx, key idx) -> track index
-
-    def sel_id(ns_scope: tuple, selector) -> int:
-        k = (ns_scope, None if selector is None else selector._key())
-        if k not in sels:
-            sels[k] = len(sels)
-            sel_objs.append((ns_scope, selector))
-        return sels[k]
-
-    def key_id(name: str) -> int:
-        if name not in keys:
-            keys[name] = len(keys)
-            key_names.append(name)
-        return keys[name]
-
-    def track_id(s: int, k: int) -> int:
-        if (s, k) not in tracks:
-            tracks[(s, k)] = len(tracks)
-        return tracks[(s, k)]
+    axes = SelectorAxes()
+    sel_id, key_id, track_id = axes.sel_id, axes.key_id, axes.track_id
+    sel_objs, keys, key_names, tracks = (
+        axes.sel_objs, axes.keys, axes.key_names, axes.tracks
+    )
 
     def term_ids(pod: Pod, term) -> tuple[int, int, int]:
         """(sel, key, track) for a PodAffinityTerm scoped to the pod."""
@@ -429,32 +641,10 @@ def _build_selector_tables(
         k = key_id(term.topology_key)
         return s, k, track_id(s, k)
 
-    CT = max((len(p.topology_spread) for p in pending), default=1) or 1
-    spread_track = np.zeros((P, CT), I32)
-    spread_topo = np.zeros((P, CT), I32)
-    spread_max_skew = np.zeros((P, CT), I64)
-    spread_hard = np.zeros((P, CT), bool)
-    spread_self = np.zeros((P, CT), bool)
-    spread_mask = np.zeros((P, CT), bool)
-    spread_min_domains = np.zeros((P, CT), I64)
-    spread_policy_affinity = np.zeros((P, CT), bool)
-    spread_policy_taints = np.zeros((P, CT), bool)
-    for i, pod in enumerate(pending):
-        for c, tsc in enumerate(pod.topology_spread):
-            sel = _merged_spread_selector(pod, tsc)
-            s = sel_id((pod.namespace,), sel)
-            k = key_id(tsc.topology_key)
-            spread_track[i, c] = track_id(s, k)
-            spread_topo[i, c] = k
-            spread_max_skew[i, c] = tsc.max_skew
-            spread_hard[i, c] = tsc.when_unsatisfiable == "DoNotSchedule"
-            spread_self[i, c] = _sel_matches(sel, (pod.namespace,), pod)
-            spread_mask[i, c] = True
-            spread_min_domains[i, c] = tsc.min_domains or 0
-            spread_policy_affinity[i, c] = (
-                tsc.node_affinity_policy != "Ignore"
-            )
-            spread_policy_taints[i, c] = tsc.node_taints_policy == "Honor"
+    spread = spread_rows(axes, pending, P)
+    spread_policy_affinity = spread["spread_policy_affinity"]
+    spread_policy_taints = spread["spread_policy_taints"]
+    CT = spread["spread_track"].shape[1]
 
     # inter-pod affinity terms (incoming pod's own)
     AT = max((len(p.pod_affinity_required) for p in pending), default=1) or 1
@@ -583,26 +773,11 @@ def _build_selector_tables(
         for e2, c in pod_sym_terms(pod).items():
             pending_sym.append((i, e2, c))
 
-    S, K = len(sel_objs), max(len(key_names), 1)
+    K = max(len(key_names), 1)
     # topology domain codes per key (value interned per key)
-    topo_code = np.full((K, N), -1, I32)
-    topo_has = np.zeros((K, N), bool)
-    domain_values: list[dict] = [dict() for _ in range(K)]
-    for k, name in enumerate(key_names):
-        for n, node in enumerate(nodes):
-            val = node.labels.get(name)
-            if val is None:
-                continue
-            dv = domain_values[k]
-            if val not in dv:
-                dv[val] = len(dv)
-            topo_code[k, n] = dv[val]
-            topo_has[k, n] = True
+    topo_code, topo_has, domain_values = topology_tables(key_names, nodes, N)
     D = max((len(dv) for dv in domain_values), default=1) or 1
-    domain_exists = np.zeros((K, D), bool)
-    for k, dv in enumerate(domain_values):
-        for code in dv.values():
-            domain_exists[k, code] = True
+    domain_exists = domain_exists_table(domain_values, K, D)
 
     # --- static spread node-eligibility rows (upstream node-inclusion:
     # per-class all-keys presence, nodeAffinityPolicy, nodeTaintsPolicy).
@@ -656,23 +831,10 @@ def _build_selector_tables(
         track_topo[t] = k
 
     node_pos = {node.name: n for n, node in enumerate(nodes)}
-    track_node_base = np.zeros((TR, N), I64)
-    track_base = np.zeros((TR, D), I64)
-    for pod in assigned:
-        n = node_pos.get(pod.node_name)
-        if n is None:
-            continue
-        for (s, k), t in tracks.items():
-            ns, selector = sel_objs[s]
-            if _sel_matches(selector, ns, pod):
-                track_node_base[t, n] += 1
-                code = topo_code[k, n]
-                if code >= 0:
-                    track_base[t, code] += 1
-    pend_match = np.zeros((S, P), bool)
-    for i, pod in enumerate(pending):
-        for s, (ns, selector) in enumerate(sel_objs):
-            pend_match[s, i] = _sel_matches(selector, ns, pod)
+    track_node_base, track_base = track_counts(
+        axes, assigned, node_pos, topo_code, TR, N, D
+    )
+    pend_match = pend_match_rows(sel_objs, pending, P)
 
     out = dict(
         pend_match=pend_match,
@@ -683,15 +845,7 @@ def _build_selector_tables(
         track_topo=track_topo,
         track_node_base=track_node_base if needs_node_counts else None,
         track_base=track_base,
-        spread_track=spread_track,
-        spread_topo=spread_topo,
-        spread_max_skew=spread_max_skew,
-        spread_hard=spread_hard,
-        spread_self=spread_self,
-        spread_mask=spread_mask,
-        spread_min_domains=spread_min_domains,
-        spread_policy_affinity=spread_policy_affinity,
-        spread_policy_taints=spread_policy_taints,
+        **spread,
         spread_elig=spread_elig,
         spread_elig_idx=spread_elig_idx,
         spread_needs_node_counts=needs_node_counts,

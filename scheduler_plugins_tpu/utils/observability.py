@@ -373,6 +373,23 @@ SERVE_GANG_FALLBACKS = "scheduler_serve_gang_fallbacks_total"
 #: O(nodes) lowering is back on every tick (serving/engine.py
 #: `_sync_metrics`)
 SERVE_METRICS_RELOWERS = "scheduler_serve_metrics_relowers_total"
+#: +-1 contributions a serving refresh folded into the resident selector
+#: counts: one per (bind or delete of a pod, track its labels match) — in
+#: a steady mix about twice a cycle's binds a track
+#: (docs/SERVING.md "Resident selector counts")
+SERVE_SELECTOR_ROWS = "scheduler_serve_selector_rows_total"
+#: node rows of the resident `topo_code` table written outside a rebuild
+#: (a node that arrived, or whose labels were sent again)
+SERVE_TOPO_ROWS = "scheduler_serve_topo_rows_total"
+#: O(assigned) rebuilds of the resident selector tables from the store:
+#: the cold build, and after it only when the set of tracks the store's
+#: pods declare changes, a tracked label of a node that holds pods
+#: changes, or a key or domain outgrows its bucket
+SERVE_SELECTOR_REBASES = "scheduler_serve_selector_rebases_total"
+#: labels: reason — serving refreshes that handed the cycle back to the
+#: O(cluster) `Cluster.snapshot`, by the clause of
+#: `ServeEngine.compatible` that refused it
+SERVE_FALLBACKS = "scheduler_serve_fallback_total"
 #: gauge (labels: objective): the latest cycle's placement-quality
 #: objective values (tuning.quality — fragmentation, util_imbalance,
 #: gang_wait_frac, unplaced_frac, preemptions, nominations), stamped by
@@ -547,6 +564,14 @@ HELP: dict[str, str] = {
         "roster.",
     SERVE_METRICS_RELOWERS:
         "Lowerings of the load watcher's report into resident columns.",
+    SERVE_SELECTOR_ROWS:
+        "Signed contributions folded into the resident selector counts.",
+    SERVE_TOPO_ROWS:
+        "Node rows of the resident topology-domain table written.",
+    SERVE_SELECTOR_REBASES:
+        "Rebuilds of the resident selector tables from the store.",
+    SERVE_FALLBACKS:
+        "Serve refreshes that fell back to a full snapshot, by reason.",
     PLACEMENT_QUALITY:
         "Latest cycle's placement-quality objective values (gauge).",
     DEGRADED: "1 while serving from the host-side parity solve (gauge).",

@@ -557,7 +557,7 @@ class TestNativeStoreGate:
         # and removing the spec-carrying pods re-engages the fast path
         c.remove_pod("default/e")
         c.remove_pod("default/p")
-        assert not c._selector_spec_pods
+        assert not c._affinity_spec_pods
 
 
 class TestWaveCapacityHostLevelBypass:
