@@ -613,10 +613,12 @@ def _postbind_store(ctx: CycleCtx, attribution: bool) -> None:
         failed=len(report.failed),
     ) as said:
         # the host's victim search: how many of the failed pods it was
-        # run for (none of a gang rejected whole), and what it found
+        # run for (none of a gang rejected whole), whether it got as far
+        # as its post-bind snapshot, and what it found
         said["candidates"] = _run_preemption(
             ctx.scheduler, cluster, ctx.pending, report, now
         )
+        said["searched"] = said["candidates"] > 0
         said["victims"] = sum(
             len(victims) for _node, victims in report.preempted.values()
         )
@@ -1003,7 +1005,9 @@ def _run_preemption(scheduler, cluster, pending, report, now):
     run victim removal across all nodes, nominate the best candidate, mark
     victims terminating (the apiserver DELETE boundary in the reference)
     and record the nomination (SURVEY.md §3.3). Returns how many pods the
-    search was run for.
+    search was run for: the failed pods less those of a gang rejected
+    whole. With none left it returns before its snapshot, and the plugins
+    stay bound to the cycle's own meta.
 
     Runs against a FRESH snapshot (this cycle's binds must count as node
     usage, or just-bound pods double as phantom victims) and threads the
@@ -1013,11 +1017,23 @@ def _run_preemption(scheduler, cluster, pending, report, now):
     engine = scheduler.profile.preemption
     if engine is None or not report.failed:
         return 0
-    candidates = 0
     rejected = set(report.rejected_gangs)
     by_uid = {p.uid: p for p in pending}
     failed_pods = [by_uid[uid] for uid in report.failed if uid in by_uid]
+    # whom the search is for, in queue order: a gang rejected whole gains
+    # nothing from a victim. Settled before anything O(cluster): a cycle
+    # whose failed pods all belong to such gangs builds no snapshot,
+    # re-prepares no plugin and scans no hold.
+    candidates = [
+        pod for pod in failed_pods
+        if (pg := cluster.pod_group_of(pod)) is None
+        or pg.full_name not in rejected
+    ]
+    if not candidates:
+        return 0
     # post-bind state: assigned pods now include this cycle's placements
+    # (the pending list stays every failed pod: the quota nominee tables
+    # and the pod rows are built from it)
     snap, meta = cluster.snapshot(failed_pods, now_ms=now)
     # re-prepare: the preemption snapshot's resource-axis layout can differ
     # from the main cycle's (extended names are interned in first-seen
@@ -1055,11 +1071,7 @@ def _run_preemption(scheduler, cluster, pending, report, now):
         and not pod.terminating
         and pod.nominated_node_name in node_pos
     ]
-    for pod in failed_pods:
-        pg = cluster.pod_group_of(pod)
-        if pg is not None and pg.full_name in rejected:
-            continue  # the whole gang was rejected; no point preempting
-        candidates += 1
+    for pod in candidates:
         obs.metrics.inc(obs.PREEMPTION_ATTEMPTS)
         # PodEligibleToPreemptOthers runs inside preempt(): while pods this
         # pod could benefit from are still terminating on its nominated
@@ -1116,7 +1128,7 @@ def _run_preemption(scheduler, cluster, pending, report, now):
         holds.append((n, demand, pod.priority, pod.uid))
         nominated_extra[n] -= victim_freed
         report.preempted[pod.uid] = (result.nominated_node, result.victims)
-    return candidates
+    return len(candidates)
 
 
 def _refresh_metrics(scheduler, cluster: Cluster, now: int):
