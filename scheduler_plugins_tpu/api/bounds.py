@@ -129,6 +129,10 @@ LABEL_BOUNDS = (
      r"|spread_max_skew|spread_min_domains)$", I32_MAX - 1, "plain"),
     (r"^snap\.numa\.distances$", NUMA_DISTANCE_MAX, "plain"),
     (r"^state\.sel_dom_counts$", I32_MAX - 1, "plain"),
+    # the resident selector tables and their packed +-1 rows
+    # (serving_selector_apply): counts of pods, never quantities
+    (r"^state\.sel\[\d\]$", I32_MAX - 1, "plain"),
+    (r"^sel\.delta$", I32_MAX - 1, "plain"),
     # plugin weight vectors ride the aux channel as small int64 config
     # scalars (profile weights are <= 2^20 by construction — framework
     # normalizes weights to the reference's int32 plugin-weight range)

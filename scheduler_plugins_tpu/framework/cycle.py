@@ -639,19 +639,37 @@ def _cycle_finalize(ctx: CycleCtx, attribution: bool = False) -> None:
             tid=ctx.tid, led=ctx.led,
         )
     view = ctx.quality_view
-    if view is None:
-        if ctx.served and ctx.serve.generation != ctx.serve_generation:
-            raise RuntimeError(
-                "Finalize after the serving engine's next refresh: the "
-                "resident node columns this cycle solved on were donated "
-                f"to it (generation {ctx.serve_generation} -> "
-                f"{ctx.serve.generation}) and no host copy was taken"
-            )
-        view = ctx.snap
+
+    def donated() -> bool:
+        # no host copy, and the engine has refreshed since this cycle's
+        # snapshot: the node columns it solved on were donated away
+        return (view is None and ctx.served
+                and ctx.serve.generation != ctx.serve_generation)
+
     with obs.tracer.span("Finalize", tid=ctx.tid):
-        _observe_quality(
-            ctx.report, view, ctx.assignment, ctx.admitted, ctx.wait,
-        )
+        try:
+            if donated():
+                raise RuntimeError("donated before Finalize")
+            _observe_quality(
+                ctx.report, view or ctx.snap, ctx.assignment, ctx.admitted,
+                ctx.wait,
+            )
+        except RuntimeError:
+            if not donated():
+                raise
+            # another thread refreshed the engine under the feed lock
+            # between this tick's locked stages and its epilogue (the
+            # benchmark's resident-state check does; PR 34's chip run of
+            # `gangs-quota-1024n.backlog` lost its daemon to the raise
+            # that stood here). The epilogue is report-only: this cycle
+            # goes without its placement quality, and never reads what
+            # the refresh donated.
+            obs.logger.warning(
+                "Finalize after the serving engine's next refresh "
+                "(generation %s -> %s): no host copy was taken, the "
+                "cycle's placement quality is not observed",
+                ctx.serve_generation, ctx.serve.generation,
+            )
         if ctx.rec is not None:
             ctx.rec.commit(ctx.report)
 
@@ -662,8 +680,9 @@ def _quality_view(snap):
     node tensors were donated to a later cycle's delta apply. The pipelined
     engine needs them: its deferred finalize runs after the next refresh.
     The serial engine's two calls (`cycle_store_stages`, then
-    `cycle_report_stages`) are the opposite case and take no copy: only
-    the tick's thread calls `refresh`, and it finalizes first."""
+    `cycle_report_stages`) are the opposite case and take no copy: the
+    tick's thread finalizes before it refreshes again. Should another
+    thread refresh in between, `_cycle_finalize` leaves the quality out."""
     from types import SimpleNamespace
 
     return SimpleNamespace(

@@ -8,7 +8,7 @@ not speed: what a program costs on a chip is measured on the chip
 (``chip_smoke.py``, PERF.md). This module is the one copy of that
 arithmetic, read by three consumers:
 
-- ``tools/cost_observatory.py`` measures the full 24-program registry
+- ``tools/cost_observatory.py`` measures the full 25-program registry
   (the same one ``tools/tpu_lower.py`` / jaxpr_audit / kernel_audit
   share) and commits ``docs/cost_model.json``;
 - ``tools/perf_sentry.py`` runs the cost arm: the deterministic second

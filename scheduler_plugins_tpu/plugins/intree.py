@@ -313,7 +313,7 @@ class InterPodAffinity(Plugin):
         match_at = jnp.take_along_axis(dc, jnp.maximum(code, 0), axis=1)
         ok = has & (
             (match_at > 0)
-            | ((total == 0) & s.aff_self[p][:, None])
+            | ((total == 0) & s.aff_self[p])[:, None]
         )
         verdict &= jnp.all(
             jnp.where(s.aff_mask[p][:, None], ok, True), axis=0

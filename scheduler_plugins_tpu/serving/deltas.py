@@ -588,13 +588,20 @@ def apply_side_deltas(tables: SideTables, g_idx, g_assigned, g_gated,
 
 
 class SelectorDeltas:
-    """Packed +-1 contributions to the resident (TR, D) selector counts:
-    one row per (track, domain) a window's binds and deletes touched, the
-    host having summed what falls on the same cell. Padded with zero rows
-    (a scatter-add of zero is a no-op) to the usage batch's own buckets
-    (`UsageDeltas.MIN_BUCKET` and up): a zone-keyed track touches a handful
-    of cells whatever the batch, a hostname-keyed one about as many as the
-    batch has pods, so the program has the usage program's few shapes."""
+    """Packed +-1 contributions to the resident selector tables: one row
+    per (table row, domain) a window's binds and deletes touched, the host
+    having summed what falls on the same cell. The rows of the three tables
+    share one axis, in their order: the (TR, D) matching-pod counts first,
+    then the (E, D) carriers of required anti-affinity terms, then the
+    (E2, D) carriers of the score's symmetric terms, so a carrier's bind is
+    one more row of the batch its labels' tracks are in. Padded with zero
+    rows (a scatter-add of zero is a no-op) to `UsageDeltas.MIN_BUCKET`
+    and its doublings: a zone-keyed track touches a handful of cells
+    whatever the batch, a hostname-keyed one as many as the batch has pod
+    events, and twice that where every pod carries a term, so a window's
+    cells run from one to four thousand and the doublings keep that to
+    three shapes (the 1,024-steps of `bucket_size` would make a shape of
+    every thousand, some of them rare enough to compile in a window)."""
 
     __slots__ = ("track", "domain", "delta")
 
@@ -605,8 +612,10 @@ class SelectorDeltas:
 
     @classmethod
     def pack(cls, cells: dict) -> "SelectorDeltas":
-        """`cells`: {(track row, domain code): signed count}."""
-        K = bucket_size(max(len(cells), 1), minimum=UsageDeltas.MIN_BUCKET)
+        """`cells`: {(row, domain code): signed count}."""
+        K = UsageDeltas.MIN_BUCKET
+        while K < len(cells):
+            K *= 2
         track = np.zeros(K, I32)
         domain = np.zeros(K, I32)
         delta = np.zeros(K, I64)
@@ -624,11 +633,29 @@ class SelectorDeltas:
                 "delta": self.delta}
 
 
-def apply_selector_deltas(track_base, track, domain, delta):
-    """Fold one packed batch into the resident (TR, D) matching-pod counts:
-    a plain scatter-add, like the usage columns'. `track_base` is donated at
-    the jit boundary (`selector_apply_program`)."""
-    return track_base.at[track, domain].add(delta)
+def apply_selector_deltas(tables, track, domain, delta):
+    """Fold one packed batch into the resident selector tables: `tables` is
+    (track_base (TR, D), anti_count (E, D) | None, sym_base (E2, D) | None),
+    a row of the batch falls on the table whose span of the shared row axis
+    it is in, and each table takes a plain scatter-add, like the usage
+    columns'. Returns the tables and `anti_count > 0`, the (E, D) presence
+    the scan reads as `exist_anti_base` (None without the table): a delete
+    lifts a block only when the last carrier of the domain leaves. `tables`
+    is donated at the jit boundary (`selector_apply_program`)."""
+    import jax.numpy as jnp
+
+    out, first = [], 0
+    for table in tables:
+        if table is None:
+            out.append(None)
+            continue
+        local = track - first
+        mine = (local >= 0) & (local < table.shape[0])
+        out.append(table.at[jnp.where(mine, local, 0), domain].add(
+            jnp.where(mine, delta, 0)
+        ))
+        first += table.shape[0]
+    return tuple(out), None if out[1] is None else out[1] > 0
 
 
 #: process-wide memo keyed by sanitize mode: every `ServeEngine` (and a

@@ -43,7 +43,9 @@ since ISSUE 29 — its nine columns are lowered once per report and the
 unreported-CPU column is kept O(changed), docs/SERVING.md "Resident
 node metrics". PodTopologySpread's selector and topology-domain counts
 are OWNED since ISSUE 32 (`serving.selectors.ResidentSelectors`;
-docs/SERVING.md "Resident selector counts"). What still gates is listed,
+docs/SERVING.md "Resident selector counts"), InterPodAffinity's terms and
+carrier counts since ISSUE 34 (the same class; "Resident affinity
+terms"). What still gates is listed,
 clause by clause, in `ServeEngine.fallback_reason` (the same shape of
 condition as the native-store fast path in `Cluster.snapshot`). While
 incompatible, `refresh` returns None (the cycle falls back to the full
@@ -281,18 +283,23 @@ class ServeEngine:
         quota (ElasticQuota) rosters are OWNED since ISSUE 12, the load
         watcher's report since ISSUE 29 (`_sync_metrics`), topology-spread
         constraints since ISSUE 32 (`ResidentSelectors`: any key, the
-        hostname key included); a resource name is no reason to fall back
-        (`_outside_axis`: the axis widens by a rebase). What still falls
-        back, each counted under its reason in
-        `scheduler_serve_fallback_total`:
+        hostname key included), pod (anti-)affinity terms since ISSUE 34
+        (required and preferred, the incoming pod's own and the assigned
+        carriers': `aff_*`, `anti_*`, `waff_*` are built O(batch),
+        `exist_anti_base` and `sym_base` are resident carrier counts); a
+        resource name is no reason to fall back (`_outside_axis`: the axis
+        widens by a rebase). What still falls back, each counted under its
+        reason in `scheduler_serve_fallback_total`:
 
         - `nrt`: the store holds NodeResourceTopology objects;
         - `app-group`: it holds AppGroups (network-aware tables);
         - `seccomp`: it holds seccomp profiles (SySched tables);
         - `taints`: some node carries a taint (`tol_ok` / `tol_prefer`);
-        - `pod-affinity`: some pod, pending OR bound, carries a pod
-          (anti-)affinity term (`aff_*`, `anti_*`, `waff_*`,
-          `exist_anti_*`, `sym_*` need the assigned pod objects);
+        - `affinity-namespace-selector`: some pod, pending OR bound,
+          carries a pod (anti-)affinity term with a non-empty
+          `namespaceSelector`: its scope moves when a Namespace's labels
+          do, so no row keeps it across cycles
+          (`Cluster.selectors.unscoped`);
         - `nomination`: a nominated node anywhere (a gated or reserved
           nominee, or one in the batch);
         - `node-affinity`: a pod of the batch has a nodeSelector or a node
@@ -309,8 +316,8 @@ class ServeEngine:
             return "seccomp"
         if self._tainted:
             return "taints"
-        if cluster._affinity_spec_pods:
-            return "pod-affinity"
+        if cluster.selectors.unscoped:
+            return "affinity-namespace-selector"
         # nominations OUTSIDE the pending batch still count into the full
         # snapshot's nominated column / nominee holds: scheduling-gated
         # nominees (sink-tracked at upsert) and reserved nominees
@@ -1478,10 +1485,13 @@ class ServeEngine:
 
     def _assemble_selectors(self, cluster, pending, P: int):
         """This cycle's `SchedulingState` over the resident selector
-        tables: the O(batch) rows (`pend_match`, `spread_*`) under the span
+        tables: the O(batch) rows (`pend_match`, `spread_*`, and under
+        `ServeRefresh/affinity` the `aff_*` / `anti_*` / `waff_*` /
+        `exist_anti_*` / `sym_*` ones) under the span
         `ServeRefresh/selectors`, opened only where the store's pods
-        declare a spread constraint. None where the batch carries none,
-        as a fresh build has it."""
+        declare a spread constraint or a pod (anti-)affinity term. None
+        where the batch carries no constraint and the store no term, as a
+        fresh build has it."""
         if not cluster.selectors.tracks:
             return None
         with obs.tracer.span(
@@ -2064,6 +2074,28 @@ def side_lower_args(n_gangs: int = 8, n_ns: int = 4, n_rows: int = 16):
     packed = D.SideDeltas.pack(gang_rows, ns_rows, R, G, Q)
     args = (tables, *(jnp.asarray(a) for a in packed.as_args()))
     return D.side_apply_program(), args
+
+
+def selector_lower_args(n_tracks: int = 3, n_terms: int = 2,
+                        n_domains: int = 48, n_rows: int = 40):
+    """(jitted fn, sample args) for the AOT compile-readiness gate — the
+    exact donated selector apply program `ResidentSelectors.apply` folds a
+    window's +-1 rows with (`tools/tpu_lower.py` serving_selector_apply),
+    at a reduced shape with all three tables: the matching-pod counts, the
+    carriers of required anti terms and those of the score's terms."""
+    import jax.numpy as jnp
+
+    TR, Dp = bucket_size(n_tracks), bucket_size(n_domains)
+    E = bucket_size(n_terms, minimum=1)
+    tables = tuple(
+        jnp.zeros((rows, Dp), jnp.int64) for rows in (TR, E, E)
+    )
+    packed = D.SelectorDeltas.pack({
+        (j % (TR + 2 * E), j % n_domains): 1 - 2 * (j % 2)
+        for j in range(n_rows)
+    })
+    args = (tables, *(jnp.asarray(a) for a in packed.as_args()))
+    return D.selector_apply_program(), args
 
 
 def lower_program_args(n_nodes: int = 256, n_upserts: int = 8,

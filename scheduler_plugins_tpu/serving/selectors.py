@@ -1,4 +1,5 @@
-"""Resident selector counts: PodTopologySpread's tables across cycles.
+"""Resident selector counts: PodTopologySpread's and InterPodAffinity's
+tables across cycles.
 
 A fresh `state.scheduling.build_scheduling` recounts every assigned pod
 against every selector group, in Python, whenever a batch carries a spread
@@ -11,9 +12,18 @@ columns do (docs/SERVING.md "Resident selector counts"):
   labels match a track is a +-1 on (track, domain(node)), packed beside
   the usage deltas (`serving.deltas.SelectorDeltas`) and folded by one
   donated scatter-add (`selector_apply_program`).
+- `anti_count` (E, D) and `sym_base` (E2, D) int64, on the device (ISSUE
+  34; docs/SERVING.md "Resident affinity terms"): the assigned CARRIERS of
+  each required anti-affinity term, and of each of the score's symmetric
+  terms, by the domain of their node under the term's key. A carrier's bind
+  or delete is a +-1 (its term count for `sym_base`) in the same batch and
+  the same scatter-add; `exist_anti_base`, the presence the scan reads, is
+  `anti_count > 0`, so a delete lifts a block only when the last carrier of
+  the domain leaves.
 - `topo_code` / `topo_has` (K, N), `domain_exists` (K, D), `track_sel` /
-  `track_topo` (TR,): host tables, staged again only when they change. A
-  node that arrives writes one column.
+  `track_topo` (TR,), `exist_anti_sel` / `exist_anti_topo` (E,), `sym_*`
+  (E2,): host tables, staged again only when they change. A node that
+  arrives writes one column.
 
 The track, key, domain and selector axes are padded to `bucket_size`
 buckets, so that selector groups that come and go give the solve no shape
@@ -25,9 +35,11 @@ tables are built, and built again (O(assigned), counted as
 store's pods declare moves, a tracked label of a known node changes, or a
 key or domain outgrows its bucket.
 
-What a cycle still builds, O(batch), is `scheduling_state`: `pend_match`
-and the (P, CT) `spread_*` rows, through the functions the fresh build
-uses (`state.scheduling.spread_rows`).
+What a cycle still builds, O(batch), is `scheduling_state`: `pend_match`,
+the (P, CT) `spread_*` rows and, where the store's pods declare pod
+(anti-)affinity terms, the (P, AT/BT/WT) `aff_*` / `anti_*` / `waff_*` rows
+and the (E, P) / (E2, P) carrier and match rows, through the functions the
+fresh build uses (`state.scheduling.spread_rows`, `affinity_rows`).
 """
 
 from __future__ import annotations
@@ -89,10 +101,21 @@ class ResidentSelectors:
         self.track_topo = None
         self.sel_rows = 0  # the selector axis's bucket
         self.track_base = None  # (TR, D) int64, device, donated
+        #: (E, D) int64 carriers of each required anti term, device, donated;
+        #: None while the store's pods carry no such term
+        self.anti_count = None
+        self.exist_anti_base = None  # (E, D) bool, device: anti_count > 0
+        self.sym_base = None  # (E2, D) int64 carriers of each score term
+        self.anti_keys: list = []  # the registry's E keys, in row order
+        self.sym_keys: list = []  # the registry's E2 keys, in row order
+        #: E / E2 key -> (its row on the batch's shared row axis, key code)
+        self._carrier_rows: dict = {}
+        self._terms_static: dict = {}  # the (E,) and (E2,) host tables
         self._static = None  # the host tables, staged
         self._static_stale = True
-        self._cells: dict = {}  # (track, domain) -> signed count, undrained
+        self._cells: dict = {}  # (row, domain) -> signed count, undrained
         self._rows = 0
+        self._carried = 0  # of which on carrier rows
         self._pod_tracks: dict = {}  # labels key -> ((track, key), ...)
         self._match_rows: dict = {}  # labels key -> (S,) bool
         self._constants: dict = {}  # (N, P) -> the trivial node tables
@@ -104,7 +127,7 @@ class ResidentSelectors:
         base dropped): build them again before they are served."""
         self.live = False
         self._cells.clear()
-        self._rows = 0
+        self._rows = self._carried = 0
         self._uneven.clear()
 
     # -- the O(assigned) build ------------------------------------------
@@ -144,9 +167,13 @@ class ResidentSelectors:
                 codes = topo_code[k][topo_code[k] >= 0]
                 domain_nodes[k] = np.bincount(codes, minlength=Dp)
             slots = {name: i for i, name in enumerate(names)}
+            assigned = cluster._assigned_pods()
             _, track_base = S.track_counts(
-                axes, cluster._assigned_pods(), slots, topo_code, TR, npad,
-                Dp, per_node=False,
+                axes, assigned, slots, topo_code, TR, npad, Dp,
+                per_node=False,
+            )
+            self._rebuild_carriers(
+                registry, axes, assigned, slots, topo_code, TR, Sp, Dp
             )
             # a padded track is in a selector row that holds no pod
             track_sel = np.full(TR, Sp - 1, I32)
@@ -164,13 +191,49 @@ class ResidentSelectors:
             self.track_base = jnp.asarray(track_base)
             self._static_stale = True
             self._cells.clear()
-            self._rows = 0
+            self._rows = self._carried = 0
             self._pod_tracks.clear()
             self._match_rows.clear()
             self._uneven.clear()
             self.version = registry.version
             self.live = True
             obs.metrics.inc(obs.SERVE_SELECTOR_REBASES)
+
+    def _rebuild_carriers(self, registry, axes, assigned, slots, topo_code,
+                          TR: int, Sp: int, Dp: int) -> None:
+        """The E and E2 halves of `rebuild`: the terms the assigned pods
+        carry, counted by domain through the fresh build's functions."""
+        import jax.numpy as jnp
+
+        self.anti_keys = list(registry.anti_terms)
+        self.sym_keys = list(registry.sym_terms)
+        self.anti_count = self.exist_anti_base = self.sym_base = None
+        self._carrier_rows = {}
+        self._terms_static = {}
+        if not (self.anti_keys or self.sym_keys):
+            return
+        E = bucket_size(len(self.anti_keys), minimum=1) if (
+            self.anti_keys) else 0
+        anti, sym = S.assigned_carriers(axes, assigned)
+        if self.anti_keys:
+            terms = S.anti_term_tables(axes, E=E, pad_sel=Sp - 1)
+            anti_count = S.carrier_counts(
+                anti, slots, topo_code, terms["exist_anti_topo"], E, Dp
+            )
+            self.anti_count = jnp.asarray(anti_count)
+            self.exist_anti_base = jnp.asarray(anti_count > 0)
+            self._terms_static.update(terms)
+            for e, key in enumerate(self.anti_keys):
+                self._carrier_rows[key] = (TR + e, axes.keys[key[2]])
+        if self.sym_keys:
+            E2 = bucket_size(len(self.sym_keys), minimum=1)
+            terms = S.sym_term_tables(axes, E2=E2, pad_sel=Sp - 1)
+            self.sym_base = jnp.asarray(S.carrier_counts(
+                sym, slots, topo_code, terms["sym_topo"], E2, Dp
+            ))
+            self._terms_static.update(terms)
+            for e2, key in enumerate(self.sym_keys):
+                self._carrier_rows[key] = (TR + E + e2, axes.keys[key[2]])
 
     def grow(self, npad: int) -> None:
         """The node bucket grew: the (K, N) tables follow it."""
@@ -242,6 +305,27 @@ class ResidentSelectors:
             if d >= 0:
                 cells[(t, d)] = cells.get((t, d), 0) + sign
                 self._rows += 1
+        if self._carrier_rows and S.has_affinity_terms(pod):
+            self._carrier_event(pod, slot, sign)
+
+    def _carrier_event(self, pod, slot: int, sign: int) -> None:
+        """+-1 on the row of every term the pod carries, in the node's
+        domain under the term's key: a carrier took or gave up its node."""
+        keys = S.affinity_term_keys(pod)
+        rows = None if keys is None else [
+            self._carrier_rows.get(key) for key in keys[1] + keys[2]
+        ]
+        if rows is None or None in rows:
+            # a term the axes do not hold (its scope moves with the
+            # Namespaces, or the registry moved): build again
+            self.live = False
+            return
+        cells = self._cells
+        for row, k in rows:
+            d = int(self.topo_code[k, slot])
+            if d >= 0:
+                cells[(row, d)] = cells.get((row, d), 0) + sign
+                self._carried += 1
 
     def apply(self) -> None:
         """Fold the window's cells into the resident counts."""
@@ -250,6 +334,7 @@ class ResidentSelectors:
             return
         cells, self._cells = self._cells, {}
         rows, self._rows = self._rows, 0
+        carried, self._carried = self._carried, 0
         if not self.live:
             return
         packed = D.SelectorDeltas.pack(
@@ -260,11 +345,15 @@ class ResidentSelectors:
             warnings.filterwarnings(
                 "ignore", message=".*donated buffers were not usable.*"
             )
-            self.track_base = self._apply(
-                self.track_base, *self._engine._stage_args(packed.as_args())
+            tables, self.exist_anti_base = self._apply(
+                (self.track_base, self.anti_count, self.sym_base),
+                *self._engine._stage_args(packed.as_args()),
             )
+        self.track_base, self.anti_count, self.sym_base = tables
         self.last_packed = packed.as_dict()
         obs.metrics.inc(obs.SERVE_SELECTOR_ROWS, rows)
+        if carried:
+            obs.metrics.inc(obs.SERVE_AFFINITY_CARRIER_ROWS, carried)
 
     # -- what `compatible` asks -------------------------------------------
     def needs_node_counts(self, cluster, pending) -> bool:
@@ -304,44 +393,100 @@ class ResidentSelectors:
 
     def scheduling_state(self, pending, P: int, N: int):
         """This cycle's `SchedulingState` over the resident tables, or None
-        where no pod of the batch carries a spread constraint (the fresh
-        build's own rule). Call after `ensure`."""
-        if not self.live or not any(p.topology_spread for p in pending):
+        where no pod of the batch carries a spread constraint and no pod of
+        the store a pod (anti-)affinity term (an assigned carrier blocks a
+        plain pod its term matches: the fresh build's own rule). Call
+        after `ensure`."""
+        spread_any = any(p.topology_spread for p in pending)
+        if not self.live or not (spread_any or self._carrier_rows):
             return None
         axes = self.axes
-        widest = max(len(p.topology_spread) for p in pending)
-        spread = S.spread_rows(
-            axes, pending, P, CT=bucket_size(widest, minimum=1)
-        )
-        if len(axes.tracks) != len(self.track_keys):
+        stage = self._engine._stage_pods
+        batch: dict = {}
+        if spread_any:
+            widest = max(len(p.topology_spread) for p in pending)
+            batch = S.spread_rows(
+                axes, pending, P, CT=bucket_size(widest, minimum=1)
+            )
+            batch["spread_elig_idx"] = np.zeros(
+                batch["spread_track"].shape, I32
+            )
+        pend_carriers = pending_sym = ()
+        if self._carrier_rows:
+            with obs.tracer.span(
+                "ServeRefresh/affinity", tid="serve", pending=len(pending)
+            ):
+                rows, pend_carriers = S.affinity_rows(
+                    axes, pending, P, **self._term_widths(pending)
+                )
+                batch.update(rows)
+                if self.sym_keys:
+                    pending_sym = [
+                        (i, e2, c) for i, pod in enumerate(pending)
+                        for e2, c in S.pod_sym_rows(axes, pod).items()
+                    ]
+        if (len(axes.tracks), len(axes.anti_terms), len(axes.sym_terms)) != (
+            len(self.track_keys), len(self.anti_keys), len(self.sym_keys)
+        ):
             # `ensure` follows the registry, which holds every pod's tracks
-            # from `add_pod` on; serving without the row would drop the
-            # constraint in silence
+            # and terms from `add_pod` on; serving without the row would
+            # drop the constraint in silence
             raise RuntimeError(
-                "a pending pod names a spread track the resident selector "
-                "tables do not hold"
+                "a pending pod names a track or a term the resident "
+                "selector tables do not hold"
             )
         pend_match = S.pend_match_rows(
             axes.sel_objs, pending, P, S=self.sel_rows,
             memo=self._match_rows,
         )
+        batch["pend_match"] = pend_match
         if self._static_stale:
-            self._static = self._engine._stage_pods(dict(
+            self._static = stage(dict(
                 topo_code=self.topo_code.copy(),
                 topo_has=self.topo_has.copy(),
                 domain_exists=self.domain_nodes > 0,
                 track_sel=self.track_sel,
                 track_topo=self.track_topo,
+                **self._terms_static,
             ))
             self._static_stale = False
-        batch = self._engine._stage_pods(dict(
-            pend_match=pend_match,
-            spread_elig_idx=np.zeros(spread["spread_track"].shape, I32),
-            **spread,
-        ))
+        resident = dict(track_base=self.track_base)
+        with obs.tracer.span("ServeRefresh/affinity", tid="serve"):
+            if self.anti_keys:
+                batch.update(S.anti_batch_rows(
+                    pend_carriers, pend_match,
+                    self._terms_static["exist_anti_sel"], P,
+                ))
+                resident["exist_anti_base"] = self.exist_anti_base
+            if self.sym_keys:
+                batch["sym_carrier"] = S.sym_batch_rows(
+                    pending_sym, self.sym_base.shape[0], P
+                )
+                resident["sym_base"] = self.sym_base
         return S.SchedulingState(
-            **self._trivial(N, P), **self._static, **batch,
-            track_base=self.track_base,
+            **self._trivial(N, P), **self._static, **stage(batch), **resident
+        )
+
+    def _term_widths(self, pending) -> dict:
+        """The AT / BT / WT axes of the batch's own terms, on buckets. An
+        axis of a kind of term that no pod of the STORE carries has no row
+        at all: the scan then gathers nothing for it, where a row that is
+        masked off would cost it an (N,) gather out of a (D,) table at
+        every step; and the shape stays the store's, not the batch's."""
+        def pad(held: bool, count) -> int:
+            if not held:
+                return 0
+            widest = max((count(p) for p in pending), default=1)
+            return bucket_size(widest, minimum=1)
+
+        hard = any(key[4] for key in self.sym_keys)
+        weighted = any(not key[4] for key in self.sym_keys)
+        return dict(
+            AT=pad(hard, lambda p: len(p.pod_affinity_required)),
+            BT=pad(bool(self.anti_keys),
+                   lambda p: len(p.pod_anti_affinity_required)),
+            WT=pad(weighted, lambda p: len(p.pod_affinity_preferred)
+                   + len(p.pod_anti_affinity_preferred)),
         )
 
     # -- anti-entropy -----------------------------------------------------
@@ -378,6 +523,40 @@ class ResidentSelectors:
                     counts[cell] = counts.get(cell, 0) + 1
         return counts
 
+    def expected_carriers(self, cluster) -> dict:
+        """{(E or E2 key, domain value): carriers} from the pods that carry
+        a term (`Cluster._affinity_spec_pods`) and their nodes' labels."""
+        counts: dict = {}
+        for uid in cluster._affinity_spec_pods:
+            pod = cluster.pods[uid]
+            node = cluster.nodes.get(
+                pod.node_name or cluster.reserved.get(uid)
+            )
+            if node is None:
+                continue
+            keys = S.affinity_term_keys(pod)
+            if keys is None:
+                continue  # nothing of it is interned (`unscoped`)
+            for key in keys[1] + keys[2]:
+                value = node.labels.get(key[2])
+                if value is not None:
+                    counts[(key, value)] = counts.get((key, value), 0) + 1
+        return counts
+
+    def _decoded(self, table, rows) -> Optional[dict]:
+        """{(key, domain value): count} of a device table through its rows
+        [(row, key, key code), ...]; None where a cell that no row and
+        domain owns is not zero."""
+        host = np.asarray(table)
+        seen = np.zeros(host.shape, bool)
+        out: dict = {}
+        for row, key, k in rows:
+            for value, code in self.domain_values[k].items():
+                seen[row, code] = True
+                if host[row, code]:
+                    out[(key, value)] = int(host[row, code])
+        return None if (host[~seen] != 0).any() else out
+
     def divergence(self, cluster, names) -> Optional[str]:
         """The resident tables against the store, or None. Tables that are
         about to be built again (not live, or the registry moved) hold
@@ -385,18 +564,30 @@ class ResidentSelectors:
         if not self.live or self.version != cluster.selectors.version:
             return None
         axes = self.axes
-        host = np.asarray(self.track_base)
-        mine: dict = {}
-        seen = np.zeros(host.shape, bool)
-        for (s, k), t in axes.tracks.items():
-            for value, code in self.domain_values[k].items():
-                seen[t, code] = True
-                if host[t, code]:
-                    mine[(self.track_keys[t], value)] = int(host[t, code])
-        if (host[~seen] != 0).any():
-            return "selector-counts"  # a cell no track and domain owns
-        if mine != self.expected_counts(cluster):
+        if self._decoded(self.track_base, [
+            (t, self.track_keys[t], k) for (_s, k), t in axes.tracks.items()
+        ]) != self.expected_counts(cluster):
             return "selector-counts"
+        if self._carrier_rows:
+            carried: dict = {}
+            for table, keys in ((self.anti_count, self.anti_keys),
+                                (self.sym_base, self.sym_keys)):
+                if table is None:
+                    continue
+                held = self._decoded(table, [
+                    (row, key, self._carrier_rows[key][1])
+                    for row, key in enumerate(keys)
+                ])
+                if held is None:
+                    return "affinity-carriers"
+                carried.update(held)
+            if carried != self.expected_carriers(cluster) or (
+                self.anti_count is not None and not np.array_equal(
+                    np.asarray(self.exist_anti_base),
+                    np.asarray(self.anti_count) > 0,
+                )
+            ):
+                return "affinity-carriers"
         code = np.full(self.topo_code.shape, -1, I32)
         for slot, name in enumerate(names):
             labels = cluster.nodes[name].labels

@@ -27,7 +27,10 @@ from scheduler_plugins_tpu.api.objects import (
     SeccompProfile,
 )
 from scheduler_plugins_tpu.obs import ledger as podledger
-from scheduler_plugins_tpu.state.scheduling import SelectorRegistry
+from scheduler_plugins_tpu.state.scheduling import (
+    SelectorRegistry,
+    has_affinity_terms,
+)
 from scheduler_plugins_tpu.state.snapshot import build_snapshot
 
 
@@ -81,14 +84,14 @@ class Cluster:
     #: holds it. The daemon's `bound_total` is this number
     binds_total: int = 0
     #: uids of LIVE pods, pending or bound, carrying pod (anti-)affinity
-    #: terms: the scheduling tables then need the assigned pod objects,
-    #: which the native snapshot fast path skips and the resident engine
-    #: does not keep (`ServeEngine.compatible`, reason "pod-affinity")
+    #: terms: a fresh build of the scheduling tables then needs the
+    #: assigned pod objects, which the native snapshot fast path skips
     _affinity_spec_pods: set = field(default_factory=set)
-    #: the spread tracks the store's pods declare, interned where a pod is
-    #: added and released where it is removed (pod specs are immutable):
-    #: the resident engine keeps its selector counts by them
-    #: (docs/SERVING.md "Resident selector counts")
+    #: the spread tracks and the pod (anti-)affinity terms the store's pods
+    #: declare, interned where a pod is added and released where it is
+    #: removed (pod specs are immutable): the resident engine keeps its
+    #: selector and carrier counts by them (docs/SERVING.md "Resident
+    #: selector counts", "Resident affinity terms")
     selectors: SelectorRegistry = field(default_factory=SelectorRegistry)
     # EnqueueExtensions bookkeeping (upstream scheduling queue): a monotonic
     # event counter, the last counter value per event kind, and per-pod
@@ -333,14 +336,7 @@ class Cluster:
         if self.native is not None:
             self._native_rebuild()
 
-    @staticmethod
-    def _has_affinity_terms(pod: Pod) -> bool:
-        return bool(
-            pod.pod_affinity_required
-            or pod.pod_anti_affinity_required
-            or pod.pod_affinity_preferred
-            or pod.pod_anti_affinity_preferred
-        )
+    _has_affinity_terms = staticmethod(has_affinity_terms)
 
     def _held_node(self, pod: Optional[Pod]) -> Optional[str]:
         """The node whose usage columns `pod` currently contributes to:
@@ -395,14 +391,15 @@ class Cluster:
                 self.delta_sink.pod_assigned(pod, new_hold)
             self.delta_sink.note_nomination(pod)
         self._binding_touched(pod.uid)
-        if self._has_affinity_terms(pod):
+        affine = self._has_affinity_terms(pod)
+        if affine:
             # affinity tables need ASSIGNED pod objects at snapshot build,
             # which the native fast path skips (pod specs are immutable,
             # so count on add/remove)
             self._affinity_spec_pods.add(pod.uid)
         elif old is not None:
             self._affinity_spec_pods.discard(pod.uid)
-        if pod.topology_spread or old is not None:
+        if affine or pod.topology_spread or old is not None:
             self.selectors.add(pod)
         if self.nrt_cache is not None and hasattr(self.nrt_cache, "track_pod"):
             # foreign-pod detection (cache/foreign_pods.go:42-99)

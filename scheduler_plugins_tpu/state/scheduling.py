@@ -223,23 +223,22 @@ def _node_filter_matches(pod: Pod, node: Node) -> bool:
     return True
 
 
+def has_affinity_terms(pod: Pod) -> bool:
+    """The pod carries a pod (anti-)affinity term, required or preferred."""
+    return bool(
+        pod.pod_affinity_required
+        or pod.pod_anti_affinity_required
+        or pod.pod_affinity_preferred
+        or pod.pod_anti_affinity_preferred
+    )
+
+
 def _has_selector_specs(pending, assigned) -> bool:
     # assigned pods' terms matter too: required anti (symmetry blocks) and
     # preferred/required affinity (symmetric score toward incoming pods)
     return any(
-        p.topology_spread
-        or p.pod_affinity_required
-        or p.pod_anti_affinity_required
-        or p.pod_affinity_preferred
-        or p.pod_anti_affinity_preferred
-        for p in pending
-    ) or any(
-        p.pod_anti_affinity_required
-        or p.pod_affinity_required
-        or p.pod_affinity_preferred
-        or p.pod_anti_affinity_preferred
-        for p in assigned
-    )
+        p.topology_spread or has_affinity_terms(p) for p in pending
+    ) or any(has_affinity_terms(p) for p in assigned)
 
 
 def relevant(nodes, pending, assigned=()) -> bool:
@@ -406,6 +405,10 @@ class SelectorAxes:
         self.keys: dict = {}  # topology key -> index
         self.key_names: list[str] = []
         self.tracks: dict = {}  # (sel idx, key idx) -> track index
+        #: the E axis: required anti-affinity terms, (sel idx, key idx) -> e
+        self.anti_terms: dict = {}
+        #: the E2 axis: (sel idx, key idx, signed weight, hard) -> e2
+        self.sym_terms: dict = {}
 
     def sel_id(self, ns_scope: tuple, selector) -> int:
         k = (ns_scope, None if selector is None else selector._key())
@@ -427,6 +430,18 @@ class SelectorAxes:
         if t is None:
             t = self.tracks[(s, k)] = len(self.tracks)
         return t
+
+    def anti_id(self, s: int, k: int) -> int:
+        e = self.anti_terms.get((s, k))
+        if e is None:
+            e = self.anti_terms[(s, k)] = len(self.anti_terms)
+        return e
+
+    def sym_id(self, s: int, k: int, weight: int, hard: bool) -> int:
+        e2 = self.sym_terms.get((s, k, weight, hard))
+        if e2 is None:
+            e2 = self.sym_terms[(s, k, weight, hard)] = len(self.sym_terms)
+        return e2
 
 
 def spread_track_keys(pod: Pod) -> list:
@@ -570,51 +585,312 @@ def pend_match_rows(sel_objs, pending, P: int, S=None, memo=None
     return pend_match
 
 
+def affinity_term_keys(pod: Pod):
+    """([track key, ...], [E key, ...], [E2 key, ...], {key: selector}) of
+    the pod's pod (anti-)affinity terms, or None where a term's scope
+    depends on the cluster's Namespace objects (`static_term_scope`). A
+    track and an E key are (ns scope, selector key | None, topology key);
+    an E2 key adds (signed weight, hard). Every term names a track."""
+    tracks, antis, syms, selectors = [], [], [], {}
+
+    def key(term):
+        scope = static_term_scope(pod, term)
+        if scope is None:
+            raise LookupError(term)
+        sel = term.label_selector
+        k = (scope, None if sel is None else sel._key(), term.topology_key)
+        selectors[k] = sel
+        tracks.append(k)
+        return k
+
+    try:
+        for term in pod.pod_affinity_required:
+            syms.append(key(term) + (1, True))
+        for term in pod.pod_anti_affinity_required:
+            antis.append(key(term))
+        for term, weight in _weighted_terms(pod):
+            syms.append(key(term) + (weight, False))
+    except LookupError:
+        return None
+    return tracks, antis, syms, selectors
+
+
 class SelectorRegistry:
-    """The spread tracks the store's pods declare, pending or bound:
+    """The tracks and terms the store's pods declare, pending or bound:
     interned where a pod is added and released where it is removed (pod
     specs are immutable), so nothing has to rediscover them from every
-    assigned pod at every snapshot. `version` moves when the SET of live
-    tracks does: a resident engine rebuilds its selector tables then, and
-    only then. O(constraints) a pod."""
+    assigned pod at every snapshot. `tracks` are the (selector group,
+    topology key) pairs of spread constraints and of pod (anti-)affinity
+    terms; `anti_terms` the required anti-affinity terms pods carry (the E
+    axis), `sym_terms` the score's symmetric terms (E2). `version` moves
+    when the SET of any of them does: a resident engine rebuilds its
+    selector tables then, and only then. O(constraints + terms) a pod."""
 
     def __init__(self):
         #: (ns scope, selector key | None, topology key) -> [pods, selector]
         self.tracks: dict = {}
-        self._by_pod: dict = {}  # uid -> its track keys
+        self.anti_terms: dict = {}  # the same key -> [pods, selector]
+        #: (scope, selector key, topology key, weight, hard) -> [pods, sel]
+        self.sym_terms: dict = {}
+        #: uids of pods with a term under a non-empty namespaceSelector:
+        #: its scope moves with the Namespaces' labels, nothing is interned
+        self.unscoped: set = set()
+        self._by_pod: dict = {}  # uid -> ((table, key), ...) it holds
         self.version = 0
 
     def add(self, pod: Pod) -> None:
         """`pod` replaces whatever was held under its uid."""
-        keys = [
-            ((scope, None if sel is None else sel._key(), topo), scope, sel)
+        held = [
+            (self.tracks, (scope, None if sel is None else sel._key(), topo),
+             sel)
             for scope, sel, topo in spread_track_keys(pod)
         ] if pod.topology_spread else []
-        for key, _scope, sel in keys:
-            held = self.tracks.get(key)
-            if held is None:
-                self.tracks[key] = [1, sel]
+        unscoped = False
+        if has_affinity_terms(pod):
+            keys = affinity_term_keys(pod)
+            if keys is None:
+                unscoped = True
+            else:
+                tracks, antis, syms, selectors = keys
+                held += [(self.tracks, k, selectors[k]) for k in tracks]
+                held += [(self.anti_terms, k, selectors[k]) for k in antis]
+                held += [(self.sym_terms, k, selectors[k[:3]]) for k in syms]
+        for table, key, sel in held:
+            entry = table.get(key)
+            if entry is None:
+                table[key] = [1, sel]
                 self.version += 1
             else:
-                held[0] += 1
+                entry[0] += 1
         self.remove(pod.uid)
-        if keys:
-            self._by_pod[pod.uid] = [key for key, _, _ in keys]
+        if held:
+            self._by_pod[pod.uid] = [(table, key) for table, key, _ in held]
+        if unscoped:
+            self.unscoped.add(pod.uid)
 
     def remove(self, uid: str) -> None:
-        for key in self._by_pod.pop(uid, ()):
-            held = self.tracks[key]
-            held[0] -= 1
-            if not held[0]:
-                del self.tracks[key]
+        self.unscoped.discard(uid)
+        for table, key in self._by_pod.pop(uid, ()):
+            entry = table[key]
+            entry[0] -= 1
+            if not entry[0]:
+                del table[key]
                 self.version += 1
 
     def axes(self) -> SelectorAxes:
-        """The live tracks as axes, in the registry's order."""
+        """The live tracks and terms as axes, in the registry's order."""
         axes = SelectorAxes()
         for (scope, _selkey, topo), (_refs, sel) in self.tracks.items():
             axes.track_id(axes.sel_id(scope, sel), axes.key_id(topo))
+        for (scope, _selkey, topo), (_refs, sel) in self.anti_terms.items():
+            axes.anti_id(axes.sel_id(scope, sel), axes.key_id(topo))
+        for (scope, _selkey, topo, weight, hard), (_refs, sel) in (
+            self.sym_terms.items()
+        ):
+            axes.sym_id(
+                axes.sel_id(scope, sel), axes.key_id(topo), weight, hard
+            )
         return axes
+
+
+def static_term_scope(pod: Pod, term):
+    """`_term_scope` where it does not depend on the cluster's Namespace
+    objects (an explicit list, none, or the EMPTY selector's every
+    namespace), else None: the scope of a term with a non-empty
+    namespaceSelector moves when a Namespace's labels do, so nothing keeps
+    it across cycles."""
+    sel = getattr(term, "namespace_selector", None)
+    if sel is not None and (sel.match_labels or sel.match_expressions):
+        return None
+    return _term_scope(pod, term, ())
+
+
+def _weighted_terms(pod: Pod):
+    """(term, signed weight) of the pod's preferred terms, affinity first:
+    the order of the (P, WT) rows and of the E2 axis."""
+    for wt in pod.pod_affinity_preferred:
+        yield wt.term, wt.weight
+    for wt in pod.pod_anti_affinity_preferred:
+        yield wt.term, -wt.weight
+
+
+def _term_sel_key(axes: SelectorAxes, pod: Pod, term, namespaces) -> tuple:
+    """(selector group, key code) of a PodAffinityTerm scoped to the pod."""
+    s = axes.sel_id(_term_scope(pod, term, namespaces), term.label_selector)
+    return s, axes.key_id(term.topology_key)
+
+
+def pod_anti_rows(axes: SelectorAxes, pod: Pod, namespaces=()) -> list:
+    """The E rows of the required anti-affinity terms the pod carries, one
+    entry a term."""
+    return [
+        axes.anti_id(*_term_sel_key(axes, pod, term, namespaces))
+        for term in pod.pod_anti_affinity_required
+    ]
+
+
+def pod_sym_rows(axes: SelectorAxes, pod: Pod, namespaces=()) -> dict:
+    """{E2 row: how many of the pod's terms it is}: the pod's preferred
+    terms at their signed weight and its required affinity terms as hard
+    rows (upstream interpodaffinity PreScore's symmetric half)."""
+    counts: dict = {}
+    for term, weight in _weighted_terms(pod):
+        e2 = axes.sym_id(
+            *_term_sel_key(axes, pod, term, namespaces), weight, False
+        )
+        counts[e2] = counts.get(e2, 0) + 1
+    for term in pod.pod_affinity_required:
+        e2 = axes.sym_id(
+            *_term_sel_key(axes, pod, term, namespaces), 1, True
+        )
+        counts[e2] = counts.get(e2, 0) + 1
+    return counts
+
+
+def affinity_rows(axes: SelectorAxes, pending, P: int, namespaces=(),
+                  AT=None, BT=None, WT=None) -> tuple:
+    """(the (P, AT/BT/WT) `aff_*` / `anti_*` / `waff_*` rows of the batch's
+    own terms, [(E row, pod index), ...] for the required anti terms its
+    pods carry). O(batch): one function for the fresh build and the
+    resident engine, which pads the term axes (`AT`, `BT`, `WT`; by
+    default the batch's widest pod, and one where it has none: a pad of 0
+    leaves an axis no pod uses without a row)."""
+    def width(count, pad):
+        widest = max((count(p) for p in pending), default=0)
+        return (widest or 1) if pad is None else max(pad, widest)
+
+    AT = width(lambda p: len(p.pod_affinity_required), AT)
+    BT = width(lambda p: len(p.pod_anti_affinity_required), BT)
+    WT = width(
+        lambda p: len(p.pod_affinity_preferred)
+        + len(p.pod_anti_affinity_preferred), WT,
+    )
+    aff_track = np.zeros((P, AT), I32)
+    aff_topo = np.zeros((P, AT), I32)
+    aff_self = np.zeros((P, AT), bool)
+    aff_mask = np.zeros((P, AT), bool)
+    anti_track = np.zeros((P, BT), I32)
+    anti_topo = np.zeros((P, BT), I32)
+    anti_mask = np.zeros((P, BT), bool)
+    waff_track = np.zeros((P, WT), I32)
+    waff_topo = np.zeros((P, WT), I32)
+    waff_weight = np.zeros((P, WT), I64)
+    waff_mask = np.zeros((P, WT), bool)
+    pend_carriers: list = []
+    for i, pod in enumerate(pending):
+        for c, term in enumerate(pod.pod_affinity_required):
+            s, k = _term_sel_key(axes, pod, term, namespaces)
+            aff_track[i, c] = axes.track_id(s, k)
+            aff_topo[i, c] = k
+            aff_self[i, c] = _sel_matches(
+                term.label_selector, axes.sel_objs[s][0], pod
+            )
+            aff_mask[i, c] = True
+        for c, term in enumerate(pod.pod_anti_affinity_required):
+            s, k = _term_sel_key(axes, pod, term, namespaces)
+            anti_track[i, c] = axes.track_id(s, k)
+            anti_topo[i, c] = k
+            anti_mask[i, c] = True
+            pend_carriers.append((axes.anti_id(s, k), i))
+        for w, (term, weight) in enumerate(_weighted_terms(pod)):
+            s, k = _term_sel_key(axes, pod, term, namespaces)
+            waff_track[i, w] = axes.track_id(s, k)
+            waff_topo[i, w] = k
+            waff_weight[i, w] = weight
+            waff_mask[i, w] = True
+    return dict(
+        aff_track=aff_track,
+        aff_topo=aff_topo,
+        aff_self=aff_self,
+        aff_mask=aff_mask,
+        anti_track=anti_track,
+        anti_topo=anti_topo,
+        anti_mask=anti_mask,
+        waff_track=waff_track,
+        waff_topo=waff_topo,
+        waff_weight=waff_weight,
+        waff_mask=waff_mask,
+    ), pend_carriers
+
+
+def assigned_carriers(axes: SelectorAxes, assigned, namespaces=()) -> tuple:
+    """([(node name, E row, 1), ...], [(node name, E2 row, count), ...]):
+    the terms the assigned pods carry, every anti term interned before any
+    score term. O(assigned): with `carrier_counts`, the part of the
+    inter-pod tables a resident engine keeps instead of rebuilding."""
+    anti = [
+        (pod.node_name, e, 1)
+        for pod in assigned
+        for e in pod_anti_rows(axes, pod, namespaces)
+    ]
+    sym: list = []
+    for pod in assigned:
+        rows = pod_sym_rows(axes, pod, namespaces)
+        if pod.node_name is not None:
+            sym.extend((pod.node_name, e2, c) for e2, c in rows.items())
+    return anti, sym
+
+
+def carrier_counts(carriers, node_pos, topo_code, row_topo, rows: int,
+                   D: int) -> np.ndarray:
+    """(rows, D) int64: the carriers of each term by the domain of their
+    node under the term's key (`row_topo`); a node unknown or without the
+    key carries nothing."""
+    counts = np.zeros((rows, D), I64)
+    for node_name, row, count in carriers:
+        n = node_pos.get(node_name)
+        if n is None:
+            continue
+        code = topo_code[row_topo[row], n]
+        if code >= 0:
+            counts[row, code] += count
+    return counts
+
+
+def anti_term_tables(axes: SelectorAxes, E=None, pad_sel: int = 0) -> dict:
+    """`exist_anti_sel` / `exist_anti_topo` (E,): a padded row is in
+    selector group `pad_sel`, one no pod is in."""
+    E = len(axes.anti_terms) if E is None else E
+    exist_anti_sel = np.full(E, pad_sel, I32)
+    exist_anti_topo = np.zeros(E, I32)
+    for (s, k), e in axes.anti_terms.items():
+        exist_anti_sel[e] = s
+        exist_anti_topo[e] = k
+    return dict(exist_anti_sel=exist_anti_sel,
+                exist_anti_topo=exist_anti_topo)
+
+
+def sym_term_tables(axes: SelectorAxes, E2=None, pad_sel: int = 0) -> dict:
+    """`sym_sel` / `sym_topo` / `sym_weight` / `sym_hard` (E2,)."""
+    E2 = len(axes.sym_terms) if E2 is None else E2
+    sym_sel = np.full(E2, pad_sel, I32)
+    sym_topo = np.zeros(E2, I32)
+    sym_weight = np.zeros(E2, I64)
+    sym_hard = np.zeros(E2, bool)
+    for (s, k, weight, hard), e2 in axes.sym_terms.items():
+        sym_sel[e2], sym_topo[e2] = s, k
+        sym_weight[e2], sym_hard[e2] = weight, hard
+    return dict(sym_sel=sym_sel, sym_topo=sym_topo, sym_weight=sym_weight,
+                sym_hard=sym_hard)
+
+
+def anti_batch_rows(pend_carriers, pend_match, exist_anti_sel, P: int
+                    ) -> dict:
+    """(E, P) which pods of the batch carry each required anti term, and
+    which its selector matches."""
+    exist_anti_carrier = np.zeros((len(exist_anti_sel), P), bool)
+    for e, i in pend_carriers:
+        exist_anti_carrier[e, i] = True
+    return dict(exist_anti_carrier=exist_anti_carrier,
+                exist_anti_match=pend_match[exist_anti_sel])
+
+
+def sym_batch_rows(pending_sym, E2: int, P: int) -> np.ndarray:
+    """`sym_carrier` (E2, P): how many of pod i's terms are row e2."""
+    sym_carrier = np.zeros((E2, P), I64)
+    for i, e2, count in pending_sym:
+        sym_carrier[e2, i] = count
+    return sym_carrier
 
 
 def _build_selector_tables(
@@ -629,149 +905,31 @@ def _build_selector_tables(
         return {}
 
     axes = SelectorAxes()
-    sel_id, key_id, track_id = axes.sel_id, axes.key_id, axes.track_id
     sel_objs, keys, key_names, tracks = (
         axes.sel_objs, axes.keys, axes.key_names, axes.tracks
     )
-
-    def term_ids(pod: Pod, term) -> tuple[int, int, int]:
-        """(sel, key, track) for a PodAffinityTerm scoped to the pod."""
-        scope = _term_scope(pod, term, namespaces)
-        s = sel_id(scope, term.label_selector)
-        k = key_id(term.topology_key)
-        return s, k, track_id(s, k)
 
     spread = spread_rows(axes, pending, P)
     spread_policy_affinity = spread["spread_policy_affinity"]
     spread_policy_taints = spread["spread_policy_taints"]
     CT = spread["spread_track"].shape[1]
 
-    # inter-pod affinity terms (incoming pod's own)
-    AT = max((len(p.pod_affinity_required) for p in pending), default=1) or 1
-    BT = (
-        max((len(p.pod_anti_affinity_required) for p in pending), default=1)
-        or 1
-    )
-    WT = (
-        max(
-            (
-                len(p.pod_affinity_preferred)
-                + len(p.pod_anti_affinity_preferred)
-                for p in pending
-            ),
-            default=1,
+    # the inter-pod half, first part: the batch's own terms, then the
+    # assigned pods' (E: required anti terms carried by assigned OR pending
+    # pods, whose carriers block matching pods; E2: the score's symmetric
+    # terms), interned in that order
+    with obs.tracer.span("Snapshot/affinity", tid="snapshot",
+                         assigned=len(assigned)):
+        affinity, pend_carriers = affinity_rows(
+            axes, pending, P, namespaces
         )
-        or 1
-    )
-    aff_track = np.zeros((P, AT), I32)
-    aff_topo = np.zeros((P, AT), I32)
-    aff_self = np.zeros((P, AT), bool)
-    aff_mask = np.zeros((P, AT), bool)
-    anti_track = np.zeros((P, BT), I32)
-    anti_topo = np.zeros((P, BT), I32)
-    anti_mask = np.zeros((P, BT), bool)
-    waff_track = np.zeros((P, WT), I32)
-    waff_topo = np.zeros((P, WT), I32)
-    waff_weight = np.zeros((P, WT), I64)
-    waff_mask = np.zeros((P, WT), bool)
-    # E axis: unique required anti-affinity (selector, key) pairs carried by
-    # assigned OR pending pods (symmetry: carriers block matching pods)
-    anti_terms: dict = {}  # (sel, key) -> e index
-
-    def anti_term_id(s: int, k: int) -> int:
-        if (s, k) not in anti_terms:
-            anti_terms[(s, k)] = len(anti_terms)
-        return anti_terms[(s, k)]
-
-    pend_carriers: list[list[int]] = []  # per e, pending carrier indices
-    for i, pod in enumerate(pending):
-        for c, term in enumerate(pod.pod_affinity_required):
-            s, k, t = term_ids(pod, term)
-            aff_track[i, c] = t
-            aff_topo[i, c] = k
-            aff_self[i, c] = _sel_matches(
-                term.label_selector, _term_scope(pod, term, namespaces), pod
-            )
-            aff_mask[i, c] = True
-        for c, term in enumerate(pod.pod_anti_affinity_required):
-            s, k, t = term_ids(pod, term)
-            anti_track[i, c] = t
-            anti_topo[i, c] = k
-            anti_mask[i, c] = True
-            e = anti_term_id(s, k)
-            while len(pend_carriers) <= e:
-                pend_carriers.append([])
-            pend_carriers[e].append(i)
-        w = 0
-        for wt in pod.pod_affinity_preferred:
-            s, k, t = term_ids(pod, wt.term)
-            waff_track[i, w] = t
-            waff_topo[i, w] = k
-            waff_weight[i, w] = wt.weight
-            waff_mask[i, w] = True
-            w += 1
-        for wt in pod.pod_anti_affinity_preferred:
-            s, k, t = term_ids(pod, wt.term)
-            waff_track[i, w] = t
-            waff_topo[i, w] = k
-            waff_weight[i, w] = -wt.weight
-            waff_mask[i, w] = True
-            w += 1
-
-    # assigned pods' anti terms join E; remember who carries each term
-    assigned_carrier_terms: list[tuple[Pod, int]] = []
-    for pod in assigned:
-        for term in pod.pod_anti_affinity_required:
-            scope = _term_scope(pod, term, namespaces)
-            s = sel_id(scope, term.label_selector)
-            k = key_id(term.topology_key)
-            e = anti_term_id(s, k)
-            while len(pend_carriers) <= e:
-                pend_carriers.append([])
-            assigned_carrier_terms.append((pod, e))
-
-    # --- symmetric score terms (E2 axis) --------------------------------
-    sym_terms: dict = {}  # (sel, key, weight, hard) -> e2
-    sym_rows: list = []
-
-    def sym_id(sel: int, k: int, weight: int, hard: bool) -> int:
-        key = (sel, k, weight, hard)
-        if key not in sym_terms:
-            sym_terms[key] = len(sym_rows)
-            sym_rows.append(key)
-        return sym_terms[key]
-
-    def pod_sym_terms(pod: Pod):
-        """(e2, count) pairs for one pod's score-symmetric terms."""
-        out_counts: dict = {}
-        for wt in pod.pod_affinity_preferred:
-            s2 = sel_id(_term_scope(pod, wt.term, namespaces),
-                        wt.term.label_selector)
-            e2 = sym_id(s2, key_id(wt.term.topology_key), wt.weight, False)
-            out_counts[e2] = out_counts.get(e2, 0) + 1
-        for wt in pod.pod_anti_affinity_preferred:
-            s2 = sel_id(_term_scope(pod, wt.term, namespaces),
-                        wt.term.label_selector)
-            e2 = sym_id(s2, key_id(wt.term.topology_key), -wt.weight, False)
-            out_counts[e2] = out_counts.get(e2, 0) + 1
-        for term in pod.pod_affinity_required:
-            s2 = sel_id(_term_scope(pod, term, namespaces),
-                        term.label_selector)
-            e2 = sym_id(s2, key_id(term.topology_key), 1, True)
-            out_counts[e2] = out_counts.get(e2, 0) + 1
-        return out_counts
-
-    assigned_sym: list[tuple[str, int, int]] = []  # (node name, e2, count)
-    for pod in assigned:
-        terms = pod_sym_terms(pod)
-        if terms and pod.node_name is not None:
-            assigned_sym.extend(
-                (pod.node_name, e2, c) for e2, c in terms.items()
-            )
-    pending_sym: list[tuple[int, int, int]] = []  # (pod idx, e2, count)
-    for i, pod in enumerate(pending):
-        for e2, c in pod_sym_terms(pod).items():
-            pending_sym.append((i, e2, c))
+        assigned_anti, assigned_sym = assigned_carriers(
+            axes, assigned, namespaces
+        )
+        pending_sym = [
+            (i, e2, c) for i, pod in enumerate(pending)
+            for e2, c in pod_sym_rows(axes, pod, namespaces).items()
+        ]
 
     K = max(len(key_names), 1)
     # topology domain codes per key (value interned per key)
@@ -849,76 +1007,36 @@ def _build_selector_tables(
         spread_elig=spread_elig,
         spread_elig_idx=spread_elig_idx,
         spread_needs_node_counts=needs_node_counts,
-        aff_track=aff_track,
-        aff_topo=aff_topo,
-        aff_self=aff_self,
-        aff_mask=aff_mask,
-        anti_track=anti_track,
-        anti_topo=anti_topo,
-        anti_mask=anti_mask,
-        waff_track=waff_track,
-        waff_topo=waff_topo,
-        waff_weight=waff_weight,
-        waff_mask=waff_mask,
+        **affinity,
     )
 
-    if anti_terms:
-        E = len(anti_terms)
-        exist_anti_sel = np.zeros(E, I32)
-        exist_anti_topo = np.zeros(E, I32)
-        for (s, k), e in anti_terms.items():
-            exist_anti_sel[e] = s
-            exist_anti_topo[e] = k
-        exist_anti_base = np.zeros((E, D), bool)
-        for pod, e in assigned_carrier_terms:
-            n = node_pos.get(pod.node_name)
-            if n is None:
-                continue
-            code = topo_code[exist_anti_topo[e], n]
-            if code >= 0:
-                exist_anti_base[e, code] = True
-        exist_anti_carrier = np.zeros((E, P), bool)
-        for e, carriers in enumerate(pend_carriers):
-            for i in carriers:
-                exist_anti_carrier[e, i] = True
-        exist_anti_match = np.zeros((E, P), bool)
-        for e in range(E):
-            exist_anti_match[e] = pend_match[exist_anti_sel[e]]
-        out.update(
-            exist_anti_sel=exist_anti_sel,
-            exist_anti_topo=exist_anti_topo,
-            exist_anti_base=exist_anti_base,
-            exist_anti_carrier=exist_anti_carrier,
-            exist_anti_match=exist_anti_match,
-        )
-    if sym_rows:
-        E2 = len(sym_rows)
-        sym_sel = np.zeros(E2, I32)
-        sym_topo = np.zeros(E2, I32)
-        sym_weight = np.zeros(E2, I64)
-        sym_hard = np.zeros(E2, bool)
-        for e2, (s2, k, w, hard) in enumerate(sym_rows):
-            sym_sel[e2], sym_topo[e2] = s2, k
-            sym_weight[e2], sym_hard[e2] = w, hard
-        sym_base = np.zeros((E2, D), I64)
-        for node_name, e2, cnt in assigned_sym:
-            n = node_pos.get(node_name)
-            if n is None:
-                continue
-            code = topo_code[sym_topo[e2], n]
-            if code >= 0:
-                sym_base[e2, code] += cnt
-        sym_carrier = np.zeros((E2, P), I64)
-        for i, e2, cnt in pending_sym:
-            sym_carrier[e2, i] = cnt
-        out.update(
-            sym_sel=sym_sel,
-            sym_topo=sym_topo,
-            sym_weight=sym_weight,
-            sym_hard=sym_hard,
-            sym_base=sym_base,
-            sym_carrier=sym_carrier,
-        )
+    # the inter-pod half, second part: the E and E2 tables and their bases
+    with obs.tracer.span("Snapshot/affinity", tid="snapshot",
+                         anti_terms=len(axes.anti_terms),
+                         sym_terms=len(axes.sym_terms)):
+        if axes.anti_terms:
+            terms = anti_term_tables(axes)
+            out.update(
+                **terms,
+                exist_anti_base=carrier_counts(
+                    assigned_anti, node_pos, topo_code,
+                    terms["exist_anti_topo"], len(axes.anti_terms), D,
+                ) > 0,
+                **anti_batch_rows(
+                    pend_carriers, pend_match, terms["exist_anti_sel"], P
+                ),
+            )
+        if axes.sym_terms:
+            E2 = len(axes.sym_terms)
+            terms = sym_term_tables(axes)
+            out.update(
+                **terms,
+                sym_base=carrier_counts(
+                    assigned_sym, node_pos, topo_code, terms["sym_topo"],
+                    E2, D,
+                ),
+                sym_carrier=sym_batch_rows(pending_sym, E2, P),
+            )
     return out
 
 

@@ -378,13 +378,17 @@ SERVE_METRICS_RELOWERS = "scheduler_serve_metrics_relowers_total"
 #: a steady mix about twice a cycle's binds a track
 #: (docs/SERVING.md "Resident selector counts")
 SERVE_SELECTOR_ROWS = "scheduler_serve_selector_rows_total"
+#: +-1 contributions folded into the resident CARRIER counts of pod
+#: (anti-)affinity terms: one per (bind or delete of a pod, term it
+#: carries) (docs/SERVING.md "Resident affinity terms")
+SERVE_AFFINITY_CARRIER_ROWS = "scheduler_serve_affinity_carrier_rows_total"
 #: node rows of the resident `topo_code` table written outside a rebuild
 #: (a node that arrived, or whose labels were sent again)
 SERVE_TOPO_ROWS = "scheduler_serve_topo_rows_total"
 #: O(assigned) rebuilds of the resident selector tables from the store:
-#: the cold build, and after it only when the set of tracks the store's
-#: pods declare changes, a tracked label of a node that holds pods
-#: changes, or a key or domain outgrows its bucket
+#: the cold build, and after it only when the set of tracks and pod
+#: (anti-)affinity terms the store's pods declare changes, a tracked label
+#: of a node that holds pods changes, or a key or domain outgrows its bucket
 SERVE_SELECTOR_REBASES = "scheduler_serve_selector_rebases_total"
 #: labels: reason — serving refreshes that handed the cycle back to the
 #: O(cluster) `Cluster.snapshot`, by the clause of
@@ -566,6 +570,9 @@ HELP: dict[str, str] = {
         "Lowerings of the load watcher's report into resident columns.",
     SERVE_SELECTOR_ROWS:
         "Signed contributions folded into the resident selector counts.",
+    SERVE_AFFINITY_CARRIER_ROWS:
+        "Signed contributions folded into the resident carrier counts of "
+        "pod (anti-)affinity terms.",
     SERVE_TOPO_ROWS:
         "Node rows of the resident topology-domain table written.",
     SERVE_SELECTOR_REBASES:

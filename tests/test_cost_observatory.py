@@ -5,7 +5,7 @@ Covers the five claims the cost layer makes:
 - roofline projections match hand-computed oracles (pure arithmetic);
 - the compiled cost census is deterministic (two independent compiles of
   the same program produce identical rows and digests) and the committed
-  docs/cost_model.json is self-consistent: full 24-program coverage,
+  docs/cost_model.json is self-consistent: full 25-program coverage,
   zero budget violations, digests and rooflines re-derivable from the
   committed rows without compiling anything;
 - the `--check` gate fails closed: missing manifest, coverage gap,
@@ -224,7 +224,7 @@ class TestCommittedManifest:
         for name in static:
             row = manifest["programs"][name]
             # still joined: TPU digest + VMEM envelope + census all
-            # present, so 24/24 coverage is real, not vacuous
+            # present, so 25/25 coverage is real, not vacuous
             assert row["tpu"]["sha256"]
             assert row["kernels"], name
             assert row["collectives"], name

@@ -186,7 +186,9 @@ def test_rosters_fail_a_pod_where_they_say_so():
 class TestFinalizeBeforeTheNextRefresh:
     """The serial engine takes no host copy of the node columns for its
     epilogue: its thread finalizes, then refreshes. Asked the other way
-    round it raises; it never reads what the refresh donated."""
+    round (another thread refreshed under the feed lock, as the
+    benchmark's resident-state check does) it leaves the quality out; it
+    never reads what the refresh donated, and the daemon lives."""
 
     def _served_cycle(self):
         cluster, scheduler = plain_roster()
@@ -195,13 +197,15 @@ class TestFinalizeBeforeTheNextRefresh:
         assert ctx.served and ctx.serve_generation == engine.generation
         return cluster, scheduler, engine, ctx
 
-    def test_finalize_after_the_engines_next_refresh_raises(self):
+    def test_finalize_after_the_engines_next_refresh_skips_quality(
+            self, caplog):
         cluster, _scheduler, engine, ctx = self._served_cycle()
         engine.refresh(cluster, [], now_ms=2000)  # the cycle's own binds
         assert engine.generation == ctx.serve_generation + 1
-        with pytest.raises(RuntimeError, match="next refresh"):
-            cycle_report_stages(ctx)
-        assert ctx.report.quality is None
+        with caplog.at_level("WARNING"):
+            report = cycle_report_stages(ctx)
+        assert report.quality is None and report.bound
+        assert "next refresh" in caplog.text
 
     def test_finalize_in_time_reads_the_columns_the_cycle_solved_on(self):
         cluster, scheduler, engine, ctx = self._served_cycle()

@@ -407,10 +407,11 @@ def _plain_pod(name="q0", **spec):
     )
 
 
-def _web_term():
+def _web_term(**scope):
     return PodAffinityTerm(
         topology_key=ZONE_LABEL,
         label_selector=LabelSelector(match_labels={"app": "web"}),
+        **scope,
     )
 
 
@@ -429,9 +430,13 @@ CLAUSES = {
         allocatable={CPU: 4000, MEMORY: 16 * gib, PODS: 110},
         taints=[Taint(key="dedicated", value="x")],
     )),
-    "pod-affinity": lambda c: c.add_pod(_bound(_plain_pod(
+    # ISSUE 34 narrowed `pod-affinity` to the terms no row can keep: a
+    # non-empty namespaceSelector (tests/test_resident_affinity.py)
+    "affinity-namespace-selector": lambda c: c.add_pod(_bound(_plain_pod(
         "carrier", labels={"app": "web"},
-        pod_anti_affinity_required=[_web_term()],
+        pod_anti_affinity_required=[_web_term(
+            namespace_selector=LabelSelector(match_labels={"team": "a"}),
+        )],
     ))),
     "nomination": lambda c: c.add_pod(_plain_pod(
         "nominee", nominated_node_name="n001",

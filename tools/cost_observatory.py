@@ -1,6 +1,6 @@
 """Compiled-cost observatory: the static FLOP/byte/memory census (ISSUE 20).
 
-Walks the SAME 24-program registry that tools/tpu_lower.py, jaxpr_audit
+Walks the SAME 25-program registry that tools/tpu_lower.py, jaxpr_audit
 and kernel_audit share (`tpu_lower.PROGRAMS` — one registry, four
 auditors), compiles each program on the deterministic CPU backend, and
 records XLA's own `cost_analysis()` / `memory_analysis()` numbers joined
@@ -18,7 +18,7 @@ verdict and step-time floor (a projection from counts, not a timing).
 The three Mosaic-kernel programs cannot CPU-compile (`Only interpret
 mode is supported on CPU backend`) and get STATIC-ONLY rows: null CPU
 cost, digest based on the TPU StableHLO sha + collective census — still
-counted toward 24/24 coverage, still drift-gated.
+counted toward 25/25 coverage, still drift-gated.
 
 Manifest discipline (the tpu_lower pattern):
 

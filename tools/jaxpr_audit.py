@@ -180,6 +180,12 @@ ROLE_OVERRIDES = {
         "sd.g_idx", "sd.g_assigned", "sd.g_gated", "sd.g_slack",
         "sd.q_idx", "sd.q_used", "sd.q_count",
     ),
+    # apply_selector_deltas((track_base, anti_count, sym_base), track,
+    # domain, delta): the tables are the donated RESIDENT selector and
+    # carrier counts, same labeling rationale as serving_delta_apply
+    "serving_selector_apply": (
+        "state.sel", "sel.track", "sel.domain", "sel.delta",
+    ),
     # shrink_select(rank_nodes, live, node_block, block_cost, n_release):
     # rank_nodes is the RESIDENT rank-assignment carry (the elastic delta
     # program mutates resident state, not a snapshot); the release count

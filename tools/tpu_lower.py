@@ -611,6 +611,20 @@ def build_serving_side_apply():
     return fn, args, None
 
 
+def build_serving_selector_apply():
+    """`serving.deltas.selector_apply_program` — the donated O(changed)
+    scatter-add that keeps the resident selector tables: the (track,
+    domain) matching-pod counts (ISSUE 32) and the (term, domain) carrier
+    counts of pod (anti-)affinity terms with the presence derived from
+    them (ISSUE 34), at the reduced shape
+    `serving.engine.selector_lower_args` builds. Same donated-carry
+    calling convention as serving_delta_apply."""
+    from scheduler_plugins_tpu.serving.engine import selector_lower_args
+
+    fn, args = selector_lower_args()
+    return fn, args, None
+
+
 def build_wave_gang_solve():
     """`gangs.waves.wave_solve_body` — one wave of the wave-batched gang
     solve: the sequential scan's own per-gang body
@@ -814,6 +828,7 @@ PROGRAMS = {
     "wave_gang_solve": build_wave_gang_solve,
     "elastic_shrink": build_elastic_shrink,
     "serving_side_apply": build_serving_side_apply,
+    "serving_selector_apply": build_serving_selector_apply,
     "bench_cfg0_tpu_smoke": build_cfg0_tpu_smoke,
     "bench_cfg1_flagship": build_cfg1_flagship,
     "bench_cfg2_trimaran_sequential": build_cfg2_trimaran_sequential,
