@@ -129,6 +129,9 @@ LABEL_BOUNDS = (
      r"|spread_max_skew|spread_min_domains)$", I32_MAX - 1, "plain"),
     (r"^snap\.numa\.distances$", NUMA_DISTANCE_MAX, "plain"),
     (r"^state\.sel_dom_counts$", I32_MAX - 1, "plain"),
+    # its node-space view and the symmetric carriers': the same counts of
+    # pods, one per node where the table has one per domain
+    (r"^state\.(sel_dom_view|sym_view)$", I32_MAX - 1, "plain"),
     # the resident selector tables and their packed +-1 rows
     # (serving_selector_apply): counts of pods, never quantities
     (r"^state\.sel\[\d\]$", I32_MAX - 1, "plain"),

@@ -850,8 +850,14 @@ def _store_stages(seen: dict, scheduler, cluster, now, stream_chunk=None,
             # rows emitted by run_chunk_pipeline itself
             with obs.extension_span(
                 "Solve", scheduler.profile.name, pending=len(ctx.pending)
-            ):
+            ) as said:
+                views = obs.metrics.get(obs.SOLVE_NODE_VIEWS)
                 _cycle_solve_dispatch(ctx)
+                # whether the solve dispatched read node-space views of
+                # its domain tables (`Scheduler.solve` counts those)
+                said["node_views"] = (
+                    obs.metrics.get(obs.SOLVE_NODE_VIEWS) > views
+                )
                 _cycle_solve_fence(ctx)
             _cycle_post_solve(ctx)
         _cycle_bind(ctx)

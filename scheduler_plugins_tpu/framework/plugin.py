@@ -79,6 +79,21 @@ class SolverState:
     #: (E2, D) live symmetric-score carrier counts (existing pods'
     #: preferred/required affinity terms per domain); built-in commit
     sym_counts: Optional[jnp.ndarray] = None
+    #: NODE-SPACE VIEWS of the three domain carries above, one row per
+    #: table row: (TR, N) / (E, N) / (E2, N) in the tables' own dtypes.
+    #: Invariant, after every step of the scan (`tests/
+    #: test_domain_node_views.py` holds it): `view[t, n] == table[t,
+    #: code[topo[t], n]]` where node n has row t's topology key, 0 / False
+    #: where it has not — what InterPodAffinity and PodTopologySpread need
+    #: per node, kept current by the built-in commit BY COMPARE instead of
+    #: gathered out of the (., D) table at every pod. Derived state with
+    #: no static snapshot counterpart: `ops.selectors.attach_node_views`
+    #: gathers them once, inside the solve and before its scan, and the
+    #: solve drops them from its result; None anywhere else (the readers
+    #: then gather, `ops.selectors.domain_at`).
+    sel_dom_view: Optional[jnp.ndarray] = None
+    anti_view: Optional[jnp.ndarray] = None
+    sym_view: Optional[jnp.ndarray] = None
     #: (G2, M) live rank -> node assignment of the rank-aware gang phase
     #: (`gangs.topology.gang_solve_body`): initialized from the resident
     #: assignment (`RankGangState.prev_assigned`, its static snapshot

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from flax import struct
 
 from scheduler_plugins_tpu.framework.plugin import Plugin, SolverState
+from scheduler_plugins_tpu.ops import selectors
 from scheduler_plugins_tpu.ops.fit import fits_one, free_capacity, pod_fit_demand
 from scheduler_plugins_tpu.state.snapshot import ClusterSnapshot, SnapshotMeta
 from scheduler_plugins_tpu.utils import observability as obs
@@ -285,14 +286,16 @@ def _encode_fail(ok0, admit_code, fit0_any, filter_code, fallback):
     )
 
 
-def _solve_step(plugins, carry, p, snap: ClusterSnapshot):
+def _solve_step(plugins, carry, p, snap: ClusterSnapshot, view_codes=None):
     """One pod of the bit-faithful sequential scan: PreFilter -> built-in
     fit (nominee holds) -> Filter chain -> Score/Normalize weighted sum ->
     argmax select -> Reserve commits — THE parity-path step body, shared by
     `Scheduler.solve`, the vmapped counterfactual sweep
     (`parallel.solver.sweep_solve_fn`) and the K-lane speculative solve
     (`parallel.lanes.lane_solve_fn`, which feeds it a one-pod snapshot
-    view per step), so no fast path can drift from the parity program."""
+    view per step), so no fast path can drift from the parity program.
+    `view_codes`: the code rows of the carry's node-space views
+    (`ops.selectors.attach_node_views`), from a scan that carries views."""
     state = carry
     # PreFilter, with per-plugin attribution (shared helper)
     ok0 = snap.pods.mask[p] & ~snap.pods.gated[p]
@@ -334,9 +337,9 @@ def _solve_step(plugins, carry, p, snap: ClusterSnapshot):
     if snap.scheduling is not None:
         # built-in: selector/domain carries are shared by multiple
         # plugins (spread, inter-pod affinity) — commit once
-        from scheduler_plugins_tpu.ops.selectors import commit_tracks
-
-        state = commit_tracks(state, snap.scheduling, p, choice)
+        state = selectors.commit_tracks(
+            state, snap.scheduling, p, choice, view_codes
+        )
     for plugin in plugins:
         state = plugin.commit(state, snap, p, choice)
     # attribution code (SolveResult.failed_plugin); fallback 0:
@@ -370,11 +373,15 @@ def sequential_solve_body(plugins, snap: ClusterSnapshot,
     # loop-invariant per-solve precomputes (hoisted out of the scan)
     for plugin in plugins:
         plugin.bind_presolve(plugin.prepare_solve(snap))
+    # the domain tables' node-space views: gathered here, once a solve,
+    # kept by compare in the scan's built-in commit, dropped from the result
+    state0, codes = selectors.attach_node_views(state0, snap.scheduling)
     P = snap.num_pods
     state, (assignment, admitted, failed_plugin) = jax.lax.scan(
-        lambda c, p: _solve_step(plugins, c, p, snap), state0,
+        lambda c, p: _solve_step(plugins, c, p, snap, codes), state0,
         jnp.arange(P), unroll=unroll,
     )
+    state = selectors.drop_node_views(state)
     wait = jnp.zeros(P, bool)
     if snap.gangs is not None and state.gang_scheduled is not None:
         # Permit quorum: previously-assigned + this cycle's placements
@@ -714,6 +721,10 @@ class Scheduler:
             state0 = self.initial_state(snap)
         if auxes is None:
             auxes = tuple(plugin.aux() for plugin in self.profile.plugins)
+        if selectors.has_domain_tables(snap.scheduling):
+            # static per compiled shape: this program reads node-space
+            # views of its domain tables (`ops.selectors`)
+            obs.metrics.inc(obs.SOLVE_NODE_VIEWS)
         unroll = self._scan_unroll()
         live = self._live_weights
         if live is not None:

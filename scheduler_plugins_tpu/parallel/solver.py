@@ -541,6 +541,9 @@ def _donation_safe_state(state0):
         sel_dom_counts=copy(state0.sel_dom_counts),
         anti_domains=copy(state0.anti_domains),
         sym_counts=copy(state0.sym_counts),
+        sel_dom_view=copy(state0.sel_dom_view),
+        anti_view=copy(state0.anti_view),
+        sym_view=copy(state0.sym_view),
     )
 
 

@@ -35,6 +35,7 @@ import jax.numpy as jnp
 
 from scheduler_plugins_tpu.framework.plugin import Plugin
 from scheduler_plugins_tpu.ops.normalize import default_normalize
+from scheduler_plugins_tpu.ops.selectors import domain_at
 from scheduler_plugins_tpu.api import events as ev
 
 
@@ -184,14 +185,23 @@ class PodTopologySpread(Plugin):
         minm = jnp.where((md > 0) & (dn < md), 0, minm)
         return s, dc, minm, code, has
 
+    def _match_at(self, state, s, p, dc, code):
+        """(CT, N) count in each node's domain, 0 where the node lacks the
+        key: rows of the mirror's node-space view
+        (`SolverState.sel_dom_view`) where the solve carries one. The
+        policy branch's `dc` is counted per pod under the pod's own
+        eligibility row, which no shared view can serve: it gathers."""
+        view = None
+        if state is not None and not s.spread_needs_node_counts:
+            view = state.sel_dom_view
+        return domain_at(view, s.spread_track[p], dc, code)
+
     def filter(self, state, snap, p):
         s = snap.scheduling
         if s is None or s.spread_track is None:
             return None
         s, dc, minm, code, has = self._constraint_state(state, snap, p)
-        match_at = jnp.take_along_axis(
-            dc, jnp.maximum(code, 0), axis=1
-        )  # (CT, N)
+        match_at = self._match_at(state, s, p, dc, code)  # (CT, N)
         selfm = s.spread_self[p][:, None].astype(jnp.int64)
         ok = match_at + selfm - minm[:, None] <= s.spread_max_skew[p][:, None]
         applies = (s.spread_mask[p] & s.spread_hard[p])[:, None]
@@ -205,7 +215,7 @@ class PodTopologySpread(Plugin):
         if s is None or s.spread_track is None:
             return None
         s, dc, _, code, has = self._constraint_state(state, snap, p)
-        match_at = jnp.take_along_axis(dc, jnp.maximum(code, 0), axis=1)
+        match_at = self._match_at(state, s, p, dc, code)
         applies = (s.spread_mask[p] & ~s.spread_hard[p])[:, None] & has
         return jnp.sum(jnp.where(applies, match_at, 0), axis=0)
 
@@ -296,11 +306,18 @@ class InterPodAffinity(Plugin):
             return state.anti_domains
         return snap.scheduling.exist_anti_base
 
+    def _sym_counts(self, state, snap):
+        if state is not None and state.sym_counts is not None:
+            return state.sym_counts
+        return snap.scheduling.sym_base
+
     def filter(self, state, snap, p):
         s = snap.scheduling
         if s is None or s.aff_track is None:
             return None
         counts = self._counts(state, snap)
+        # the counts' node-space view, where the solve carries one
+        view = None if state is None else state.sel_dom_view
         N = snap.num_nodes
         verdict = jnp.ones(N, bool)
 
@@ -310,7 +327,7 @@ class InterPodAffinity(Plugin):
         dc = counts[s.aff_track[p]]  # (AT, D)
         exists = s.domain_exists[s.aff_topo[p]]
         total = jnp.sum(jnp.where(exists, dc, 0), axis=1)  # (AT,)
-        match_at = jnp.take_along_axis(dc, jnp.maximum(code, 0), axis=1)
+        match_at = domain_at(view, s.aff_track[p], dc, code)
         ok = has & (
             (match_at > 0)
             | ((total == 0) & s.aff_self[p])[:, None]
@@ -323,7 +340,7 @@ class InterPodAffinity(Plugin):
         codeb = s.topo_code[s.anti_topo[p]]
         hasb = s.topo_has[s.anti_topo[p]]
         dcb = counts[s.anti_track[p]]  # (BT, D)
-        match_b = jnp.take_along_axis(dcb, jnp.maximum(codeb, 0), axis=1)
+        match_b = domain_at(view, s.anti_track[p], dcb, codeb)
         okb = ~hasb | (match_b == 0)
         verdict &= jnp.all(
             jnp.where(s.anti_mask[p][:, None], okb, True), axis=0
@@ -331,11 +348,10 @@ class InterPodAffinity(Plugin):
 
         # symmetry: carriers of matching anti terms block the domain
         if s.exist_anti_sel is not None:
-            domains = self._anti_domains(state, snap)  # (E, D)
-            codee = s.topo_code[s.exist_anti_topo]  # (E, N)
-            blocked = (
-                jnp.take_along_axis(domains, jnp.maximum(codee, 0), axis=1)
-                & (codee >= 0)
+            blocked = domain_at(
+                None if state is None else state.anti_view, None,
+                self._anti_domains(state, snap),  # (E, D)
+                s.topo_code[s.exist_anti_topo],  # (E, N)
             )
             m = s.exist_anti_match[:, p]  # (E,)
             verdict &= ~jnp.any(m[:, None] & blocked, axis=0)
@@ -348,8 +364,10 @@ class InterPodAffinity(Plugin):
         counts = self._counts(state, snap)
         code = s.topo_code[s.waff_topo[p]]  # (WT, N)
         has = s.topo_has[s.waff_topo[p]]
-        dc = counts[s.waff_track[p]]  # (WT, D)
-        match_at = jnp.take_along_axis(dc, jnp.maximum(code, 0), axis=1)
+        match_at = domain_at(
+            None if state is None else state.sel_dom_view,
+            s.waff_track[p], counts[s.waff_track[p]], code,
+        )  # (WT, N)
         contrib = jnp.where(
             s.waff_mask[p][:, None] & has,
             s.waff_weight[p][:, None] * match_at,
@@ -358,14 +376,11 @@ class InterPodAffinity(Plugin):
         total = jnp.sum(contrib, axis=0)
         if s.sym_sel is not None:
             # symmetric part: existing carriers' terms matching THIS pod
-            sym = (
-                state.sym_counts
-                if state is not None and state.sym_counts is not None
-                else s.sym_base
-            )  # (E2, D)
-            codee = s.topo_code[s.sym_topo]  # (E2, N)
-            at = jnp.take_along_axis(sym, jnp.maximum(codee, 0), axis=1)
-            at = jnp.where(codee >= 0, at, 0)
+            at = domain_at(
+                None if state is None else state.sym_view, None,
+                self._sym_counts(state, snap),  # (E2, D)
+                s.topo_code[s.sym_topo],  # (E2, N)
+            )
             w_eff = jnp.where(
                 s.sym_hard,
                 self.hard_pod_affinity_weight * s.sym_weight,

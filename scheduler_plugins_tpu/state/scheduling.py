@@ -58,7 +58,11 @@ I32 = np.int32
 #: in-cycle placements. Companion map to
 #: `state.snapshot.CARRY_COUNTERPARTS`; consumed by `tools/jaxpr_audit.py`
 #: rule JA001 (a compiled solve must not derive live counts from these
-#: static bases while the carry is dead).
+#: static bases while the carry is dead). The three domain carries'
+#: node-space views (`SolverState.sel_dom_view` / `anti_view` / `sym_view`)
+#: are DERIVED from the carries inside the solve
+#: (`ops.selectors.attach_node_views`) and have no base here: nothing
+#: static could be read in their place.
 TRACK_CARRY_COUNTERPARTS = {
     ".scheduling.track_node_base": "sel_counts",
     ".scheduling.track_base": "sel_dom_counts",

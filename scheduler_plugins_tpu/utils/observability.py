@@ -312,6 +312,9 @@ PODS_BOUND = "scheduler_pods_bound_total"
 PODS_FAILED = "scheduler_pods_unschedulable_total"
 PREEMPTION_ATTEMPTS = "scheduler_preemption_attempts_total"
 PREEMPTION_VICTIMS = "scheduler_preemption_victims_total"
+#: sequential solves whose program reads node-space views of its domain
+#: tables (`ops.selectors`): one a solve of a snapshot that has such tables
+SOLVE_NODE_VIEWS = "scheduler_solve_node_views_total"
 GANG_REJECTIONS = "scheduler_gang_rejections_total"
 #: pods an ElasticQuota refused in a cycle (CapacityScheduling's PreFilter
 #: made them unschedulable: own Max, or the aggregate over Min)
@@ -541,6 +544,9 @@ HELP: dict[str, str] = {
     PODS_FAILED: "Pods reported unschedulable.",
     PREEMPTION_ATTEMPTS: "Preemption attempts (upstream PreemptionAttempts).",
     PREEMPTION_VICTIMS: "Pods nominated for eviction by preemption.",
+    SOLVE_NODE_VIEWS:
+        "Sequential solves whose program reads node-space views of its "
+        "domain tables.",
     GANG_REJECTIONS: "Whole-gang admission rejections.",
     CACHE_RESYNC_FLUSHES: "NRT cache resync flushes.",
     UNSCHEDULABLE_BY_PLUGIN:
