@@ -722,6 +722,8 @@ class Daemon:
         #: tick started, a stop rings it too
         self._doorbell = _Doorbell()
         self._pod_waiting = False
+        #: `time.monotonic()` of the ring that raised `_pod_waiting`
+        self._pod_rang_at = 0.0
         self.cluster.on_pending_gain = self._pod_arrived
         if args.trace:
             obs.tracer.start()
@@ -885,6 +887,9 @@ class Daemon:
         lock this caller holds, so a pod that found it up is in that tick's
         batch."""
         if not self._pod_waiting:
+            # stamped before the flag goes up: the loop reads the flag
+            # first, so the stamp it then reads is this ring's
+            self._pod_rang_at = time.monotonic()
             self._pod_waiting = True
             self._doorbell.ring()
 
@@ -1012,14 +1017,22 @@ class Daemon:
         otherwise at the first moment from `DEMAND_TICK_SPACING` durations
         after `started` at which a pod has entered the pending set
         ("demand"). A leader-election standby ticks on the interval only.
-        `stop_event` ends the wait at once."""
+        `stop_event` ends the wait at once.
+
+        The decision is recorded where it is made: the span's args carry
+        `woke`, `locked_ms` (the `duration` the rule multiplied),
+        `since_start_ms` (the wait's end less `started`: on a demand wake
+        at least `DEMAND_TICK_SPACING` x `locked_ms`) and `held_ms` (the
+        wait's end less the first ring since `started`, 0 where no pod
+        waited), and `scheduler_tick_hold_ms{woke}` takes the last."""
         interval = self.args.cycle_interval_s
         heartbeat = started + interval
         earliest = started + min(interval, DEMAND_TICK_SPACING * duration)
         standby = self.elector is not None and not self.elector.is_leader
         # with every tick's spans this tiles the thread's wall clock:
         # what is in neither is unaccounted
-        with obs.tracer.span("Loop/sleep", tid="daemon") as said:
+        with obs.tracer.span("Loop/sleep", tid="daemon",
+                             locked_ms=duration * 1000) as said:
             woke = "interval"
             while not self.stop_event.is_set():
                 now = time.monotonic()
@@ -1034,7 +1047,15 @@ class Daemon:
                 self._doorbell.wait(
                     (earliest if now < earliest else heartbeat) - now
                 )
+            woke_at = time.monotonic()
+            held_ms = (
+                (woke_at - self._pod_rang_at) * 1000
+                if self._pod_waiting else 0.0
+            )
             said["woke"] = woke
+            said["since_start_ms"] = (woke_at - started) * 1000
+            said["held_ms"] = held_ms
+        obs.metrics.observe_ms(obs.TICK_HOLD, held_ms, woke=woke)
         return woke
 
     def run(self):

@@ -78,6 +78,7 @@ gRPC via `bridge.grpc_feed` (HTTP/2, JSON codec, no protobuf stubs).
 
 from __future__ import annotations
 
+import itertools
 import json
 import socket
 import socketserver
@@ -593,47 +594,139 @@ def _apply_op(cluster: Cluster, event: dict, op) -> dict:
 TALLY_FLUSH_EVENTS = 32
 TALLY_FLUSH_NS = 100_000_000
 
+#: The longest gap between an ack's flush and the connection's next line
+#: that still counts as the client answering (`turnaround`: the two socket
+#: hops, the client's decode and encode, this thread's wake-up); a longer
+#: one is `quiet`: the client had nothing to send. 5 ms is ~25 x the
+#: ~200 us turnaround the records imply (PERF.md finding 17: a ~240 us
+#: acknowledged event of which ~65 us are the handler's), so a client that
+#: merely ran slow is not taken for one that stopped, and a fifth of the
+#: shortest calm tick (~25 ms, `trimaran-5000n.steady`), so the gaps a
+#: closed-loop client leaves between two ticks of a backlog are.
+FEED_QUIET_NS = 5_000_000
+#: a quiet gap this long is a stall (`scheduler_feed_stalls_total`)
+FEED_STALL_NS = 1_000_000_000
+
 
 class FeedTally:
     """What one connection (TCP) or one worker thread (gRPC) spent on its
-    events since its last flush: a count and three nanosecond sums. Owned
-    by one thread, so it takes no lock; `flush` adds it to
+    events since its last flush: a count and nanosecond sums per stage.
+    Owned by one thread, so it takes no lock; `flush` adds it to
     `scheduler_feed_events_total` / `scheduler_feed_event_ns_total{stage}`
-    — four registry writes per 32 events instead of per event, because
-    ingest is the served path's first bottleneck (PERF.md)."""
+    — a handful of registry writes per 32 events instead of per event,
+    because ingest is the served path's first bottleneck (PERF.md).
+
+    A TCP connection's tally has a `row` (`feed/<n>`) and is made when the
+    connection's first byte is in hand. Its stamps are contiguous, so its
+    wall clock from there to its last ack flushed is tiled exactly by six
+    parts: `codec`, `lock_wait` and `apply` (`apply_raw`), `write`
+    (`wrote`), and the gap to the next line: beyond `FEED_QUIET_NS` quiet
+    (`apply_raw`'s first stamp sees it), else `turnaround`, which `flush`
+    takes as what is left of the stretch it flushes. `mark_ns` is where
+    the running gap began: the last ack's flush. It stays 0 without a row
+    (gRPC: the library owns its reads and writes, so its worker threads
+    keep the three stages `apply_raw` times).
+
+    With the tracer on, each flush of a tally with a row also records the
+    stretch since `since_ns` (the last flush, or the end of a quiet gap)
+    as one B/E pair on that row: a segment of a burst."""
 
     __slots__ = ("events", "codec_ns", "lock_wait_ns", "apply_ns",
-                 "flushed_ns")
+                 "write_ns", "out_ns", "mark_ns", "since_ns",
+                 "quiet_before_ns", "row")
 
-    def __init__(self):
+    def __init__(self, row: Optional[str] = None):
         self.events = self.codec_ns = self.lock_wait_ns = self.apply_ns = 0
-        self.flushed_ns = time.perf_counter_ns()
+        self.write_ns = self.quiet_before_ns = 0
+        self.since_ns = self.out_ns = time.perf_counter_ns()
+        # a connection's tally is made with its first byte in hand: its
+        # first stamp
+        self.mark_ns = self.since_ns if row is not None else 0
+        self.row = row
 
-    def flush(self) -> None:
-        self.flushed_ns = time.perf_counter_ns()
-        if not self.events:
+    def flush(self, now_ns: int) -> None:
+        """Everything since `since_ns` goes to the registry (and, as one
+        segment ending at `now_ns`, to the tracer); `now_ns` is a stamp
+        the caller has taken."""
+        since, self.since_ns = self.since_ns, now_ns
+        if not self.events and not self.write_ns:
             return
-        obs.metrics.inc(obs.FEED_EVENTS, self.events)
-        obs.metrics.inc(obs.FEED_EVENT_NS, self.codec_ns, stage="codec")
-        obs.metrics.inc(
-            obs.FEED_EVENT_NS, self.lock_wait_ns, stage="lock_wait"
-        )
-        obs.metrics.inc(obs.FEED_EVENT_NS, self.apply_ns, stage="apply")
+        inc = obs.metrics.inc
+        inc(obs.FEED_EVENTS, self.events)
+        inc(obs.FEED_EVENT_NS, self.codec_ns, stage="codec")
+        inc(obs.FEED_EVENT_NS, self.lock_wait_ns, stage="lock_wait")
+        inc(obs.FEED_EVENT_NS, self.apply_ns, stage="apply")
+        if self.row is not None:
+            # no quiet gap lies inside the stretch (`quiet` ends it), so
+            # what of it no line was in hand for is turnaround
+            busy_ns = (self.codec_ns + self.lock_wait_ns + self.apply_ns
+                       + self.write_ns)
+            turnaround_ns = (now_ns - since) - busy_ns
+            inc(obs.FEED_EVENT_NS, self.write_ns, stage="write")
+            inc(obs.FEED_EVENT_NS, turnaround_ns, stage="turnaround")
+            if obs.tracer.enabled:
+                obs.tracer.complete(
+                    "Feed/segment", since - obs.tracer.origin_ns,
+                    now_ns - since, tid=self.row, paired=True,
+                    args={
+                        "events": self.events,
+                        "busy_us": busy_ns / 1000.0,
+                        "lock_wait_us": self.lock_wait_ns / 1000.0,
+                        "turnaround_us": turnaround_ns / 1000.0,
+                        "quiet_before_us": self.quiet_before_ns / 1000.0,
+                    },
+                )
+        self.quiet_before_ns = 0
         self.events = self.codec_ns = self.lock_wait_ns = self.apply_ns = 0
+        self.write_ns = 0
+
+    def wrote(self) -> None:
+        """The ack that was in hand at `out_ns` (`apply_raw`'s last
+        stamp) has been written and flushed: the TCP handler's stamp."""
+        now = time.perf_counter_ns()
+        self.write_ns += now - self.out_ns
+        self.mark_ns = now
+
+    def close(self) -> None:
+        """The connection's (or stream's) end: what is left goes out, as
+        a segment that ends at the last ack's flush where there is one."""
+        self.flush(
+            self.mark_ns if self.mark_ns > self.since_ns
+            else time.perf_counter_ns()
+        )
+
+    def quiet(self, gap_ns: int, now_ns: int) -> None:
+        """The connection's next line came `gap_ns` (over `FEED_QUIET_NS`)
+        after the last ack's flush: what preceded the gap is flushed as a
+        segment that ends where the gap began, the gap is one observation
+        of `scheduler_feed_quiet_ms`, and what follows begins a burst."""
+        self.flush(self.mark_ns)
+        self.since_ns = now_ns
+        self.quiet_before_ns = gap_ns
+        # the histogram alone: `observe_ms` would add its legacy counters
+        obs.metrics.observe_batch(((obs.FEED_QUIET_MS, gap_ns / 1e6, ()),))
+        if gap_ns >= FEED_STALL_NS:
+            obs.metrics.inc(obs.FEED_STALLS)
 
 
 def apply_raw(tally: FeedTally, raw: bytes, cluster: Cluster, lock,
               rv_table: Optional[dict]) -> bytes:
     """One wire event, decoded, applied under `lock` and acknowledged:
-    the body both front ends (TCP here, `bridge.grpc_feed`) send back.
-    Times three stages into `tally` — `codec` (decode + ack encode),
-    `lock_wait` (asking for the lock to holding it), `apply`
-    (`apply_event` under it) — with five clock reads and no registry
-    write but the tally's rare flush. A malformed event is an event: its
+    the body both front ends (TCP here, `bridge.grpc_feed`) send back
+    (`tally.out_ns` is the stamp at which it was in hand). Times three
+    stages into `tally` — `codec` (decode + ack encode), `lock_wait`
+    (asking for the lock to holding it), `apply` (`apply_event` under it)
+    — with five clock reads and no registry write but the tally's rare
+    flush. The first of the five is also the line in hand: on a
+    connection whose handler stamps its writes (`FeedTally.wrote`) it
+    closes the gap since the last one. A malformed event is an event: its
     decode and its error ack are `codec` time."""
     clock = time.perf_counter_ns
     # a stage the event never reaches keeps both its stamps equal
     t_in = t_ask = t_held = t_done = clock()
+    mark = tally.mark_ns
+    if mark and t_in - mark > FEED_QUIET_NS:
+        tally.quiet(t_in - mark, t_in)
     try:
         event = json.loads(raw)
         t_ask = t_held = t_done = clock()
@@ -647,13 +740,14 @@ def apply_raw(tally: FeedTally, raw: bytes, cluster: Cluster, lock,
         ack = {"ok": False, "error": str(exc)}
     body = json.dumps(ack).encode()
     t_out = clock()
+    tally.out_ns = t_out
     tally.events += 1
     tally.lock_wait_ns += t_held - t_ask
     tally.apply_ns += t_done - t_held
     tally.codec_ns += (t_out - t_in) - (t_done - t_ask)
     if (tally.events >= TALLY_FLUSH_EVENTS
-            or t_out - tally.flushed_ns >= TALLY_FLUSH_NS):
-        tally.flush()
+            or t_out - tally.since_ns >= TALLY_FLUSH_NS):
+        tally.flush(t_out)
     return body
 
 
@@ -672,41 +766,40 @@ class FeedServer:
         #: (kind, id) -> last applied resource version (shared across
         #: connections: redundant agents fence against each other)
         self.rv_table: dict = {}
+        #: connections so far: the <n> of a connection's `feed/<n>` row
+        self._rows = itertools.count()
         outer = self
 
         class Handler(socketserver.StreamRequestHandler):
-            def setup(self):
-                super().setup()
-                # one connection, one thread: the tally is this
-                # handler's own, flushed by `apply_raw` and at the end
-                self.tally = FeedTally()
-
-            def _apply(self, raw: bytes) -> bytes:
-                return apply_raw(
-                    self.tally, raw, outer.cluster, outer.lock,
-                    outer.rv_table,
-                )
-
             def handle(self):
                 # transport sniff: a gRPC-style frame starts with the
                 # 0x00/0x01 compressed-flag byte; newline-JSON starts with
                 # "{" — one port speaks both
+                first = self.rfile.peek(1)[:1]
+                # one connection, one thread: the tally is this
+                # handler's own, begun with the first byte in hand,
+                # flushed by `apply_raw` and at the end
+                self.tally = FeedTally(row=f"feed/{next(outer._rows)}")
                 try:
-                    first = self.rfile.peek(1)[:1]
                     if first in (b"\x00", b"\x01"):
                         self._handle_framed()
                     else:
                         self._handle_lines()
                 finally:
-                    self.tally.flush()
+                    self.tally.close()
 
             def _handle_lines(self):
+                tally = self.tally
                 for raw in self.rfile:
                     raw = raw.strip()
                     if not raw:
                         continue
-                    self.wfile.write(self._apply(raw) + b"\n")
+                    self.wfile.write(apply_raw(
+                        tally, raw, outer.cluster, outer.lock,
+                        outer.rv_table,
+                    ) + b"\n")
                     self.wfile.flush()
+                    tally.wrote()
 
             def _handle_framed(self):
                 """gRPC message framing (1-byte compressed flag + 4-byte
@@ -714,6 +807,7 @@ class FeedServer:
                 shape a Go agent's grpc stack produces, minus HTTP/2."""
                 import struct as _struct
 
+                tally = self.tally
                 while True:
                     header = self.rfile.read(5)
                     if len(header) < 5:
@@ -736,9 +830,13 @@ class FeedServer:
                     payload = self.rfile.read(length)
                     if len(payload) < length:
                         return
-                    body = self._apply(payload)
+                    body = apply_raw(
+                        tally, payload, outer.cluster, outer.lock,
+                        outer.rv_table,
+                    )
                     self.wfile.write(_struct.pack(">BI", 0, len(body)) + body)
                     self.wfile.flush()
+                    tally.wrote()
 
         self._server = socketserver.ThreadingTCPServer((host, port), Handler)
         self._server.daemon_threads = True

@@ -81,7 +81,7 @@ class GrpcFeedServer:
                 for request in request_iterator:
                     yield _apply(request)
             finally:
-                _tally().flush()  # the stream's end, like a connection's
+                _tally().close()  # the stream's end, like a connection's
 
         ident = lambda b: b  # noqa: E731 — JSON codec: bytes through
         handler = grpc.method_handlers_generic_handler(

@@ -1204,7 +1204,6 @@ class LaneSolver:
             pod_quorum = np.where(in_gang, quorum[np.maximum(gang, 0)], True)
             wait = (assignment >= 0) & ~pod_quorum
         stats.fence_ms = (time.perf_counter() - t0) * 1000.0
-        obs.metrics.observe_ms(obs.LANE_COMMIT, stats.fence_ms)
         for j in range(self.k):
             with obs.tracer.span("Lane/commit", tid=f"Lane/{j}",
                                  committed=stats.committed[j],

@@ -293,7 +293,6 @@ class CycleRecord:
                 self.manifest["report"]["quality"] = dict(report.quality)
         self.manifest["drift"] = drift
         self.complete = True
-        obs.metrics.inc(obs.FLIGHTREC_CYCLES)
 
     def to_manifest(self) -> dict:
         line = {

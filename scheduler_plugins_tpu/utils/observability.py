@@ -12,7 +12,8 @@ Mirrors the reference's observability surface (SURVEY.md §5):
   `UnschedulablePlugins` shape), rendered in prometheus text format by
   `Metrics.prometheus_text` (the daemon's `/metrics`);
 - a `Tracer` recording host-side spans as Chrome-trace-event / Perfetto
-  JSON ("traceEvents" with X complete events + M thread-name metadata), so
+  JSON ("traceEvents" with X complete events, B/E pairs on the feed's rows
+  and M thread-name metadata), so
   one scheduling cycle or one chunk-pipeline run loads as a timeline in
   ui.perfetto.dev. Device-side numbers always come from host-transfer
   timestamps — never wall clocks inside jit-traced code (CLAUDE.md; lint
@@ -342,8 +343,6 @@ JIT_CACHE_MISS = "scheduler_jit_cache_misses_total"
 #: ones it answered — requests minus hits were compiled from scratch
 COMPILE_CACHE_REQUESTS = "scheduler_compile_cache_requests_total"
 COMPILE_CACHE_HITS = "scheduler_compile_cache_hits_total"
-#: cycles captured by the flight recorder (utils.flightrec)
-FLIGHTREC_CYCLES = "scheduler_flightrec_cycles_total"
 #: serve-mode decision latency histogram: wall ms from delta ingest to
 #: host-visible bind decisions for one resident-state cycle
 #: (framework.cycle.run_cycle(serve=...))
@@ -472,9 +471,6 @@ TUNER_STATE = "scheduler_tuner_state"
 #: of lane j failed the speculative-vs-committed step-signature check —
 #: the whole remaining suffix re-resolves against committed state
 LANE_CONFLICTS = "scheduler_lane_conflicts_total"
-#: wall-clock ms of the host conflict fence per laned cycle (serial-order
-#: validation walk + wait recomputation + any suffix repair dispatch)
-LANE_COMMIT = "scheduler_lane_commit_ms"
 #: pods re-resolved against committed state by the suffix repair solve
 LANE_RERESOLVES = "scheduler_lane_reresolves_total"
 #: laned cycles that fell back to the sequential parity solve because the
@@ -511,10 +507,22 @@ DEVICE_PEAK_BYTES = "scheduler_device_peak_bytes_in_use"
 #: lags an event by at most that much
 FEED_EVENTS = "scheduler_feed_events_total"
 #: nanoseconds those events spent per stage (labels: stage ∈ codec |
-#: lock_wait | apply): the JSON decode + ack encode, the wait for the feed
-#: lock, `apply_event` under it. Flushed with FEED_EVENTS;
-#: `rate(ns) / rate(events)` is the mean cost of an event per stage
+#: lock_wait | apply | write | turnaround): the JSON decode + ack encode,
+#: the wait for the feed lock, `apply_event` under it; and, on the TCP
+#: front end only, the ack's `write` + `flush`, and the gap from that flush
+#: to the connection's next line where the client answered within
+#: `bridge.feed.FEED_QUIET_NS` (the round trip and the client's own work).
+#: Flushed with FEED_EVENTS; `rate(ns) / rate(events)` is the mean cost of
+#: an event per stage
 FEED_EVENT_NS = "scheduler_feed_event_ns_total"
+#: the gaps longer than that, in ms (histogram, one observation a gap, TCP
+#: only): the client had nothing to send. Its count is the bursts a
+#: connection's traffic came in, its sum the time the feed sat quiet, its
+#: top buckets (1 s, 2.5 s, 5 s) a sender that stopped
+FEED_QUIET_MS = "scheduler_feed_quiet_ms"
+#: the quiet gaps of a second or more: no line for that long on a
+#: connection that then sent another
+FEED_STALLS = "scheduler_feed_stalls_total"
 #: wall ms of one `GET /healthz`, entry to reply written: the SLI summary
 #: over the ledger ring, the thread census, the JSON, and the handler's
 #: own waits for the interpreter lock (histogram, one observation a poll)
@@ -534,6 +542,11 @@ TICK_WAKEUPS = "scheduler_tick_wakeups_total"
 #: spacing rule multiplies (`__main__.DEMAND_TICK_SPACING`); the cycle's
 #: report-only epilogue (`Finalize`) runs after it, with no lock held
 TICK_LOCKED = "scheduler_tick_locked"
+#: wall ms from the first pod that entered the pending set after a tick
+#: started to the end of the loop's wait for the next one (histogram,
+#: labels: woke ∈ demand | interval, one observation a wait; 0 where no
+#: pod waited): how long the loop makes a waiting pod wait, by what held it
+TICK_HOLD = "scheduler_tick_hold_ms"
 
 #: `# HELP` registry for `prometheus_text` (exposition format 0.0.4
 #: requires families to be self-describing; families not listed here get
@@ -560,7 +573,6 @@ HELP: dict[str, str] = {
         "Backend compiles that consulted the persistent compile cache.",
     COMPILE_CACHE_HITS:
         "Backend compiles answered by the persistent compile cache.",
-    FLIGHTREC_CYCLES: "Cycles captured by the flight recorder.",
     SERVE_DECISION_LATENCY:
         "Delta ingest to host-visible bind decisions, per cycle, in ms.",
     SERVE_GENERATION: "Resident-state generation (gauge).",
@@ -616,7 +628,6 @@ HELP: dict[str, str] = {
         "Tuner controller state: 0 idle, 1 probation, 2 cooldown, "
         "3 disabled (gauge).",
     LANE_CONFLICTS: "Conflict-fence rejections per lane.",
-    LANE_COMMIT: "Host conflict-fence wall ms per laned cycle.",
     LANE_RERESOLVES: "Pods re-resolved by the suffix repair solve.",
     LANE_SERIAL_FALLBACKS:
         "Laned cycles that fell back to the sequential parity solve.",
@@ -639,7 +650,11 @@ HELP: dict[str, str] = {
         "connection every 32 events or 100 ms.",
     FEED_EVENT_NS:
         "Nanoseconds feed events spent per stage (codec, lock_wait, "
-        "apply); divide by scheduler_feed_events_total.",
+        "apply, write, turnaround); divide by scheduler_feed_events_total.",
+    FEED_QUIET_MS:
+        "Gaps of over 5 ms between an ack and the connection's next line, "
+        "in ms: the client had nothing to send.",
+    FEED_STALLS: "Quiet gaps of a second or more on a feed connection.",
     HEALTHZ_HANDLER_MS:
         "Wall ms of one GET /healthz inside its handler, entry to reply "
         "written.",
@@ -647,6 +662,9 @@ HELP: dict[str, str] = {
     TICK_LOCKED:
         "Wall ms a tick kept the feed lock (cycle and tail, waits for it "
         "included); demand ticks are spaced by six times this.",
+    TICK_HOLD:
+        "Wall ms the first waiting pod waited for the loop's next tick, "
+        "by what ended the wait (0 where no pod waited).",
     TICK_WAKEUPS:
         "Ticks by what ended the wait before them: a pod became "
         "schedulable (demand) or the cycle interval was up (interval).",
@@ -845,6 +863,12 @@ class Tracer:
         """Current timestamp on the tracer clock (ns since `start()`)."""
         return time.perf_counter_ns() - self._origin_ns
 
+    @property
+    def origin_ns(self) -> int:
+        """`start()` on `time.perf_counter_ns`: what a caller that keeps
+        stamps of its own takes from them before `complete()`."""
+        return self._origin_ns
+
     def _tid(self, name: str) -> int:
         tid = self._tids.get(name)
         if tid is None:
@@ -852,16 +876,21 @@ class Tracer:
         return tid
 
     def complete(self, name: str, start_ns: int, dur_ns: int,
-                 tid: str = "host", args: dict | None = None) -> None:
+                 tid: str = "host", args: dict | None = None,
+                 paired: bool = False) -> None:
         """Record one complete ("X") event from explicit tracer-clock
-        stamps (ns since `start()`), e.g. replayed pipeline timelines."""
+        stamps (ns since `start()`), e.g. replayed pipeline timelines.
+        `paired` writes the same span as a "B" / "E" pair: for a thread
+        whose spans overlap another thread's X events, which readers of
+        the X events take for one thread's (the feed's `feed/<n>` rows)."""
         if not self._enabled:
             return
+        dur_ns = max(dur_ns, 0)
         event = {
             "name": name,
             "ph": "X",
             "ts": start_ns / 1000.0,
-            "dur": max(dur_ns, 0) / 1000.0,
+            "dur": dur_ns / 1000.0,
             "pid": os.getpid(),
         }
         if args:
@@ -869,6 +898,14 @@ class Tracer:
         with self._lock:
             event["tid"] = self._tid(tid)
             self._events.append(event)
+            if paired:
+                event["ph"] = "B"
+                del event["dur"]
+                self._events.append({
+                    "name": name, "ph": "E",
+                    "ts": (start_ns + dur_ns) / 1000.0,
+                    "pid": event["pid"], "tid": event["tid"],
+                })
 
     @contextmanager
     def span(self, name: str, tid: str = "host", **args):
@@ -887,7 +924,8 @@ class Tracer:
             )
 
     def export(self) -> dict:
-        """{"traceEvents": [...]} — X spans plus M thread_name metadata.
+        """{"traceEvents": [...]} — X spans (and the B/E pairs of
+        `complete(paired=True)`) plus M thread_name metadata.
         `otherData.origin_monotonic_ns` is `start()` on CLOCK_MONOTONIC:
         `ts` 0 of this file, so it can be laid beside a profiler trace."""
         with self._lock:

@@ -836,6 +836,111 @@ class TestServedLoopTrace:
         origin = served["trace"]["otherData"]["origin_monotonic_ns"]
         assert isinstance(origin, int) and origin > 0
 
+    # -- ISSUE 36: the loop's decision recorded where it is made, and the
+    # feed's segments on rows of their own, as B/E pairs
+
+    @pytest.mark.parametrize("arg", [
+        "woke", "locked_ms", "since_start_ms", "held_ms",
+    ])
+    def test_every_sleep_says_what_the_loop_decided(self, served, arg):
+        sleeps = [e for e in served["spans"] if e["name"] == "Loop/sleep"]
+        assert len(sleeps) >= self.WAVES
+        for sleep in sleeps:
+            assert arg in sleep["args"], sleep
+            if arg != "woke":
+                assert sleep["args"][arg] >= 0.0
+        if arg == "since_start_ms":
+            # the wait ended no sooner than it began: the tick before it,
+            # and the sleep itself, lie between `started` and the wake
+            for sleep in sleeps:
+                assert sleep["args"][arg] >= sleep["dur"] / 1000.0 - 1e-6
+
+    def test_a_demand_wake_kept_the_spacing_rule(self, served):
+        from scheduler_plugins_tpu.__main__ import DEMAND_TICK_SPACING
+
+        demand = [
+            e["args"] for e in served["spans"]
+            if e["name"] == "Loop/sleep" and e["args"]["woke"] == "demand"
+        ]
+        assert demand  # every wave's first pod rang the bell
+        for args in demand:
+            assert args["since_start_ms"] >= (
+                DEMAND_TICK_SPACING * args["locked_ms"] - 1e-6
+            ), args
+            # a pod waited, and no longer than since the tick before
+            assert 0.0 < args["held_ms"] <= args["since_start_ms"] + 1e-6
+
+    def test_an_interval_wake_with_no_pod_held_nothing(self, served):
+        idle = [
+            e["args"] for e in served["spans"]
+            if e["name"] == "Loop/sleep" and e["args"]["woke"] == "interval"
+        ]
+        assert any(args["held_ms"] == 0.0 for args in idle), idle
+
+    def test_feed_segments_pair_up_on_rows_of_their_own(self, served):
+        from tools.trace_smoke import validate_trace
+
+        events = served["trace"]["traceEvents"]
+        assert validate_trace(served["trace"]) == []
+        rows = {
+            e["tid"]: e["args"]["name"] for e in events if e["ph"] == "M"
+        }
+        paired = [e for e in events if e["ph"] in ("B", "E")]
+        assert paired and len(paired) % 2 == 0
+        assert {rows[e["tid"]] for e in paired} == {"feed/0"}
+        assert {e["name"] for e in paired} == {"Feed/segment"}
+        open_at = None
+        for e in paired:  # B, E, B, E ... each E at or after its B
+            if e["ph"] == "B":
+                assert open_at is None
+                open_at = e["ts"]
+            else:
+                assert open_at is not None and e["ts"] >= open_at
+                open_at = None
+        assert open_at is None
+        begun = [e["args"] for e in paired if e["ph"] == "B"]
+        # every event the client sent is in one segment
+        sent = 4 + self.WAVES * self.PODS_A_WAVE
+        assert sum(a["events"] for a in begun) >= sent
+        # the client waited for each wave to bind: quiet gaps between them
+        assert sum(1 for a in begun if a["quiet_before_us"] > 0) >= 1
+
+    def test_no_x_event_is_on_a_feed_row(self, served):
+        # `benchmark/harness/tracing.HostSpans.stop()` keeps the X events
+        # and reads them as one thread's: the feed's rows hold none, and
+        # the X events alone still nest or are disjoint
+        from tools.trace_smoke import validate_trace
+
+        events = served["trace"]["traceEvents"]
+        feed_rows = {
+            e["tid"] for e in events
+            if e["ph"] == "M" and e["args"]["name"].startswith("feed/")
+        }
+        assert feed_rows
+        assert not [e for e in served["spans"] if e["tid"] in feed_rows]
+        one_row = [dict(e, tid=0) for e in events if e["ph"] in ("X", "M")]
+        assert validate_trace({"traceEvents": one_row}) == []
+
+    @pytest.mark.parametrize("stage", ["write", "turnaround"])
+    def test_metrics_expose_the_feeds_way_out_and_back(self, served, stage):
+        key = 'scheduler_feed_event_ns_total{stage="%s"} ' % stage
+        lines = [
+            line for line in served["metrics"].splitlines()
+            if line.startswith(key)
+        ]
+        assert lines and float(lines[0].split()[-1]) > 0
+
+    def test_metrics_expose_the_quiet_gaps_and_the_hold(self, served):
+        text = served["metrics"]
+        assert "# TYPE scheduler_feed_quiet_ms histogram" in text
+        assert "# TYPE scheduler_tick_hold_ms histogram" in text
+        holds = sum(
+            float(line.split()[-1]) for line in text.splitlines()
+            if line.startswith("scheduler_tick_hold_ms_count{")
+        )
+        assert holds >= self.WAVES  # one observation a wait
+        assert 'scheduler_tick_hold_ms_count{woke="demand"}' in text
+
 
 class TestServedTrimaran:
     """The load-aware profile on the served path (ISSUE 29): the load

@@ -287,9 +287,17 @@ def test_the_wait_is_one_span_that_says_what_ended_it(loop):
         obs.tracer.stop()
     sleeps = [e for e in obs.tracer.export()["traceEvents"]
               if e.get("name") == "Loop/sleep"]
-    assert [e["args"] for e in sleeps] == [
-        {"woke": "interval"}, {"woke": "demand"},
-    ]
+    assert [e["args"]["woke"] for e in sleeps] == ["interval", "demand"]
+    # ISSUE 36: and what the decision rested on
+    for e in sleeps:
+        assert set(e["args"]) == {
+            "woke", "locked_ms", "since_start_ms", "held_ms",
+        }
+        assert e["args"]["locked_ms"] == pytest.approx(1.0)
+    idle, rung = (e["args"] for e in sleeps)
+    assert idle["held_ms"] == 0.0 and idle["since_start_ms"] >= 50.0
+    # the ring came before the wait began: the pod waited all of it
+    assert rung["held_ms"] >= rung["since_start_ms"] >= 6 * 1.0 - 1e-6
 
 
 # --- ISSUE 31: `Finalize` outside the feed lock and the spacing clock ------

@@ -734,3 +734,275 @@ class TestFeedStageCounters:
         else:
             assert TALLY_FLUSH_EVENTS <= delta["events"] <= n
         assert delta["codec"] > 0 and delta["apply"] > 0
+
+    # -- ISSUE 36: a TCP connection's wall clock, from its first byte in
+    # hand to its last ack flushed, is tiled by six parts: `codec`,
+    # `lock_wait`, `apply`, `write`, `turnaround` and the quiet gaps (over
+    # `FEED_QUIET_NS`). With the tracer on, each flush is one B/E pair on
+    # the connection's `feed/<n>` row.
+
+    @staticmethod
+    def _feed_registry():
+        from scheduler_plugins_tpu.utils import observability as obs
+
+        hists = obs.metrics.histograms().get(
+            obs.FEED_QUIET_MS, {"count": 0, "sum": 0.0})
+        return {
+            "events": obs.metrics.get(obs.FEED_EVENTS),
+            "write": obs.metrics.get(obs.FEED_EVENT_NS, stage="write"),
+            "turnaround": obs.metrics.get(
+                obs.FEED_EVENT_NS, stage="turnaround"),
+            "quiet_count": hists["count"],
+            "quiet_sum_ms": hists["sum"],
+            "stalls": obs.metrics.get(obs.FEED_STALLS),
+        }
+
+    @classmethod
+    def _registry_after(cls, before, events, timeout_s=10.0):
+        import time
+
+        deadline = time.monotonic() + timeout_s
+        while True:
+            now = cls._feed_registry()
+            delta = {k: now[k] - before[k] for k in now}
+            if delta["events"] >= events or time.monotonic() > deadline:
+                return delta
+            time.sleep(0.01)
+
+    @staticmethod
+    def _segments(trace):
+        """[(row, begin event, end event), ...] of the export's B/E pairs."""
+        rows = {
+            e["tid"]: e["args"]["name"]
+            for e in trace["traceEvents"] if e["ph"] == "M"
+        }
+        out, began = [], {}
+        for e in trace["traceEvents"]:
+            if e["ph"] == "B":
+                assert e["tid"] not in began, "a B inside a B"
+                began[e["tid"]] = e
+            elif e["ph"] == "E":
+                out.append((rows[e["tid"]], began.pop(e["tid"]), e))
+        assert not began, began
+        return out
+
+    def _drive(self, monkeypatch, client_class, sends, pauses=()):
+        """One connection: `sends` acknowledged `sync` events, sleeping
+        `pauses[i]` seconds before event i. Returns the connection's
+        recording tally once its handler has closed it."""
+        import time
+
+        from scheduler_plugins_tpu.bridge import feed
+
+        made: list = []
+        monkeypatch.setattr(feed, "FeedTally", _recording_tally(made))
+        pauses = dict(pauses)
+        server = FeedServer(Cluster()).start()
+        try:
+            before = self._feed_registry()
+            client = client_class(*server.address)
+            for i in range(sends):
+                if i in pauses:
+                    time.sleep(pauses[i])
+                assert client.send({"op": "sync"})["ok"]
+            client.close()
+            delta = self._registry_after(before, sends)
+        finally:
+            server.stop()
+        assert delta["events"] == sends
+        assert len(made) == 1
+        return made[0], delta
+
+    @pytest.mark.parametrize("transport", ["lines", "framed"])
+    @pytest.mark.parametrize("sends", [1, 33, 100])
+    def test_six_parts_tile_the_connections_wall_clock(
+            self, monkeypatch, transport, sends):
+        from scheduler_plugins_tpu.bridge.feed import FramedFeedClient
+
+        client_class = FeedClient if transport == "lines" else FramedFeedClient
+        tally, delta = self._drive(
+            monkeypatch, client_class, sends, pauses={sends // 2: 0.02})
+        wall = tally.mark_ns - tally.first_ns
+        assert wall > 0
+        # exactly: the stamps are contiguous, nothing is measured twice
+        # and nothing falls between two parts (`turnaround` is what each
+        # flushed stretch leaves: the registry's integers hold it)
+        assert (sum(tally.parts.values()) + delta["turnaround"]
+                + sum(tally.gaps)) == wall
+        assert tally.parts["write"] > 0 and delta["turnaround"] > 0
+        assert delta["write"] == tally.parts["write"]
+        assert delta["quiet_count"] == len(tally.gaps)
+        assert delta["quiet_sum_ms"] == pytest.approx(
+            sum(tally.gaps) / 1e6, rel=1e-9, abs=1e-9)
+
+    @pytest.mark.parametrize("pause_s, quiet, bursts", [
+        (0.02, 1, 2),   # the client had nothing to send: a quiet gap
+        (0.001, 0, 1),  # the client answered: turnaround
+    ])
+    def test_a_pause_is_quiet_or_turnaround_by_its_length(
+            self, monkeypatch, pause_s, quiet, bursts):
+        from scheduler_plugins_tpu.bridge.feed import FEED_QUIET_NS
+        from scheduler_plugins_tpu.utils import observability as obs
+        from tools.trace_smoke import validate_trace
+
+        for _attempt in range(5):
+            obs.tracer.start()  # clears the events
+            try:
+                tally, delta = self._drive(
+                    monkeypatch, FeedClient, 2, pauses={1: pause_s})
+                trace = obs.tracer.export()
+            finally:
+                obs.tracer.stop()
+            # a loaded runner can oversleep 1 ms by 4: try again
+            if quiet or not tally.gaps:
+                break
+        assert delta["quiet_count"] == quiet and len(tally.gaps) == quiet
+        assert delta["stalls"] == 0
+        if quiet:
+            assert tally.gaps[0] >= 20_000_000 > FEED_QUIET_NS
+        else:
+            assert delta["turnaround"] >= 1_000_000
+        assert validate_trace(trace) == []
+        segments = self._segments(trace)
+        assert segments and {row for row, _b, _e in segments} == {"feed/0"}
+        assert all(b["name"] == "Feed/segment" for _r, b, _e in segments)
+        # consecutive segments with no quiet gap before them are one burst
+        assert 1 + sum(
+            1 for _r, b, _e in segments if b["args"]["quiet_before_us"] > 0
+        ) == bursts
+        assert sum(b["args"]["events"] for _r, b, _e in segments) == 2
+        for _row, b, e in segments:
+            args = b["args"]
+            # a segment's length is its busy time and its turnaround
+            assert (e["ts"] - b["ts"]) == pytest.approx(
+                args["busy_us"] + args["turnaround_us"], abs=0.01)
+            assert 0 <= args["lock_wait_us"] <= args["busy_us"]
+        if quiet:
+            assert segments[-1][1]["args"]["quiet_before_us"] == (
+                pytest.approx(tally.gaps[0] / 1000.0))
+
+    @pytest.mark.parametrize("gap_ns, part", [
+        (5_000_000, "turnaround"),      # FEED_QUIET_NS itself still answers
+        (5_000_001, "quiet"),
+        (999_999_999, "quiet"),
+        (1_000_000_000, "stall"),
+    ])
+    def test_the_quiet_boundary_on_a_scripted_clock(
+            self, monkeypatch, gap_ns, part):
+        import threading
+        import types
+
+        from scheduler_plugins_tpu.bridge import feed
+
+        assert feed.FEED_QUIET_NS == 5_000_000
+        assert feed.FEED_STALL_NS == 1_000_000_000
+        ticks = iter(range(1_000, 10_000_000, 1_000))
+        clock = types.SimpleNamespace(perf_counter_ns=lambda: next(ticks))
+        monkeypatch.setattr(feed, "time", clock)
+        before = self._feed_registry()
+        tally = feed.FeedTally(row="feed/t")  # stamps 1_000
+        lock, cluster = threading.Lock(), Cluster()
+        feed.apply_raw(tally, b'{"op": "sync"}', cluster, lock, None)
+        tally.wrote()
+        flushed = tally.mark_ns  # 1_000 after the first byte, 6 stamps on
+        assert flushed == 7_000
+        # the next line comes `gap_ns` after that flush
+        ticks = iter(range(flushed + gap_ns, flushed + gap_ns + 10**6, 1_000))
+        feed.apply_raw(tally, b'{"op": "sync"}', cluster, lock, None)
+        tally.wrote()
+        tally.close()
+        delta = self._registry_after(before, 2)
+        assert delta["events"] == 2
+        if part == "turnaround":
+            assert delta["turnaround"] == 1_000 + gap_ns
+            assert delta["quiet_count"] == 0 and delta["stalls"] == 0
+        else:
+            assert delta["turnaround"] == 1_000
+            assert delta["quiet_count"] == 1
+            assert delta["quiet_sum_ms"] == pytest.approx(gap_ns / 1e6)
+            assert delta["stalls"] == (1 if part == "stall" else 0)
+
+    @pytest.mark.parametrize("rpc", ["unary", "stream"])
+    def test_grpc_keeps_its_three_stages(self, monkeypatch, rpc):
+        pytest.importorskip("grpc")
+        from scheduler_plugins_tpu.bridge import feed
+        from scheduler_plugins_tpu.bridge.grpc_feed import (
+            GrpcFeedClient,
+            GrpcFeedServer,
+        )
+        from scheduler_plugins_tpu.utils import observability as obs
+
+        obs.tracer.start()
+        server = GrpcFeedServer(Cluster()).start()
+        try:
+            before = self._feed_registry()
+            client = GrpcFeedClient("127.0.0.1", server.port)
+            if rpc == "stream":
+                n = 5
+                client.send_batch([{"op": "sync"}] * n)
+            else:
+                n = 8 * feed.TALLY_FLUSH_EVENTS
+                for _ in range(n):
+                    client.send({"op": "sync"})
+            delta = self._registry_after(
+                before, n if rpc == "stream" else feed.TALLY_FLUSH_EVENTS)
+            client.close()
+            trace = obs.tracer.export()
+        finally:
+            server.stop()
+            obs.tracer.stop()
+        assert delta["events"] >= min(n, feed.TALLY_FLUSH_EVENTS)
+        # the library owns its reads and writes: no stamp around them
+        assert delta["write"] == 0 and delta["turnaround"] == 0
+        assert delta["quiet_count"] == 0 and delta["stalls"] == 0
+        assert [e for e in trace["traceEvents"] if e["ph"] in "BE"] == []
+
+    def test_tracer_off_a_flush_records_nothing(self, monkeypatch):
+        from scheduler_plugins_tpu.utils import observability as obs
+
+        obs.tracer.stop()
+
+        def refuse(*_args, **_kwargs):
+            raise AssertionError("the tracer is off: no record is made")
+
+        monkeypatch.setattr(obs.tracer, "complete", refuse)
+        from scheduler_plugins_tpu.bridge.feed import FeedTally
+
+        slots = FeedTally.__slots__
+        tally, _delta = self._drive(
+            monkeypatch, FeedClient, 40, pauses={20: 0.01})
+        assert len(tally.gaps) >= 1
+        # and the tally holds numbers and its row's name, nothing that grows
+        for slot in slots:
+            assert isinstance(getattr(tally, slot), (int, str)), slot
+
+
+def _recording_tally(made: list):
+    """A `FeedTally` subclass that keeps, in integer nanoseconds,
+    everything its instances hand to the registry: the stage sums of every
+    flush but `turnaround` (which `flush` works out), every quiet gap,
+    and the first stamp (`mark_ns` is the last).
+    Each instance is appended to `made`."""
+    from scheduler_plugins_tpu.bridge import feed
+
+    class Recording(feed.FeedTally):
+        __slots__ = ("first_ns", "parts", "gaps")
+
+        def __init__(self, row=None):
+            super().__init__(row)
+            self.first_ns = self.mark_ns
+            self.parts = {s: 0 for s in (
+                "codec", "lock_wait", "apply", "write")}
+            self.gaps = []
+            made.append(self)
+
+        def flush(self, now_ns):
+            for stage in self.parts:
+                self.parts[stage] += getattr(self, f"{stage}_ns")
+            super().flush(now_ns)
+
+        def quiet(self, gap_ns, now_ns):
+            self.gaps.append(gap_ns)
+            super().quiet(gap_ns, now_ns)
+
+    return Recording

@@ -252,11 +252,13 @@ class TestServedLoopNames:
     @pytest.mark.parametrize("name", [
         obs.FEED_EVENTS, obs.FEED_EVENT_NS, obs.HEALTHZ_HANDLER_MS,
         obs.TICKS, obs.TICK_WAKEUPS, obs.TICK_LOCKED,
+        obs.FEED_QUIET_MS, obs.FEED_STALLS, obs.TICK_HOLD,
     ])
     def test_help_covers_the_new_names(self, name):
         assert name.startswith("scheduler_") and obs.HELP[name]
         m = obs.Metrics()
-        if name in (obs.HEALTHZ_HANDLER_MS, obs.TICK_LOCKED):
+        if name in (obs.HEALTHZ_HANDLER_MS, obs.TICK_LOCKED,
+                    obs.FEED_QUIET_MS, obs.TICK_HOLD):
             m.observe_ms(name, 1.5)
             kind = "histogram"
         else:
@@ -265,6 +267,55 @@ class TestServedLoopNames:
         text = m.prometheus_text()
         assert f"# HELP {name} {obs.HELP[name]}" in text
         assert f"# TYPE {name} {kind}" in text
+
+    @pytest.mark.parametrize("name", [
+        "scheduler_flightrec_cycles_total", "scheduler_lane_commit_ms",
+    ])
+    def test_names_nobody_read_are_gone(self, name):
+        # ISSUE 36: registered and incremented, named by no document,
+        # test or benchmark entry; `LaneStats.fence_ms` and the recorder's
+        # ring say the same
+        assert name not in obs.HELP
+        assert name not in {
+            v for k, v in vars(obs).items()
+            if k.isupper() and isinstance(v, str)
+        }
+
+    def test_a_paired_span_is_a_b_and_an_e_and_no_x(self):
+        # what the feed's rows hold: readers of the X events take those for
+        # one thread's, and a feed thread's spans overlap the tick's
+        obs.tracer.start()
+        obs.tracer.complete("Cycle", 1_000, 9_000, tid="cycle")
+        obs.tracer.complete("Feed/segment", 2_000, 5_000, tid="feed/0",
+                            args={"events": 3}, paired=True)
+        obs.tracer.complete("Feed/segment", 7_000, -5, tid="feed/0",
+                            paired=True)
+        obs.tracer.stop()
+        trace = obs.tracer.export()
+        assert validate_trace(trace) == []
+        assert [e["name"] for e in trace["traceEvents"] if e["ph"] == "X"] == [
+            "Cycle"]
+        pairs = [e for e in trace["traceEvents"] if e["ph"] in ("B", "E")]
+        assert [(e["ph"], e["ts"]) for e in pairs] == [
+            ("B", 2.0), ("E", 7.0), ("B", 7.0), ("E", 7.0)]
+        assert pairs[0]["args"] == {"events": 3}
+        assert all("args" not in e for e in pairs[1:])
+        assert len({e["tid"] for e in pairs}) == 1
+        assert all("dur" not in e for e in pairs)
+
+    def test_a_paired_span_costs_nothing_while_the_tracer_is_off(self):
+        obs.tracer.start()
+        obs.tracer.stop()
+        obs.tracer.complete("Feed/segment", 0, 10, tid="feed/0", paired=True)
+        assert [e for e in obs.tracer.export()["traceEvents"]] == []
+
+    def test_origin_ns_turns_a_callers_stamp_into_the_tracers(self):
+        import time
+
+        obs.tracer.start()
+        stamp = time.perf_counter_ns()
+        assert 0 <= stamp - obs.tracer.origin_ns <= obs.tracer.now_ns()
+        obs.tracer.stop()
 
     @pytest.mark.parametrize("name", ["Cycle", "PendingScan", "Finalize"])
     def test_traced_cycle_records_the_span_on_the_cycle_row(self, name):
