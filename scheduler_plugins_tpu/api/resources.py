@@ -71,11 +71,17 @@ class ResourceIndex:
     def position(self, name: str) -> int:
         return self._pos[name]
 
-    def encode(self, quantities: Mapping[str, int], default: int = 0) -> np.ndarray:
-        vec = np.full(len(self._names), default, dtype=np.int64)
+    def slots(self, quantities: Mapping[str, int], default: int = 0) -> list[int]:
+        """`encode` as a plain list: for a caller that lowers several
+        mappings and makes one array of them. Unknown names raise."""
+        vals = [default] * len(self._names)
+        pos = self._pos
         for name, qty in quantities.items():
-            vec[self._pos[name]] = int(qty)
-        return vec
+            vals[pos[name]] = int(qty)
+        return vals
+
+    def encode(self, quantities: Mapping[str, int], default: int = 0) -> np.ndarray:
+        return np.array(self.slots(quantities, default), dtype=np.int64)
 
     def decode(self, vec: np.ndarray) -> dict[str, int]:
         return {name: int(vec[i]) for i, name in enumerate(self._names) if vec[i]}

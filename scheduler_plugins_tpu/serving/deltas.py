@@ -41,9 +41,9 @@ from __future__ import annotations
 import numpy as np
 from flax import struct
 
-from scheduler_plugins_tpu.api.resources import PODS, ResourceIndex
+from scheduler_plugins_tpu.api.resources import ResourceIndex
 from scheduler_plugins_tpu.resilience import faults as _faults
-from scheduler_plugins_tpu.state.snapshot import NodeState, nonzero_request
+from scheduler_plugins_tpu.state.snapshot import NodeState, usage_rows
 from scheduler_plugins_tpu.utils.intmath import bucket_size
 
 #: the axis an engine starts from: the canonical four (what the C++
@@ -51,8 +51,6 @@ from scheduler_plugins_tpu.utils.intmath import bucket_size
 #: (`ServeEngine.index`) is this plus the extended resources its store
 #: names, taken at a rebase; a cluster without any never leaves this one
 CANON_INDEX = ResourceIndex(())
-#: the canonical names come first on every axis: one slot for all of them
-PODS_I = CANON_INDEX.position(PODS)
 
 I64 = np.int64
 I32 = np.int32
@@ -83,19 +81,18 @@ def pod_usage_vectors(
     pod, index: ResourceIndex
 ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
     """(requested, nonzero_requested, limits) contribution of ONE assigned
-    pod to its node's usage columns — the exact per-pod accumulation
-    `build_snapshot` performs: nonzero defaults applied, limits clamped to
-    >= requests per pod (SetMaxLimits), and the pods slot carrying the
-    count contribution (1) on the requested/nonzero columns (the snapshot
-    overwrites those slots with pod_count). Raises `UnsupportedResource`
-    on a resource outside `index`."""
-    req = _encode(pod.effective_request(), index)
-    nz = nonzero_request(req, index)
-    lim = np.maximum(_encode(pod.effective_limits(), index), req)
-    req = req.copy()
-    req[PODS_I] = 1
-    nz[PODS_I] = 1
-    return req, nz, lim
+    pod to its node's usage columns, lowered cold from the pod's dicts
+    (`state.snapshot.usage_rows` holds the arithmetic; the serving
+    engine reads the same vectors off the pod's `PodRecord`). Raises
+    `UnsupportedResource` on a resource outside `index`."""
+    try:
+        rows = usage_rows(
+            index.slots(pod.effective_request()),
+            index.slots(pod.effective_limits()), index,
+        )
+    except KeyError as exc:
+        raise UnsupportedResource(str(exc)) from exc
+    return tuple(np.array(rows, dtype=I64))
 
 
 # ---------------------------------------------------------------------------
@@ -128,6 +125,12 @@ GANG_GATED = "gang_gated"
 #: so a dropped, doubled or reordered event cannot add a pod twice. Sent
 #: only while the store holds a load watcher's report
 BINDING_TOUCHED = "binding_touched"
+#: an UNHELD pod object left the store (a pending pod deleted, or replaced
+#: by an upsert): no column moves, but the engine's `PodRecord` of it has no
+#: reader left. Carries the uid alone; the engine drops the entry only where
+#: the store no longer holds the recorded object. A held pod needs none: its
+#: `POD_UNASSIGN` says the same
+POD_FORGET = "pod_forget"
 
 
 class DeltaSink:
@@ -216,6 +219,10 @@ class DeltaSink:
     # -- resident node metrics -----------------------------------------
     def binding_touched(self, uid: str) -> None:
         self._push((BINDING_TOUCHED, uid))
+
+    # -- per-pod records -------------------------------------------------
+    def pod_forgotten(self, uid: str) -> None:
+        self._push((POD_FORGET, uid))
 
     # -- sticky compatibility flags -------------------------------------
     def note_nomination(self, pod) -> None:
@@ -332,22 +339,30 @@ class UsageDeltas:
 
     @classmethod
     def pack(cls, rows: list[tuple], R: int) -> "UsageDeltas":
-        """`rows`: [(slot, req_vec, nz_vec, lim_vec, d_count, d_term)]
-        where the vectors already carry the event's sign."""
-        K = bucket_size(max(len(rows), 1), minimum=cls.MIN_BUCKET)
+        """`rows`: [(slot, usage, sign, d_term)]: `usage` the pod's (3, R)
+        (requested, nonzero, limits) block as its `PodRecord` holds it,
+        `sign` +1 for an assign, -1 for an unassign and 0 for an event
+        without a resource payload, `d_term` what the event adds to the
+        terminating count. The blocks are stacked and signed in one call,
+        not a row at a time."""
+        n = len(rows)
+        K = bucket_size(max(n, 1), minimum=cls.MIN_BUCKET)
         idx = np.zeros(K, I32)
-        requested = np.zeros((K, R), I64)
-        nonzero = np.zeros((K, R), I64)
-        limits = np.zeros((K, R), I64)
+        usage = np.zeros((K, 3, R), I64)
         pod_count = np.zeros(K, I32)
         terminating = np.zeros(K, I32)
-        for j, (slot, req, nz, lim, d_count, d_term) in enumerate(rows):
-            idx[j] = slot
-            requested[j] = req
-            nonzero[j] = nz
-            limits[j] = lim
-            pod_count[j] = d_count
-            terminating[j] = d_term
+        if n:
+            slots, blocks, signs, terms = zip(*rows)
+            idx[:n] = slots
+            pod_count[:n] = signs
+            terminating[:n] = terms
+            np.multiply(
+                np.concatenate(blocks).reshape(n, 3, R),
+                pod_count[:n, None, None], out=usage[:n],
+            )
+        requested, nonzero, limits = (
+            np.ascontiguousarray(part) for part in usage.transpose(1, 0, 2)
+        )
         return cls(idx, requested, nonzero, limits, pod_count, terminating)
 
     def as_args(self) -> tuple:

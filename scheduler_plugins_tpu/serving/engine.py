@@ -56,6 +56,7 @@ and serving resumes without a rebase once the side objects go away.
 from __future__ import annotations
 
 import heapq
+import time
 from typing import Optional
 
 import numpy as np
@@ -66,6 +67,7 @@ from scheduler_plugins_tpu.state.snapshot import (
     ClusterSnapshot,
     GangState,
     MetricsState,
+    PodRecord,
     QuotaState,
     SnapshotMeta,
     _Interner,
@@ -197,6 +199,24 @@ class ServeEngine:
         #: same drained events; inert while no pod of the store declares a
         #: spread constraint
         self._selectors = ResidentSelectors(self)
+        # -- per-pod records (ISSUE 37; docs/SERVING.md) ------------------
+        #: uid -> `PodRecord`: a pod object's spec lowered once, read by
+        #: the batch's axis test and its assembly, by the classification of
+        #: its assign and unassign events and by the cadenced anti-entropy
+        #: check. An entry follows the store's object: dropped with the
+        #: pod's last event (`POD_UNASSIGN` or `POD_FORGET` of an object the
+        #: store no longer holds), wholesale when the axis changes, and
+        #: rebuilt for the assigned population by every rebase
+        self._records: dict = {}
+        #: lookups since the last flush to
+        #: `scheduler_serve_pod_lowerings_total`, by (reader, result)
+        self._lookups: dict = {}
+
+    @property
+    def _vec_cache(self) -> dict:
+        """The record table under the name it had while it held the usage
+        vectors alone (tests/test_pipeline_cycle.py reads it)."""
+        return self._records
 
     @staticmethod
     def _verify_every_default() -> int:
@@ -237,6 +257,7 @@ class ServeEngine:
         self._ns_rows.clear()
         self._drop_metrics()
         self._selectors.reset()
+        self._records.clear()
 
     @property
     def generation(self) -> int:
@@ -350,7 +371,10 @@ class ServeEngine:
         the resident axis does not hold: the fresh snapshot's axis is the
         union of all of them (`build_snapshot`), so the next refresh
         rebases and widens. Nodes and assigned pods are caught where their
-        events are classified. O(G + Q + batch), objects only."""
+        events are classified. A pending pod answers with its record: one
+        that exists was lowered on this axis; a miss lowers the pod here,
+        once, and `_assemble` reads the record a moment later.
+        O(G + Q + batch), objects only."""
         index = self._index
         for pg in cluster.pod_groups.values():
             if pg.min_resources and any(
@@ -362,11 +386,11 @@ class ServeEngine:
                 r not in index for r in eq.max
             ):
                 return True
-        for pod in pending:
-            if any(r not in index for r in pod.effective_request()) or any(
-                r not in index for r in pod.effective_limits()
-            ):
-                return True
+        try:
+            for pod in pending:
+                self._record(pod, "batch")
+        except D.UnsupportedResource:
+            return True
         return False
 
     # -- the per-cycle entry --------------------------------------------
@@ -375,6 +399,12 @@ class ServeEngine:
         own the state (caller falls back to `Cluster.snapshot`). Drains
         the sink either way — deltas are absorbed even while falling
         back, so the resident columns never go stale."""
+        try:
+            return self._refresh(cluster, pending, now_ms)
+        finally:
+            self._flush_lookups()
+
+    def _refresh(self, cluster, pending, now_ms: int):
         with obs.tracer.span("ServeRefresh/drain", tid="serve"):
             events = self._sink.drain()
         obs.metrics.set_gauge(obs.SERVE_PENDING_DELTAS, len(events))
@@ -432,12 +462,15 @@ class ServeEngine:
         self._sync_metrics(cluster, now_ms)
         self._selectors.ensure(cluster, self._names, self._npad)
         self._refreshes += 1
-        if self._verify_pending or (
-            self.verify_every and self._refreshes % self.verify_every == 0
-        ):
+        divergence = None
+        if self._verify_pending:
+            # after a fault or a restore nothing of the delta path is
+            # trusted, the records included: the fresh-snapshot digest
             divergence = self.verify(cluster, now_ms)
-            if divergence is not None:
-                return self._rebase(cluster, pending, now_ms)
+        elif self.verify_every and self._refreshes % self.verify_every == 0:
+            divergence = self.verify_assigned(cluster, now_ms)
+        if divergence is not None:
+            return self._rebase(cluster, pending, now_ms)
         if (cluster.pod_groups or cluster.quotas) and not self._ensure_side(
             cluster
         ):
@@ -455,20 +488,76 @@ class ServeEngine:
         forcing a rebase."""
         return self._classify(events)
 
-    def _pod_vectors(self, pod, final=False):
+    # -- per-pod records ---------------------------------------------------
+    def _lower(self, pod) -> PodRecord:
+        """`pod`'s spec lowered on the engine's axis. Raises
+        `UnsupportedResource` where it names a resource outside it (no
+        record is made: the rebase that follows widens the axis)."""
+        try:
+            return PodRecord(pod, self._index, self._cluster.tlp_prediction)
+        except KeyError as exc:
+            raise D.UnsupportedResource(str(exc)) from exc
+
+    def _record(self, pod, reader: str, final: bool = False) -> PodRecord:
+        """`pod`'s record: the table's where it is valid for this pod
+        object, axis and TLP parameters (a hit), else lowered now and kept
+        (a miss). `final` marks the last event of a pod object the store
+        no longer holds: its entry is released, and a miss keeps none."""
+        rec = self._records.get(pod.uid)
+        if rec is not None and rec.valid(
+            pod, self._index, self._cluster.tlp_prediction
+        ):
+            self._looked(reader, "hit")
+            if final:
+                del self._records[pod.uid]
+            return rec
+        self._looked(reader, "miss")
+        if not final:
+            rec = self._records[pod.uid] = self._lower(pod)
+            return rec
+        if rec is not None and rec.pod is pod:
+            del self._records[pod.uid]  # its own, lowered on another axis
+        return self._lower(pod)
+
+    def _pod_vectors(self, pod, final=False, reader="classify"):
         """One pod's (requested, nonzero, limits, quota) contribution
         vectors — the node usage columns' per-pod arithmetic plus the
-        ElasticQuota `used` row's raw request encode. The streaming
-        subclass memoizes this per pod object (`final` marks the pod's
-        last event, releasing its entry)."""
-        return D.pod_usage_vectors(pod, self._index) + (
-            D.pod_quota_vector(pod, self._index),
-        )
+        ElasticQuota `used` row's raw request encode — read off its record
+        (`_record`)."""
+        return self._record(pod, reader, final).vectors
 
-    def _row_cache(self):
-        """Per-pod assembly memo passed to `build_pod_state` (None in the
-        base engine: every cycle lowers its batch from scratch)."""
-        return None
+    def _looked(self, reader: str, result: str, n: int = 1) -> None:
+        key = (reader, result)
+        self._lookups[key] = self._lookups.get(key, 0) + n
+
+    def _flush_lookups(self) -> None:
+        """One registry write per (reader, result) a refresh or a check,
+        not one a lookup."""
+        if self._lookups:
+            for (reader, result), n in self._lookups.items():
+                obs.metrics.inc(
+                    obs.SERVE_POD_LOWERINGS, n, reader=reader, result=result
+                )
+            self._lookups.clear()
+
+    def _prime_records(self, cluster) -> None:
+        """Records for the assigned population, on the store's REAL pod
+        objects (never `_assigned_pods`'s per-reserved copies: a copy-keyed
+        entry can never hit the identity check), and none for an object the
+        store no longer holds. A rebase is already O(cluster): paying the
+        lowerings here keeps the first cadenced check of a run from owning
+        them on a timed cycle."""
+        pods = cluster.pods
+        self._records = {
+            uid: rec for uid, rec in self._records.items()
+            if pods.get(uid) is rec.pod
+        }
+        try:
+            for pod in pods.values():
+                if pod.node_name is not None or pod.uid in cluster.reserved:
+                    self._record(pod, "rebase")
+        except D.UnsupportedResource:
+            pass  # outside the axis: the cadenced check falls through
 
     def _stage_args(self, args):
         """Host->device staging of one packed delta batch. The base
@@ -512,8 +601,9 @@ class ServeEngine:
         index = self._index
         R = len(index)
         # events without a resource payload (terminating flips)
-        zero = np.zeros(R, np.int64)
+        zero = np.zeros((3, R), np.int64)
         rebase = None
+        store = self._cluster.pods
 
         def fail(reason):
             nonlocal rebase
@@ -547,6 +637,11 @@ class ServeEngine:
                 continue
             if kind == D.BINDING_TOUCHED:
                 self._touched.add(ev[1])
+                continue
+            if kind == D.POD_FORGET:
+                rec = self._records.get(ev[1])
+                if rec is not None and store.get(ev[1]) is not rec.pod:
+                    del self._records[ev[1]]
                 continue
             if kind == D.NODE_DELETE:
                 # the row order dies with the node — but so do its label/
@@ -622,12 +717,16 @@ class ServeEngine:
                     if slot is None:
                         fail("unknown-node")
                         continue
-                    usage.append((slot, zero, zero, zero, 0, 1))
+                    usage.append((slot, zero, 0, 1))
                     continue
                 sign = 1 if kind == D.POD_ASSIGN else -1
                 try:
-                    req, nz, lim, qreq = self._pod_vectors(
-                        pod, final=kind == D.POD_UNASSIGN
+                    # the entry goes with the last event of an object
+                    # the store no longer holds (a released reservation
+                    # leaves the pod, and its record, where they are)
+                    rec = self._record(
+                        pod, "classify",
+                        final=sign < 0 and store.get(pod.uid) is not pod,
                     )
                 except D.UnsupportedResource:
                     fail("extended-resource")
@@ -637,11 +736,11 @@ class ServeEngine:
                 # node existence (build_snapshot's rule); gang slack only
                 # when the node is known (fresh drops unknown-node slack)
                 if self._quota_tracking:
-                    ns_add(pod.namespace, sign * qreq, sign)
+                    ns_add(pod.namespace, sign * rec.req, sign)
                 if gang:
                     gang_add(
                         f"{pod.namespace}/{gang}", sign, 0,
-                        sign * req if slot is not None else None,
+                        sign * rec.vectors[0] if slot is not None else None,
                     )
                 if slot is None:
                     # pod referenced a node the engine never saw (cross-
@@ -655,10 +754,7 @@ class ServeEngine:
                 # queues its own +1 — a drain-time read would double-count
                 term = 1 if ev[3] else 0
                 self._selectors.pod_event(pod, slot, sign)
-                usage.append((
-                    slot, sign * req, sign * nz, sign * lim, sign,
-                    sign * term,
-                ))
+                usage.append((slot, rec.usage, sign, sign * term))
         side = (
             [(row, a, g, s) for row, (a, g, s) in gang_acc.items()],
             [(row, u, c) for row, (u, c) in ns_acc.items()],
@@ -870,6 +966,7 @@ class ServeEngine:
         # layout (padded axes, the registry's row order), so that the solve
         # of the cold build is the program of every cycle after it
         self._selectors.rebuild(cluster, self._names, npad)
+        self._prime_records(cluster)
         if snap.scheduling is not None:
             snap = snap.replace(scheduling=self._assemble_selectors(
                 cluster, pending, snap.num_pods
@@ -890,8 +987,9 @@ class ServeEngine:
         return snap, meta
 
     def _axis_changed(self) -> None:
-        """The axis widened: per-pod vectors memoized on the old one are
-        the wrong length (the streaming engine drops its memo)."""
+        """The axis widened: every record lowered on the old one is the
+        wrong length."""
+        self._records.clear()
 
     # -- resident gang/quota side tables --------------------------------
     def _ensure_side(self, cluster) -> bool:
@@ -902,12 +1000,13 @@ class ServeEngine:
             return True
         return self._rebuild_side_tables(cluster)
 
-    def _scan_side_aggregates(self, cluster):
+    def _scan_side_aggregates(self, cluster, vectors):
         """ONE store scan producing the gang/quota aggregate dicts a
         fresh `build_snapshot` would accumulate: {gang full_name:
         [assigned, gated, slack_vec]} + {namespace: [used_vec, count]}.
-        Shared by the rebuild (packs them resident) and the anti-entropy
-        verify (compares them against the resident copies). Raises
+        Shared by the rebuild (packs them resident; `vectors` reads the
+        records) and the fresh-snapshot verify (compares them against the
+        resident copies; `vectors` lowers every pod cold). Raises
         `UnsupportedResource` when an assigned pod names a resource
         outside the axis (the next rebase widens it)."""
         R = len(self._index)
@@ -924,7 +1023,7 @@ class ServeEngine:
             held = pod.node_name or cluster.reserved.get(pod.uid)
             gang = pod.pod_group()
             if held is not None:
-                req, _nz, _lim, qreq = self._pod_vectors(pod)
+                req, _nz, _lim, qreq = vectors(pod)
                 if self._quota_tracking:
                     acc = namespaces.get(pod.namespace)
                     if acc is None:
@@ -967,7 +1066,9 @@ class ServeEngine:
             pods=len(cluster.pods),
         ):
             try:
-                gangs, namespaces = self._scan_side_aggregates(cluster)
+                gangs, namespaces = self._scan_side_aggregates(
+                    cluster, lambda pod: self._pod_vectors(pod, reader="side")
+                )
             except D.UnsupportedResource:
                 self._side_dirty = True
                 return False
@@ -1054,16 +1155,23 @@ class ServeEngine:
         return None
 
     def _verify_side(self, cluster) -> Optional[str]:
-        """Anti-entropy over the gang/quota side tables: recompute the
-        expected aggregates from the store (independent of the delta
-        path) and compare to the resident copies. Skipped — costing
-        nothing — while no gang/quota state is live. (The streaming
-        engine folds the expectation into its single `_expected_columns`
-        pass instead of paying a second store scan.)"""
+        """Anti-entropy over the gang/quota side tables for the
+        fresh-snapshot verify: recompute the expected aggregates from the
+        store, every pod lowered cold (independent of the delta path and of
+        the records), and compare to the resident copies. Skipped — costing
+        nothing — while no gang/quota state is live. (The cadenced check
+        folds the expectation into its single `_expected_columns` pass.)"""
         if not self._side_verify_live(cluster):
             return None
+        index = self._index
+
+        def cold(pod):
+            return D.pod_usage_vectors(pod, index) + (
+                D.pod_quota_vector(pod, index),
+            )
+
         try:
-            gangs, namespaces = self._scan_side_aggregates(cluster)
+            gangs, namespaces = self._scan_side_aggregates(cluster, cold)
         except D.UnsupportedResource:
             return "axis-width"
         return self._side_divergence(gangs, namespaces)
@@ -1217,56 +1325,62 @@ class ServeEngine:
 
     def verify(self, cluster, now_ms: Optional[int] = None
                ) -> Optional[str]:
-        """Anti-entropy digest: blake2b over the canonical tensor bytes
-        of the resident node columns (the flight-recorder content-address
-        scheme) vs the same columns of a freshly built snapshot, and the
-        resident metrics columns vs that snapshot's. Returns
-        a divergence reason (caller re-bases) or None (resident state is
-        byte-exact). O(cluster) host work — cadenced by `verify_every`,
-        forced by `note_fault`; a corrupted or dropped delta can
-        therefore poison at most one verification window
-        (tests/test_resilience.py::TestAntiEntropy). The snapshot is
-        taken at `now_ms`, by default the clock of the last refresh: what
-        the unreported-CPU column holds depends on it."""
-        from scheduler_plugins_tpu.utils import flightrec
+        """Anti-entropy digest against a freshly built snapshot: blake2b
+        over the canonical tensor bytes of the resident node columns (the
+        flight-recorder content-address scheme) vs the same columns of
+        `cluster.snapshot`, the resident metrics columns vs that
+        snapshot's, the side tables vs a cold store scan, the selector
+        tables vs the store. Returns a divergence reason (caller re-bases)
+        or None (resident state is byte-exact). O(cluster) host work and
+        nothing of the delta path in its expectation, the pod records
+        included: the check `note_fault` and a checkpoint restore force,
+        and the one an audit asks for by name
+        (tests/test_resilience.py::TestAntiEntropy). The snapshot is taken
+        at `now_ms`, by default the clock of the last refresh: what the
+        unreported-CPU column holds depends on it."""
+        return self._checked(cluster, now_ms, fast=False)
 
+    def verify_assigned(self, cluster, now_ms: Optional[int] = None
+                        ) -> Optional[str]:
+        """The cadenced anti-entropy check (every `verify_every` refreshes):
+        the same digests as `verify` and the same verdicts, with the
+        expected node columns accumulated O(nodes + assigned) straight
+        from the store's objects (`_expected_columns`) instead of through
+        an O(cluster) snapshot rebuild — byte-identical expectations by
+        construction (tests/test_resilience.py holds the two kinds to one
+        verdict on clean and on corrupted state). Independence: the
+        resident columns were built through the sink and the device, the
+        expectation comes from the store; the one thing both read is the
+        `PodRecord` of a pod object that was not replaced since it was
+        lowered. A corrupted or dropped delta can therefore poison at most
+        one verification window. A store this path cannot describe (a
+        resource outside the axis) gets the fresh-snapshot check, which
+        names it, inside the same span and count."""
+        return self._checked(cluster, now_ms, fast=True)
+
+    def _checked(self, cluster, now_ms: Optional[int], fast: bool
+                 ) -> Optional[str]:
+        """One anti-entropy check of either kind: one count, one
+        `ServeRefresh/verify` span whose `fast` says which kind ran, one
+        `scheduler_serve_verify_ms{kind}` observation."""
         if now_ms is None:
             now_ms = self._metrics_now
-
+        start = time.perf_counter_ns()
         with obs.tracer.span(
-            "ServeRefresh/verify", tid="serve", staleness=self._staleness
-        ):
+            "ServeRefresh/verify", tid="serve", staleness=self._staleness,
+            fast=fast,
+        ) as said:
             self._verify_pending = False
             obs.metrics.inc(obs.ANTIENTROPY_CHECKS)
-            if self._nodes is None:
-                return None
-            fresh, meta = cluster.snapshot(
-                [], now_ms=now_ms, pad_nodes=self._npad,
-                extra_resources=self._extended(),
-            )
             reason = None
-            if meta.index.names != self._index.names:
-                # the store names a resource the axis does not hold
-                reason = "axis-width"
-            elif list(meta.node_names) != self._names:
-                reason = "row-order"
-            else:
-                mine = flightrec._pack_digest(
-                    {k: np.asarray(v)
-                     for k, v in self._node_columns().items()}
-                )
-                theirs = flightrec._pack_digest(
-                    {k: np.asarray(getattr(fresh.nodes, k))
-                     for k in self._node_columns()}
-                )
-                if mine != theirs:
-                    reason = "column-digest"
-            if reason is None:
-                reason = self._metrics_divergence(fresh.metrics)
-            if reason is None:
-                reason = self._verify_side(cluster)
-            if reason is None:
-                reason = self._selectors.divergence(cluster, self._names)
+            if self._nodes is not None:
+                if fast:
+                    try:
+                        reason = self._divergence_assigned(cluster, now_ms)
+                    except D.UnsupportedResource:
+                        fast = said["fast"] = False
+                if not fast:
+                    reason = self._divergence_snapshot(cluster, now_ms)
             if reason is not None:
                 self.antientropy_divergences += 1
                 obs.metrics.inc(obs.ANTIENTROPY_DIVERGENCE)
@@ -1276,7 +1390,226 @@ class ServeEngine:
                     f" (last fault: {self.last_fault})"
                     if self.last_fault else "",
                 )
-            return reason
+        obs.metrics.observe_ms(
+            obs.SERVE_VERIFY_MS, (time.perf_counter_ns() - start) / 1e6,
+            kind="assigned" if fast else "snapshot",
+        )
+        self._flush_lookups()
+        return reason
+
+    def _columns_digest(self) -> str:
+        from scheduler_plugins_tpu.utils import flightrec
+
+        return flightrec._pack_digest(
+            {k: np.asarray(v) for k, v in self._node_columns().items()}
+        )
+
+    def _divergence_snapshot(self, cluster, now_ms: int) -> Optional[str]:
+        """`verify`'s comparison: everything against a fresh snapshot."""
+        from scheduler_plugins_tpu.utils import flightrec
+
+        fresh, meta = cluster.snapshot(
+            [], now_ms=now_ms, pad_nodes=self._npad,
+            extra_resources=self._extended(),
+        )
+        if meta.index.names != self._index.names:
+            # the store names a resource the axis does not hold
+            return "axis-width"
+        if list(meta.node_names) != self._names:
+            return "row-order"
+        if self._columns_digest() != flightrec._pack_digest(
+            {k: np.asarray(getattr(fresh.nodes, k))
+             for k in self._node_columns()}
+        ):
+            return "column-digest"
+        return (
+            self._metrics_divergence(fresh.metrics)
+            or self._verify_side(cluster)
+            or self._selectors.divergence(cluster, self._names)
+        )
+
+    def _divergence_assigned(self, cluster, now_ms: int) -> Optional[str]:
+        """`verify_assigned`'s comparison: the same things in the same
+        order, against the store's objects. Raises `UnsupportedResource`
+        where an assigned pod names a resource outside the axis."""
+        from scheduler_plugins_tpu.utils import flightrec
+
+        names = list(cluster.nodes)
+        if names != self._names:
+            return "row-order"
+        expected, side_exp = self._expected_columns(
+            cluster, names, want_side=self._side_verify_live(cluster)
+        )
+        if self._columns_digest() != flightrec._pack_digest(expected):
+            return "column-digest"
+        reason = self._metrics_divergence(
+            self._expected_metrics(cluster, now_ms)
+        )
+        if reason is None and side_exp is not None:
+            reason = self._side_divergence(*side_exp)
+        return reason or self._selectors.divergence(cluster, self._names)
+
+    def _expected_metrics(self, cluster, now_ms: int):
+        """The `MetricsState` a fresh `build_snapshot` at this padding and
+        clock would produce, from the store's own merge
+        (`Cluster._metrics_with_missing`) and the shared lowering: nothing
+        of the delta path is read. Staged as the resident columns and a
+        fresh snapshot's are: the digest compares what the device holds,
+        and a float64 column read back from a TPU is not, bit for bit, the
+        host array that was put there (`PERF.md` finding 40). None where
+        the store holds no report."""
+        merged = cluster._metrics_with_missing(now_ms)
+        if merged is None:
+            return None
+        node_pos = {name: i for i, name in enumerate(cluster.nodes)}
+        return self._stage_pods(MetricsState(
+            **node_metric_columns(merged, node_pos, self._npad)
+        ))
+
+    def _expected_columns(self, cluster, names, want_side=False):
+        """The node columns a fresh `build_snapshot` at this padding
+        would produce, accumulated O(nodes + assigned): every assigned pod
+        adds its record's `usage` block at its node's row (requested/
+        nonzero carry the pods-count slot per pod, so their sums equal the
+        snapshot's pod_count overwrite), stacked and added in one call.
+        With `want_side`, the SAME pass also accumulates the expected
+        gang/quota side aggregates (`_scan_side_aggregates` semantics —
+        one store walk covers both verifications); returns
+        (columns, (gangs, namespaces) | None)."""
+        index = self._index
+        R = len(index)
+        side_gangs: dict = {}
+        side_ns: dict = {}
+
+        def side_gang_acc(name):
+            acc = side_gangs.get(name)
+            if acc is None:
+                acc = side_gangs[name] = [0, 0, np.zeros(R, np.int64)]
+            return acc
+
+        def side_assigned(pod, held, rec):
+            if self._quota_tracking:
+                acc = side_ns.get(pod.namespace)
+                if acc is None:
+                    acc = side_ns[pod.namespace] = [
+                        np.zeros(R, np.int64), 0,
+                    ]
+                acc[0] = acc[0] + rec.req
+                acc[1] += 1
+            gang = pod.pod_group()
+            if gang:
+                acc = side_gang_acc(f"{pod.namespace}/{gang}")
+                acc[0] += 1
+                if held in cluster.nodes:
+                    acc[2] = acc[2] + rec.usage[0]
+        npad = self._npad
+        alloc = np.zeros((npad, R), np.int64)
+        capacity = np.zeros((npad, R), np.int64)
+        mask = np.zeros(npad, bool)
+        region = np.full(npad, -1, np.int32)
+        zone = np.full(npad, -1, np.int32)
+        # fresh first-seen label interning in store order (NOT the
+        # engine's surviving tables): this keeps the label-drift check
+        # the fresh-snapshot verify performs — deleting the first-seen
+        # carrier of a code diverges here and rebases
+        regions: dict = {}
+        zones: dict = {}
+        node_pos = {}
+        for i, node in enumerate(cluster.nodes.values()):
+            node_pos[node.name] = i
+            alloc[i] = D._encode(node.allocatable, index)
+            capacity[i] = D._encode(node.capacity, index)
+            mask[i] = not node.unschedulable
+            if node.region:
+                region[i] = regions.setdefault(node.region, len(regions))
+            if node.zone:
+                zone[i] = zones.setdefault(node.zone, len(zones))
+        # the assigned view, on the REAL pod objects: bound pods at their
+        # node plus reserved (permit-waiting) pods at their held node —
+        # the same definition `Cluster._assigned_pods` materializes, but
+        # without its per-reserved-pod copies (a copy would miss the
+        # record's identity check and evict the real pod's entry on every
+        # check)
+        records = self._records
+        tlp = cluster.tlp_prediction
+        rows: list = []  # the node row of each assigned pod ...
+        blocks: list = []  # ... and its (3, R) usage block
+        terminating_rows: list = []
+        hits = 0
+
+        def record_of(pod):
+            # `_record`, with its hit spelled out: this runs once an
+            # assigned pod and a method call each would be a tenth of it
+            nonlocal hits
+            rec = records.get(pod.uid)
+            if (
+                rec is None or rec.pod is not pod
+                or rec.index is not index or rec.tlp != tlp
+            ):
+                return self._record(pod, "check")
+            hits += 1
+            return rec
+
+        def held_at(pod, i, rec):
+            rows.append(i)
+            blocks.append(rec.usage)
+            if pod.deletion_ms is not None:
+                terminating_rows.append(i)
+
+        for pod in cluster.pods.values():
+            node_name = pod.node_name
+            if node_name is None:
+                if want_side:
+                    # the `gated_pods()` predicate, INDEPENDENT of a
+                    # permit reservation: a reserved gated pod counts
+                    # both gated (here) and assigned (the reserved
+                    # loop), exactly like the fresh snapshot and the
+                    # delta stream (`_scan_side_aggregates`)
+                    gang = pod.pod_group()
+                    if (
+                        gang and pod.scheduling_gated
+                        and not pod.terminating
+                    ):
+                        side_gang_acc(f"{pod.namespace}/{gang}")[1] += 1
+                continue
+            i = node_pos.get(node_name)
+            if i is None:
+                if want_side:
+                    # bound to a node the store no longer has: still
+                    # counts into quota used + gang assigned (never
+                    # slack) — build_snapshot's rule
+                    side_assigned(pod, node_name, record_of(pod))
+                continue
+            rec = record_of(pod)
+            held_at(pod, i, rec)
+            if want_side:
+                side_assigned(pod, node_name, rec)
+        for uid, node in cluster.reserved.items():
+            pod = cluster.pods.get(uid)
+            if pod is None or pod.node_name is not None:
+                continue
+            rec = record_of(pod)
+            if want_side:
+                side_assigned(pod, node, rec)
+            i = node_pos.get(node)
+            if i is not None:
+                held_at(pod, i, rec)
+        self._looked("check", "hit", hits)
+        usage = np.zeros((npad, 3, R), np.int64)
+        at = np.array(rows, np.intp)
+        if rows:
+            np.add.at(usage, at, np.concatenate(blocks).reshape(-1, 3, R))
+        requested, nonzero, limits = usage.transpose(1, 0, 2)
+        # same key order as _node_columns so the digests align
+        return {
+            "alloc": alloc, "capacity": capacity, "requested": requested,
+            "nonzero_requested": nonzero, "limits": limits,
+            "mask": mask, "region": region, "zone": zone,
+            "pod_count": np.bincount(at, minlength=npad).astype(np.int32),
+            "terminating": np.bincount(
+                np.array(terminating_rows, np.intp), minlength=npad
+            ).astype(np.int32),
+        }, ((side_gangs, side_ns) if want_side else None)
 
     # -- checkpoint / restore -------------------------------------------
     #: checkpoint format version (bump on layout change; restore refuses
@@ -1464,7 +1797,7 @@ class ServeEngine:
                 gang_code = gang_of
             pods = self._stage_pods(build_pod_state(
                 pending, P, index, ns_in, gang_code,
-                cluster.tlp_prediction, row_cache=self._row_cache(),
+                cluster.tlp_prediction, row_cache=self._records,
             ))
         gang_state = quota_state = None
         if pod_groups or cluster.quotas:
@@ -1623,11 +1956,11 @@ def _shift_gather_args(npad: int, slot: int, survivors: int):
 
 
 class StreamingServeEngine(ServeEngine):
-    """O(changed)-everything serving engine for the pipelined cycle
-    engine (`framework.pipeline_cycle.PipelinedCycle`; docs/SCALING.md
-    measured breakdown). Same exactness contract as the base engine —
-    the differential gates hold it bit-identical to fresh snapshots —
-    with three streaming-ingest upgrades:
+    """The serving engine of the pipelined cycle engine
+    (`framework.pipeline_cycle.PipelinedCycle`; docs/SCALING.md measured
+    breakdown). Same exactness contract as the base engine — the
+    differential gates hold it bit-identical to fresh snapshots — and what
+    is its own:
 
     - **Node-delete compaction**: a Node/Delete no longer forces the
       O(cluster) rebase. The resident rows are shift-compacted in place
@@ -1642,54 +1975,27 @@ class StreamingServeEngine(ServeEngine):
       compaction, so deleting the first-seen carrier of a label code can
       make the next anti-entropy digest diverge from a fresh re-intern —
       the divergence rebases (exact, just slower), never mis-serves.
-    - **Usage-vector memo**: `pod_usage_vectors` is cached per pod
-      OBJECT (a feed upsert replaces the object wholesale, naturally
-      invalidating); a pod's final unassign releases its entry.
-    - **Pod-row memo**: `build_pod_state` runs with a per-pod row cache,
-      so retried pods re-lower nothing (hits are bit-identical by
-      construction — the cache stores the same encodes the cold path
-      computes).
-    """
+    - **Staging**: packed deltas and pod tensors go to pjit as numpy
+      (`_stage_args`, `_stage_pods`).
+    - **`verify` is the O(nodes + assigned) check** whoever asks: the
+      engine compacts rows in place, and every check of it reads the
+      store's objects (`ServeEngine.verify_assigned`).
 
-    #: safety valve on the memo tables (not a tuning knob): beyond this
-    #: many entries the caches clear wholesale and rebuild from misses
-    MAX_CACHE = 1 << 16
+    The per-pod records and the O(assigned) expectation it used to
+    override are the base engine's since ISSUE 37.
+    """
 
     def __init__(self):
         super().__init__()
         self._compact_fn = D.node_compact_program()
         self._compact_warm: set = set()
-        self._vec_cache: dict = {}
-        self._rows: dict = {}
         #: node-delete row compactions performed (each replaces what the
         #: base engine counts as a rebase)
         self.compactions = 0
 
-    # -- memo seams ------------------------------------------------------
-    def _row_cache(self):
-        if len(self._rows) > self.MAX_CACHE:
-            self._rows.clear()
-        return self._rows
-
-    def _pod_vectors(self, pod, final=False):
-        ent = self._vec_cache.get(pod.uid)
-        if ent is not None and ent[0] is pod:
-            if final:
-                del self._vec_cache[pod.uid]
-            return ent[1]
-        vecs = D.pod_usage_vectors(pod, self._index) + (
-            D.pod_quota_vector(pod, self._index),
-        )
-        if final:
-            self._vec_cache.pop(pod.uid, None)
-        else:
-            if len(self._vec_cache) > self.MAX_CACHE:
-                self._vec_cache.clear()
-            self._vec_cache[pod.uid] = (pod, vecs)
-        return vecs
-
-    def _axis_changed(self) -> None:
-        self._vec_cache.clear()
+    def verify(self, cluster, now_ms: Optional[int] = None
+               ) -> Optional[str]:
+        return self.verify_assigned(cluster, now_ms)
 
     def _stage_args(self, args):
         # pjit stages numpy args itself in one C++ pass; the explicit
@@ -1703,18 +2009,6 @@ class StreamingServeEngine(ServeEngine):
 
     def _rebase_inner(self, cluster, pending, now_ms: int):
         out = super()._rebase_inner(cluster, pending, now_ms)
-        # prime the usage-vector memo for the whole assigned population:
-        # a rebase is already O(cluster), and paying the per-pod encodes
-        # here keeps the FIRST O(assigned) verify from owning them on a
-        # timed cycle (every later verify then runs at memo speed). Prime
-        # on the REAL pod objects (never `_assigned_pods`'s per-reserved
-        # copies — a copy-keyed entry can never hit the identity check)
-        try:
-            for pod in cluster.pods.values():
-                if pod.node_name is not None or pod.uid in cluster.reserved:
-                    self._pod_vectors(pod)
-        except D.UnsupportedResource:
-            pass  # outside the axis: verify falls back to base anyway
         if self._nodes is not None and self._npad not in self._compact_warm:
             # compile the compaction program for this resident shape NOW,
             # on a throwaway zero-state (NEVER the live carry — the
@@ -1788,217 +2082,6 @@ class StreamingServeEngine(ServeEngine):
         ups, use, side, seg_rebase = self._classify(segment)
         side = (side_gang + side[0], side_ns + side[1])
         return ups, use, side, rebase if rebase is not None else seg_rebase
-
-    # -- O(assigned) anti-entropy ---------------------------------------
-    def verify(self, cluster, now_ms: Optional[int] = None
-               ) -> Optional[str]:
-        """Anti-entropy digest without the O(cluster) snapshot rebuild:
-        the expected node columns are accumulated directly from the store
-        objects through the SAME shared per-pod encode
-        (`pod_usage_vectors`, memoized per pod object) and per-node
-        encode the fresh snapshot would use, then digest-compared to the
-        resident columns — byte-identical expectations by construction
-        (tests/test_pipeline_cycle.py::TestStreamingVerify holds this
-        against the base engine's fresh-snapshot verify on clean AND
-        corrupted state). Independence is preserved: the resident
-        columns were built through the sink+device path, the expectation
-        comes straight from the store objects. Anything outside the
-        engine's axis falls back to the base engine's full verify, which
-        classifies it exactly."""
-        from scheduler_plugins_tpu.utils import flightrec
-
-        if self._nodes is None:
-            self._verify_pending = False
-            obs.metrics.inc(obs.ANTIENTROPY_CHECKS)
-            return None
-        if now_ms is None:
-            now_ms = self._metrics_now
-        names = list(cluster.nodes)
-        expected = side_exp = metrics_exp = None
-        if names == self._names:
-            try:
-                expected, side_exp = self._expected_columns(
-                    cluster, names, want_side=self._side_verify_live(cluster)
-                )
-            except D.UnsupportedResource:
-                # a resource outside the axis somewhere: delegate to the
-                # base engine's fresh-snapshot verify, which names it,
-                # BEFORE opening this path's span/counter (one check =
-                # one count, one span)
-                return super().verify(cluster, now_ms)
-            metrics_exp = self._expected_metrics(cluster, now_ms)
-        with obs.tracer.span(
-            "ServeRefresh/verify", tid="serve", staleness=self._staleness,
-            fast=True,
-        ):
-            self._verify_pending = False
-            obs.metrics.inc(obs.ANTIENTROPY_CHECKS)
-            reason = None
-            if expected is None:
-                reason = "row-order"
-            else:
-                mine = flightrec._pack_digest(
-                    {k: np.asarray(v)
-                     for k, v in self._node_columns().items()}
-                )
-                theirs = flightrec._pack_digest(expected)
-                if mine != theirs:
-                    reason = "column-digest"
-            if reason is None and expected is not None:
-                reason = self._metrics_divergence(metrics_exp)
-            if reason is None and side_exp is not None:
-                reason = self._side_divergence(*side_exp)
-            if reason is None and expected is not None:
-                reason = self._selectors.divergence(cluster, self._names)
-            if reason is not None:
-                self.antientropy_divergences += 1
-                obs.metrics.inc(obs.ANTIENTROPY_DIVERGENCE)
-                obs.logger.warning(
-                    "serve anti-entropy divergence (%s) after %d delta "
-                    "events%s: re-basing", reason, self._staleness,
-                    f" (last fault: {self.last_fault})"
-                    if self.last_fault else "",
-                )
-            return reason
-
-    def _expected_metrics(self, cluster, now_ms: int):
-        """The `MetricsState` a fresh `build_snapshot` at this padding and
-        clock would produce, from the store's own merge
-        (`Cluster._metrics_with_missing`) and the shared lowering: nothing
-        of the delta path is read. None where the store holds no report."""
-        merged = cluster._metrics_with_missing(now_ms)
-        if merged is None:
-            return None
-        node_pos = {name: i for i, name in enumerate(cluster.nodes)}
-        return MetricsState(
-            **node_metric_columns(merged, node_pos, self._npad)
-        )
-
-    def _expected_columns(self, cluster, names, want_side=False):
-        """The node columns a fresh `build_snapshot` at this padding
-        would produce, accumulated O(nodes + assigned) — the exact
-        per-pod arithmetic rides the shared `pod_usage_vectors`
-        (requested/nonzero carry the pods-count slot per pod, so their
-        sums equal the snapshot's pod_count overwrite). With
-        `want_side`, the SAME pass also accumulates the expected
-        gang/quota side aggregates (`_scan_side_aggregates` semantics —
-        one store walk covers both verifications); returns
-        (columns, (gangs, namespaces) | None)."""
-        index = self._index
-        R = len(index)
-        side_gangs: dict = {}
-        side_ns: dict = {}
-
-        def side_gang_acc(name):
-            acc = side_gangs.get(name)
-            if acc is None:
-                acc = side_gangs[name] = [0, 0, np.zeros(R, np.int64)]
-            return acc
-
-        def side_assigned(pod, held, req, qreq):
-            if self._quota_tracking:
-                acc = side_ns.get(pod.namespace)
-                if acc is None:
-                    acc = side_ns[pod.namespace] = [
-                        np.zeros(R, np.int64), 0,
-                    ]
-                acc[0] = acc[0] + qreq
-                acc[1] += 1
-            gang = pod.pod_group()
-            if gang:
-                acc = side_gang_acc(f"{pod.namespace}/{gang}")
-                acc[0] += 1
-                if held in cluster.nodes:
-                    acc[2] = acc[2] + req
-        npad = self._npad
-        alloc = np.zeros((npad, R), np.int64)
-        capacity = np.zeros((npad, R), np.int64)
-        requested = np.zeros((npad, R), np.int64)
-        nonzero = np.zeros((npad, R), np.int64)
-        limits = np.zeros((npad, R), np.int64)
-        mask = np.zeros(npad, bool)
-        region = np.full(npad, -1, np.int32)
-        zone = np.full(npad, -1, np.int32)
-        pod_count = np.zeros(npad, np.int32)
-        terminating = np.zeros(npad, np.int32)
-        # fresh first-seen label interning in store order (NOT the
-        # engine's surviving tables): this keeps the label-drift check
-        # the fresh-snapshot verify performs — deleting the first-seen
-        # carrier of a code diverges here and rebases
-        regions: dict = {}
-        zones: dict = {}
-        node_pos = {}
-        for i, node in enumerate(cluster.nodes.values()):
-            node_pos[node.name] = i
-            alloc[i] = D._encode(node.allocatable, index)
-            capacity[i] = D._encode(node.capacity, index)
-            mask[i] = not node.unschedulable
-            if node.region:
-                region[i] = regions.setdefault(node.region, len(regions))
-            if node.zone:
-                zone[i] = zones.setdefault(node.zone, len(zones))
-        # the assigned view, on the REAL pod objects: bound pods at their
-        # node plus reserved (permit-waiting) pods at their held node —
-        # the same definition `Cluster._assigned_pods` materializes, but
-        # without its per-reserved-pod copies (a copy would miss the
-        # usage-vector memo's identity check and evict the real pod's
-        # entry on every verify)
-        for pod in cluster.pods.values():
-            if pod.node_name is None:
-                if want_side:
-                    # the `gated_pods()` predicate, INDEPENDENT of a
-                    # permit reservation: a reserved gated pod counts
-                    # both gated (here) and assigned (the reserved
-                    # loop), exactly like the fresh snapshot and the
-                    # delta stream (`_scan_side_aggregates`)
-                    gang = pod.pod_group()
-                    if (
-                        gang and pod.scheduling_gated
-                        and not pod.terminating
-                    ):
-                        side_gang_acc(f"{pod.namespace}/{gang}")[1] += 1
-                continue
-            i = node_pos.get(pod.node_name)
-            if i is None:
-                if want_side:
-                    # bound to a node the store no longer has: still
-                    # counts into quota used + gang assigned (never
-                    # slack) — build_snapshot's rule
-                    req, _nz, _lim, qreq = self._pod_vectors(pod)
-                    side_assigned(pod, pod.node_name, req, qreq)
-                continue
-            req, nz, lim, qreq = self._pod_vectors(pod)
-            requested[i] += req
-            nonzero[i] += nz
-            limits[i] += lim
-            pod_count[i] += 1
-            if pod.terminating:
-                terminating[i] += 1
-            if want_side:
-                side_assigned(pod, pod.node_name, req, qreq)
-        for uid, node in cluster.reserved.items():
-            pod = cluster.pods.get(uid)
-            if pod is None or pod.node_name is not None:
-                continue
-            req, nz, lim, qreq = self._pod_vectors(pod)
-            if want_side:
-                side_assigned(pod, node, req, qreq)
-            i = node_pos.get(node)
-            if i is None:
-                continue
-            requested[i] += req
-            nonzero[i] += nz
-            limits[i] += lim
-            pod_count[i] += 1
-            if pod.terminating:
-                terminating[i] += 1
-        # same key order as _node_columns so the digests align
-        return {
-            "alloc": alloc, "capacity": capacity, "requested": requested,
-            "nonzero_requested": nonzero, "limits": limits,
-            "mask": mask, "region": region, "zone": zone,
-            "pod_count": pod_count, "terminating": terminating,
-        }, ((side_gangs, side_ns) if want_side else None)
 
     def _compact_row(self, name: str, slot: int) -> None:
         import warnings
@@ -2119,8 +2202,8 @@ def lower_program_args(n_nodes: int = 256, n_upserts: int = 8,
         R,
     )
     use = D.UsageDeltas.pack(
-        [(j % n_nodes, np.zeros(R, np.int64), np.zeros(R, np.int64),
-          np.zeros(R, np.int64), 0, 0) for j in range(n_deltas)],
+        [(j % n_nodes, np.zeros((3, R), np.int64), 0, 0)
+         for j in range(n_deltas)],
         R,
     )
     args = (

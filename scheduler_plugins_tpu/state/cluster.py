@@ -375,6 +375,8 @@ class Cluster:
             old_hold = self._held_node(old)
             if old_hold is not None:
                 self.delta_sink.pod_unassigned(old, old_hold)
+            elif old is not None:
+                self.delta_sink.pod_forgotten(pod.uid)
             # gated-gang-membership transition, captured at event time
             # (the upsert replaces the object wholesale)
             old_gated = self._gang_gated_key(old)
@@ -431,6 +433,8 @@ class Cluster:
                     # bound pod's usage leaves with it (a reserved pod's
                     # hold was already released above)
                     self.delta_sink.pod_unassigned(pod, pod.node_name)
+                else:
+                    self.delta_sink.pod_forgotten(uid)
                 gated = self._gang_gated_key(pod)
                 if gated is not None:
                     self.delta_sink.gang_gated(gated, -1)

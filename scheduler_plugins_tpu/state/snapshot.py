@@ -343,9 +343,11 @@ class _Interner:
         return self.pos.get(name, -1)
 
 
-def nonzero_request(req: np.ndarray, index: ResourceIndex) -> np.ndarray:
+def nonzero_request(req, index: ResourceIndex):
     """Apply the upstream non-zero defaults used for scoring accounting:
-    pods without cpu/memory requests are charged 100m / 200Mi."""
+    pods without cpu/memory requests are charged 100m / 200Mi. `req` is an
+    encoded vector or its plain list (`ResourceIndex.slots`); a copy of
+    the same kind comes back."""
     out = req.copy()
     cpu_i = index.position(CPU)
     mem_i = index.position(MEMORY)
@@ -356,32 +358,72 @@ def nonzero_request(req: np.ndarray, index: ResourceIndex) -> np.ndarray:
     return out
 
 
-class _PodRow:
-    """Cached per-pod lowering pieces for `build_pod_state` — everything
-    derivable from the pod SPEC alone (requests/limits encodes, container
-    rows, QoS, TLP prediction), keyed by pod object identity so a feed
-    upsert (which replaces the object wholesale) naturally invalidates.
-    Meta-dependent codes (namespace interning, gang code) and in-place
-    mutable flags (scheduling gate) are never cached."""
+def usage_rows(req: list, limits: list, index: ResourceIndex) -> list:
+    """[requested, nonzero_requested, limits]: the three rows that ONE
+    assigned pod adds to its node's usage columns, from its raw request and
+    limit slots (`ResourceIndex.slots`: plain lists in, plain lists out) —
+    the per-pod arithmetic of `build_snapshot`'s assigned loop: nonzero
+    defaults applied, limits clamped to >= requests (SetMaxLimits), and the
+    pods slot carrying the count contribution (1) on the requested/nonzero
+    rows (the snapshot overwrites those slots with pod_count)."""
+    requested = req.copy()
+    nonzero = nonzero_request(req, index)
+    requested[index.position(PODS)] = nonzero[index.position(PODS)] = 1
+    return [requested, nonzero, [max(l, r) for l, r in zip(limits, req)]]
+
+
+class PodRecord:
+    """One pod object lowered once: everything derivable from the pod SPEC
+    alone — the request and limit encodes, the container rows, QoS, the TLP
+    prediction (what `build_pod_state` reads) and the usage vectors (what
+    the serving engine's classification and its cadenced anti-entropy
+    check read; `req` is also the raw ElasticQuota vector). Keyed by uid in
+    its table, valid while `record.pod is pod`, the axis is the same object
+    and the TLP parameters are equal (`valid`): a feed upsert replaces the
+    pod object wholesale and so invalidates by itself. Meta-dependent codes
+    (namespace interning, gang code) and flags that mutate in place
+    (scheduling gate, terminating, node_name) are never recorded. Every
+    row is lowered as a plain list and the record makes ONE array of them
+    (a pod costs one numpy call, not one a vector); `req`, `limits`,
+    `usage` and `creq` are views of it, read-only: every reader copies or
+    adds out of them. Raises KeyError where the spec names a resource
+    outside `index`."""
 
     __slots__ = ("pod", "index", "tlp", "req", "limits", "predicted",
-                 "creq", "cinit", "qos")
+                 "creq", "cinit", "qos", "usage", "vectors")
 
     def __init__(self, pod, index, tlp_prediction):
         self.pod = pod
         self.index = index
         self.tlp = tlp_prediction
-        self.req = index.encode(pod.effective_request())
-        self.limits = index.encode(pod.effective_limits())
-        self.predicted = pod.tlp_predicted_cpu_millis(*tlp_prediction)
-        conts = list(pod.init_containers) + list(pod.containers)
-        self.creq = np.stack(
-            [index.encode(c.requests) for c in conts]
-        ) if conts else np.zeros((0, len(index)), I64)
-        self.cinit = np.array(
-            [c < len(pod.init_containers) for c in range(len(conts))], bool
+        req = index.slots(pod.effective_request())
+        limits = index.slots(pod.effective_limits())
+        n_init = len(pod.init_containers)
+        conts = [*pod.init_containers, *pod.containers]
+        block = np.array(
+            [req, limits, *usage_rows(req, limits, index),
+             *(index.slots(c.requests) for c in conts)],
+            dtype=I64,
         )
+        block.flags.writeable = False
+        self.req = block[0]
+        self.limits = block[1]
+        #: `usage_rows` as one (3, R) piece, so that the cadenced check
+        #: stacks the assigned population's in one call
+        self.usage = block[2:5]
+        self.creq = block[5:]
+        self.cinit = np.arange(len(conts)) < n_init
+        self.predicted = pod.tlp_predicted_cpu_millis(*tlp_prediction)
         self.qos = int(pod.qos_class())
+        #: (requested, nonzero, limits, quota): the usage rows and the raw
+        #: request encode, the tuple `ServeEngine._pod_vectors` hands out
+        self.vectors = (block[2], block[3], block[4], self.req)
+
+    def valid(self, pod, index, tlp_prediction) -> bool:
+        return (
+            self.pod is pod and self.index is index
+            and self.tlp == tlp_prediction
+        )
 
 
 def build_pod_state(
@@ -399,10 +441,10 @@ def build_pod_state(
     so the two paths produce bit-identical pod tensors by construction.
     `ns_in` interns namespace codes into the caller's meta table;
     `gang_of(pod) -> int` maps a pod to its gang code (-1 outside).
-    `row_cache` (uid -> `_PodRow`, the streaming serve engine's O(changed)
-    assembly) memoizes the spec-derived pieces across cycles for pods
-    that retry — entries re-derive whenever the pod object, resource axis
-    or TLP parameters differ, so a hit is bit-identical by construction."""
+    `row_cache` (uid -> `PodRecord`: the serving engine's record table)
+    memoizes the spec-derived pieces across cycles — entries re-derive
+    whenever the pod object, resource axis or TLP parameters differ, so a
+    hit is bit-identical by construction."""
     R = len(index)
     preq = np.zeros((P, R), I64)
     plimits = np.zeros((P, R), I64)
@@ -428,11 +470,10 @@ def build_pod_state(
         row = None
         if row_cache is not None:
             row = row_cache.get(pod.uid)
-            if (
-                row is None or row.pod is not pod or row.index is not index
-                or row.tlp != tlp_prediction
-            ):
-                row = row_cache[pod.uid] = _PodRow(pod, index, tlp_prediction)
+            if row is None or not row.valid(pod, index, tlp_prediction):
+                row = row_cache[pod.uid] = PodRecord(
+                    pod, index, tlp_prediction
+                )
         if row is not None:
             preq[i] = row.req
             plimits[i] = row.limits

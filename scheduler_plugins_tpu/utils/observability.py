@@ -428,6 +428,18 @@ ANTIENTROPY_CHECKS = "scheduler_serve_antientropy_checks_total"
 #: anti-entropy divergences detected (each forces a rebase — a corrupted
 #: or dropped delta can poison at most one verification window)
 ANTIENTROPY_DIVERGENCE = "scheduler_serve_antientropy_divergence_total"
+#: how long one anti-entropy check held the refresh, by `kind`: "assigned"
+#: (the cadenced check, O(nodes + assigned) from the store's objects) or
+#: "snapshot" (a fresh `Cluster.snapshot`: after a fault, a restore, or
+#: asked for through `ServeEngine.verify`). One observation a check
+SERVE_VERIFY_MS = "scheduler_serve_verify_ms"
+#: lookups of a pod's `PodRecord` (its spec lowered once; docs/SERVING.md
+#: "One record a pod") by `reader` ("batch": the pending batch's axis test,
+#: whose misses are the lowerings `build_pod_state` then reads; "classify":
+#: an assign or unassign event; "check": the cadenced anti-entropy check;
+#: "side": a side-table rebuild; "rebase": priming the assigned population)
+#: and `result` ("hit" | "miss": a miss lowers the spec)
+SERVE_POD_LOWERINGS = "scheduler_serve_pod_lowerings_total"
 #: unschedulable pods currently parked in a requeue backoff window
 #: (upstream backoffQ semantics; framework.cycle._requeue_eligible)
 REQUEUE_BACKOFF_SKIPS = "scheduler_requeue_backoff_skips_total"
@@ -609,6 +621,9 @@ HELP: dict[str, str] = {
         "Live threads unknown to the committed concurrency manifest.",
     ANTIENTROPY_CHECKS: "Anti-entropy digest checks of resident state.",
     ANTIENTROPY_DIVERGENCE: "Anti-entropy divergences detected.",
+    SERVE_VERIFY_MS: "Duration of one anti-entropy check, by kind.",
+    SERVE_POD_LOWERINGS:
+        "Per-pod record lookups of the serving engine, by reader and result.",
     REQUEUE_BACKOFF_SKIPS:
         "Requeue attempts skipped inside a backoff window.",
     CYCLE_OVERLAP_EFFICIENCY:

@@ -441,6 +441,251 @@ class TestAntiEntropy:
         assert engine.antientropy_divergences == 0
 
 
+def _full_store():
+    """A store with every kind of resident state, built without a solve:
+    zoned nodes, a load watcher's report, a PodGroup under an ElasticQuota
+    with bound members, bound pods under a zone-spread constraint, and a
+    second window of binds that reached the columns as deltas."""
+    from scheduler_plugins_tpu.api.objects import (
+        POD_GROUP_LABEL,
+        ZONE_LABEL,
+        ElasticQuota,
+        LabelSelector,
+        PodGroup,
+        TopologySpreadConstraint,
+    )
+
+    cluster = Cluster()
+    for i in range(6):
+        cluster.add_node(Node(
+            name=f"n{i:03d}",
+            allocatable={CPU: 8000, MEMORY: 32 * gib, PODS: 32},
+            labels={ZONE_LABEL: f"z{i % 3}"},
+        ))
+    cluster.node_metrics = {
+        name: {"cpu_avg": 10.0 + i, "mem_avg": 5.0}
+        for i, name in enumerate(cluster.nodes)
+    }
+    cluster.add_quota(ElasticQuota(
+        name="eq", namespace="team",
+        min={CPU: 24_000, MEMORY: 96 * gib},
+        max={CPU: 48_000, MEMORY: 160 * gib},
+    ))
+    cluster.add_pod_group(PodGroup(
+        name="g0", namespace="team", min_member=2, creation_ms=100,
+    ))
+    spread = TopologySpreadConstraint(
+        max_skew=1, topology_key=ZONE_LABEL,
+        label_selector=LabelSelector(match_labels={"app": "web"}),
+    )
+
+    def bind(serial, node, now, **kw):
+        pod = make_pod(serial, now=now, **kw)
+        cluster.add_pod(pod)
+        cluster.bind(pod.uid, node, now_ms=now)
+
+    def window(first, now):
+        bind(first, "n000", now)
+        bind(first + 1, "n001", now, namespace="team",
+             labels={POD_GROUP_LABEL: "g0"})
+        bind(first + 2, "n002", now, labels={"app": "web"},
+             topology_spread=[spread])
+
+    window(1, 500)
+    engine = ServeEngine().attach(cluster)
+    engine.verify_every = 0
+    assert engine.refresh(cluster, [], now_ms=1000) is not None  # cold build
+    window(11, 1500)
+    assert engine.refresh(cluster, [], now_ms=2000) is not None  # deltas
+    assert engine.rebases == 1
+    return cluster, engine
+
+
+def _plant_nothing(cluster, engine):
+    return None
+
+
+def _plant_dropped_delta(cluster, engine):
+    pod = make_pod(90, now=2100)
+    cluster.add_pod(pod)
+    cluster.bind(pod.uid, "n003", now_ms=2100)
+    engine._sink.drain()  # the window's events never reach the engine
+    return "column-digest"
+
+
+def _plant_corrupted_column(cluster, engine):
+    nodes = engine._nodes
+    engine._nodes = nodes.replace(
+        requested=nodes.requested.at[2, 0].add(1 << 20)
+    )
+    return "column-digest"
+
+
+def _plant_row_order(cluster, engine):
+    engine._names = list(reversed(engine._names))
+    return "row-order"
+
+
+def _plant_metrics_column(cluster, engine):
+    state = engine._metrics_state
+    cell = np.asarray(state.cpu_avg).copy()
+    cell[1] += 7
+    engine._metrics_state = state.replace(cpu_avg=cell)
+    return "metrics-digest"
+
+
+def _plant_gang_side_table(cluster, engine):
+    side = engine._side
+    engine._side = side.replace(
+        gang_assigned=side.gang_assigned.at[0].add(1)
+    )
+    return "side-gang"
+
+
+def _plant_quota_side_table(cluster, engine):
+    side = engine._side
+    engine._side = side.replace(quota_used=side.quota_used.at[0, 0].add(5))
+    return "side-quota"
+
+
+def _plant_selector_count(cluster, engine):
+    held = engine._selectors
+    held.track_base = held.track_base.at[0, 1].add(1)
+    return "selector-counts"
+
+
+PLANTED = {
+    "clean": _plant_nothing,
+    "dropped_delta": _plant_dropped_delta,
+    "corrupted_column": _plant_corrupted_column,
+    "row_order": _plant_row_order,
+    "metrics_column": _plant_metrics_column,
+    "gang_side_table": _plant_gang_side_table,
+    "quota_side_table": _plant_quota_side_table,
+    "selector_count": _plant_selector_count,
+}
+
+
+def _verify_spans(run):
+    """`run()` under the tracer: its `ServeRefresh/verify` spans' args and
+    the checks it counted."""
+    checks0 = obs.metrics.get(obs.ANTIENTROPY_CHECKS)
+    obs.tracer.start()
+    try:
+        out = run()
+    finally:
+        obs.tracer.stop()
+    spans = [
+        e["args"] for e in obs.tracer.export()["traceEvents"]
+        if e.get("name") == "ServeRefresh/verify"
+    ]
+    return out, spans, obs.metrics.get(obs.ANTIENTROPY_CHECKS) - checks0
+
+
+class TestAntiEntropyKinds:
+    """ISSUE 37: the cadenced check reads the store's objects through the
+    pod records (`verify_assigned`), the forced one a fresh snapshot
+    (`verify`); they compare the same things and say the same."""
+
+    @pytest.mark.parametrize("fault", sorted(PLANTED))
+    def test_both_kinds_return_the_same_verdict(self, fault):
+        cluster, engine = _full_store()
+        assert engine.verify_assigned(cluster) is None
+        assert engine.verify(cluster) is None
+        expected = PLANTED[fault](cluster, engine)
+        (fast, snapshot), spans, counted = _verify_spans(lambda: (
+            engine.verify_assigned(cluster), engine.verify(cluster),
+        ))
+        assert fast == snapshot == expected
+        # one check = one count, one span, and the span says which ran
+        assert counted == 2
+        assert [a["fast"] for a in spans] == [True, False]
+        assert engine.antientropy_divergences == (2 if expected else 0)
+
+    @pytest.mark.parametrize("fault", ["clean", "corrupted_column"])
+    def test_cadence_runs_the_assigned_kind_and_a_fault_the_snapshot(
+        self, fault
+    ):
+        cluster, engine = _full_store()
+        engine.verify_every = 1
+        expected = PLANTED[fault](cluster, engine)
+        scoped = obs.metrics.scoped()
+
+        def observed(kind):
+            return scoped.hist_count(obs.SERVE_VERIFY_MS, kind=kind)
+
+        out, spans, counted = _verify_spans(
+            lambda: engine.refresh(cluster, [], now_ms=3000)
+        )
+        assert out is not None and counted == 1
+        assert [a["fast"] for a in spans] == [True]
+        assert (observed("assigned"), observed("snapshot")) == (1, 0)
+        assert engine.rebases == (2 if expected else 1)
+        engine.verify_every = 0
+        engine.note_fault("test-fault")
+        out, spans, counted = _verify_spans(
+            lambda: engine.refresh(cluster, [], now_ms=4000)
+        )
+        assert out is not None and counted == 1
+        assert [a["fast"] for a in spans] == [False]
+        assert (observed("assigned"), observed("snapshot")) == (1, 1)
+        # the fault's check ran once: the next refresh runs none
+        out, spans, counted = _verify_spans(
+            lambda: engine.refresh(cluster, [], now_ms=5000)
+        )
+        assert (spans, counted) == ([], 0)
+
+    def test_a_resource_outside_the_axis_gets_the_snapshot_kind(self):
+        """A store the O(assigned) path cannot describe falls through to
+        the fresh snapshot inside the same span and count."""
+        cluster, engine = _full_store()
+        pod = Pod(
+            name="gpu", creation_ms=2100,
+            containers=[Container(requests={CPU: 100, "example.com/gpu": 1})],
+        )
+        pod.node_name = "n004"
+        cluster.add_pod(pod)
+        engine._sink.drain()  # the engine has not seen it yet
+        reason, spans, counted = _verify_spans(
+            lambda: engine.verify_assigned(cluster)
+        )
+        assert reason == "axis-width"
+        assert counted == 1 and [a["fast"] for a in spans] == [False]
+
+    @pytest.mark.parametrize("streaming", [False, True],
+                             ids=["base", "streaming"])
+    def test_the_metrics_expectation_is_staged_as_the_resident_columns(
+        self, streaming
+    ):
+        """The digest compares what the device holds: a float64 column read
+        back from a TPU is not bit for bit the host array put there, so the
+        cadenced check's expectation goes where the resident columns (and a
+        fresh snapshot's) went (`PERF.md` finding 40)."""
+        from scheduler_plugins_tpu.serving import StreamingServeEngine
+
+        cluster, _ = _full_store()
+        engine = (StreamingServeEngine if streaming else ServeEngine)()
+        engine.attach(cluster)
+        assert engine.refresh(cluster, [], now_ms=2500) is not None
+        expected = engine._expected_metrics(cluster, 2500)
+        for field in ("cpu_avg", "missing_cpu_millis", "cpu_valid"):
+            held = getattr(engine._metrics_state, field)
+            assert type(getattr(expected, field)) is type(held), field
+        assert engine.verify_assigned(cluster) is None
+
+    def test_the_check_reads_records_and_lowers_nothing(self):
+        cluster, engine = _full_store()
+        scoped = obs.metrics.scoped()
+        assert engine.verify_assigned(cluster) is None
+        held = sum(1 for p in cluster.pods.values() if p.node_name)
+        assert scoped.get(
+            obs.SERVE_POD_LOWERINGS, reader="check", result="hit"
+        ) == held
+        assert scoped.get(
+            obs.SERVE_POD_LOWERINGS, reader="check", result="miss"
+        ) == 0
+
+
 class TestCheckpointRestore:
     def _served_engine(self, scheduler, cluster):
         engine = ServeEngine().attach(cluster)

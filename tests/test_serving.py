@@ -1181,3 +1181,253 @@ class TestResidentMetrics:
             assert len(engine._recent) <= 3 * 30
             assert len(engine._recent_heap) <= 3 * 31
             assert_metrics_equal_fresh(engine, cluster, 2_000_000)
+
+
+# ---------------------------------------------------------------------------
+# one record a pod (ISSUE 37): the delta-equivalence differential, extended
+# to the per-pod records the base engine reads
+# ---------------------------------------------------------------------------
+
+
+def assert_records_follow_store(engine, cluster):
+    """The record table holds the pods alive and nothing else: every entry
+    is the record of the object the store holds under that uid, and every
+    pod that holds capacity (bound or reserved) has one."""
+    for uid, rec in engine._records.items():
+        assert cluster.pods.get(uid) is rec.pod, f"stale record {uid}"
+        assert rec.index is engine.index, uid
+    held = {
+        uid for uid, p in cluster.pods.items()
+        if p.node_name is not None or uid in cluster.reserved
+    }
+    assert held <= set(engine._records)
+
+
+def assert_columns_equal_fresh(engine, cluster, now):
+    """`assert_resident_matches` on whatever axis the engine holds."""
+    assert engine.refresh(cluster, [], now_ms=now) is not None
+    snap, _ = cluster.snapshot(
+        [], now_ms=now, pad_nodes=engine.npad,
+        extra_resources=engine._extended(),
+    )
+    for col in NODE_COLUMNS:
+        np.testing.assert_array_equal(
+            np.asarray(getattr(engine.resident_nodes, col)),
+            np.asarray(getattr(snap.nodes, col)),
+            err_msg=f"resident column {col} at {now} ms",
+        )
+
+
+def _records_bound_pod_replaced(c, engine, sched, check):
+    uid = bind_new(c, 1, "n000", 1000)
+    check(1500)
+    first = engine._records[uid]
+    bigger = make_pod(1, 1000, cpu=2500, mem=3 * gib)  # same uid
+    bigger.node_name = "n000"
+    c.add_pod(bigger)
+    check(2500)
+    assert engine._records[uid] is not first
+    assert engine._records[uid].pod is bigger
+    moved = make_pod(1, 1000, cpu=700)  # and again, onto another node
+    moved.node_name = "n003"
+    c.add_pod(moved)
+    check(3500)
+    c.remove_pod(uid)
+    check(4500)
+    assert uid not in engine._records
+
+
+def _records_terminate_flip_between_event_and_drain(c, engine, sched, check):
+    pod = make_pod(1, 500)
+    c.add_pod(pod)
+    c.bind(pod.uid, "n001", now_ms=1000)
+    c.mark_terminating(pod.uid, 1200)  # before the assign event is drained
+    check(1500)
+    record = engine._records[pod.uid]
+    other = bind_new(c, 2, "n001", 2000)
+    check(2500)
+    c.mark_terminating(other, 2600)
+    c.remove_pod(other)  # flip and delete inside one window
+    check(3500)
+    assert engine._records[pod.uid] is record  # a flag is no new spec
+    c.remove_pod(pod.uid)
+    check(4500)
+    assert not engine._records
+
+
+def _records_axis_widens_mid_run(c, engine, sched, check):
+    plain = bind_new(c, 1, "n000", 1000)
+    check(1500)
+    narrow = engine._records[plain]
+    rebases0 = engine.rebases
+    c.add_node(make_node(50, extra={EXT: 4}))
+    gpu = Pod(
+        name="gpu-pod", creation_ms=1600,
+        containers=[Container(requests={CPU: 100, EXT: 1})],
+    )
+    c.add_pod(gpu)
+    report = run_cycle(sched, c, now=2000, serve=engine)
+    assert gpu.uid in report.bound
+    assert engine.rebases == rebases0 + 1 and EXT in engine.index
+    check(2500)
+    # every record was lowered again on the wider axis
+    assert engine._records[plain] is not narrow
+    assert len(engine._records[plain].req) == len(engine.index)
+    assert engine._records[gpu.uid].req[engine.index.position(EXT)] == 1
+    c.remove_pod(gpu.uid)
+    c.remove_pod(plain)
+    check(3500)
+    assert not engine._records
+
+
+def _records_pod_deleted_while_pending(c, engine, sched, check):
+    big = make_pod(1, 500, cpu=64_000)  # fits nowhere: stays pending
+    c.add_pod(big)
+    c.add_pod(make_pod(2, 600))
+    report = run_cycle(sched, c, now=1000, serve=engine)
+    assert big.uid in report.failed
+    assert engine._records[big.uid].pod is big  # lowered with its batch
+    check(1500)
+    c.remove_pod(big.uid)  # no column moves: POD_FORGET alone says so
+    check(2500)
+    assert big.uid not in engine._records
+    # replaced while pending: the old object's record goes with it
+    waiting = make_pod(3, 2600, cpu=64_000)
+    c.add_pod(waiting)
+    run_cycle(sched, c, now=3000, serve=engine)
+    assert engine._records[waiting.uid].pod is waiting
+    smaller = make_pod(3, 2600, cpu=300)
+    c.add_pod(smaller)
+    check(3500)
+    assert waiting.uid not in engine._records
+    c.add_node(make_node(7))  # an event, and past backoff: it runs again
+    report = run_cycle(sched, c, now=60_000, serve=engine)
+    assert smaller.uid in report.bound
+    check(61_000)
+    assert engine._records[smaller.uid].pod is smaller
+
+
+def _records_reservation_released(c, engine, sched, check):
+    pod = make_pod(1, 500)
+    c.add_pod(pod)
+    c.reserve(pod.uid, "n002")
+    check(1500)
+    record = engine._records[pod.uid]
+    c.release_reservation(pod.uid)  # unassigned, and still in the store
+    check(2500)
+    assert engine._records[pod.uid] is record
+    c.reserve(pod.uid, "n004")
+    c.bind(pod.uid, "n004", now_ms=3000)
+    check(3500)
+    assert engine._records[pod.uid] is record
+    c.remove_pod(pod.uid)
+    check(4500)
+    assert not engine._records
+
+
+RECORD_CASES = {
+    "bound_pod_replaced_by_upsert": _records_bound_pod_replaced,
+    "terminate_flip_between_event_and_drain":
+        _records_terminate_flip_between_event_and_drain,
+    "axis_widens_mid_run": _records_axis_widens_mid_run,
+    "pod_deleted_while_pending": _records_pod_deleted_while_pending,
+    "reservation_released": _records_reservation_released,
+}
+
+
+class TestPodRecords:
+    """One record a pod object (ISSUE 37): after every step of a scripted
+    sequence the resident columns are bit-equal to a fresh snapshot, both
+    kinds of anti-entropy check agree that they are, and the record table
+    holds exactly the pods alive."""
+
+    @pytest.mark.parametrize("case", sorted(RECORD_CASES))
+    @pytest.mark.parametrize("streaming", [False, True],
+                             ids=["base", "streaming"])
+    def test_records_follow_the_store(self, case, streaming):
+        from scheduler_plugins_tpu.serving import StreamingServeEngine
+
+        cluster = make_cluster(6)
+        engine = (StreamingServeEngine if streaming else ServeEngine)()
+        engine.attach(cluster)
+        sched = make_scheduler()
+        assert engine.refresh(cluster, [], now_ms=0) is not None  # cold build
+
+        def check(now):
+            assert_columns_equal_fresh(engine, cluster, now)
+            assert_records_follow_store(engine, cluster)
+            assert engine.verify_assigned(cluster) is None
+            assert ServeEngine.verify(engine, cluster) is None
+
+        RECORD_CASES[case](cluster, engine, sched, check)
+        assert engine.antientropy_divergences == 0
+
+    def test_rebase_primes_the_assigned_population_and_prunes(self):
+        cluster = make_cluster(4)
+        for serial in range(1, 6):
+            bind_new(cluster, serial, f"n00{serial % 4}", 100 * serial)
+        cluster.add_pod(make_pod(9, 900))  # pending: no record of it yet
+        engine = ServeEngine().attach(cluster)
+        assert engine.refresh(cluster, [], now_ms=1000) is not None
+        assert len(engine._records) == 5
+        assert_records_follow_store(engine, cluster)
+        # an entry whose event was lost is gone after the next rebase
+        engine._records["default/ghost"] = engine._records["default/p00001"]
+        engine._nodes = None
+        assert engine.refresh(cluster, [], now_ms=2000) is not None
+        assert_records_follow_store(engine, cluster)
+
+    @pytest.mark.parametrize("pod", [
+        make_pod(1, 0),
+        Pod(name="bare", creation_ms=1),
+        Pod(
+            name="multi", creation_ms=2, overhead={CPU: 10},
+            init_containers=[Container(requests={CPU: 900},
+                                       limits={CPU: 900, MEMORY: gib})],
+            containers=[
+                Container(requests={CPU: 200, MEMORY: gib},
+                          limits={CPU: 100}),
+                Container(requests={CPU: 300}),
+            ],
+        ),
+    ], ids=["one_container", "no_container", "init_and_overhead"])
+    def test_hit_and_cold_lowering_are_byte_identical(self, pod):
+        """What a record hands its readers is what each of them lowered
+        for itself before: the `PodState` row of `build_pod_state`'s cold
+        path, `pod_usage_vectors` and `pod_quota_vector`."""
+        from scheduler_plugins_tpu.serving import deltas as D
+        from scheduler_plugins_tpu.state.snapshot import (
+            _Interner,
+            build_pod_state,
+        )
+
+        cluster = make_cluster(2)
+        engine = ServeEngine().attach(cluster)
+        cluster.add_pod(pod)
+        assert not engine._outside_axis(cluster, [pod])  # the miss
+        record = engine._records[pod.uid]
+        hits0 = dict(engine._lookups)
+        vectors = engine._pod_vectors(pod)  # a hit
+        assert engine._lookups[("classify", "hit")] == 1
+        assert hits0 == {("batch", "miss"): 1}
+        cold = D.pod_usage_vectors(pod, engine.index) + (
+            D.pod_quota_vector(pod, engine.index),
+        )
+        for mine, theirs in zip(vectors, cold):
+            assert mine.dtype == theirs.dtype
+            assert mine.tobytes() == theirs.tobytes()
+            assert not mine.flags.writeable
+        rows = {}
+        for name, cache in (("cold", None), ("hit", engine._records)):
+            rows[name] = build_pod_state(
+                [pod], 4, engine.index, _Interner([]), lambda p: -1,
+                cluster.tlp_prediction, row_cache=cache,
+            )
+        assert engine._records[pod.uid] is record  # read, not replaced
+        import dataclasses
+
+        for leaf in dataclasses.fields(rows["cold"]):
+            a = np.asarray(getattr(rows["cold"], leaf.name))
+            b = np.asarray(getattr(rows["hit"], leaf.name))
+            assert a.dtype == b.dtype and a.shape == b.shape, leaf.name
+            assert a.tobytes() == b.tobytes(), leaf.name
