@@ -45,7 +45,9 @@ node metrics". PodTopologySpread's selector and topology-domain counts
 are OWNED since ISSUE 32 (`serving.selectors.ResidentSelectors`;
 docs/SERVING.md "Resident selector counts"), InterPodAffinity's terms and
 carrier counts since ISSUE 34 (the same class; "Resident affinity
-terms"). What still gates is listed,
+terms"), NodeAffinity's (spec, node) verdict and score rows since ISSUE 38
+(`serving.node_terms.ResidentNodeTerms`; "Resident node-term rows"). What
+still gates is listed,
 clause by clause, in `ServeEngine.fallback_reason` (the same shape of
 condition as the native-store fast path in `Cluster.snapshot`). While
 incompatible, `refresh` returns None (the cycle falls back to the full
@@ -62,6 +64,7 @@ from typing import Optional
 import numpy as np
 
 from scheduler_plugins_tpu.serving import deltas as D
+from scheduler_plugins_tpu.serving.node_terms import ResidentNodeTerms
 from scheduler_plugins_tpu.serving.selectors import ResidentSelectors
 from scheduler_plugins_tpu.state.snapshot import (
     ClusterSnapshot,
@@ -199,6 +202,12 @@ class ServeEngine:
         #: same drained events; inert while no pod of the store declares a
         #: spread constraint
         self._selectors = ResidentSelectors(self)
+        # -- resident node-term rows (ISSUE 38; docs/SERVING.md) ----------
+        #: NodeAffinity's `node_term_ok` / `pref_score` rows, one a spec,
+        #: evaluated once and kept O(held specs) a node event; holds
+        #: nothing while no pending pod names a nodeSelector or a node
+        #: affinity term
+        self._node_terms = ResidentNodeTerms(self)
         # -- per-pod records (ISSUE 37; docs/SERVING.md) ------------------
         #: uid -> `PodRecord`: a pod object's spec lowered once, read by
         #: the batch's axis test and its assembly, by the classification of
@@ -257,6 +266,7 @@ class ServeEngine:
         self._ns_rows.clear()
         self._drop_metrics()
         self._selectors.reset()
+        self._node_terms.reset()
         self._records.clear()
 
     @property
@@ -307,7 +317,10 @@ class ServeEngine:
         hostname key included), pod (anti-)affinity terms since ISSUE 34
         (required and preferred, the incoming pod's own and the assigned
         carriers': `aff_*`, `anti_*`, `waff_*` are built O(batch),
-        `exist_anti_base` and `sym_base` are resident carrier counts); a
+        `exist_anti_base` and `sym_base` are resident carrier counts), a
+        nodeSelector and node-affinity terms, required and preferred, since
+        ISSUE 38 (`ResidentNodeTerms`: the rows of `node_term_ok` and
+        `pref_score` are kept, a pod's two indices sit in its record); a
         resource name is no reason to fall back (`_outside_axis`: the axis
         widens by a rebase). What still falls back, each counted under its
         reason in `scheduler_serve_fallback_total`:
@@ -323,8 +336,12 @@ class ServeEngine:
           (`Cluster.selectors.unscoped`);
         - `nomination`: a nominated node anywhere (a gated or reserved
           nominee, or one in the batch);
-        - `node-affinity`: a pod of the batch has a nodeSelector or a node
-          affinity term (`node_term_ok`, `pref_score`);
+        - `spread-node-affinity`: a pod of the batch has a nodeSelector or
+          a required node-affinity term AND a topology-spread constraint
+          whose `nodeAffinityPolicy` is Honor (the default): the
+          constraint's eligibility row (`spread_elig`) reads the pod's
+          verdict row, and the resident selector tables keep the all-true
+          one;
         - `spread-node-counts`: a pod of the batch names, in one class,
           several topology keys that some node carries only in part, so
           its domains are counted by node (`spread_needs_node_counts`,
@@ -349,17 +366,19 @@ class ServeEngine:
             p = cluster.pods.get(uid)
             if p is not None and p.nominated_node_name is not None:
                 return "nomination"
-        # batch-local specs (O(batch), not O(cluster)): node affinity
-        # feeds SchedulingState; nominations feed the nominee holds
+        # batch-local specs (O(batch), not O(cluster)): nominations feed
+        # the nominee holds; a spread constraint that honours the pod's own
+        # node affinity needs an eligibility row nobody keeps
         for pod in pending:
             if pod.nominated_node_name is not None:
                 return "nomination"
-            if (
-                pod.node_selector
-                or pod.node_affinity_required
-                or pod.node_affinity_preferred
+            if pod.topology_spread and (
+                pod.node_selector or pod.node_affinity_required
+            ) and any(
+                c.node_affinity_policy != "Ignore"
+                for c in pod.topology_spread
             ):
-                return "node-affinity"
+                return "spread-node-affinity"
         if cluster.selectors.tracks and self._selectors.needs_node_counts(
             cluster, pending
         ):
@@ -440,6 +459,7 @@ class ServeEngine:
                 self._nodes = None
                 self._side_dirty = True
                 self._selectors.invalidate()
+                self._node_terms.invalidate()
             elif self._nodes is not None:
                 if grow:
                     self._grow(bucket_size(n_nodes))
@@ -681,6 +701,7 @@ class ServeEngine:
                         # that exist) — rebuild rather than drift
                         self._side_dirty = True
                 self._selectors.node_row(node, slot, new_node)
+                self._node_terms.node_column(node, slot, new_node)
                 try:
                     alloc = D._encode(node.allocatable, index)
                     cap = D._encode(node.capacity, index)
@@ -921,6 +942,7 @@ class ServeEngine:
         self._npad = new_npad
         self._metrics_stale = True
         self._selectors.grow(new_npad)
+        self._node_terms.invalidate()
 
     def _rebase(self, cluster, pending, now_ms: int):
         """Full re-snapshot: rebuild the resident base from the store (the
@@ -966,9 +988,12 @@ class ServeEngine:
         # layout (padded axes, the registry's row order), so that the solve
         # of the cold build is the program of every cycle after it
         self._selectors.rebuild(cluster, self._names, npad)
+        # the node-term rows are evaluated again on first use, in the new
+        # row order: by this cycle where its batch names a spec
+        self._node_terms.invalidate()
         self._prime_records(cluster)
         if snap.scheduling is not None:
-            snap = snap.replace(scheduling=self._assemble_selectors(
+            snap = snap.replace(scheduling=self._assemble_scheduling(
                 cluster, pending, snap.num_pods
             ))
         self._generation += 1
@@ -1426,6 +1451,7 @@ class ServeEngine:
             self._metrics_divergence(fresh.metrics)
             or self._verify_side(cluster)
             or self._selectors.divergence(cluster, self._names)
+            or self._node_terms.divergence(cluster, self._names)
         )
 
     def _divergence_assigned(self, cluster, now_ms: int) -> Optional[str]:
@@ -1447,7 +1473,11 @@ class ServeEngine:
         )
         if reason is None and side_exp is not None:
             reason = self._side_divergence(*side_exp)
-        return reason or self._selectors.divergence(cluster, self._names)
+        return (
+            reason
+            or self._selectors.divergence(cluster, self._names)
+            or self._node_terms.divergence(cluster, self._names)
+        )
 
     def _expected_metrics(self, cluster, now_ms: int):
         """The `MetricsState` a fresh `build_snapshot` at this padding and
@@ -1729,6 +1759,8 @@ class ServeEngine:
         self._drop_metrics()
         # and the selector tables: built from the store at the first refresh
         self._selectors.reset()
+        # and the node-term rows: each evaluated again on first use
+        self._node_terms.reset()
         self._base_digest = None
         self._last = None
         self.note_fault("checkpoint-restore")
@@ -1812,9 +1844,21 @@ class ServeEngine:
         snap = ClusterSnapshot(
             nodes=self._nodes, pods=pods, gangs=gang_state,
             quota=quota_state, metrics=self._metrics_state,
-            scheduling=self._assemble_selectors(cluster, pending, P),
+            scheduling=self._assemble_scheduling(cluster, pending, P),
         )
         return snap, meta
+
+    def _assemble_scheduling(self, cluster, pending, P: int):
+        """This cycle's `SchedulingState`: the selector tables'
+        (`_assemble_selectors`), with the resident node-term rows and the
+        batch's two index columns laid over it where a pod of the batch
+        names a nodeSelector or a node-affinity term (span
+        `ServeRefresh/node_terms`, opened for such a batch only). None
+        where the batch carries neither, as a fresh build has it."""
+        return self._node_terms.scheduling_state(
+            pending, P, self._npad,
+            self._assemble_selectors(cluster, pending, P),
+        )
 
     def _assemble_selectors(self, cluster, pending, P: int):
         """This cycle's `SchedulingState` over the resident selector
@@ -2109,6 +2153,7 @@ class StreamingServeEngine(ServeEngine):
             self._slots = {n: i for i, n in enumerate(self._names)}
             self._metrics_stale = True
             self._selectors.invalidate()
+            self._node_terms.invalidate()
             if self._gang_rows:
                 # fresh snapshots drop gang slack of pods bound to a
                 # deleted node — rebuild rather than drift (the base

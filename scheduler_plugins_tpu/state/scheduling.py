@@ -36,6 +36,7 @@ real row (the empty set is a legitimate set that tolerates nothing).
 
 from __future__ import annotations
 
+import collections
 from typing import Optional, Sequence
 
 import numpy as np
@@ -227,6 +228,104 @@ def _node_filter_matches(pod: Pod, node: Node) -> bool:
     return True
 
 
+def node_spec_keys(pod: Pod):
+    """(`_node_filter_key` | None, `_pref_key` | None) of the pod's
+    nodeSelector / required node affinity and of its preferred terms, or
+    None where it has none of them: what its rows of `node_term_ok` and
+    `pref_score` are interned by, in a fresh build and in the resident
+    tables (`serving.node_terms`)."""
+    required = pod.node_selector or pod.node_affinity_required
+    if not (required or pod.node_affinity_preferred):
+        return None
+    return (
+        _node_filter_key(pod) if required else None,
+        _pref_key(pod) if pod.node_affinity_preferred else None,
+    )
+
+
+#: A pod's node-placement spec without the pod: what a resident row keeps
+#: to evaluate a node's cell later. It stands in for the pod wherever the
+#: functions below read these three fields.
+NodeSpec = collections.namedtuple(
+    "NodeSpec", "node_selector node_affinity_required node_affinity_preferred"
+)
+
+
+def node_spec(pod: Pod) -> NodeSpec:
+    return NodeSpec(
+        pod.node_selector, pod.node_affinity_required,
+        pod.node_affinity_preferred,
+    )
+
+
+def node_pref_score(pod: Pod, node: Node) -> int:
+    """The summed weights of the pod's preferred terms the node matches."""
+    return sum(
+        t.weight
+        for t in pod.node_affinity_preferred
+        if t.preference.matches(node)
+    )
+
+
+def node_term_row(pod: Pod, nodes: Sequence[Node], N: int) -> np.ndarray:
+    """(N,) bool: one spec's required verdict over the nodes, in their
+    order; a padded column admits nothing. O(nodes) label tests."""
+    row = np.zeros(N, bool)
+    for n, node in enumerate(nodes):
+        row[n] = _node_filter_matches(pod, node)
+    return row
+
+
+def node_pref_row(pod: Pod, nodes: Sequence[Node], N: int) -> np.ndarray:
+    """(N,) int64: one spec's preferred score over the nodes."""
+    row = np.zeros(N, I64)
+    for n, node in enumerate(nodes):
+        row[n] = node_pref_score(pod, node)
+    return row
+
+
+def node_term_tables(nodes: Sequence[Node], pending: Sequence[Pod], N: int,
+                     P: int) -> tuple:
+    """(node_term_ok (T+1, N), pod_node_term (P,), pref_score (U+1, N),
+    pod_pref (P,)) of a fresh build: every unique spec of the batch
+    against every node, specs interned in the batch's order, the all-true
+    row at T and the all-zero one at U. O(specs x nodes), in Python."""
+    term_rows: dict = {}
+    pref_rows: dict = {}
+    # a slot past the batch keeps row 0, whatever that row is: it is masked
+    pod_node_term = np.zeros(P, I32)
+    pod_pref = np.zeros(P, I32)
+    term_pods: list[Pod] = []
+    pref_pods: list[Pod] = []
+    for i, pod in enumerate(pending):
+        term_key, pref_key = node_spec_keys(pod) or (None, None)
+        if term_key is None:
+            pod_node_term[i] = -1  # remapped to the all-true row below
+        else:
+            if term_key not in term_rows:
+                term_rows[term_key] = len(term_rows)
+                term_pods.append(pod)
+            pod_node_term[i] = term_rows[term_key]
+        if pref_key is None:
+            pod_pref[i] = -1
+        else:
+            if pref_key not in pref_rows:
+                pref_rows[pref_key] = len(pref_rows)
+                pref_pods.append(pod)
+            pod_pref[i] = pref_rows[pref_key]
+    T, U = len(term_rows), len(pref_rows)
+    node_term_ok = np.zeros((T + 1, N), bool)
+    node_term_ok[T] = True  # unconstrained row
+    pref_score = np.zeros((U + 1, N), I64)
+    for t, pod in enumerate(term_pods):
+        node_term_ok[t] = node_term_row(pod, nodes, N)
+    for u, pod in enumerate(pref_pods):
+        pref_score[u] = node_pref_row(pod, nodes, N)
+    pod_node_term = np.where(pod_node_term < 0, T, pod_node_term).astype(I32)
+    pod_pref = np.where(pod_pref < 0, U, pod_pref).astype(I32)
+    return node_term_ok, pod_node_term, pref_score, pod_pref
+
+
 def has_affinity_terms(pod: Pod) -> bool:
     """The pod carries a pod (anti-)affinity term, required or preferred."""
     return bool(
@@ -273,55 +372,27 @@ def build_scheduling(
     if not relevant(nodes, pending, assigned):
         return None
 
-    term_rows: dict = {}
-    pref_rows: dict = {}
+    # the node half: nodeSelector / node-affinity specs against the nodes
+    # (the span is the fallback path's twin of `ServeRefresh/node_terms`)
+    with obs.tracer.span("Snapshot/node_terms", tid="snapshot",
+                         pending=len(pending)):
+        node_term_ok, pod_node_term, pref_score, pod_pref = node_term_tables(
+            nodes, pending, N, P
+        )
+
     tol_rows: dict = {}
-    pod_node_term = np.zeros(P, I32)
-    pod_pref = np.zeros(P, I32)
     pod_tol = np.zeros(P, I32)
-    term_pods: list[Pod] = []
-    pref_pods: list[Pod] = []
     tol_pods: list[Pod] = []
     for i, pod in enumerate(pending):
-        if pod.node_selector or pod.node_affinity_required:
-            k = _node_filter_key(pod)
-            if k not in term_rows:
-                term_rows[k] = len(term_rows)
-                term_pods.append(pod)
-            pod_node_term[i] = term_rows[k]
-        else:
-            pod_node_term[i] = -1  # remapped to the all-true pad row below
-        if pod.node_affinity_preferred:
-            k = _pref_key(pod)
-            if k not in pref_rows:
-                pref_rows[k] = len(pref_rows)
-                pref_pods.append(pod)
-            pod_pref[i] = pref_rows[k]
-        else:
-            pod_pref[i] = -1
         k = _tol_key(pod)
         if k not in tol_rows:
             tol_rows[k] = len(tol_rows)
             tol_pods.append(pod)
         pod_tol[i] = tol_rows[k]
 
-    T, U, T2 = len(term_rows), len(pref_rows), max(len(tol_rows), 1)
-    node_term_ok = np.zeros((T + 1, N), bool)
-    node_term_ok[T] = True  # unconstrained row
-    pref_score = np.zeros((U + 1, N), I64)
+    T2 = max(len(tol_rows), 1)
     tol_ok = np.ones((T2, N), bool)
     tol_prefer = np.zeros((T2, N), I64)
-
-    for t, pod in enumerate(term_pods):
-        for n, node in enumerate(nodes):
-            node_term_ok[t, n] = _node_filter_matches(pod, node)
-    for u, pod in enumerate(pref_pods):
-        for n, node in enumerate(nodes):
-            pref_score[u, n] = sum(
-                t.weight
-                for t in pod.node_affinity_preferred
-                if t.preference.matches(node)
-            )
     for s, pod in enumerate(tol_pods):
         for n, node in enumerate(nodes):
             for taint in node.taints:
@@ -338,16 +409,14 @@ def build_scheduling(
                          assigned=len(assigned)):
         selector_tables = _build_selector_tables(
             nodes, pending, assigned, N, P, namespaces,
-            pod_aff_rows=node_term_ok[
-                np.where(pod_node_term < 0, T, pod_node_term)
-            ],
+            pod_aff_rows=node_term_ok[pod_node_term],
             pod_tol_rows=tol_ok[pod_tol],
         )
     return SchedulingState(
         node_term_ok=node_term_ok,
-        pod_node_term=np.where(pod_node_term < 0, T, pod_node_term).astype(I32),
+        pod_node_term=pod_node_term,
         pref_score=pref_score,
-        pod_pref=np.where(pod_pref < 0, U, pod_pref).astype(I32),
+        pod_pref=pod_pref,
         tol_ok=tol_ok,
         tol_prefer=tol_prefer,
         pod_tol=pod_tol,

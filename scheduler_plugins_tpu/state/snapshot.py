@@ -390,7 +390,8 @@ class PodRecord:
     outside `index`."""
 
     __slots__ = ("pod", "index", "tlp", "req", "limits", "predicted",
-                 "creq", "cinit", "qos", "usage", "vectors")
+                 "creq", "cinit", "qos", "usage", "vectors", "node_keys",
+                 "node_rows")
 
     def __init__(self, pod, index, tlp_prediction):
         self.pod = pod
@@ -418,6 +419,13 @@ class PodRecord:
         #: (requested, nonzero, limits, quota): the usage rows and the raw
         #: request encode, the tuple `ServeEngine._pod_vectors` hands out
         self.vectors = (block[2], block[3], block[4], self.req)
+        #: the keys of the pod's nodeSelector / node-affinity specs
+        #: (`state.scheduling.node_spec_keys`; None for a pod without any),
+        #: and where the serving engine's resident rows hold them: (the
+        #: tables' epoch, row of `node_term_ok`, row of `pref_score`), set
+        #: by `serving.node_terms` the first time a batch holds the pod
+        self.node_keys = _sched.node_spec_keys(pod)
+        self.node_rows = None
 
     def valid(self, pod, index, tlp_prediction) -> bool:
         return (

@@ -392,6 +392,17 @@ SERVE_TOPO_ROWS = "scheduler_serve_topo_rows_total"
 #: (anti-)affinity terms the store's pods declare changes, a tracked label
 #: of a node that holds pods changes, or a key or domain outgrows its bucket
 SERVE_SELECTOR_REBASES = "scheduler_serve_selector_rebases_total"
+#: nodeSelector / node-affinity spec rows the serving engine evaluated over
+#: the nodes, O(nodes) label tests each: one per spec the first time a
+#: pending pod names it (and again after it was released or the rows were
+#: dropped), never one a cycle (docs/SERVING.md "Resident node-term rows")
+SERVE_NODE_TERM_ROWS = "scheduler_serve_node_term_rows_total"
+#: node columns of the resident node-term rows written, O(held specs)
+#: each: a node that arrived, or a known one whose labels changed
+SERVE_NODE_TERM_COLUMNS = "scheduler_serve_node_term_columns_total"
+#: times the spec axis of the resident node-term rows passed its bucket
+#: and the tables were laid out and staged again (idle rows released)
+SERVE_NODE_TERM_REBASES = "scheduler_serve_node_term_rebases_total"
 #: labels: reason — serving refreshes that handed the cycle back to the
 #: O(cluster) `Cluster.snapshot`, by the clause of
 #: `ServeEngine.compatible` that refused it
@@ -607,6 +618,13 @@ HELP: dict[str, str] = {
         "Node rows of the resident topology-domain table written.",
     SERVE_SELECTOR_REBASES:
         "Rebuilds of the resident selector tables from the store.",
+    SERVE_NODE_TERM_ROWS:
+        "Node-selector / node-affinity spec rows evaluated over the nodes.",
+    SERVE_NODE_TERM_COLUMNS:
+        "Node columns of the resident node-term rows written.",
+    SERVE_NODE_TERM_REBASES:
+        "Times the resident node-term rows passed their bucket and were "
+        "staged again.",
     SERVE_FALLBACKS:
         "Serve refreshes that fell back to a full snapshot, by reason.",
     PLACEMENT_QUALITY:

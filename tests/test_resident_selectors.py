@@ -16,6 +16,7 @@ one case per clause `ServeEngine.fallback_reason` still refuses, asserting
 the reason's counter.
 """
 
+import dataclasses
 import os
 import sys
 
@@ -441,8 +442,10 @@ CLAUSES = {
     "nomination": lambda c: c.add_pod(_plain_pod(
         "nominee", nominated_node_name="n001",
     )),
-    "node-affinity": lambda c: c.add_pod(_plain_pod(
-        "picky", node_selector={ZONE_LABEL: "z1"},
+    # ISSUE 38 narrowed `node-affinity` to the pod whose spread constraint
+    # honours its own node term (tests/test_resident_node_terms.py)
+    "spread-node-affinity": lambda c: c.add_pod(dataclasses.replace(
+        spread_pod(901, 1), node_selector={ZONE_LABEL: "z1"},
     )),
     # a node with the zone key and no hostname key: a pod naming both in
     # one class has its domains counted by node

@@ -20,8 +20,11 @@ from scheduler_plugins_tpu.api.objects import (
     Container,
     ElasticQuota,
     Node,
+    NodeSelectorRequirement,
+    NodeSelectorTerm,
     Pod,
     PodGroup,
+    PreferredSchedulingTerm,
     Taint,
 )
 from scheduler_plugins_tpu.api.resources import CPU, MEMORY, PODS
@@ -277,9 +280,6 @@ def _pending(**spec):
 @pytest.mark.parametrize("refuse", [
     _nrt, _app_group, _seccomp, _tainted_node,
     _gated_nominee, _reserved_nominee,
-    _pending(node_selector={"disk": "ssd"}),
-    _pending(node_affinity_required=[{"disk": ["ssd"]}]),
-    _pending(node_affinity_preferred=[(1, {"disk": ["ssd"]})]),
     _pending(nominated_node_name="n000"),
 ], ids=lambda f: f.__name__.strip("_"))
 def test_compatible_still_refuses_each_of_its_other_cases(refuse):
@@ -292,6 +292,43 @@ def test_compatible_still_refuses_each_of_its_other_cases(refuse):
     pending = cluster.pending_pods()
     assert not engine.compatible(cluster, pending)
     assert engine.refresh(cluster, pending, now_ms=2000) is None
+
+
+def _ssd_term():
+    return NodeSelectorTerm(match_expressions=[
+        NodeSelectorRequirement("disk", "In", ("ssd",)),
+    ])
+
+
+@pytest.mark.parametrize("spec", [
+    lambda: dict(node_selector={"disk": "ssd"}),
+    lambda: dict(node_affinity_required=[_ssd_term()]),
+    lambda: dict(node_affinity_preferred=[
+        PreferredSchedulingTerm(1, _ssd_term()),
+    ]),
+], ids=["node_selector", "node_affinity_required", "node_affinity_preferred"])
+def test_compatible_no_longer_refuses_a_node_term(spec):
+    """ISSUE 38 took these three off the list above: the spec's row over
+    the nodes is resident state, the cycle is served, and what it gathers
+    is what a fresh build gathers."""
+    cluster = make_cluster(4)
+    engine = ServeEngine().attach(cluster)
+    cluster.add_pod(make_pod(1, 500))
+    run_cycle(make_scheduler(), cluster, now=1000, serve=engine)
+    _pending(**spec())(cluster, engine)
+    pending = cluster.pending_pods()
+    assert engine.compatible(cluster, pending)
+    mine, _meta = engine.refresh(cluster, pending, now_ms=2000)
+    fresh, _ = cluster.snapshot(pending, now_ms=2000, pad_nodes=engine.npad)
+    got, want = mine.scheduling, fresh.scheduling
+    for table, index in (("node_term_ok", "pod_node_term"),
+                         ("pref_score", "pod_pref")):
+        np.testing.assert_array_equal(
+            np.asarray(getattr(got, table))[
+                np.asarray(getattr(got, index))[:len(pending)]],
+            getattr(want, table)[getattr(want, index)[:len(pending)]],
+        )
+    assert engine.rebases == 1 and engine.verify(cluster, 2000) is None
 
 
 def test_compatible_no_longer_refuses_a_load_watchers_report():
