@@ -725,6 +725,10 @@ class Scheduler:
             # static per compiled shape: this program reads node-space
             # views of its domain tables (`ops.selectors`)
             obs.metrics.inc(obs.SOLVE_NODE_VIEWS)
+        if snap.scheduling is not None and snap.scheduling.spread_node_domains:
+            # static per compiled shape too: its spread counts one-node
+            # domains in node space (`PodTopologySpread._constraint_state`)
+            obs.metrics.inc(obs.SOLVE_SPREAD_NODE_DOMAINS)
         unroll = self._scan_unroll()
         live = self._live_weights
         if live is not None:

@@ -104,7 +104,8 @@ class PodTopologySpread(Plugin):
 
     Live counts are (TR, N) per (selector-track, NODE), carried through the
     solve and aggregated into (CT, D) domain counts per pod under the
-    node-inclusion policies; the check per node is then
+    node-inclusion policies (read in node space where every domain holds
+    one node); the check per node is then
 
         matchNum(node) = dc[constraint, domain(node)]
         verdict(node)  = has_key(node)
@@ -155,7 +156,12 @@ class PodTopologySpread(Plugin):
         (`spread_elig`); when NO row excludes a keyed node
         (`spread_needs_node_counts` False — the common case) the counting
         is provably identical to the (TR, D) domain mirror and this
-        reduces to row gathers."""
+        reduces to row gathers.
+
+        Where every domain of every held key holds one node
+        (`spread_node_domains`: hostname keys) a domain's count is its
+        node's own, so the counts stay in node space: `dc` is then (CT, N),
+        the count in each node's domain, and no scatter builds a table."""
         s = snap.scheduling
         code = s.topo_code[s.spread_topo[p]]  # (CT, N)
         has = s.topo_has[s.spread_topo[p]]  # (CT, N)
@@ -163,14 +169,20 @@ class PodTopologySpread(Plugin):
             counts = self._counts(state, snap)  # (TR, N)
             dcn = counts[s.spread_track[p]]  # (CT, N)
             elig = s.spread_elig[s.spread_elig_idx[p]] & (code >= 0)
-            CT, N = code.shape
-            D = s.domain_exists.shape[1]
-            rows = jnp.broadcast_to(jnp.arange(CT)[:, None], (CT, N))
-            col = jnp.maximum(code, 0)
-            dc = jnp.zeros((CT, D), counts.dtype).at[rows, col].add(
-                jnp.where(elig, dcn, 0)
-            )
-            exists = jnp.zeros((CT, D), bool).at[rows, col].max(elig)
+            if s.spread_node_domains:
+                # an ineligible node's one-node domain counts nothing and
+                # does not exist
+                dc = jnp.where(elig, dcn, 0)  # (CT, N)
+                exists = elig
+            else:
+                CT, N = code.shape
+                D = s.domain_exists.shape[1]
+                rows = jnp.broadcast_to(jnp.arange(CT)[:, None], (CT, N))
+                col = jnp.maximum(code, 0)
+                dc = jnp.zeros((CT, D), counts.dtype).at[rows, col].add(
+                    jnp.where(elig, dcn, 0)
+                )
+                exists = jnp.zeros((CT, D), bool).at[rows, col].max(elig)
         else:
             dc = self._dom_counts(state, snap)[s.spread_track[p]]  # (CT, D)
             exists = s.domain_exists[s.spread_topo[p]]  # (CT, D)
@@ -190,7 +202,10 @@ class PodTopologySpread(Plugin):
         key: rows of the mirror's node-space view
         (`SolverState.sel_dom_view`) where the solve carries one. The
         policy branch's `dc` is counted per pod under the pod's own
-        eligibility row, which no shared view can serve: it gathers."""
+        eligibility row, which no shared view can serve: it gathers, where
+        `dc` is not in node space already."""
+        if s.spread_node_domains:
+            return dc
         view = None
         if state is not None and not s.spread_needs_node_counts:
             view = state.sel_dom_view
@@ -234,9 +249,12 @@ class PodTopologySpread(Plugin):
         s, dc, minm, code, has = self._constraint_state(state, snap, p)
         code_n = code[:, node]  # (CT,)
         has_n = has[:, node]
-        match_at = jnp.take_along_axis(
-            dc, jnp.maximum(code_n, 0)[:, None], axis=1
-        ).squeeze(1)
+        if s.spread_node_domains:
+            match_at = dc[:, node]
+        else:
+            match_at = jnp.take_along_axis(
+                dc, jnp.maximum(code_n, 0)[:, None], axis=1
+            ).squeeze(1)
         selfm = s.spread_self[p].astype(jnp.int64)
         ok = match_at + selfm - minm <= s.spread_max_skew[p]
         applies = s.spread_mask[p] & s.spread_hard[p]

@@ -48,7 +48,9 @@ key or domain outgrows its bucket.
   (`spread_needs_node_counts`): the (track, node) counts are then resident
   on the device beside `track_base`, a bind or a delete one more +-1 of
   the same batch and the same donated program. Otherwise neither is
-  staged and the batch keeps the trivial row.
+  staged and the batch keeps the trivial row. Where besides no domain of
+  any held key holds more than one node (`domain_nodes`: a hostname key),
+  the solve reads those counts in node space (`spread_node_domains`).
 
 What a cycle still builds, O(batch), is `scheduling_state`: `pend_match`,
 the (P, CT) `spread_*` rows and, where the store's pods declare pod
@@ -186,10 +188,7 @@ class ResidentSelectors:
                 axes.key_names, nodes, npad, K=K
             )
             Dp = bucket_size(max(len(dv) for dv in domain_values) or 1)
-            domain_nodes = np.zeros((K, Dp), I32)
-            for k in range(len(axes.key_names)):
-                codes = topo_code[k][topo_code[k] >= 0]
-                domain_nodes[k] = np.bincount(codes, minlength=Dp)
+            domain_nodes = S.domain_node_counts(topo_code, Dp)
             slots = {name: i for i, name in enumerate(names)}
             assigned = cluster._assigned_pods()
             _, track_base = S.track_counts(
@@ -502,6 +501,9 @@ class ResidentSelectors:
             **dict(self._trivial(N, P), **node_counts), **self._static,
             **stage(batch), **resident,
             spread_needs_node_counts=bool(node_counts),
+            spread_node_domains=S.one_node_domains(
+                bool(node_counts), self.domain_nodes
+            ),
         )
 
     # -- the eligibility rows ---------------------------------------------
@@ -745,12 +747,7 @@ class ResidentSelectors:
                 value = labels.get(key)
                 if value is not None:
                     code[k, slot] = self.domain_values[k].get(value, -2)
-        domain_nodes = np.zeros(self.domain_nodes.shape, I32)
-        for k in range(len(axes.key_names)):
-            held = code[k][code[k] >= 0]
-            domain_nodes[k] = np.bincount(
-                held, minlength=domain_nodes.shape[1]
-            )[:domain_nodes.shape[1]]
+        domain_nodes = S.domain_node_counts(code, self.domain_nodes.shape[1])
         if not (
             np.array_equal(code, self.topo_code)
             and np.array_equal(code >= 0, self.topo_has)

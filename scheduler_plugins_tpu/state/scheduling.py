@@ -130,6 +130,13 @@ class SchedulingState:
     spread_needs_node_counts: bool = struct.field(
         pytree_node=False, default=False
     )
+    #: STATIC python bool: `spread_needs_node_counts`, and no domain of any
+    #: key that `topo_code` holds has more than one node (a hostname key). A
+    #: domain's count is then its node's own: the spread plugin counts in
+    #: node space, with no per-pod scatter into (CT, D) (`one_node_domains`)
+    spread_node_domains: bool = struct.field(
+        pytree_node=False, default=False
+    )
     # per-pod inter-pod affinity terms, padded to AT/BT/WT. `*_self` marks
     # the upstream first-pod special case: the term matches the incoming
     # pod itself, so an otherwise-empty cluster does not deadlock.
@@ -698,6 +705,25 @@ def topology_tables(key_names, nodes, N: int, K=None) -> tuple:
     return topo_code, topo_has, domain_values
 
 
+def domain_node_counts(topo_code: np.ndarray, D: int) -> np.ndarray:
+    """(K, D) int32: the nodes in each domain of each key (a node without
+    the key, code < 0, is in none)."""
+    counts = np.zeros((topo_code.shape[0], D), I32)
+    for k, row in enumerate(topo_code):
+        counts[k] = np.bincount(row[row >= 0], minlength=D)[:D]
+    return counts
+
+
+def one_node_domains(needs_node_counts: bool, domain_nodes) -> bool:
+    """`SchedulingState.spread_node_domains`: the spread counts by node
+    and no domain of any held key (`domain_nodes` (K, D), the nodes each
+    holds) has more than one node. Over every key, not the batch's: it
+    moves only when the cluster's keys or nodes do."""
+    return bool(needs_node_counts) and int(
+        np.max(domain_nodes, initial=0)
+    ) <= 1
+
+
 def domain_exists_table(domain_values, K: int, D: int) -> np.ndarray:
     exists = np.zeros((K, D), bool)
     for k, dv in enumerate(domain_values):
@@ -1170,6 +1196,9 @@ def _build_selector_tables(
         spread_elig=spread_elig,
         spread_elig_idx=spread_elig_idx,
         spread_needs_node_counts=needs_node_counts,
+        spread_node_domains=one_node_domains(
+            needs_node_counts, domain_node_counts(topo_code, D)
+        ),
         **affinity,
     )
 

@@ -316,6 +316,10 @@ PREEMPTION_VICTIMS = "scheduler_preemption_victims_total"
 #: sequential solves whose program reads node-space views of its domain
 #: tables (`ops.selectors`): one a solve of a snapshot that has such tables
 SOLVE_NODE_VIEWS = "scheduler_solve_node_views_total"
+#: sequential solves whose PodTopologySpread counts its node-inclusion
+#: domains in node space (`SchedulingState.spread_node_domains`: every
+#: domain of every held key one node): one a solve of such a snapshot
+SOLVE_SPREAD_NODE_DOMAINS = "scheduler_solve_spread_node_domains_total"
 GANG_REJECTIONS = "scheduler_gang_rejections_total"
 #: pods an ElasticQuota refused in a cycle (CapacityScheduling's PreFilter
 #: made them unschedulable: own Max, or the aggregate over Min)
@@ -600,6 +604,9 @@ HELP: dict[str, str] = {
     SOLVE_NODE_VIEWS:
         "Sequential solves whose program reads node-space views of its "
         "domain tables.",
+    SOLVE_SPREAD_NODE_DOMAINS:
+        "Sequential solves whose topology spread counts its one-node "
+        "domains in node space, with no per-pod scatter.",
     GANG_REJECTIONS: "Whole-gang admission rejections.",
     CACHE_RESYNC_FLUSHES: "NRT cache resync flushes.",
     UNSCHEDULABLE_BY_PLUGIN:

@@ -21,7 +21,11 @@ equals the gather of its table masked by "the node has the key";
 (iii) the lowering: the scan body of an antiaffinity-shaped and of a
 spread-shaped solve holds no gather with a node's worth of indices, and the
 solve of a snapshot without selector tables lowers to the program it
-lowered to before.
+lowered to before; (iv) the node-count branch of `PodTopologySpread`
+(`SchedulingState.spread_node_domains`): the scan body of a
+nodeinclusion-shaped solve, hostname domains counted by node, holds no
+node-wide scatter and no node-wide gather, and a zone-keyed one keeps its
+scatter (`ScatterSpread`, kept here, is what that branch was).
 """
 
 import hashlib
@@ -36,8 +40,11 @@ from scheduler_plugins_tpu.api.objects import (
     Container,
     LabelSelector,
     Node,
+    NodeSelectorRequirement,
+    NodeSelectorTerm,
     Pod,
     PodAffinityTerm,
+    Taint,
     TopologySpreadConstraint,
     WeightedPodAffinityTerm,
 )
@@ -48,7 +55,9 @@ from scheduler_plugins_tpu.ops import selectors
 from scheduler_plugins_tpu.plugins import NodeResourcesAllocatable
 from scheduler_plugins_tpu.plugins.intree import (
     InterPodAffinity,
+    NodeAffinity,
     PodTopologySpread,
+    TaintToleration,
 )
 from scheduler_plugins_tpu.state.cluster import Cluster
 from scheduler_plugins_tpu.utils import observability as obs
@@ -69,7 +78,61 @@ def gather(dc, code):
     return jnp.take_along_axis(dc, jnp.maximum(code, 0), axis=1)
 
 
-class GatherSpread(PodTopologySpread):
+class ScatterSpread(PodTopologySpread):
+    """`PodTopologySpread` with the node-count branch it had before counts
+    were read in node space: wherever an eligibility row leaves out a keyed
+    node, every pod's counts are scattered into a (CT, D) table, whatever
+    its domains hold, and looked up out of it by gather."""
+
+    def _constraint_state(self, state, snap, p):
+        s = snap.scheduling
+        code = s.topo_code[s.spread_topo[p]]  # (CT, N)
+        has = s.topo_has[s.spread_topo[p]]  # (CT, N)
+        if s.spread_needs_node_counts:
+            counts = self._counts(state, snap)  # (TR, N)
+            dcn = counts[s.spread_track[p]]  # (CT, N)
+            elig = s.spread_elig[s.spread_elig_idx[p]] & (code >= 0)
+            CT, N = code.shape
+            D = s.domain_exists.shape[1]
+            rows = jnp.broadcast_to(jnp.arange(CT)[:, None], (CT, N))
+            col = jnp.maximum(code, 0)
+            dc = jnp.zeros((CT, D), counts.dtype).at[rows, col].add(
+                jnp.where(elig, dcn, 0)
+            )
+            exists = jnp.zeros((CT, D), bool).at[rows, col].max(elig)
+        else:
+            dc = self._dom_counts(state, snap)[s.spread_track[p]]  # (CT, D)
+            exists = s.domain_exists[s.spread_topo[p]]  # (CT, D)
+        big = jnp.int64(1) << 62
+        minm = jnp.min(jnp.where(exists, dc, big), axis=1)  # (CT,)
+        dn = jnp.sum(exists, axis=1)  # (CT,)
+        md = s.spread_min_domains[p]
+        minm = jnp.where((md > 0) & (dn < md), 0, minm)
+        return s, dc, minm, code, has
+
+    def _match_at(self, state, s, p, dc, code):
+        view = None
+        if state is not None and not s.spread_needs_node_counts:
+            view = state.sel_dom_view
+        return selectors.domain_at(view, s.spread_track[p], dc, code)
+
+    def validate_at(self, state, snap, p, node):
+        s = snap.scheduling
+        if s is None or s.spread_track is None:
+            return jnp.bool_(True)
+        s, dc, minm, code, has = self._constraint_state(state, snap, p)
+        code_n = code[:, node]  # (CT,)
+        has_n = has[:, node]
+        match_at = jnp.take_along_axis(
+            dc, jnp.maximum(code_n, 0)[:, None], axis=1
+        ).squeeze(1)
+        selfm = s.spread_self[p].astype(jnp.int64)
+        ok = match_at + selfm - minm <= s.spread_max_skew[p]
+        applies = s.spread_mask[p] & s.spread_hard[p]
+        return jnp.all(jnp.where(applies, has_n & ok, True))
+
+
+class GatherSpread(ScatterSpread):
     """`PodTopologySpread` with the filter and the score it had before."""
 
     def filter(self, state, snap, p):
@@ -498,8 +561,27 @@ def node_wide_gathers(closed, width=N_NODES):
     return found[True], found[False]
 
 
+def node_wide_scatters(closed, width=N_NODES):
+    """(in a loop body, outside one): the scatters of `closed` that write
+    at least `width` separate indices — a node's worth of counts folded
+    into a (D,) row. A row update (`table.at[rows, node]`: one index a
+    row) is not one."""
+    found = {True: [], False: []}
+    for jaxpr, in_loop in jaxprs(closed.jaxpr):
+        for eqn in jaxpr.eqns:
+            if not eqn.primitive.name.startswith("scatter"):
+                continue
+            indices = eqn.invars[1].aval  # (..., index depth)
+            if int(np.prod(indices.shape[:-1])) >= width:
+                found[in_loop].append(str(eqn))
+    return found[True], found[False]
+
+
 def traced_solve(scheduler, topology, mix):
-    cluster, pending = store(topology, mix)
+    return trace_solve(scheduler, *store(topology, mix))
+
+
+def trace_solve(scheduler, cluster, pending):
     snap, meta = cluster.snapshot(pending, now_ms=0)
     scheduler.prepare(meta, cluster)
     auxes = tuple(plugin.aux() for plugin in scheduler.profile.plugins)
@@ -515,8 +597,9 @@ def traced_solve(scheduler, topology, mix):
     pytest.param("three_zones", "spread_min_domains", id="spread"),
     pytest.param("three_zones", "everything", id="everything"),
     # (with a spread constraint on some_keyless, a node-inclusion policy
-    # excludes a keyed node: that branch counts per pod and keeps its own
-    # scatter and gather, `PodTopologySpread._match_at`)
+    # excludes a keyed node and the zone key's domains hold several
+    # nodes: the scatter branch of `PodTopologySpread._constraint_state`
+    # counts per pod, keeping its scatter and `_match_at`'s gather)
     pytest.param("some_keyless", "symmetric_carriers", id="keyless"),
 ])
 def test_no_node_wide_gather_in_the_scan_body(topology, mix):
@@ -535,6 +618,86 @@ def test_the_gather_reference_has_what_the_test_looks_for():
         traced_solve(gather_scheduler(), "zone_per_node", "own_anti")
     )
     assert in_loop
+
+
+# ---------------------------------------------------------------------------
+# (iv) the node-count branch: one-node domains counted in node space
+# ---------------------------------------------------------------------------
+
+#: the taint a fifth of `inclusion_store`'s nodes carry, which no pod
+#: tolerates
+POOL_TAINT = Taint("pool", "reserved", "NoSchedule")
+
+
+def inclusion_store(key=HOSTNAME, n_nodes=N_NODES, batch=14):
+    """(cluster, pending) of nodeinclusion-5000n's shape: every node its
+    own hostname in one of three zones, the last fifth tainted; bound and
+    pending pods under a required node term that admits every node and a
+    hard spread constraint on `key` with both policies Honor, so that every
+    eligibility row leaves the tainted nodes out."""
+    cluster = Cluster()
+    for i in range(n_nodes):
+        cluster.add_node(Node(
+            name=f"n{i:03d}", labels={ZONE_LABEL: f"z{i % 3}",
+                                      HOSTNAME: f"n{i:03d}"},
+            taints=[POOL_TAINT] if i >= n_nodes - n_nodes // 5 else [],
+            allocatable={CPU: 4000 * (1 + i % 2), MEMORY: 16 * gib,
+                         PODS: 110},
+        ))
+
+    def honouring(serial):
+        return pod(serial, color="red", topology_spread=[spread(
+            key, node_affinity_policy="Honor", node_taints_policy="Honor",
+        )], node_affinity_required=[NodeSelectorTerm(match_expressions=[
+            NodeSelectorRequirement(ZONE_LABEL, "NotIn", ("z9",)),
+        ])])
+
+    for serial in range(BOUND):
+        held = honouring(serial)
+        cluster.add_pod(held)
+        cluster.bind(held.uid, f"n{serial * 5 % n_nodes:03d}")
+    pending = [honouring(serial) for serial in range(BOUND, BOUND + batch)]
+    for new in pending:
+        cluster.add_pod(new)
+    return cluster, pending
+
+
+def inclusion_scheduler(spread_plugin=PodTopologySpread):
+    return Scheduler(Profile(plugins=[
+        NodeResourcesAllocatable(), NodeAffinity(), TaintToleration(),
+        spread_plugin(),
+    ]))
+
+
+@pytest.mark.parametrize("key, node_domains", [
+    # nodeinclusion-5000n's: a domain a node, counted in node space
+    pytest.param(HOSTNAME, True, id="hostname"),
+    # zones of four nodes: counted by node and scattered into domains
+    pytest.param(ZONE_LABEL, False, id="zone"),
+])
+def test_one_node_domains_leave_the_scan_body_no_scatter(key, node_domains):
+    cluster, pending = inclusion_store(key)
+    snap, _meta = cluster.snapshot(pending, now_ms=0)
+    assert snap.scheduling.spread_needs_node_counts
+    assert snap.scheduling.spread_node_domains is node_domains
+    closed = trace_solve(inclusion_scheduler(), cluster, pending)
+    scatters, _ = node_wide_scatters(closed)
+    gathers, _ = node_wide_gathers(closed)
+    if node_domains:
+        assert scatters == [] and gathers == []
+    else:
+        # the detectors see the scatter branch: per pod, a scatter of
+        # the counts and of the domains' existence, and a gather back
+        assert len(scatters) >= 2 and gathers
+
+
+def test_the_scatter_reference_has_what_the_test_looks_for():
+    """The detector sees the old expression on the hostname store itself:
+    the scatter branch's scan body holds its node-wide scatters."""
+    closed = trace_solve(inclusion_scheduler(ScatterSpread),
+                         *inclusion_store())
+    scatters, _ = node_wide_scatters(closed)
+    assert len(scatters) >= 2
 
 
 def plain_store():
