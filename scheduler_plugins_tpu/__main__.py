@@ -20,8 +20,9 @@ Wiring per tick:
         -> Cluster store  (--scheduler-name gates the queue per profile)
     cycle loop:  [--leader-elect: only while holding the Lease]
                  a tick when a pod becomes schedulable, as early as
-                 DEMAND_TICK_SPACING allows, and every --cycle-interval-s
-                 otherwise (`Daemon.run`)
+                 DEMAND_TICK_SPACING allows or sooner where the feed has
+                 gone quiet and left the lock unused (QUIET_TICK_SHARE),
+                 and every --cycle-interval-s otherwise (`Daemon.run`)
                  run_cycle (QueueSort..Bind, collector ticks, NRT resync)
                  reconcile_pod_groups / reconcile_elastic_quotas
                  bindings POSTed back to the apiserver [--bind-back]
@@ -56,7 +57,11 @@ from pathlib import Path
 
 from scheduler_plugins_tpu.api.config import load_profile
 from scheduler_plugins_tpu.bridge.agent import DEFAULT_WATCH_PATHS, ClusterAgent
-from scheduler_plugins_tpu.bridge.feed import FeedClient, FeedServer
+from scheduler_plugins_tpu.bridge.feed import (
+    FEED_QUIET_NS,
+    FeedClient,
+    FeedServer,
+)
 from scheduler_plugins_tpu.controllers.elasticquota import (
     reconcile_elastic_quotas,
 )
@@ -240,8 +245,29 @@ def parse_args(argv=None):
 #: this many times: it pauses the loop once, and multiplied it sent the
 #: tick after it to the interval whatever was waiting (PERF.md finding 42):
 #: a demand tick comes no sooner than `check + this × (d − check)` after
-#: that tick started.
+#: that tick started. The rule has a quiet exit (`QUIET_TICK_SHARE`): it
+#: keeps the feed a share of the lock, and a feed that has stopped using
+#: it gives the loop nothing to wait for.
 DEMAND_TICK_SPACING = 6
+
+#: The quiet exit from the spacing rule. While a pod waits, the loop
+#: starts the next tick before `DEMAND_TICK_SPACING` allows at the first
+#: moment at which the feed has had no line in hand for
+#: `bridge.feed.FEED_QUIET_NS` and has been quiet for at least this share
+#: of the time since the last tick gave the feed lock up, its gaps counted
+#: as `bridge.feed.FeedTally` counts them (`FeedPulse`). Why a half: of the
+#: loop's sleep the feed left quiet 0.824 in `trimaran-5000n.steady` (120
+#: pods/s open), and 0.30 / 0.255 in `spread-` / `nodeaffinity-5000n.
+#: steady`, 0.072 in `basic-5000n.steady` and 0.027–0.194 in the five
+#: closed cells (PERF.md finding 45): a feed that uses its
+#: share of the lock reads under 0.2 and keeps the rule, the light open
+#: cell's feed reads four times that and does not. One gap does not pass
+#: the gate unless it is as long as everything else since the lock came
+#: free. Where the loop cannot see the store's writers go quiet (agents of
+#: `--apiserver`, a `--grpc-port` front end, no feed connection open) or
+#: the engine paces itself (`--pipeline`, `--lanes`), the rule alone
+#: decides.
+QUIET_TICK_SHARE = 0.5
 
 
 class _Doorbell:
@@ -768,6 +794,16 @@ class Daemon:
         #: (`ServeEngine.refresh_check_s`; 0.0 on a tick that ran none):
         #: the spacing rule counts them once
         self.tick_check_s = 0.0
+        #: whether the loop sees every writer of the store go quiet: the
+        #: TCP feed's connections alone, and the serial engine
+        self._sees_feed = (
+            not args.apiserver and self.grpc_feed is None
+            and self.pipeline is None and self.laned is None
+        )
+        #: (`perf_counter_ns`, the feed's `quiet_total` then) where the
+        #: last tick gave the feed lock up, `None` where the quiet exit
+        #: does not apply to the wait after it
+        self._released = None
         self.last_pending = 0
         self.last_quality = None
         self.last_memory = None  # /healthz device-memory block (ISSUE 20)
@@ -913,6 +949,7 @@ class Daemon:
     def tick(self):
         entered = time.monotonic()
         self.tick_check_s = 0.0
+        self._released = None
         if self.elector is not None and not self.elector.is_leader:
             # standby: reflectors keep the store warm, scheduling waits
             # (client-go leaderelection semantics — informers run, the
@@ -978,6 +1015,11 @@ class Daemon:
         finally:
             lock.release()
         self.tick_locked_s = cycle_s + (time.monotonic() - tail_from)
+        if self._sees_feed:
+            released = time.perf_counter_ns()
+            self._released = (
+                released, self.feed.pulse.quiet_total(released)[0]
+            )
         obs.metrics.observe_ms(obs.TICK_LOCKED, self.tick_locked_s * 1000)
         if ctx is not None:
             # `Finalize`, with no lock held and outside `tick_locked_s`:
@@ -1021,6 +1063,33 @@ class Daemon:
             self.last_memory = costmodel.stamp_device_memory(obs.metrics)
         return report
 
+    def _quiet_wake_in(self) -> float | None:
+        """Seconds until the spacing rule's quiet exit may end the wait,
+        0.0 where it does now, `None` where it does not apply (see
+        `QUIET_TICK_SHARE`; `_released` is where the last tick gave the
+        feed lock up). It does when the feed has had no line in hand for
+        `FEED_QUIET_NS` and has been quiet for `QUIET_TICK_SHARE` of the
+        time since `_released`. Otherwise the answer is the first moment
+        both could hold were the feed to stay quiet from now on: no wait
+        ends at it sooner than it could, and a busy feed's wakes come
+        ever further apart (where the feed is never quiet, each doubles
+        the time since the lock came free)."""
+        if self._released is None or not self.feed.pulse.connections:
+            return None
+        released, quiet_then = self._released
+        now = time.perf_counter_ns()
+        quiet, since = self.feed.pulse.quiet_total(now)
+        # the gap open now, as it will count once it is FEED_QUIET_NS
+        # long; a line in hand has its flush still to come
+        quiet_at = now + FEED_QUIET_NS if since is None else (
+            since + FEED_QUIET_NS
+        )
+        if since is not None and now < quiet_at:
+            quiet += now - since
+        short = QUIET_TICK_SHARE * (now - released) - (quiet - quiet_then)
+        at = max(quiet_at, now + short / (1.0 - QUIET_TICK_SHARE))
+        return max(at - now, 0) / 1e9
+
     def _wait_for_tick(self, started: float, duration: float,
                        check: float = 0.0) -> str:
         """The loop's wait between two ticks, in its own thread; returns
@@ -1031,8 +1100,10 @@ class Daemon:
         ("interval"), and otherwise at the first moment from
         `check + DEMAND_TICK_SPACING × (duration − check)` after `started`
         at which a pod has entered the pending set ("demand"): a check is
-        counted once. A leader-election standby ticks on the interval only.
-        `stop_event` ends the wait at once.
+        counted once. Before that moment a waiting pod starts it where
+        the feed has gone quiet and left the lock unused ("quiet",
+        `_quiet_wake_in`). A leader-election standby ticks on the interval
+        only. `stop_event` ends the wait at once.
 
         The decision is recorded where it is made: the span's args carry
         `woke`, `locked_ms` (the `duration`), `check_ms` (the `check`),
@@ -1057,15 +1128,21 @@ class Daemon:
                 now = time.monotonic()
                 if now >= heartbeat:
                     break
-                if now >= earliest and self._pod_waiting and not standby:
-                    woke = "demand"
-                    break
                 # to the next of the two moments, or a ring: a pod's
                 # first arrival, a stop (a ring before `earliest` only
                 # brings the loop round once more)
-                self._doorbell.wait(
-                    (earliest if now < earliest else heartbeat) - now
-                )
+                until = earliest if now < earliest else heartbeat
+                if self._pod_waiting and not standby:
+                    if now >= earliest:
+                        woke = "demand"
+                        break
+                    quiet_in = self._quiet_wake_in()
+                    if quiet_in == 0.0:
+                        woke = "quiet"
+                        break
+                    if quiet_in is not None:
+                        until = min(until, now + quiet_in)
+                self._doorbell.wait(until - now)
             woke_at = time.monotonic()
             held_ms = (
                 (woke_at - self._pod_rang_at) * 1000

@@ -709,8 +709,61 @@ class FeedTally:
             obs.metrics.inc(obs.FEED_STALLS)
 
 
+class FeedPulse:
+    """The feed as a whole, as the daemon's loop reads it between ticks
+    (`__main__.Daemon._quiet_wake_in`): the newest line in hand on any
+    TCP connection (`in_ns`, `apply_raw`'s first stamp), the newest ack
+    flushed (`mark_ns`, `FeedTally.wrote`'s stamp), and `quiet_ns`, a
+    running count of the gaps of over `FEED_QUIET_NS` from a flush to the
+    next line in hand with none in hand between, on whichever connection:
+    with one connection, the sum of its tally's `quiet` gaps. A line is in
+    hand from its first stamp to its flush, so `in_ns > mark_ns` says one
+    is now. `connections` counts the TCP connections open.
+
+    The stamps are plain attributes the handler threads write with no
+    lock: the event path reads no clock for them and writes no registry.
+    `arrived` writes `in_ns` before `quiet_ns` and `quiet_total` reads
+    them the other way round, so a reader that meets a line half arrived
+    counts its gap later, never twice. Two connections whose lines arrive
+    at the same instant after a quiet gap may both count it."""
+
+    __slots__ = ("in_ns", "mark_ns", "quiet_ns", "connections", "_opening")
+
+    def __init__(self):
+        self.in_ns = self.quiet_ns = self.connections = 0
+        self.mark_ns = time.perf_counter_ns()
+        # connections open and close rarely: their count takes a lock
+        self._opening = threading.Lock()
+
+    def count_connection(self, delta: int) -> None:
+        """A connection opened (+1) or closed (-1)."""
+        with self._opening:
+            self.connections += delta
+
+    def arrived(self, t_in: int) -> None:
+        """A line is in hand at `t_in` (`apply_raw`'s first stamp)."""
+        mark, idle = self.mark_ns, self.in_ns <= self.mark_ns
+        self.in_ns = t_in
+        if idle and t_in - mark > FEED_QUIET_NS:
+            self.quiet_ns += t_in - mark
+
+    def quiet_total(self, now_ns: int) -> tuple:
+        """(`quiet_ns` as of `now_ns`, the flush the feed has had nothing
+        in hand since, `None` while a line is in hand). The gap still
+        open counts once it is `FEED_QUIET_NS` long, from its flush, as it
+        will when it closes."""
+        quiet = self.quiet_ns
+        mark = self.mark_ns
+        if self.in_ns > mark:
+            return quiet, None
+        if now_ns - mark >= FEED_QUIET_NS:
+            quiet += now_ns - mark
+        return quiet, mark
+
+
 def apply_raw(tally: FeedTally, raw: bytes, cluster: Cluster, lock,
-              rv_table: Optional[dict]) -> bytes:
+              rv_table: Optional[dict],
+              pulse: Optional[FeedPulse] = None) -> bytes:
     """One wire event, decoded, applied under `lock` and acknowledged:
     the body both front ends (TCP here, `bridge.grpc_feed`) send back
     (`tally.out_ns` is the stamp at which it was in hand). Times three
@@ -719,14 +772,17 @@ def apply_raw(tally: FeedTally, raw: bytes, cluster: Cluster, lock,
     — with five clock reads and no registry write but the tally's rare
     flush. The first of the five is also the line in hand: on a
     connection whose handler stamps its writes (`FeedTally.wrote`) it
-    closes the gap since the last one. A malformed event is an event: its
-    decode and its error ack are `codec` time."""
+    closes the gap since the last one, and, given the feed's `pulse`,
+    the feed's. A malformed event is an event: its decode and its error
+    ack are `codec` time."""
     clock = time.perf_counter_ns
     # a stage the event never reaches keeps both its stamps equal
     t_in = t_ask = t_held = t_done = clock()
     mark = tally.mark_ns
     if mark and t_in - mark > FEED_QUIET_NS:
         tally.quiet(t_in - mark, t_in)
+    if pulse is not None:
+        pulse.arrived(t_in)
     try:
         event = json.loads(raw)
         t_ask = t_held = t_done = clock()
@@ -768,6 +824,8 @@ class FeedServer:
         self.rv_table: dict = {}
         #: connections so far: the <n> of a connection's `feed/<n>` row
         self._rows = itertools.count()
+        #: what the daemon's loop reads of the TCP connections' lines
+        self.pulse = FeedPulse()
         outer = self
 
         class Handler(socketserver.StreamRequestHandler):
@@ -780,26 +838,29 @@ class FeedServer:
                 # handler's own, begun with the first byte in hand,
                 # flushed by `apply_raw` and at the end
                 self.tally = FeedTally(row=f"feed/{next(outer._rows)}")
+                outer.pulse.count_connection(1)
                 try:
                     if first in (b"\x00", b"\x01"):
                         self._handle_framed()
                     else:
                         self._handle_lines()
                 finally:
+                    outer.pulse.count_connection(-1)
                     self.tally.close()
 
             def _handle_lines(self):
-                tally = self.tally
+                tally, pulse = self.tally, outer.pulse
                 for raw in self.rfile:
                     raw = raw.strip()
                     if not raw:
                         continue
                     self.wfile.write(apply_raw(
                         tally, raw, outer.cluster, outer.lock,
-                        outer.rv_table,
+                        outer.rv_table, pulse,
                     ) + b"\n")
                     self.wfile.flush()
                     tally.wrote()
+                    pulse.mark_ns = tally.mark_ns
 
             def _handle_framed(self):
                 """gRPC message framing (1-byte compressed flag + 4-byte
@@ -807,7 +868,7 @@ class FeedServer:
                 shape a Go agent's grpc stack produces, minus HTTP/2."""
                 import struct as _struct
 
-                tally = self.tally
+                tally, pulse = self.tally, outer.pulse
                 while True:
                     header = self.rfile.read(5)
                     if len(header) < 5:
@@ -832,11 +893,12 @@ class FeedServer:
                         return
                     body = apply_raw(
                         tally, payload, outer.cluster, outer.lock,
-                        outer.rv_table,
+                        outer.rv_table, pulse,
                     )
                     self.wfile.write(_struct.pack(">BI", 0, len(body)) + body)
                     self.wfile.flush()
                     tally.wrote()
+                    pulse.mark_ns = tally.mark_ns
 
         self._server = socketserver.ThreadingTCPServer((host, port), Handler)
         self._server.daemon_threads = True

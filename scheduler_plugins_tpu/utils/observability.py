@@ -570,10 +570,12 @@ HEALTHZ_HANDLER_MS = "scheduler_healthz_handler_ms"
 #: what `--max-cycles` counts)
 TICKS = "scheduler_ticks_total"
 #: the same ticks by what ended the wait before them (labels: reason ∈
-#: demand | interval): a pod entered the pending set and the last tick's
-#: length allowed an early start, or the cycle interval was up (the first
+#: demand | quiet | interval): a pod entered the pending set and the last
+#: tick's length allowed an early start, or a pod waited and the feed had
+#: gone quiet and left the lock unused before that
+#: (`__main__.QUIET_TICK_SHARE`), or the cycle interval was up (the first
 #: tick, a standby's, and every tick that cost a sixth of the interval or
-#: more). The two add up to TICKS
+#: more). The three add up to TICKS
 TICK_WAKEUPS = "scheduler_tick_wakeups_total"
 #: wall ms a tick kept the feed lock, from asking for it to giving it up,
 #: for the cycle and again for the tail (histogram, one observation a tick
@@ -583,8 +585,9 @@ TICK_WAKEUPS = "scheduler_tick_wakeups_total"
 TICK_LOCKED = "scheduler_tick_locked"
 #: wall ms from the first pod that entered the pending set after a tick
 #: started to the end of the loop's wait for the next one (histogram,
-#: labels: woke ∈ demand | interval, one observation a wait; 0 where no
-#: pod waited): how long the loop makes a waiting pod wait, by what held it
+#: labels: woke ∈ demand | quiet | interval, one observation a wait; 0
+#: where no pod waited): how long the loop makes a waiting pod wait, by
+#: what held it
 TICK_HOLD = "scheduler_tick_hold_ms"
 #: wall ms from the start of a tick that ran an anti-entropy check to the
 #: end of the loop's wait after it (histogram, one observation a wait that
@@ -731,7 +734,8 @@ HELP: dict[str, str] = {
         "by what ended the wait (0 where no pod waited).",
     TICK_WAKEUPS:
         "Ticks by what ended the wait before them: a pod became "
-        "schedulable (demand) or the cycle interval was up (interval).",
+        "schedulable (demand), a pod waited on a quiet feed (quiet) or "
+        "the cycle interval was up (interval).",
     TICK_AFTER_CHECK:
         "Wall ms from the start of a tick that ran an anti-entropy check "
         "to the end of the wait after it; the check is spaced once.",

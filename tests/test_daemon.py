@@ -870,6 +870,23 @@ class TestServedLoopTrace:
             # a pod waited, and no longer than since the tick before
             assert 0.0 < args["held_ms"] <= args["since_start_ms"] + 1e-6
 
+    def test_a_quiet_wake_came_early_and_after_the_feed_fell_quiet(
+            self, served):
+        from scheduler_plugins_tpu.__main__ import DEMAND_TICK_SPACING
+        from scheduler_plugins_tpu.bridge.feed import FEED_QUIET_NS
+
+        quiet = [
+            e["args"] for e in served["spans"]
+            if e["name"] == "Loop/sleep" and e["args"]["woke"] == "quiet"
+        ]
+        for args in quiet:
+            # before the spacing rule's moment and the 0.1 s interval
+            assert args["since_start_ms"] < min(100.0, DEMAND_TICK_SPACING
+                                                * args["locked_ms"]), args
+            # the ring came with a line, and the wait ended no sooner
+            # than FEED_QUIET_NS after that line's ack was flushed
+            assert args["held_ms"] >= FEED_QUIET_NS / 1e6, args
+
     def test_an_interval_wake_with_no_pod_held_nothing(self, served):
         idle = [
             e["args"] for e in served["spans"]

@@ -13,8 +13,16 @@ import types
 import pytest
 
 from scheduler_plugins_tpu import __main__ as daemon_main
-from scheduler_plugins_tpu.__main__ import DEMAND_TICK_SPACING, Daemon
-from scheduler_plugins_tpu.bridge.feed import apply_event
+from scheduler_plugins_tpu.__main__ import (
+    DEMAND_TICK_SPACING,
+    QUIET_TICK_SHARE,
+    Daemon,
+)
+from scheduler_plugins_tpu.bridge.feed import (
+    FEED_QUIET_NS,
+    FeedPulse,
+    apply_event,
+)
 from scheduler_plugins_tpu.utils import observability as obs
 
 PROFILE = {
@@ -391,26 +399,77 @@ class FakeClock:
     def monotonic(self) -> float:
         return self.now
 
+    def perf_counter_ns(self) -> int:
+        return round(self.now * 1e9)
+
     def time(self) -> float:
         return 1.7e9 + self.now
 
 
-def _two_fake_ticks(loop, monkeypatch, check_s: float = 0.0):
+class ScriptedFeed:
+    """A feed connection's lines on a `FakeClock`, as the daemon reads
+    them: `lines` holds (in hand, flushed) in ms after the first tick's
+    start, and each read replays those begun by then through a real
+    `FeedPulse`."""
+
+    connections = 1
+
+    def __init__(self, lines):
+        self.base = round(FakeClock().now * 1e9)
+        self.lines = [
+            (self.base + round(a * 1e6), self.base + round(b * 1e6))
+            for a, b in lines
+        ]
+
+    def quiet_total(self, now_ns: int) -> tuple:
+        pulse = FeedPulse()
+        pulse.mark_ns = self.base
+        for t_in, t_out in self.lines:
+            if t_in > now_ns:
+                break
+            pulse.arrived(t_in)
+            if t_out <= now_ns:
+                pulse.mark_ns = t_out
+        return pulse.quiet_total(now_ns)
+
+
+def _two_fake_ticks(loop, monkeypatch, check_s: float = 0.0,
+                    interval_s: float = 1.0, feed=None, arrive=True,
+                    standby=False):
     """Two serial ticks of a daemon on a `FakeClock`, a pod waiting
-    whenever the loop looks: each holds the feed lock 10 ms plus an
-    anti-entropy check of `check_s` in its refresh, then finalizes for 10
-    ms more, unlocked. Returns the daemon and when each tick's cycle
-    began."""
-    d = loop(1.0, "--max-cycles", "2").daemon
+    whenever the loop looks (unless not `arrive`): each holds the feed
+    lock 10 ms plus an anti-entropy check of `check_s` in its refresh,
+    then finalizes for 10 ms more, unlocked. `feed` (a `ScriptedFeed`)
+    stands for the feed's connections; without it none is open. A
+    `standby` daemon's ticks take 10 ms and schedule nothing. Returns the
+    daemon and when each tick's cycle began."""
+    d = loop(interval_s, "--max-cycles", "2").daemon
     clock = FakeClock()
     monkeypatch.setattr(daemon_main, "time", clock)
+    if feed is not None:
+        monkeypatch.setattr(d.feed, "pulse", feed)
+    if standby:
+        d.elector = types.SimpleNamespace(
+            is_leader=False, observed_holder="other", release=lambda: None
+        )
+        inner = d.tick
+
+        def tick():
+            starts.append(clock.now)
+            if arrive:
+                d._pod_arrived()
+            clock.now += 0.010
+            return inner()
+
+        d.tick = tick  # `run` looks `tick` up on the instance
     # the serial tick reads a served cycle's check off the engine
     d.engine = types.SimpleNamespace(refresh_check_s=check_s)
     starts = []
 
     def cycle_store_stages(*_args, **_kwargs):
         starts.append(clock.now)
-        d._pod_arrived()
+        if arrive:
+            d._pod_arrived()
         clock.now += 0.010 + check_s
         return types.SimpleNamespace(served=True, report=types.SimpleNamespace(
             bound={}, failed=[], quality=None,
@@ -481,6 +540,99 @@ def test_demand_spacing_counts_a_check_once(
     assert scoped.hist_sum(obs.TICK_AFTER_CHECK) == pytest.approx(
         gap_s * 1000 if check_s else 0.0, abs=1e-6
     )
+
+
+# --- the spacing rule's quiet exit: a feed that left the lock unused ----
+
+
+def _lines(start_ms, end_ms, every_ms, held_ms=0.3):
+    """Lines every `every_ms` from `start_ms` to `end_ms`, each in hand
+    for `held_ms`: a stretch in which the feed is busy (no gap reaches
+    `FEED_QUIET_NS`)."""
+    out, t = [], start_ms
+    while t < end_ms:
+        out.append((t, t + held_ms))
+        t += every_ms
+    return out
+
+
+def _chopped(start_ms, end_ms, busy_ms, gap_ms):
+    """A closed cell's feed: busy stretches of `busy_ms` cut by quiet
+    gaps of `gap_ms` (5-7 ms: each passes `FEED_QUIET_NS`)."""
+    out, t = [], start_ms
+    while t < end_ms:
+        out += _lines(t, t + busy_ms, 0.5)
+        t += busy_ms + gap_ms
+    return out
+
+
+# the first tick starts at 0 ms and gives the lock up at 10 ms (+ its
+# check); its `Finalize` ends, and the loop's wait begins, 10 ms later.
+# One line waits for the lock through the tick and is flushed just after
+QUIET_CASES = {
+    # a line in hand as the wait begins, flushed at 20.2 ms, and nothing
+    # since: the feed was quiet 9.6 + 5.0 of the 15.2 ms since the lock
+    # came free, so the wait ends at that flush + FEED_QUIET_NS
+    "quiet": dict(feed=[(2.0, 10.3), (19.9, 20.2)], gap_s=0.0252,
+                  woke="quiet"),
+    # the closed cell's pattern: 6 ms gaps, but 8 ms busy between them
+    # from the moment the lock came free, a quiet share of 6 / 14 < 1/2:
+    # the rule's moment exactly
+    "busy-with-gaps": dict(
+        feed=[(2.0, 10.3)] + _chopped(10.3, 200.0, 8.0, 6.0),
+        gap_s=DEMAND_TICK_SPACING * 0.010, woke="demand"),
+    # no pod waits: a quiet feed is no reason to tick
+    "no-pod": dict(feed=[(2.0, 10.3)], arrive=False, gap_s=1.0,
+                   woke="interval"),
+    # a standby ticks on the interval only, whatever the feed does
+    "standby": dict(feed=[(2.0, 10.3)], standby=True, gap_s=1.0,
+                    woke="interval"),
+    # the feed would be quiet at 33 ms, past a 30 ms interval
+    "heartbeat": dict(feed=[(2.0, 10.3), (19.0, 28.0)], interval_s=0.030,
+                      gap_s=0.030, woke="interval"),
+    # a busy feed keeps the check counted once: 0.170 + 6 x 0.010
+    "check-busy": dict(feed=_lines(0.0, 400.0, 1.0), check_s=0.170,
+                       gap_s=0.170 + DEMAND_TICK_SPACING * 0.010,
+                       woke="demand"),
+}
+
+
+@pytest.mark.parametrize("case", list(QUIET_CASES))
+def test_a_quiet_feed_ends_the_wait_only_where_it_left_the_lock_unused(
+    loop, monkeypatch, case
+):
+    spec = dict(QUIET_CASES[case])
+    gap_s, woke = spec.pop("gap_s"), spec.pop("woke")
+    feed = ScriptedFeed(spec.pop("feed"))
+    assert QUIET_TICK_SHARE == 0.5 and FEED_QUIET_NS == 5_000_000
+    scoped = obs.metrics.scoped()
+    d, starts = _two_fake_ticks(loop, monkeypatch, feed=feed, **spec)
+    assert starts[1] - starts[0] == pytest.approx(gap_s, abs=1e-8)
+    for reason in ("demand", "quiet", "interval"):
+        assert scoped.get(obs.TICK_WAKEUPS, reason=reason) == (
+            (reason == woke) + (reason == "interval")
+        ), reason
+        # the first wait is observed under what ended it
+        assert scoped.hist_count(obs.TICK_HOLD, woke=reason) == (
+            reason == woke
+        ), reason
+    assert scoped.get(obs.TICKS) == 2
+
+
+@pytest.mark.parametrize("flags", [
+    (), ("--grpc-port", "0"), ("--pipeline",),
+], ids=["feed", "grpc", "pipeline"])
+def test_the_quiet_exit_needs_a_feed_it_can_see(loop, flags):
+    # where another front end or the engine's own pacing is in play the
+    # spacing rule alone decides; a daemon with no feed connection open
+    # has no quiet feed either
+    if flags and flags[0] == "--grpc-port":
+        pytest.importorskip("grpc")
+    d = loop(1.0, *flags).daemon
+    assert d._sees_feed == (not flags)
+    d._released = (0, 0)
+    assert d.feed.pulse.connections == 0
+    assert d._quiet_wake_in() is None
 
 
 def test_a_tick_whose_cycle_was_not_served_counts_no_check(

@@ -922,6 +922,87 @@ class TestFeedStageCounters:
             assert delta["quiet_sum_ms"] == pytest.approx(gap_ns / 1e6)
             assert delta["stalls"] == (1 if part == "stall" else 0)
 
+    @pytest.mark.parametrize("gaps_ns", [
+        (5_000_000, 5_000_001, 7_000_000, 1_000, 1_000_000_000),
+        (1_000, 2_000, 3_000),
+        (20_000_000,),
+    ], ids=["mixed", "answering", "one-quiet"])
+    def test_the_pulse_counts_the_quiet_the_tally_counts(
+            self, monkeypatch, gaps_ns):
+        # the daemon's loop reads the feed's quiet time off `FeedPulse`;
+        # on one connection it is the tally's quiet gaps, to the
+        # nanosecond, and the gap still open counts from its flush once
+        # it is FEED_QUIET_NS long
+        import threading
+        import types
+
+        from scheduler_plugins_tpu.bridge import feed
+
+        ticks = iter(range(1_000, 10**7, 1_000))
+        clock = types.SimpleNamespace(perf_counter_ns=lambda: next(ticks))
+        monkeypatch.setattr(feed, "time", clock)
+        made: list = []
+        tally = _recording_tally(made)(row="feed/t")  # stamps 1_000
+        pulse = feed.FeedPulse()  # stamps 2_000
+        lock, cluster = threading.Lock(), Cluster()
+
+        def line():
+            feed.apply_raw(tally, b'{"op": "sync"}', cluster, lock, None,
+                           pulse)
+            assert pulse.in_ns > pulse.mark_ns  # in hand
+            assert pulse.quiet_total(pulse.in_ns) == (pulse.quiet_ns, None)
+            tally.wrote()
+            pulse.mark_ns = tally.mark_ns
+
+        line()
+        assert pulse.quiet_ns == 0
+        for gap in gaps_ns:
+            at = tally.mark_ns + gap
+            ticks = iter(range(at, at + 10**6, 1_000))
+            line()
+        tally.close()
+        assert pulse.quiet_ns == sum(tally.gaps)
+        assert len(tally.gaps) == sum(g > feed.FEED_QUIET_NS for g in gaps_ns)
+        mark = pulse.mark_ns
+        for open_ns, counted in ((feed.FEED_QUIET_NS - 1, 0),
+                                 (feed.FEED_QUIET_NS, feed.FEED_QUIET_NS),
+                                 (9_000_000, 9_000_000)):
+            assert pulse.quiet_total(mark + open_ns) == (
+                sum(tally.gaps) + counted, mark)
+
+    @pytest.mark.parametrize("transport", ["lines", "framed"])
+    def test_a_connections_pulse_agrees_with_its_tally(
+            self, monkeypatch, transport):
+        import time
+
+        from scheduler_plugins_tpu.bridge import feed
+        from scheduler_plugins_tpu.bridge.feed import FramedFeedClient
+
+        client_class = FeedClient if transport == "lines" else FramedFeedClient
+        made: list = []
+        monkeypatch.setattr(feed, "FeedTally", _recording_tally(made))
+        server = FeedServer(Cluster()).start()
+        pulse = server.pulse
+        try:
+            client = client_class(*server.address)
+            assert client.send({"op": "sync"})["ok"]
+            assert pulse.connections == 1
+            # from the first flush on, the two count from the same stamps
+            quiet_before, gaps_before = pulse.quiet_ns, len(made[0].gaps)
+            for pause in (0.0, 0.02, 0.001, 0.012, 0.0):
+                time.sleep(pause)
+                assert client.send({"op": "sync"})["ok"]
+            client.close()
+            deadline = time.monotonic() + 10
+            while pulse.connections and time.monotonic() < deadline:
+                time.sleep(0.01)
+        finally:
+            server.stop()
+        assert pulse.connections == 0
+        gaps = made[0].gaps[gaps_before:]
+        assert len(gaps) >= 2  # the two pauses of 12 ms and more
+        assert pulse.quiet_ns - quiet_before == sum(gaps)
+
     @pytest.mark.parametrize("rpc", ["unary", "stream"])
     def test_grpc_keeps_its_three_stages(self, monkeypatch, rpc):
         pytest.importorskip("grpc")
