@@ -165,19 +165,6 @@ def parse_args(argv=None):
                          "engine upgrades to the O(changed) "
                          "StreamingServeEngine (node-delete compaction, "
                          "memoized ingest, O(assigned) anti-entropy)")
-    ap.add_argument("--lanes", type=int, default=0, metavar="K",
-                    help="K-lane optimistic-concurrency scheduling "
-                         "(framework.laned_cycle.LanedCycle): partition "
-                         "the pending queue across K solver lanes by a "
-                         "deterministic key (gang members never split), "
-                         "solve all lanes speculatively against the same "
-                         "resident state and commit through a single "
-                         "host-side conflict fence in the defined serial "
-                         "order — bit-identical to the serial cycle at "
-                         "every K. Profiles outside the fence-exact gate "
-                         "fall back to the sequential parity solve per "
-                         "cycle (counted on /healthz). Mutually "
-                         "exclusive with --pipeline")
     ap.add_argument("--tune", action="store_true",
                     help="online self-tuning shadow lane "
                          "(tuning.shadow.ShadowTuner): continuously "
@@ -265,8 +252,7 @@ DEMAND_TICK_SPACING = 6
 #: the gate unless it is as long as everything else since the lock came
 #: free. Where the loop cannot see the store's writers go quiet (agents of
 #: `--apiserver`, a `--grpc-port` front end, no feed connection open) or
-#: the engine paces itself (`--pipeline`, `--lanes`), the rule alone
-#: decides.
+#: the engine paces itself (`--pipeline`), the rule alone decides.
 QUIET_TICK_SHARE = 0.5
 
 
@@ -470,11 +456,6 @@ class HealthServer:
                             "depth": outer.pipeline.depth,
                             "inflight": outer.pipeline.inflight,
                         }
-                    if outer.laned is not None:
-                        # K-lane engine introspection: lane config +
-                        # conflict/re-resolve/fallback totals and the
-                        # latest cycle's per-lane attribution
-                        payload["lanes"] = outer.laned.stats()
                     if outer.engine is not None:
                         payload["serve"] = {
                             "generation": outer.engine.generation,
@@ -641,7 +622,7 @@ class Daemon:
         if args.scheduler_name:
             self.cluster.scheduler_names = set(args.scheduler_name)
         # every engine reads the maintained pending index (the pipelined
-        # and laned ones switch it on themselves too): the scan's list in
+        # one switches it on itself too): the scan's list in
         # the scan's order, without a walk over every pod twice a tick
         self.cluster.enable_pending_index()
         self.engine = None
@@ -652,8 +633,7 @@ class Daemon:
             )
 
             engine_cls = (
-                StreamingServeEngine if (args.pipeline or args.lanes)
-                else ServeEngine
+                StreamingServeEngine if args.pipeline else ServeEngine
             )
             self.engine = engine_cls().attach(self.cluster)
             if args.checkpoint and os.path.exists(args.checkpoint):
@@ -723,31 +703,6 @@ class Daemon:
                 self.scheduler, self.cluster, serve=self.engine,
                 resilience=self.resilience, async_bind=False,
             )
-        self.laned = None
-        if args.lanes:
-            if args.pipeline:
-                raise SystemExit(
-                    "--lanes and --pipeline are mutually exclusive "
-                    "(both recompose the cycle around their own "
-                    "concurrency model)"
-                )
-            if args.resilient:
-                raise SystemExit(
-                    "--lanes does not compose with --resilient: the "
-                    "watchdog's degraded path IS the sequential engine "
-                    "— lanes would add only fence overhead to it"
-                )
-            from scheduler_plugins_tpu.framework import LanedCycle
-
-            try:
-                # binds flush inline (async_bind=False): every store
-                # mutation happens under the feed lock the tick holds
-                self.laned = LanedCycle(
-                    self.scheduler, self.cluster, k=args.lanes,
-                    serve=self.engine, async_bind=False,
-                )
-            except ValueError as exc:
-                raise SystemExit(f"--lanes: {exc}")
         #: `run` waits on the doorbell between ticks; the store's hook
         #: rings it for the first pod that enters the pending set after a
         #: tick started, a stop rings it too
@@ -798,7 +753,7 @@ class Daemon:
         #: TCP feed's connections alone, and the serial engine
         self._sees_feed = (
             not args.apiserver and self.grpc_feed is None
-            and self.pipeline is None and self.laned is None
+            and self.pipeline is None
         )
         #: (`perf_counter_ns`, the feed's `quiet_total` then) where the
         #: last tick gave the feed lock up, `None` where the quiet exit
@@ -961,9 +916,8 @@ class Daemon:
         now_ms = int(time.time() * 1000)
         ctx = None
         try:
-            engine = self.pipeline or self.laned
-            if engine is not None:
-                # the pipelined/laned engines compose their own stage
+            if self.pipeline is not None:
+                # the pipelined engine composes its own stage
                 # functions; the tuner's two seams wrap the whole tick
                 # (weights may only change between ticks — the conflict
                 # fence keeps any in-flight solve on the weights it
@@ -971,7 +925,7 @@ class Daemon:
                 if self.tuner is not None:
                     self.tuner.begin_cycle(now_ms=now_ms)
                 with self.feed.locked():
-                    report = engine.tick(now_ms)
+                    report = self.pipeline.tick(now_ms)
                 if self.tuner is not None and report is not None:
                     self.tuner.observe_report(report)
             else:
@@ -1209,13 +1163,6 @@ class Daemon:
                         self.pipeline.close()
                 except Exception as exc:
                     obs.logger.warning("pipeline flush failed: %s", exc)
-            if self.laned is not None:
-                try:
-                    # join the lane bind flusher and shut the lane pool
-                    with self.feed.locked():
-                        self.laned.close()
-                except Exception as exc:
-                    obs.logger.warning("lane flush failed: %s", exc)
             if self.args.record and self.args.record_dir:
                 from scheduler_plugins_tpu.utils import flightrec
 

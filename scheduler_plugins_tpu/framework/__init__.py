@@ -11,9 +11,6 @@ from scheduler_plugins_tpu.framework.cycle import (  # noqa: F401
     CycleReport,
     run_cycle,
 )
-from scheduler_plugins_tpu.framework.laned_cycle import (  # noqa: F401
-    LanedCycle,
-)
 from scheduler_plugins_tpu.framework.pipeline_cycle import (  # noqa: F401
     CycleTimeline,
     PipelinedCycle,

@@ -99,12 +99,6 @@ class CycleReport:
     #: max_cost, sum_cost} — empty when the cycle ran without a gang
     #: phase or no rank-aware gang had pending members
     rank_gangs: dict = field(default_factory=dict)
-    #: lane attribution when the cycle ran under the K-lane optimistic
-    #: engine (`framework.laned_cycle.LanedCycle`): k, path
-    #: ("laned"/"serial" fallback), per-lane sizes/committed/conflicts,
-    #: re_resolved count and solve/fence wall ms (`LaneStats.as_dict`);
-    #: None for every other engine
-    lanes: dict | None = None
 
     def explain(self, uid: str, top_k: int = 5) -> dict:
         """The "why this node" score table for one pod of THIS cycle's
@@ -237,7 +231,7 @@ def _cycle_open(scheduler, cluster, now, stream_chunk=None, serve=None,
     )
     # the ledger scope opens BEFORE gang expiry: whole-gang rejections in
     # the prologue are this cycle's decisions and must attribute to it.
-    # Callers (run_cycle / PipelinedCycle.tick / LanedCycle.tick) pop the
+    # Callers (run_cycle / PipelinedCycle.tick) pop the
     # scope in their finally — the stage functions only push nested ones.
     ctx.led = podledger.LEDGER.cycle_open(now)
     podledger.LEDGER.push_scope(ctx.led, 0)

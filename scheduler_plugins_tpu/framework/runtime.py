@@ -290,10 +290,9 @@ def _solve_step(plugins, carry, p, snap: ClusterSnapshot, view_codes=None):
     """One pod of the bit-faithful sequential scan: PreFilter -> built-in
     fit (nominee holds) -> Filter chain -> Score/Normalize weighted sum ->
     argmax select -> Reserve commits — THE parity-path step body, shared by
-    `Scheduler.solve`, the vmapped counterfactual sweep
-    (`parallel.solver.sweep_solve_fn`) and the K-lane speculative solve
-    (`parallel.lanes.lane_solve_fn`, which feeds it a one-pod snapshot
-    view per step), so no fast path can drift from the parity program.
+    `Scheduler.solve` and the vmapped counterfactual sweep
+    (`parallel.solver.sweep_solve_fn`), so no fast path can drift from
+    the parity program.
     `view_codes`: the code rows of the carry's node-space views
     (`ops.selectors.attach_node_views`), from a scan that carries views."""
     state = carry

@@ -9,15 +9,6 @@ axis the data-parallel role (independent pods in a wave). XLA inserts the
 collectives from sharding annotations — no hand-written NCCL analog.
 """
 
-from scheduler_plugins_tpu.parallel.lanes import (  # noqa: F401
-    LaneSolver,
-    LaneStats,
-    fence_exact,
-    lane_key,
-    lane_of,
-    lane_solve_fn,
-    partition_lanes,
-)
 from scheduler_plugins_tpu.parallel.mesh import (  # noqa: F401
     make_mesh,
     make_node_mesh,

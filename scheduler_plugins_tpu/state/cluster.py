@@ -127,8 +127,8 @@ class Cluster:
     #: serving engine ingests instead of re-snapshotting (docs/SERVING.md)
     delta_sink: Optional[object] = None
     #: opt-in O(changed) pending index (`enable_pending_index`: the
-    #: daemon switches it on for every engine, the pipelined and laned
-    #: engines themselves): uid -> Pod for every currently-schedulable
+    #: daemon switches it on for every engine, the pipelined engine
+    #: itself): uid -> Pod for every currently-schedulable
     #: pod, maintained by the same mutators that notify the delta sink.
     #: None (the default) keeps `pending_pods` as the exact O(pods) scan.
     _pending_idx: Optional[dict] = None
@@ -679,20 +679,6 @@ class Cluster:
         if self._pending_idx is not None:
             return len(self._pending_idx)
         return len(self.pending_pods())
-
-    def admission_serial(self, uid: str) -> int:
-        """The pod's position in admission order — the reproducible
-        partition key of the K-lane engine's "hash" mode
-        (`parallel.lanes.lane_key`). With the pending index enabled this
-        is the maintained `_pod_order` serial (survives removes of other
-        pods); without it, the dict-iteration position (the same order
-        the index would have assigned). -1 for an unknown uid."""
-        if self._pod_order:
-            return self._pod_order.get(uid, -1)
-        for i, known in enumerate(self.pods):
-            if known == uid:
-                return i
-        return -1
 
     def gated_pods(self) -> list[Pod]:
         return [

@@ -506,16 +506,6 @@ TUNER_ACTIVE_WEIGHTS = "scheduler_tuner_active_weights_digest"
 #: gauge: tuner controller state (0 idle, 1 probation, 2 cooldown,
 #: 3 disabled)
 TUNER_STATE = "scheduler_tuner_state"
-#: conflict-fence rejections per lane (parallel.lanes.LaneSolver): pod p
-#: of lane j failed the speculative-vs-committed step-signature check —
-#: the whole remaining suffix re-resolves against committed state
-LANE_CONFLICTS = "scheduler_lane_conflicts_total"
-#: pods re-resolved against committed state by the suffix repair solve
-LANE_RERESOLVES = "scheduler_lane_reresolves_total"
-#: laned cycles that fell back to the sequential parity solve because the
-#: fence-exact gate rejected the profile/snapshot (side tables armed,
-#: preemption nominees present, or an admit plugin without a host twin)
-LANE_SERIAL_FALLBACKS = "scheduler_lane_serial_fallbacks_total"
 #: per-pod e2e scheduling latency histogram (labels: priority) — the
 #: upstream `scheduler_e2e_scheduling_duration_seconds` family in ms
 #: (vendored registration: cmd/scheduler/main.go:23-24), fed by the
@@ -694,10 +684,6 @@ HELP: dict[str, str] = {
     TUNER_STATE:
         "Tuner controller state: 0 idle, 1 probation, 2 cooldown, "
         "3 disabled (gauge).",
-    LANE_CONFLICTS: "Conflict-fence rejections per lane.",
-    LANE_RERESOLVES: "Pods re-resolved by the suffix repair solve.",
-    LANE_SERIAL_FALLBACKS:
-        "Laned cycles that fell back to the sequential parity solve.",
     E2E_SCHEDULING_MS:
         "Per-pod e2e scheduling latency in ms, labeled by priority "
         "(upstream scheduler_e2e_scheduling_duration_seconds, in ms).",

@@ -513,68 +513,6 @@ class TestApiserverOutageRecovery:
                 proc.communicate()
 
 
-class TestDaemonLanes:
-    """--lanes K: the daemon runs the K-lane optimistic-concurrency
-    engine (framework.laned_cycle.LanedCycle) and exposes its lane
-    attribution on /healthz."""
-
-    def test_lanes_daemon_binds_and_reports_on_healthz(self, tmp_path):
-        with FakeApiServer(expected_token="sekrit") as srv:
-            srv.lists["/api/v1/nodes"] = _listing(
-                "NodeList",
-                [_node("n0", cpu="4", rv=1), _node("n1", cpu="4", rv=1)],
-                rv=2)
-            srv.lists["/api/v1/pods"] = _listing(
-                "PodList",
-                [_pod("a", cpu="500m", rv=3), _pod("b", cpu="500m", rv=3)],
-                rv=3)
-            srv.watch_scripts["/api/v1/pods"] = [[("stall", 30)]]
-            srv.watch_scripts["/api/v1/nodes"] = [[("stall", 30)]]
-
-            proc, status = _start_daemon(
-                tmp_path, srv.url, extra_args=("--lanes", "2", "--serve"),
-            )
-            try:
-                def bound_names():
-                    with srv.lock:
-                        return {
-                            path.rsplit("/pods/", 1)[1].split("/")[0]
-                            for path, _ in srv.posts
-                            if path.endswith("/binding")
-                        }
-
-                assert _wait(lambda: bound_names() >= {"a", "b"}), (
-                    srv.posts, proc.stderr.read() if proc.poll() else "")
-                health = json.loads(urllib.request.urlopen(
-                    status["health"], timeout=5).read())
-                lanes = health["lanes"]
-                assert lanes["k"] == 2
-                assert lanes["cycles"] >= 1
-                assert lanes["serial_fallbacks"] == 0
-                assert lanes["last"]["path"] in ("laned", "serial")
-                # the lane workers are part of the audited topology
-                assert not health["threads"]["unknown"], health["threads"]
-            finally:
-                proc.send_signal(signal.SIGTERM)
-                out, err = proc.communicate(timeout=60)
-            assert proc.returncode == 0, err
-
-    def test_lanes_and_pipeline_are_mutually_exclusive(self, tmp_path):
-        profile = tmp_path / "p.json"
-        profile.write_text(
-            json.dumps({"plugins": ["NodeResourcesAllocatable"]})
-        )
-        env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO)
-        proc = subprocess.run(
-            [sys.executable, "-m", "scheduler_plugins_tpu",
-             "--profile", str(profile), "--lanes", "2", "--pipeline",
-             "--max-cycles", "1", "--health-port", "-1"],
-            cwd=REPO, env=env, capture_output=True, text=True, timeout=120,
-        )
-        assert proc.returncode != 0
-        assert "mutually exclusive" in proc.stderr
-
-
 class TestThreadTopology:
     """/healthz `threads` block: the live thread census diffed against
     the static concurrency model (tools/race_audit.py entry table +

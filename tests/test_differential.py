@@ -4,13 +4,22 @@ Python implementation of the same semantics (per-pod scan over all nodes:
 resource fit -> weighted allocatable score with Go integer division ->
 min-max normalize -> argmax with lowest-index tie-break -> commit)."""
 
+import itertools
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from scheduler_plugins_tpu.api.objects import Container, Node, Pod
+from scheduler_plugins_tpu.api.objects import (
+    POD_GROUP_LABEL,
+    Container,
+    ElasticQuota,
+    Node,
+    Pod,
+    PodGroup,
+)
 from scheduler_plugins_tpu.api.resources import CPU, MEMORY, PODS
-from scheduler_plugins_tpu.framework import Profile, Scheduler
+from scheduler_plugins_tpu.framework import Profile, Scheduler, run_cycle
 from scheduler_plugins_tpu.plugins import NodeResourcesAllocatable
 from scheduler_plugins_tpu.state.cluster import Cluster
 
@@ -454,6 +463,497 @@ class TestBatchedNumaGangHardConstraintParity:
             assert int((a_bat >= 0).sum()) >= int((a_seq >= 0).sum()), seed
 
 
+def _ns_pod(name, cpu, ns="default", created=0, labels=None, mem=gib):
+    return Pod(
+        name=name, namespace=ns, creation_ms=created, labels=labels or {},
+        containers=[Container(requests={CPU: cpu, MEMORY: mem})],
+    )
+
+
+def _deep_node(name, cpu=16_000, mem=64 * gib):
+    return Node(name=name, allocatable={CPU: cpu, MEMORY: mem, PODS: 110})
+
+
+def _allocatable_scheduler():
+    return Scheduler(Profile(plugins=[NodeResourcesAllocatable()]))
+
+
+def _gang_quota_scheduler():
+    from scheduler_plugins_tpu.plugins import (
+        CapacityScheduling,
+        Coscheduling,
+    )
+
+    return Scheduler(Profile(plugins=[
+        NodeResourcesAllocatable(),
+        Coscheduling(permit_waiting_seconds=5),
+        CapacityScheduling(),
+    ]))
+
+
+def _no_node_overcommitted(cluster):
+    used = {}
+    for p in cluster.pods.values():
+        if p.node_name is None:
+            continue
+        row = used.setdefault(p.node_name, {})
+        for r, v in p.effective_request().items():
+            row[r] = row.get(r, 0) + v
+    for name, row in used.items():
+        alloc = cluster.nodes[name].allocatable
+        for r, v in row.items():
+            assert v <= alloc.get(r, 0), (name, r, v)
+
+
+def _quota_max_held(cluster):
+    used = {}
+    for p in cluster.pods.values():
+        if p.node_name is not None:
+            used[p.namespace] = used.get(p.namespace, 0) + (
+                p.effective_request().get(CPU, 0)
+            )
+    for q in cluster.quotas.values():
+        assert used.get(q.namespace, 0) <= q.max[CPU], q.namespace
+
+
+def _gangs_all_or_nothing(cluster):
+    bound = {}
+    for p in cluster.pods.values():
+        if p.pod_group() and p.node_name is not None:
+            key = f"{p.namespace}/{p.pod_group()}"
+            bound[key] = bound.get(key, 0) + 1
+    for pg in cluster.pod_groups.values():
+        n = bound.get(f"{pg.namespace}/{pg.name}", 0)
+        assert n == 0 or n >= pg.min_member, (pg.name, n)
+
+
+def _no_events(cluster, cycle, now, rng, serial):
+    pass
+
+
+class _Scenario:
+    """One event stream for the serve-vs-fresh differential: `build(rng)`
+    makes the starting cluster, `step(cluster, cycle, now, rng, serial)`
+    applies a cycle's events before it runs (`serial` numbers the pods
+    it adds), `invariant(cluster)` must hold after
+    every cycle, and `check(reports, cluster)` pins the outcome the
+    scenario exists for."""
+
+    def __init__(self, build, step, cycles, scheduler=_allocatable_scheduler,
+                 seed=0, invariant=_no_node_overcommitted, check=None):
+        self.build, self.step, self.cycles = build, step, cycles
+        self.scheduler, self.seed = scheduler, seed
+        self.invariant, self.check = invariant, check
+
+
+def _dense():
+    from tests.test_serving import make_cluster, make_node, make_pod
+
+    def step(cluster, cycle, now, rng, serial):
+        for _ in range(int(rng.integers(1, 4))):
+            cluster.add_pod(make_pod(
+                next(serial), now, int(rng.integers(200, 2500)), gib
+            ))
+        if cycle == 3:
+            cluster.add_node(make_node(40))
+        if cycle == 5:
+            bound = sorted(
+                u for u, p in cluster.pods.items() if p.node_name is not None
+            )
+            cluster.remove_pod(bound[0])
+
+    return _Scenario(lambda rng: make_cluster(5), step, 8, seed=11)
+
+
+def _last_capacity():
+    """Two namespaces bid one node's last capacity: the earlier pod in
+    queue order wins, the later one fails on the fit."""
+    def build(rng):
+        c = Cluster()
+        c.add_node(_deep_node("n0", cpu=1000, mem=8 * gib))
+        c.add_pod(_ns_pod("first", 800, ns="team-a", created=10))
+        c.add_pod(_ns_pod("second", 800, ns="team-b", created=20))
+        return c
+
+    def check(reports, cluster):
+        assert reports[0].bound == {"team-a/first": "n0"}
+        assert reports[0].failed == ["team-b/second"]
+        assert reports[0].failed_by == {
+            "team-b/second": "NodeResourcesFit"
+        }
+        assert cluster.pods["team-b/second"].node_name is None
+
+    return _Scenario(build, _no_events, 2, check=check)
+
+
+def _quota_contention():
+    """Two quota'd namespaces contend for the aggregate Min, then one of
+    them for its own Max: queue order decides the first, and no cycle
+    leaves a namespace over its Max."""
+    def build(rng):
+        c = Cluster()
+        c.add_node(_deep_node("n0"))
+        c.add_node(_deep_node("n1"))
+        for ns, cap in (("team-a", 2500), ("team-b", 16_000)):
+            c.add_quota(ElasticQuota(
+                name=f"eq-{ns}", namespace=ns,
+                min={CPU: 1000, MEMORY: 8 * gib},
+                max={CPU: cap, MEMORY: 64 * gib},
+            ))
+        # aggregate Min 2000: the first 1500 borrows, the second's 1500
+        # overflows the aggregate once the first's usage is counted
+        c.add_pod(_ns_pod("first", 1500, ns="team-a", created=10))
+        c.add_pod(_ns_pod("second", 1500, ns="team-b", created=20))
+        return c
+
+    def step(cluster, cycle, now, rng, serial):
+        if cycle == 1:
+            # 1500 + 1200 would put team-a over its Max of 2500
+            cluster.add_pod(_ns_pod("over", 1200, ns="team-a", created=now))
+
+    def check(reports, cluster):
+        first = reports[0]
+        assert first.bound == {"team-a/first": "n0"}
+        assert first.failed == ["team-b/second"]
+        assert first.failed_by["team-b/second"] == "CapacityScheduling"
+        assert cluster.pods["team-a/over"].node_name is None
+        assert any(
+            r.failed_by.get("team-a/over") == "CapacityScheduling"
+            for r in reports
+        )
+
+    def invariant(cluster):
+        _no_node_overcommitted(cluster)
+        _quota_max_held(cluster)
+
+    return _Scenario(build, step, 3, scheduler=_gang_quota_scheduler,
+                     invariant=invariant, check=check)
+
+
+def _disjoint_tenants():
+    """Four tenants with traffic of their own on roomy nodes: every pod
+    binds, in the cycle it arrives."""
+    tenants = [f"tenant-{t}" for t in range(4)]
+
+    def build(rng):
+        c = Cluster()
+        for i in range(4):
+            c.add_node(_deep_node(f"n{i}"))
+        return c
+
+    def step(cluster, cycle, now, rng, serial):
+        for j, ns in enumerate(tenants):
+            for i in range(3):
+                cluster.add_pod(_ns_pod(
+                    f"c{cycle}-p{i}", 500, ns=ns, created=now + j * 10 + i,
+                ))
+
+    def check(reports, cluster):
+        for cycle, r in enumerate(reports):
+            assert len(r.bound) == 12 and not r.failed, cycle
+        assert all(p.node_name for p in cluster.pods.values())
+
+    return _Scenario(build, step, 2, check=check)
+
+
+def _nominee_held():
+    """A preemption nominee holds its node: the pod ahead of it in queue
+    order may not take the nominee's room on the nominated node."""
+    def build(rng):
+        c = Cluster()
+        c.add_node(_deep_node("n0", cpu=1000, mem=8 * gib))
+        c.add_node(_deep_node("n1", cpu=1000, mem=8 * gib))
+        c.add_pod(_ns_pod("early", 800, created=5))
+        nominee = _ns_pod("nom", 800, created=10)
+        nominee.nominated_node_name = "n0"
+        c.add_pod(nominee)
+        return c
+
+    def check(reports, cluster):
+        assert reports[0].bound == {
+            "default/early": "n1", "default/nom": "n0",
+        }
+
+    return _Scenario(build, _no_events, 1, check=check)
+
+
+def _gang_quota_tables():
+    """Gang and quota tables together: a gang the quota cannot hold
+    whole binds no member, the one beside it binds whole."""
+    def build(rng):
+        c = Cluster()
+        c.add_node(_deep_node("n0"))
+        c.add_quota(ElasticQuota(
+            name="eq", namespace="team",
+            min={CPU: 4000, MEMORY: 16 * gib},
+            max={CPU: 8000, MEMORY: 32 * gib},
+        ))
+        c.add_pod_group(PodGroup(name="small", namespace="team",
+                                 min_member=1))
+        c.add_pod(_ns_pod("small-m0", 500, ns="team",
+                          labels={POD_GROUP_LABEL: "small"}))
+        # three members of 3000 each: 9000 against a Max of 8000
+        c.add_pod_group(PodGroup(name="big", namespace="team",
+                                 min_member=3))
+        for m in range(3):
+            c.add_pod(_ns_pod(f"big-m{m}", 3000, ns="team", created=m + 1,
+                              labels={POD_GROUP_LABEL: "big"}))
+        return c
+
+    def check(reports, cluster):
+        assert reports[0].bound == {"team/small-m0": "n0"}
+        assert not any(
+            cluster.pods[f"team/big-m{m}"].node_name for m in range(3)
+        )
+
+    def invariant(cluster):
+        _no_node_overcommitted(cluster)
+        _quota_max_held(cluster)
+        _gangs_all_or_nothing(cluster)
+
+    return _Scenario(build, _no_events, 2,
+                     scheduler=_gang_quota_scheduler, invariant=invariant,
+                     check=check)
+
+
+def _orphan_gang_label():
+    """A pending pod names a PodGroup that registers cycles later: until
+    then it schedules as a plain pod (and finds no room), and once the
+    group is there with its quorum the group binds whole, in one cycle."""
+    def build(rng):
+        c = Cluster()
+        c.add_node(_deep_node("n0", cpu=4000))
+        c.add_pod(_ns_pod("solo", 500, ns="t0", created=1))
+        c.add_pod(_ns_pod("orphan", 4000, ns="t0", created=2,
+                          labels={POD_GROUP_LABEL: "late"}))
+        return c
+
+    def step(cluster, cycle, now, rng, serial):
+        if cycle == 2:
+            cluster.add_pod_group(PodGroup(
+                name="late", namespace="t0", min_member=2, creation_ms=now,
+            ))
+            cluster.add_pod(_ns_pod("member", 4000, ns="t0", created=now,
+                                    labels={POD_GROUP_LABEL: "late"}))
+        if cycle == 3:
+            cluster.add_node(_deep_node("n1", cpu=4000))
+            cluster.add_node(_deep_node("n2", cpu=4000))
+
+    def check(reports, cluster):
+        assert reports[0].bound == {"t0/solo": "n0"}
+        assert reports[0].failed_by == {"t0/orphan": "NodeResourcesFit"}
+        gang = {"t0/orphan", "t0/member"}
+        assert [set(r.bound) & gang for r in reports if set(r.bound) & gang
+                ] == [gang]
+        assert {cluster.pods[u].node_name for u in gang} == {"n1", "n2"}
+
+    def invariant(cluster):
+        _no_node_overcommitted(cluster)
+        _gangs_all_or_nothing(cluster)
+
+    return _Scenario(build, step, 6, scheduler=_gang_quota_scheduler,
+                     invariant=invariant, check=check)
+
+
+def _churn_micro():
+    """Multi-cycle churn over zoned tenants, with four bidders for a hot
+    node's two slots of a scarce resource on the last cycle: the node
+    takes two, and no node is ever overcommitted."""
+    n_nodes, zones, tenants, arrive, depart = 8, 4, 8, 64, 64
+    hot_slots, hot_bidders, cycles = 2, 4, 4
+
+    def build(rng):
+        c = Cluster()
+        for i in range(n_nodes):
+            c.add_node(Node(
+                name=f"node-{i:04d}",
+                allocatable={CPU: 256_000, MEMORY: 1024 * gib, PODS: 1024,
+                             f"example.com/zone-{i % zones}": 100_000},
+            ))
+        c.add_node(Node(
+            name="node-hot",
+            allocatable={CPU: 64_000, MEMORY: 256 * gib, PODS: 512,
+                         "example.com/hot": hot_slots},
+        ))
+        for i in range(32):
+            j = i % n_nodes
+            pod = Pod(
+                name=f"bound-{i:06d}", creation_ms=i,
+                namespace=f"tenant-{i % tenants:03d}",
+                containers=[Container(requests={
+                    CPU: int(rng.integers(100, 900)), MEMORY: gib,
+                    f"example.com/zone-{j % zones}": 1,
+                })],
+            )
+            pod.node_name = f"node-{j:04d}"
+            c.add_pod(pod)
+        return c
+
+    def add(cluster, rng, serial, n, hot=False):
+        for _ in range(n):
+            s = next(serial)
+            t = s % tenants
+            extra = ("example.com/hot" if hot
+                     else f"example.com/zone-{t % zones}")
+            cluster.add_pod(Pod(
+                name=f"{'hot' if hot else 'arr'}-{s:06d}",
+                namespace=f"tenant-{t:03d}",
+                creation_ms=1_000_000 + s,
+                containers=[Container(requests={
+                    CPU: int(rng.integers(100, 900)), MEMORY: gib, extra: 1,
+                })],
+            ))
+
+    def step(cluster, cycle, now, rng, serial):
+        contended = cycle == cycles - 1
+        add(cluster, rng, serial, arrive - (hot_bidders if contended else 0))
+        if contended:
+            add(cluster, rng, serial, hot_bidders, hot=True)
+        bound = sorted(
+            u for u, p in cluster.pods.items() if p.node_name is not None
+        )
+        for i in sorted(rng.choice(len(bound), replace=False,
+                                   size=min(depart, len(bound)))):
+            cluster.remove_pod(bound[int(i)])
+
+    def check(reports, cluster):
+        hot = [p for p in cluster.pods.values() if p.name.startswith("hot-")]
+        assert len(hot) == hot_bidders
+        assert sum(1 for p in hot if p.node_name) == hot_slots
+        assert sum(1 for p in hot if p.uid in reports[-1].failed) == (
+            hot_bidders - hot_slots
+        )
+
+    return _Scenario(build, step, cycles, seed=1, check=check)
+
+
+def _plain_roster():
+    """The seeded multi-tenant serve roster: arrivals over three
+    namespaces, a node added, a pod that fits nowhere, a departure and a
+    drained node deleted."""
+    from tests.test_serving import make_cluster, make_node
+
+    def step(cluster, cycle, now, rng, serial):
+        for _ in range(int(rng.integers(1, 4))):
+            s = next(serial)
+            cluster.add_pod(Pod(
+                name=f"p{s:05d}", namespace=f"ns{s % 3}",
+                creation_ms=now + s,
+                containers=[Container(requests={
+                    CPU: int(rng.integers(200, 2500)), MEMORY: gib,
+                })],
+            ))
+        if cycle == 3:
+            cluster.add_node(make_node(40))
+        if cycle == 4:
+            cluster.add_pod(Pod(
+                name="nofit", creation_ms=now + 999,
+                containers=[Container(requests={CPU: 10**9})],
+            ))
+        if cycle == 5:
+            bound = sorted(
+                u for u, p in cluster.pods.items() if p.node_name is not None
+            )
+            cluster.remove_pod(bound[0])
+        if cycle == 7:
+            victim = next(iter(cluster.nodes))
+            for uid in [
+                u for u, p in cluster.pods.items() if p.node_name == victim
+            ]:
+                cluster.remove_pod(uid)
+            cluster.remove_node(victim)
+
+    def check(reports, cluster):
+        assert cluster.pods["default/nofit"].node_name is None
+        assert reports[4].failed_by["default/nofit"] == "NodeResourcesFit"
+        assert all(
+            p.node_name in cluster.nodes
+            for p in cluster.pods.values() if p.node_name is not None
+        )
+
+    return _Scenario(lambda rng: make_cluster(6), step, 10, seed=23,
+                     check=check)
+
+
+def _gang_quota_roster():
+    """The seeded gang+quota roster: priority-mixed arrivals under one
+    ElasticQuota, a three-member gang every fifth cycle, random
+    departures."""
+    def build(rng):
+        c = Cluster()
+        for i in range(8):
+            c.add_node(Node(
+                name=f"n{i}",
+                allocatable={CPU: 16_000, MEMORY: 64 * gib, PODS: 30},
+            ))
+        c.add_quota(ElasticQuota(
+            name="eq", namespace="team",
+            min={CPU: 64_000, MEMORY: 256 * gib},
+            max={CPU: 96_000, MEMORY: 384 * gib},
+        ))
+        return c
+
+    def step(cluster, cycle, now, rng, serial):
+        for _ in range(int(rng.integers(0, 5))):
+            s = next(serial)
+            cluster.add_pod(Pod(
+                name=f"p{s:04d}", namespace="team", creation_ms=now + s,
+                priority=int(rng.integers(0, 5)),
+                containers=[Container(requests={
+                    CPU: int(rng.integers(200, 4000)),
+                    MEMORY: int(rng.integers(1, 8)) * gib,
+                })],
+            ))
+        if cycle % 5 == 1:
+            gname = f"g{cycle}"
+            cluster.add_pod_group(PodGroup(
+                name=gname, namespace="team", min_member=3, creation_ms=now,
+            ))
+            for m in range(3):
+                next(serial)
+                cluster.add_pod(Pod(
+                    name=f"{gname}-m{m}", namespace="team",
+                    creation_ms=now + m, labels={POD_GROUP_LABEL: gname},
+                    containers=[Container(
+                        requests={CPU: 2000, MEMORY: 4 * gib}
+                    )],
+                ))
+        for pod in [
+            p for p in cluster.pods.values()
+            if p.node_name is not None and not p.pod_group()
+        ]:
+            if rng.random() < 0.15:
+                cluster.remove_pod(pod.uid)
+
+    def check(reports, cluster):
+        assert sum(len(r.bound) for r in reports) > 0
+        assert any(
+            p.pod_group() and p.node_name for p in cluster.pods.values()
+        )
+
+    def invariant(cluster):
+        _no_node_overcommitted(cluster)
+        _quota_max_held(cluster)
+        _gangs_all_or_nothing(cluster)
+
+    return _Scenario(build, step, 12, scheduler=_gang_quota_scheduler,
+                     seed=5, invariant=invariant, check=check)
+
+
+DELTA_SCENARIOS = {
+    "dense": _dense,
+    "last_capacity": _last_capacity,
+    "quota_contention": _quota_contention,
+    "disjoint_tenants": _disjoint_tenants,
+    "nominee_held": _nominee_held,
+    "gang_quota_tables": _gang_quota_tables,
+    "orphan_gang_label": _orphan_gang_label,
+    "churn_micro": _churn_micro,
+    "plain_roster": _plain_roster,
+    "gang_quota_roster": _gang_quota_roster,
+}
+
+
 class TestServeDeltaEquivalence:
     """Serve-mode differential (docs/SERVING.md): the resident-state
     engine's delta-maintained solver input must be BIT-IDENTICAL to a
@@ -461,59 +961,56 @@ class TestServeDeltaEquivalence:
     placements identical to full-resnapshot cycles — the engine changes
     where the solver input comes from, never what the solver decides.
     (The randomized per-cycle tensor diff lives in tests/test_serving.py;
-    this gate replays a fixed dense sequence through BOTH `run_cycle`
-    modes and diffs outcomes + final resident tensors.)"""
+    this gate replays each scenario's event stream through BOTH
+    `run_cycle` modes, diffs outcomes + final resident tensors, and holds
+    the serial result to the outcome the scenario exists for.)"""
 
-    def test_delta_path_matches_full_resnapshot(self):
-        from scheduler_plugins_tpu.framework import run_cycle
+    @staticmethod
+    def _run(scn, engine_cls):
+        rng = np.random.default_rng(scn.seed)
+        cluster = scn.build(rng)
+        serial = itertools.count(1)
+        engine = engine_cls().attach(cluster) if engine_cls else None
+        sched = scn.scheduler()
+        reports = []
+        for cycle in range(scn.cycles):
+            now = 1000 * (cycle + 1)
+            scn.step(cluster, cycle, now, rng, serial)
+            reports.append(run_cycle(sched, cluster, now=now, serve=engine))
+            scn.invariant(cluster)
+        return cluster, engine, reports
+
+    @pytest.mark.parametrize("scenario", list(DELTA_SCENARIOS))
+    def test_delta_path_matches_full_resnapshot(self, scenario):
         from scheduler_plugins_tpu.serving import ServeEngine
-        from tests.test_serving import (
-            NODE_COLUMNS,
-            make_cluster,
-            make_pod,
-            make_node,
-            make_scheduler,
-        )
+        from tests.test_serving import NODE_COLUMNS
 
+        scn = DELTA_SCENARIOS[scenario]()
         outcomes = {}
-        finals = {}
-        for mode in ("serve", "baseline"):
-            rng = np.random.default_rng(11)
-            cluster = make_cluster(5)
-            engine = (
-                ServeEngine().attach(cluster) if mode == "serve" else None
-            )
-            sched = make_scheduler()
-            serial, bound_log = 0, []
-            for cycle in range(8):
-                now = 1000 * (cycle + 1)
-                for _ in range(int(rng.integers(1, 4))):
-                    serial += 1
-                    cluster.add_pod(make_pod(
-                        serial, now, int(rng.integers(200, 2500)), gib
-                    ))
-                if cycle == 3:
-                    cluster.add_node(make_node(40))
-                if cycle == 5:
-                    bound = sorted(
-                        u for u, p in cluster.pods.items()
-                        if p.node_name is not None
-                    )
-                    cluster.remove_pod(bound[0])
-                report = run_cycle(sched, cluster, now=now, serve=engine)
-                bound_log.append(dict(report.bound))
-            outcomes[mode] = bound_log
-            if engine is not None:
-                assert engine.refresh(cluster, [], now_ms=9000) is not None
-                finals["resident"] = engine.resident_nodes
-                finals["fresh"], _ = cluster.snapshot(
-                    [], now_ms=9000, pad_nodes=engine.npad
+        for mode, engine_cls in (("serve", ServeEngine), ("baseline", None)):
+            cluster, engine, reports = self._run(scn, engine_cls)
+            outcomes[mode] = [
+                (
+                    dict(r.bound), list(r.failed), dict(r.failed_by),
+                    dict(r.reserved), list(r.rejected_gangs),
+                    dict(r.preempted),
                 )
+                for r in reports
+            ]
+            if engine is not None:
+                end = 1000 * (scn.cycles + 1)
+                assert engine.refresh(cluster, [], now_ms=end) is not None
+                resident = engine.resident_nodes
+                fresh, _ = cluster.snapshot(
+                    [], now_ms=end, pad_nodes=engine.npad
+                )
+            if scn.check is not None:
+                scn.check(reports, cluster)
         assert outcomes["serve"] == outcomes["baseline"]
         for col in NODE_COLUMNS:
             np.testing.assert_array_equal(
-                np.asarray(getattr(finals["resident"], col)),
-                np.asarray(getattr(finals["fresh"].nodes, col)),
+                np.asarray(getattr(resident, col)),
+                np.asarray(getattr(fresh.nodes, col)),
                 err_msg=col,
             )
 
@@ -725,230 +1222,6 @@ class TestPipelinedCycleEquivalence:
         pipe_cycles, pipe_final = self._run_gang_quota(pipelined=True)
         assert pipe_cycles == serial_cycles
         assert pipe_final == serial_final
-
-
-class TestLanedCycleEquivalence:
-    """The K-lane optimistic-concurrency differential (ISSUE 17,
-    docs/SCALING.md): `LanedCycle` at K ∈ {1, 2, 4} vs the serial
-    `run_cycle` on ONE shared seeded event stream must produce identical
-    per-cycle placements (bound/reserved/failed/attribution, plus
-    gang rejections and preemptions on the quota roster) AND an
-    identical final cluster state — the conflict fence's bit-identity
-    contract, exercised through both a plain multi-tenant serve roster
-    (disjoint namespaces across lanes) and the gang+quota roster (gangs
-    keyed whole to one lane, cross-lane quota contention re-resolved).
-    Rosters reuse the pipelined twin's exact streams and
-    tests/test_serving's compile buckets; the serial baseline runs once
-    per roster (class-level cache) so the K sweep pays one extra engine
-    run per K, not two."""
-
-    _baseline: dict = {}
-
-    def _run_plain(self, k):
-        """The pipelined twin's plain roster, multi-tenant: pods spread
-        over three namespaces so the default partition actually fans
-        out. k=0 = serial run_cycle baseline."""
-        from scheduler_plugins_tpu.framework import run_cycle
-        from scheduler_plugins_tpu.framework.laned_cycle import LanedCycle
-        from scheduler_plugins_tpu.serving import (
-            ServeEngine,
-            StreamingServeEngine,
-        )
-        from tests.test_serving import make_cluster, make_node, make_scheduler
-
-        rng = np.random.default_rng(23)
-        cluster = make_cluster(6)
-        engine = (
-            StreamingServeEngine() if k else ServeEngine()
-        ).attach(cluster)
-        sched = make_scheduler()
-        laned = LanedCycle(sched, cluster, k=k) if k else None
-        serial = 0
-        reports = []
-        for cycle in range(10):
-            now = 1000 * (cycle + 1)
-            for _ in range(int(rng.integers(1, 4))):
-                serial += 1
-                cluster.add_pod(Pod(
-                    name=f"p{serial:05d}", namespace=f"ns{serial % 3}",
-                    creation_ms=now + serial,
-                    containers=[Container(requests={
-                        CPU: int(rng.integers(200, 2500)), MEMORY: gib,
-                    })],
-                ))
-            if cycle == 3:
-                cluster.add_node(make_node(40))
-            if cycle == 4:
-                cluster.add_pod(Pod(
-                    name="nofit", creation_ms=now + 999,
-                    containers=[Container(requests={CPU: 10**9})],
-                ))
-            if cycle == 5:
-                bound = sorted(
-                    u for u, p in cluster.pods.items()
-                    if p.node_name is not None
-                )
-                cluster.remove_pod(bound[0])
-            if cycle == 7:
-                victim = next(iter(cluster.nodes))
-                for uid in [
-                    u for u, p in cluster.pods.items()
-                    if p.node_name == victim
-                ]:
-                    cluster.remove_pod(uid)
-                cluster.remove_node(victim)
-            if laned is not None:
-                report = laned.tick(now)
-            else:
-                report = run_cycle(sched, cluster, now=now, serve=engine)
-            reports.append(report)
-        if laned is not None:
-            laned.close()
-            # the fence-exact gate must have held: a silent serial
-            # fallback would make this differential vacuous
-            assert laned.serial_fallbacks == 0
-        per_cycle = [
-            (
-                dict(r.bound), dict(r.reserved),
-                list(r.failed), dict(r.failed_by),
-            )
-            for r in reports
-        ]
-        final = {u: p.node_name for u, p in sorted(cluster.pods.items())}
-        return per_cycle, final
-
-    def _run_gang_quota(self, k):
-        """The pipelined twin's gang+quota roster, verbatim (same seed,
-        same stream — shapes land on the same compile buckets)."""
-        from scheduler_plugins_tpu.api.objects import (
-            ElasticQuota,
-            PodGroup,
-            POD_GROUP_LABEL,
-        )
-        from scheduler_plugins_tpu.framework import run_cycle
-        from scheduler_plugins_tpu.framework.laned_cycle import LanedCycle
-        from scheduler_plugins_tpu.plugins import (
-            CapacityScheduling,
-            Coscheduling,
-        )
-        from scheduler_plugins_tpu.serving import (
-            ServeEngine,
-            StreamingServeEngine,
-        )
-
-        rng = np.random.default_rng(5)
-        cluster = Cluster()
-        for i in range(8):
-            cluster.add_node(Node(
-                name=f"n{i}",
-                allocatable={CPU: 16_000, MEMORY: 64 * gib, PODS: 30},
-            ))
-        cluster.add_quota(ElasticQuota(
-            name="eq", namespace="team",
-            min={CPU: 64_000, MEMORY: 256 * gib},
-            max={CPU: 96_000, MEMORY: 384 * gib},
-        ))
-        engine = (
-            StreamingServeEngine() if k else ServeEngine()
-        ).attach(cluster)
-        sched = Scheduler(Profile(plugins=[
-            NodeResourcesAllocatable(),
-            Coscheduling(permit_waiting_seconds=5),
-            CapacityScheduling(),
-        ]))
-        laned = LanedCycle(sched, cluster, k=k) if k else None
-        serial = 0
-        reports = []
-        for cycle in range(12):
-            now = 1000 * (cycle + 1)
-            for _ in range(int(rng.integers(0, 5))):
-                serial += 1
-                cluster.add_pod(Pod(
-                    name=f"p{serial:04d}", namespace="team",
-                    creation_ms=now + serial,
-                    priority=int(rng.integers(0, 5)),
-                    containers=[Container(requests={
-                        CPU: int(rng.integers(200, 4000)),
-                        MEMORY: int(rng.integers(1, 8)) * gib,
-                    })],
-                ))
-            if cycle % 5 == 1:
-                gname = f"g{cycle}"
-                cluster.add_pod_group(PodGroup(
-                    name=gname, namespace="team", min_member=3,
-                    creation_ms=now,
-                ))
-                for m in range(3):
-                    serial += 1
-                    cluster.add_pod(Pod(
-                        name=f"{gname}-m{m}", namespace="team",
-                        creation_ms=now + m,
-                        labels={POD_GROUP_LABEL: gname},
-                        containers=[Container(
-                            requests={CPU: 2000, MEMORY: 4 * gib}
-                        )],
-                    ))
-            bound = [
-                p for p in cluster.pods.values()
-                if p.node_name is not None and not p.pod_group()
-            ]
-            for pod in bound:
-                if rng.random() < 0.15:
-                    cluster.remove_pod(pod.uid)
-            if laned is not None:
-                report = laned.tick(now)
-            else:
-                report = run_cycle(sched, cluster, now=now, serve=engine)
-            reports.append(report)
-        if laned is not None:
-            laned.close()
-            assert laned.serial_fallbacks == 0
-        per_cycle = [
-            (
-                dict(r.bound), dict(r.reserved),
-                list(r.failed), dict(r.failed_by),
-                list(r.rejected_gangs), dict(r.preempted),
-            )
-            for r in reports
-        ]
-        final = {u: p.node_name for u, p in sorted(cluster.pods.items())}
-        return per_cycle, final
-
-    def _serial_baseline(self, roster):
-        if roster not in self._baseline:
-            runner = getattr(self, f"_run_{roster}")
-            type(self)._baseline[roster] = runner(0)
-        return self._baseline[roster]
-
-    @pytest.mark.parametrize("k", [2])
-    def test_plain_roster_identical(self, k):
-        serial_cycles, serial_final = self._serial_baseline("plain")
-        laned_cycles, laned_final = self._run_plain(k)
-        assert laned_cycles == serial_cycles
-        assert laned_final == serial_final
-
-    @pytest.mark.slow
-    @pytest.mark.parametrize("k", [1, 4])
-    def test_plain_roster_identical_slow(self, k):
-        serial_cycles, serial_final = self._serial_baseline("plain")
-        laned_cycles, laned_final = self._run_plain(k)
-        assert laned_cycles == serial_cycles
-        assert laned_final == serial_final
-
-    @pytest.mark.parametrize("k", [4])
-    def test_gang_quota_roster_identical(self, k):
-        serial_cycles, serial_final = self._serial_baseline("gang_quota")
-        laned_cycles, laned_final = self._run_gang_quota(k)
-        assert laned_cycles == serial_cycles
-        assert laned_final == serial_final
-
-    @pytest.mark.slow
-    @pytest.mark.parametrize("k", [1, 2])
-    def test_gang_quota_roster_identical_slow(self, k):
-        serial_cycles, serial_final = self._serial_baseline("gang_quota")
-        laned_cycles, laned_final = self._run_gang_quota(k)
-        assert laned_cycles == serial_cycles
-        assert laned_final == serial_final
 
 
 class TestShardedWaveHardConstraintParity:

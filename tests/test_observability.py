@@ -273,9 +273,9 @@ class TestServedLoopNames:
         "scheduler_flightrec_cycles_total", "scheduler_lane_commit_ms",
     ])
     def test_names_nobody_read_are_gone(self, name):
-        # ISSUE 36: registered and incremented, named by no document,
-        # test or benchmark entry; `LaneStats.fence_ms` and the recorder's
-        # ring say the same
+        # registered and incremented, named by no document, test or
+        # benchmark entry; the recorder's ring says what the first did,
+        # and the engine that fed the second is gone
         assert name not in obs.HELP
         assert name not in {
             v for k, v in vars(obs).items()

@@ -700,122 +700,8 @@ def build_sweep_solve():
     return fn, args, None
 
 
-def _lane_problem():
-    """Reduced zoned multi-tenant roster for the K-lane programs: 16
-    nodes, 96 pods over 8 tenant namespaces (12 per segment), the
-    allocatable profile — the smallest shape that exercises the lane
-    gather + scan and the segment-grain screen axes."""
-    from scheduler_plugins_tpu.api.objects import Container, Node, Pod
-    from scheduler_plugins_tpu.api.resources import CPU, MEMORY, PODS
-    from scheduler_plugins_tpu.framework import Profile, Scheduler
-    from scheduler_plugins_tpu.plugins import NodeResourcesAllocatable
-    from scheduler_plugins_tpu.state.cluster import Cluster
-
-    gib = 1 << 30
-    cluster = Cluster()
-    for i in range(16):
-        cluster.add_node(Node(
-            name=f"n{i:02d}",
-            allocatable={CPU: 64_000, MEMORY: 256 * gib, PODS: 256},
-        ))
-    for s in range(96):
-        cluster.add_pod(Pod(
-            name=f"p{s:03d}", namespace=f"t{s % 8}", creation_ms=s,
-            containers=[Container(requests={CPU: 500, MEMORY: gib})],
-        ))
-    scheduler = Scheduler(Profile(plugins=[NodeResourcesAllocatable()]))
-    pending = scheduler.sort_pending(cluster.pending_pods(), cluster)
-    snap, meta = cluster.snapshot(pending, now_ms=0)
-    scheduler.prepare(meta, cluster)
-    return cluster, scheduler, pending, snap
-
-
-def build_lane_solve():
-    """`parallel.lanes.lane_solve_fn` — the K-lane speculative solve
-    (ISSUE 17): vmap over the lane axis of a scan of THE parity step
-    body (`_solve_step`, one copy shared with `Scheduler.solve`), each
-    lane's pod rows gathered ONCE outside the scan so the step body runs
-    zero batched gathers (the CPU per-row-loop / TPU vmem-hostile
-    dynamic-slice gotcha). Lowered at K=4 lanes over the reduced zoned
-    roster — the program shape `LaneSolver._dispatch` compiles per
-    (K, bucket); the conflict repair reuses it at (1, L')."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from scheduler_plugins_tpu.parallel.lanes import (
-        _bucket,
-        lane_solve_fn,
-        partition_segments,
-    )
-
-    cluster, scheduler, pending, snap = _lane_problem()
-    k = 4
-    lanes, _, _, _, _ = partition_segments(pending, cluster, k)
-    bucket = _bucket(max(len(lane) for lane in lanes))
-    idx2d = np.zeros((k, bucket), np.int32)
-    live2d = np.zeros((k, bucket), bool)
-    for j, lane in enumerate(lanes):
-        idx2d[j, : len(lane)] = lane
-        live2d[j, : len(lane)] = True
-    state0 = scheduler.initial_state(snap)
-    auxes = tuple(p.aux() for p in scheduler.profile.plugins)
-    fn = jax.jit(lane_solve_fn(scheduler))
-    args = (snap, state0, auxes, jnp.asarray(idx2d), jnp.asarray(live2d))
-    return fn, args, None
-
-
-def build_lane_screen():
-    """`parallel.lanes.lane_screen_fn` — the conflict fence's stage-1
-    compiled monotone screen (ISSUE 17): per-lane speculative node
-    deficits + the segment-grain sufficient certificates (commit-safety
-    and the two fit arms over host-accumulated per-segment demand
-    extremes) in ONE dispatch over flat narrow arguments (the snapshot
-    pytree flattening cost is the reason for the calling convention).
-    Lowered at K=4 on the reduced zoned roster, quota/gang screens off
-    (their branches extend the same program; the decision tables in
-    tests/test_lanes.py pin the semantics)."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from scheduler_plugins_tpu.parallel.lanes import (
-        _bucket,
-        lane_screen_fn,
-        partition_segments,
-    )
-
-    cluster, scheduler, pending, snap = _lane_problem()
-    k = 4
-    _, seg_of_pod, lane_of_seg, seg_keys, _ = partition_segments(
-        pending, cluster, k
-    )
-    P = snap.num_pods
-    R = snap.pods.req.shape[1]
-    S_b = _bucket(max(1, len(seg_keys)))
-    state0 = scheduler.initial_state(snap)
-    # shape-true placeholder outputs: the screen's inputs are the lane
-    # outputs; values are irrelevant to the lowering, dtypes/shapes not
-    assignment = np.full(P, -1, np.int32)
-    lane_full = np.zeros(P, np.int32)
-    lane_full[: len(pending)] = lane_of_seg[seg_of_pod]
-    seg_lanes = np.zeros(S_b, np.int32)
-    seg_lanes[: lane_of_seg.shape[0]] = lane_of_seg
-    seg_mx = np.full((S_b, R), -np.inf, np.float64)
-    seg_mn = np.full((S_b, R), np.inf, np.float64)
-    core = (
-        snap.pods.req, snap.pods.mask, snap.pods.gated, state0.free,
-        snap.nodes.mask, jnp.asarray(assignment), jnp.asarray(lane_full),
-        jnp.asarray(seg_mx), jnp.asarray(seg_mn), jnp.asarray(seg_lanes),
-    )
-    fn = jax.jit(lane_screen_fn(k, False, False))
-    return fn, (core, (), ()), None
-
-
 PROGRAMS = {
     "entry": build_entry,
-    "lane_solve": build_lane_solve,
-    "lane_screen": build_lane_screen,
     "serving_delta_apply": build_serving_delta_apply,
     "serving_node_compact": build_serving_node_compact,
     "sharded_wave_chunk": build_sharded_wave_chunk,
